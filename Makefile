@@ -19,10 +19,14 @@ lint:            ## graftlint + concurrency model: fail on NEW findings only
 	$(PY) tools/graftcheck.py mxnet_tpu --concurrency \
 		--baseline .graftlint-baseline.json
 
-chip:            ## serial accelerator tier (needs the real chip)
+chip:            ## serial accelerator tier (needs the real chip; from the sandbox: chiprun -- make chip)
 	MXTPU_CHIP_TESTS=1 $(PY) -m pytest tests/test_consistency_sweep.py \
-		tests/test_consistency.py tests/test_convergence.py -q \
-		--numprocesses 0
+		tests/test_consistency.py tests/test_convergence.py \
+		tests/test_pallas.py -q --numprocesses 0
+	# the COMPILED Pallas kernels against XLA's TPU programs (the other
+	# tests of that module pin cpu-context contracts)
+	MXTPU_CHIP_TESTS=1 $(PY) -m pytest tests/test_pallas_kernels.py -q \
+		--numprocesses 0 -k "pool_backward or bn_"
 
 bench:           ## throughput numbers of record (run on an IDLE host)
 	$(PY) bench.py

@@ -17,7 +17,8 @@ from .base import maybe_initialize_distributed_from_env as _minit
 _minit()
 
 from .base import MXNetError, __version__
-from .context import Context, cpu, gpu, tpu, cpu_pinned, current_context, num_gpus
+from .context import (Context, cpu, gpu, tpu, cpu_pinned, current_context,
+                      num_gpus, num_tpus, on_tpu)
 
 from . import base
 from . import context as context_mod

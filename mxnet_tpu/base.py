@@ -18,6 +18,17 @@ import jax
 # models opt into bf16/f32 explicitly, so TPU perf is unaffected.
 jax.config.update("jax_enable_x64", True)
 
+# JAX's persistent compilation cache, placed from outside: where
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and nothing is set
+# here.  Otherwise a source checkout keeps its cache at the FIXED path
+# <checkout>/.jax_cache (the path is part of the cache key, so a directory
+# that moves never hits); an installed package sets none.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    _checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.isfile(os.path.join(_checkout, "pyproject.toml")):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_checkout, ".jax_cache"))
+
 __version__ = "1.0.1"  # capability parity target: MXNet 1.0.1 (python/mxnet/libinfo.py:64)
 
 

@@ -120,3 +120,22 @@ def num_gpus():
 
 
 num_tpus = num_gpus
+
+
+def on_tpu():
+    """True when the process default JAX backend is a TPU.  The ONE test
+    every backend-dependent default goes through (kernel dispatch, buffer
+    donation, the scripts' device choice).  Creates the backend on first
+    call, like any other device query."""
+    return jax.default_backend() == "tpu"
+
+
+def accelerator(index=0):
+    """The context work lands on when the caller names none: the
+    ``index``-th local TPU (wrapping past the last chip) when the default
+    backend is a TPU, ``cpu(0)`` otherwise.  The serving layer's
+    ``ctx=None`` resolves through this — replica *i* on device *i* — so a
+    server started on a TPU host serves from the chip, not the host CPU."""
+    if not on_tpu():
+        return cpu(0)
+    return tpu(index % len(jax.local_devices()))

@@ -39,6 +39,7 @@ the profiler is running (`profiler.record_counter`).
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 
@@ -167,7 +168,7 @@ def _signature(symbol, arg_dict, aux_dict, grad_names, platform, health):
     # Gradient-free binds never split — only training programs reduce.
     comm_sig = _comm.comm_signature() if grad_names else ()
     return (fp, arg_sig, aux_sig, tuple(grad_names), platform,
-            bool(health), _pk.kernel_signature(), comm_sig)
+            bool(health), _pk.kernel_signature(platform), comm_sig)
 
 
 # -- retrace explainer --------------------------------------------------------
@@ -280,6 +281,7 @@ def _build_entry(symbol, known_shapes, grad_names, platform, health=False,
     # common path)
     from . import program_cache as _program_cache
     from .executor import _Program
+    from .ops import pallas_kernels as _pk
 
     prog = _Program(symbol)
     prog.finalize_shapes(known_shapes)
@@ -302,6 +304,16 @@ def _build_entry(symbol, known_shapes, grad_names, platform, health=False,
             jitted, kind, label, key_material=key, platform=platform,
             tag=tag, static_argnums=static_argnums)
 
+    def _for_platform(impl):
+        # the ops resolve their kernel flags against the platform this
+        # entry is BOUND for, not the process default backend: an
+        # mx.cpu() executor on a TPU host traces no Mosaic kernel
+        @functools.wraps(impl)
+        def scoped(*args):
+            with _pk.trace_scope(platform=platform):
+                return impl(*args)
+        return scoped
+
     def _fwd_impl(arg_vals, aux_vals, keys, train):
         note_trace("fwd", label)
         arg_map = dict(zip(arg_names, arg_vals))
@@ -309,8 +321,8 @@ def _build_entry(symbol, known_shapes, grad_names, platform, health=False,
         outs, new_aux = prog.evaluate(arg_map, aux_map, keys, train)
         return outs, [new_aux[n] for n in aux_names]
 
-    _fwd = _wrap(jax.jit(_fwd_impl, static_argnums=(3,)), "fwd", "fwd",
-                 static_argnums=(3,))
+    _fwd = _wrap(jax.jit(_for_platform(_fwd_impl), static_argnums=(3,)),
+                 "fwd", "fwd", static_argnums=(3,))
 
     # the sentinel layout is derived from the program's static structure
     # (output count, grad-name order, attention-node names), never from
@@ -372,6 +384,7 @@ def _build_entry(symbol, known_shapes, grad_names, platform, health=False,
     # non-donating twin because the buffers it feeds stay live in
     # aux_dict.
     donate = (1,) if platform == "tpu" else ()
+    _fwd_bwd_impl = _for_platform(_fwd_bwd_impl)
     _fwd_bwd = _wrap(jax.jit(_fwd_bwd_impl, donate_argnums=donate),
                      "fwd_bwd", "fwd_bwd")
     _fwd_bwd_nd = _wrap(jax.jit(_fwd_bwd_impl), "fwd_bwd", "fwd_bwd_nd") \

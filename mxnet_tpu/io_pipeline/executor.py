@@ -155,6 +155,20 @@ class ReorderBuffer:
             self._cv.notify_all()
 
 
+def _process_worker_init(initializer, initargs):
+    """Runs once in each spawn worker, before any task.  The parent
+    holds the chip and a chip belongs to one process: decode workers are
+    numpy-only, and should one ever touch JAX it must land on the host
+    CPU (grandchildren inherit the env)."""
+    import os
+
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+    if initializer is not None:
+        initializer(*initargs)
+
+
 class PrefetchExecutor:
     """Run numbered tasks on a worker pool, yielding results in order.
 
@@ -311,8 +325,8 @@ class PrefetchExecutor:
             self._pool = ProcessPoolExecutor(
                 max_workers=self.num_workers,
                 mp_context=mp.get_context("spawn"),
-                initializer=self.initializer,
-                initargs=self.initargs)
+                initializer=_process_worker_init,
+                initargs=(self.initializer, self.initargs))
         return self._pool
 
     def _run_process(self, tasks):

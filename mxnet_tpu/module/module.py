@@ -370,13 +370,14 @@ class Module(BaseModule):
 
         self.optimizer_initialized = True
 
-        # one-dispatch-per-batch fused fwd+bwd+update (north star); falls
-        # back silently when the configuration isn't supported
-        from .fused_step import FusedTrainStep
+        # one-dispatch-per-batch fused fwd+bwd+update (north star); an
+        # unsupported configuration trains on the general path, but a
+        # step that fails to trace, lower or compile raises
+        from .fused_step import FusedStepUnsupported, FusedTrainStep
         try:
             self._fused_step = FusedTrainStep(self) \
                 if FusedTrainStep.supports(self) else None
-        except Exception as e:  # e.g. a program with baked batch shapes
+        except FusedStepUnsupported as e:
             self.logger.warning(
                 "fused train step unavailable (%s); using the general "
                 "path", e)

@@ -45,6 +45,7 @@ from ..io import DataDesc
 from ..ndarray import NDArray, zeros as nd_zeros
 from .. import optimizer as opt
 from .. import random as _random
+from ..ops import pallas_kernels as _pallas_kernels
 from ..optimizer import _is_low_precision
 from ..parallel.mesh import create_mesh, shard_params_rule, MeshSpec
 from .base_module import BaseModule, _check_input_names
@@ -384,10 +385,13 @@ class ShardedModule(BaseModule):
                 return outs, [new_aux[n] for n in aux_names]
 
             mvals = [masters[n] for n in param_names]
-            (outs, new_aux), vjp_fn = jax.vjp(f, mvals)
-            heads = [jnp.ones_like(o) for o in outs]
-            zeros_aux = [jnp.zeros_like(a) for a in new_aux]
-            (grads,) = vjp_fn((heads, zeros_aux))
+            # XLA partitions this graph over the mesh by itself, which
+            # no Mosaic kernel survives (ops/pallas_kernels.py)
+            with _pallas_kernels.trace_scope(partitioned=True):
+                (outs, new_aux), vjp_fn = jax.vjp(f, mvals)
+                heads = [jnp.ones_like(o) for o in outs]
+                zeros_aux = [jnp.zeros_like(a) for a in new_aux]
+                (grads,) = vjp_fn((heads, zeros_aux))
 
             opt_keys = jax.random.split(opt_key, len(param_names)) \
                 if needs_rng else [None] * len(param_names)
@@ -441,7 +445,8 @@ class ShardedModule(BaseModule):
             amap.update(zip(batch_names, batch_vals))
             amap.update(zip(param_names, params))
             aux_map = dict(zip(aux_names, aux_vals))
-            outs, _ = prog.evaluate(amap, aux_map, keys, False)
+            with _pallas_kernels.trace_scope(partitioned=True):
+                outs, _ = prog.evaluate(amap, aux_map, keys, False)
             return outs
 
         self._fwd = jax.jit(_fwd)

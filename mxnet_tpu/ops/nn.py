@@ -676,7 +676,8 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     if _train and not use_global_stats:
         from . import pallas_kernels as _pk
         kmode = _pk.kernel_mode("bn")
-        if kmode != "off" and not (data.ndim == 4 and ax == 1
+        if kmode != "off" and not (ax == 1
+                                   and _pk.bn_sums_eligible(data.shape)
                                    and jnp.issubdtype(data.dtype,
                                                       jnp.floating)):
             kmode = "off"  # the channel-sums kernel is NCHW-shaped

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ._smap import shard_map, UNCHECKED
+from jax import shard_map
 
 
 def _moe_local(x, gate_w, w1, w2, axis_name, capacity_factor):
@@ -84,7 +84,7 @@ def moe_ffn(x, gate_w, w1, w2, mesh=None, axis_name="ep",
                           capacity_factor=capacity_factor),
         mesh=mesh,
         in_specs=(P(batch_axis), P(), P(axis_name), P(axis_name)),
-        out_specs=P(batch_axis), **UNCHECKED)
+        out_specs=P(batch_axis), check_vma=False)
     out = fn(x, gate_w, w1, w2)
     return out.reshape(orig_shape)
 

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ._smap import shard_map, UNCHECKED
+from jax import shard_map
 
 
 def _pipeline_local(stage_params, x_micro, stage_fn, axis_name):
@@ -102,6 +102,6 @@ def pipeline_stages(stage_params, x, stage_fn, n_micro, mesh=None,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(params_spec, x_spec),
                    out_specs=x_spec,
-                   **UNCHECKED)
+                   check_vma=False)
     y_micro = fn(stage_params, x_micro)
     return y_micro.reshape((b,) + y_micro.shape[2:])

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ._smap import shard_map, UNCHECKED
+from jax import shard_map
 
 
 def _block_attn(q, k, v, bias, scale):
@@ -99,7 +99,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
         functools.partial(_ring_attn_local, axis_name=axis_name,
                           causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **UNCHECKED)
+        check_vma=False)
     return fn(q, k, v)
 
 

@@ -30,7 +30,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import comm as _comm
-from ._smap import UNCHECKED, shard_map
+from jax import shard_map
 from .mesh import batch_sharding, replicated_sharding, shard_params_rule
 
 _logger = logging.getLogger("mxnet_tpu")
@@ -149,7 +149,7 @@ class ShardedTrainStep:
                               [P("dp")] * n_res),
                     out_specs=(P(), {k: P() for k in params},
                                [P("dp")] * n_res),
-                    **UNCHECKED)(params, batch, residuals)
+                    check_vma=False)(params, batch, residuals)
             new_params, new_mom = {}, {}
             for k in params:
                 g = grads[k] + wd * params[k]

@@ -432,11 +432,19 @@ class ProgramStore:
         if header.get("device_kind") and \
                 str(devices[0].device_kind) != header["device_kind"]:
             return "device-mismatch", header, None
+        # restore onto the devices the entry was BUILT for: left to its
+        # default, deserialize_and_load spreads the executable over every
+        # local device and a one-device program refuses its arguments
+        by_id = {d.id: d for d in devices}
+        ids = header.get("device_ids") or []
+        if not ids or any(i not in by_id for i in ids):
+            return "device-mismatch", header, None
         try:
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = pickle.loads(blob)
-            loaded = _se.deserialize_and_load(payload, in_tree, out_tree,
-                                              backend=platform)
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree, backend=platform,
+                execution_devices=[by_id[i] for i in ids])
         except Exception:
             return "corrupt", header, None
         if expect_dyn is not None:
@@ -476,6 +484,9 @@ class ProgramStore:
             "platform": str(platform or ""),
             "device_kind": _device_kind(platform),
             "n_devices": self._device_count(platform),
+            "device_ids": [
+                int(d.id) for d in
+                compiled.runtime_executable().local_devices()],
             "fingerprint": version_fingerprint(),
             "created": time.time(), "writer_pid": os.getpid(),
             "blob_bytes": len(blob),

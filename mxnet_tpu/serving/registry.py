@@ -23,6 +23,7 @@ import time
 
 import numpy as np
 
+from .. import context as _context
 from .. import executor_cache
 from .. import threads as _threads
 from ..observability import memprof as _memprof
@@ -98,6 +99,9 @@ class ServedModel:
         params = {"arg:%s" % k: v for k, v in arg_params.items()}
         params.update({"aux:%s" % k: v for k, v in (aux_params or {}).items()})
         base_shapes = self._bind_shapes(self.buckets[0])
+        if ctx is None:
+            # the chip when the host has one; never silently the host CPU
+            ctx = _context.accelerator()
         self._base = Predictor(symbol.tojson(), params, base_shapes,
                                ctx=ctx, quantize=quantize,
                                calibration=calibration)

@@ -12,9 +12,10 @@ warmup"):
   state, and the per-bucket cost table measured at warmup.
 - :class:`ReplicaGroup` — N replicas of the same model set.  On a
   multi-chip host each replica binds its models to a distinct device
-  (``ctxs=[mx.tpu(0), mx.tpu(1), ...]``); the cpu smoke harness runs N
-  cpu-backend instances, which share the process-wide executor cache —
-  replica 2..N's warmups trace nothing, and a shared persistent
+  (replica *i* on chip *i* by default, or ``ctxs=[mx.tpu(0), ...]``);
+  the cpu smoke harness runs N cpu-backend instances, which share the
+  process-wide executor cache — replica 2..N's warmups trace nothing,
+  and a shared persistent
   program-cache volume (``prewarm``) makes even replica 1's boot a
   deserialization.
 - :class:`Router` — the dispatch engine: consumes the SHARED admission
@@ -65,6 +66,7 @@ import threading
 import time
 from collections import deque
 
+from .. import context as _context
 from .. import threads as _threads
 from ..base import MXNetError
 from ..log import module_logger as _module_logger
@@ -355,8 +357,10 @@ class ReplicaGroup:
             raise MXNetError(
                 "ctxs must name one context per replica (%d != %d)"
                 % (len(ctxs), n))
-        self.replicas = [Replica(i, ctx=ctxs[i] if ctxs else None)
-                         for i in range(n)]
+        # unplaced replicas spread over the chips: replica i on device i
+        self.replicas = [
+            Replica(i, ctx=ctxs[i] if ctxs else _context.accelerator(i))
+            for i in range(n)]
         for r in self.replicas:
             r._group = self
 

@@ -101,11 +101,21 @@ def test_determinism_across_depth_and_double_buffer(rec_file):
         assert got == base
 
 
-def test_process_mode_matches_thread_mode(rec_file):
+def _worker_jax_platforms():
+    import os
+
+    import jax
+    return os.environ.get("JAX_PLATFORMS"), jax.config.jax_platforms
+
+
+def test_process_mode_matches_thread_mode(rec_file, monkeypatch):
     """The spawn-process pool yields the same bitwise sequence (worker
     identity never enters the stream), and the worker-measured decode
     telemetry reaches the parent registry."""
     from mxnet_tpu.observability import telemetry
+    # what a chip host looks like to a child: no platform pinned in the
+    # inherited env (this process's own backend is long since chosen)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     src = _source(rec_file)
     dec = NoisyDecoder((FEAT,))
     thread_seq = _sequence(iop.Pipeline(src, dec, batch_size=8,
@@ -116,6 +126,11 @@ def test_process_mode_matches_thread_mode(rec_file):
                       num_workers=2, mode="process") as pipe:
         proc_seq = _sequence(pipe)
         snap = telemetry.snapshot()
+        # one process per chip: a spawn worker is pinned to the host cpu
+        # before it runs anything
+        pinned = pipe._proc_exec._pool.submit(_worker_jax_platforms).result(
+            timeout=60)
+    assert pinned == ("cpu", "cpu")
     assert proc_seq == thread_seq
     # decode runs in other processes; its wall time rides back on the
     # batches so the parent's decode_ms/records series still fill

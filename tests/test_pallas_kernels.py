@@ -4,9 +4,11 @@ int8 serving path (ISSUE 7; docs/kernels.md).
 Every Pallas kernel runs here through the interpreter (the same kernel
 code path the chip compiles) and is validated against its XLA fallback —
 the select-and-scatter / two-pass-reduction programs the flag-off path
-still traces bit-identically.  The int8 tests reuse PR 4's
-dispatch-bucket replay oracle: a served response must be bitwise equal to
-a plain Predictor run at the recorded dispatch bucket.
+still traces bit-identically.  Under ``MXTPU_CHIP_TESTS=1`` on a TPU
+(``make chip``) the same parity tests run the COMPILED kernels against
+XLA's TPU programs.  The int8 tests reuse PR 4's dispatch-bucket replay
+oracle: a served response must be bitwise equal to a plain Predictor run
+at the recorded dispatch bucket.
 """
 import os
 
@@ -22,6 +24,13 @@ from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops import quantize as quant
 from mxnet_tpu.ops.nn import _bn_train_core, _pool_core, _pooling
 from mxnet_tpu.predict import Predictor
+
+
+# the kernel side of every parity test: Mosaic-compiled on the chip tier,
+# interpreted everywhere else
+COMPILED = os.environ.get("MXTPU_CHIP_TESTS") == "1" and mx.on_tpu()
+KERNEL_MODE = "pallas" if COMPILED else "interpret"
+INTERPRET = None if COMPILED else True
 
 
 def _rng(seed=0):
@@ -56,7 +65,7 @@ POOL_CASES = [
 def test_pool_backward_matches_xla_oracle(case):
     x = jnp.asarray(_rng(1).randn(2, 3, 11, 13).astype(np.float32))
     want = _pool_grad("off", x, case)       # XLA select-and-scatter path
-    got = _pool_grad("interpret", x, case)  # Pallas kernel path
+    got = _pool_grad(KERNEL_MODE, x, case)  # Pallas kernel path
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -67,7 +76,7 @@ def test_pool_backward_bf16():
     x = jnp.asarray(_rng(2).randn(2, 4, 12, 12)).astype(jnp.bfloat16)
     cfg = ("max", (3, 3), (2, 2), (1, 1), "valid", True)
     want = _pool_grad("off", x, cfg).astype(jnp.float32)
-    got = _pool_grad("interpret", x, cfg).astype(jnp.float32)
+    got = _pool_grad(KERNEL_MODE, x, cfg).astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-2, atol=2e-2)
 
@@ -124,7 +133,7 @@ def test_count_include_pad_false_divisor():
 
 def test_bn_channel_sums_vs_two_pass():
     x = jnp.asarray(_rng(4).randn(4, 6, 5, 7).astype(np.float32))
-    s1, s2 = pk.bn_channel_sums(x, interpret=True)
+    s1, s2 = pk.bn_channel_sums(x, interpret=INTERPRET)
     np.testing.assert_allclose(np.asarray(s1),
                                np.asarray(jnp.sum(x, (0, 2, 3))),
                                rtol=1e-5, atol=1e-4)
@@ -132,7 +141,7 @@ def test_bn_channel_sums_vs_two_pass():
                                np.asarray(jnp.sum(x * x, (0, 2, 3))),
                                rtol=1e-5, atol=1e-4)
     dy = jnp.asarray(_rng(5).randn(4, 6, 5, 7).astype(np.float32))
-    a1, a2 = pk.bn_channel_sums(dy, x, interpret=True)
+    a1, a2 = pk.bn_channel_sums(dy, x, interpret=INTERPRET)
     np.testing.assert_allclose(np.asarray(a1),
                                np.asarray(jnp.sum(dy, (0, 2, 3))),
                                rtol=1e-5, atol=1e-4)
@@ -150,7 +159,7 @@ def test_bn_train_core_kernel_matches_fallback(dtype):
     x = jnp.asarray(_rng(6).randn(4, 6, 5, 7)).astype(dtype)
     g = jnp.asarray(_rng(7).rand(6).astype(np.float32))
     b = jnp.asarray(_rng(8).rand(6).astype(np.float32))
-    on = _bn_train_core(4, 1, 1e-3, "interpret")
+    on = _bn_train_core(4, 1, 1e-3, KERNEL_MODE)
     off = _bn_train_core(4, 1, 1e-3, "off")
 
     def loss(core):
@@ -170,6 +179,46 @@ def test_bn_train_core_kernel_matches_fallback(dtype):
     for a, w in zip(g_on, g_off):
         np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                    np.asarray(w, dtype=np.float32), **tol)
+
+
+# ---------------------------------------------------------------------------
+# The shapes the chip actually runs: every BatchNorm / Pooling input of the
+# ResNet-50 train step at batch 32 (read off the symbol by the lowering
+# test), compiled kernel vs XLA's TPU program
+# ---------------------------------------------------------------------------
+
+chip_only = pytest.mark.skipif(
+    not COMPILED, reason="full-size parity runs compiled, on the chip tier")
+
+
+@chip_only
+def test_bn_channel_sums_at_resnet50_shapes():
+    from test_pallas_tpu_lowering import BN_SHAPES
+    for i, shape in enumerate(BN_SHAPES):
+        x = jnp.asarray(_rng(i).randn(*shape), jnp.bfloat16)
+        dy = jnp.asarray(_rng(100 + i).randn(*shape), jnp.bfloat16)
+        x32, dy32 = x.astype(jnp.float32), dy.astype(jnp.float32)
+        # f32 sums over M = N*H*W terms: round-off grows with sqrt(M),
+        # like the sums themselves
+        tol = dict(rtol=1e-3,
+                   atol=1e-3 * (shape[0] * shape[2] * shape[3]) ** 0.5)
+        for got, want in zip(
+                pk.bn_channel_sums(x) + pk.bn_channel_sums(dy, x),
+                (jnp.sum(x32, (0, 2, 3)), jnp.sum(x32 * x32, (0, 2, 3)),
+                 jnp.sum(dy32, (0, 2, 3)), jnp.sum(dy32 * x32, (0, 2, 3)))):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       err_msg=str(shape), **tol)
+
+
+@chip_only
+def test_pool_backward_at_resnet50_shapes():
+    from test_pallas_tpu_lowering import pool_configs
+    for i, (shape, cfg) in enumerate(pool_configs()):
+        x = jnp.asarray(_rng(i).randn(*shape), jnp.bfloat16)
+        want = _pool_grad("off", x, cfg).astype(jnp.float32)
+        got = _pool_grad("pallas", x, cfg).astype(jnp.float32)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-2, atol=2e-2, err_msg=str(shape))
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,195 @@
+"""Every Pallas kernel cross-lowered for the TPU, on the CPU, in seconds.
+
+``jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs the Pallas
+TPU lowering (BlockSpec legality, the Mosaic MLIR emission) without a chip,
+so a block shape the TPU lowering refuses never reaches one again.  Shapes
+are the ones the chip runs: every BatchNorm and Pooling input of the
+ResNet-50 train step at batch 32, read off the symbol itself, and flash
+attention at head_dim 128, forward and gradient.
+
+The lowering cannot see Mosaic's own compile (layout inference, unaligned
+slices).  ``-m slow`` adds it: libtpu compiles for a named v5e topology
+with no chip attached, so every case also runs the full TPU compile here.
+Whether a compiled kernel computes the right numbers is ``make chip``
+(tests/test_pallas.py and the compiled mode of tests/test_pallas_kernels.py
+on the chip).
+"""
+import json
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu import models
+from mxnet_tpu.base import shape_attr
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops.nn import _pool_core
+
+BATCH = 32
+
+
+def _aval(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+
+def _resnet50_kernel_inputs():
+    """(BatchNorm input shapes, Pooling (input shape, attrs)) of the
+    chip_smoke train symbol."""
+    sym = models.resnet.get_symbol(num_classes=1000, num_layers=50,
+                                   image_shape="3,224,224",
+                                   dtype="bfloat16")
+    internals = sym.get_internals()
+    _, out_shapes, _ = internals.infer_shape(data=(BATCH, 3, 224, 224),
+                                             softmax_label=(BATCH,))
+    shape_of = dict(zip(internals.list_outputs(), out_shapes))
+    nodes = json.loads(sym.tojson())["nodes"]
+    bn, pools = set(), set()
+    for node in nodes:
+        if node["op"] not in ("BatchNorm", "Pooling"):
+            continue
+        src = nodes[node["inputs"][0][0]]
+        shape = tuple(shape_of[src["name"] + ("_output" if src["op"] != "null"
+                                              else "")])
+        if node["op"] == "BatchNorm":
+            bn.add(shape)
+        else:
+            pools.add((shape, json.dumps(node["attrs"], sort_keys=True)))
+    return sorted(bn), sorted(pools)
+
+
+BN_SHAPES, POOLS = _resnet50_kernel_inputs()
+FLASH_CASES = [
+    # tests/test_pallas.py shapes ...
+    ((2, 256, 2, 128), "float32", False),
+    ((1, 256, 2, 128), "float32", True),
+    ((1, 512, 1, 128), "float32", True),
+    # ... an odd length (block padding), and one real one
+    ((1, 100, 2, 128), "bfloat16", True),
+    ((4, 4096, 16, 128), "bfloat16", True),
+]
+
+
+def pool_configs():
+    """(input shape, ``_pool_core`` static config) per Pooling node."""
+    out = []
+    for shape, attrs in POOLS:
+        attrs = json.loads(attrs)
+        if attrs.get("global_pool") == "True":
+            kernel, stride, pad = shape[2:], (1, 1), (0, 0)
+        else:
+            kernel, stride, pad = (shape_attr(attrs[k])
+                                   for k in ("kernel", "stride", "pad"))
+        out.append((shape, (attrs["pool_type"], kernel, stride, pad,
+                            "valid", True)))
+    return out
+
+
+def _cases():
+    """(id, fn, avals) for every kernel program the chip must take."""
+    out = []
+    for shape in BN_SHAPES:
+        x = _aval(shape, "bfloat16")
+        out.append(("bn-sums-%s" % (shape,),
+                    lambda a: pk.bn_channel_sums(a), (x,)))         # Σx, Σx²
+        out.append(("bn-pair-%s" % (shape,),
+                    lambda a, b: pk.bn_channel_sums(a, b), (x, x)))  # Σdy, Σdy·x
+    for shape, cfg in pool_configs():
+        core = _pool_core(*cfg, "pallas")
+        out.append(("pool-%s-bwd-%s" % (cfg[0], shape),
+                    jax.grad(lambda v, core=core: jnp.sum(
+                        core(v).astype(jnp.float32) ** 2)),
+                    (_aval(shape, "bfloat16"),)))
+    for shape, dtype, causal in FLASH_CASES:
+        x = _aval(shape, dtype)
+
+        def fwd(q, k, v, n=None, causal=causal):
+            return pk.flash_attention(q, k, v, causal=causal,
+                                      use_pallas=True, kv_lens=n)
+
+        tag = "%s-%s-%s" % (shape, dtype, "causal" if causal else "full")
+        out.append(("flash-fwd-" + tag, fwd, (x, x, x)))
+        out.append(("flash-grad-" + tag,
+                    jax.grad(lambda q, k, v, fwd=fwd: jnp.sum(
+                        fwd(q, k, v).astype(jnp.float32) ** 2),
+                        argnums=(0, 1, 2)), (x, x, x)))
+        # the padding-mask operand (scalar prefetch)
+        out.append(("flash-lens-" + tag, fwd,
+                    (x, x, x, _aval(shape[:1], "int32"))))
+    return out
+
+
+CASES = _cases()
+
+
+def test_resnet50_bn_shapes_are_kernel_eligible():
+    assert all(pk.bn_sums_eligible(shape) for shape in BN_SHAPES)
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_lowers_for_tpu(case):
+    _, fn, avals = case
+    jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    """A compile-only v5e device: libtpu compiles for a named topology
+    without a chip attached, Mosaic included."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it wants a chip
+        pytest.skip("no compile-only TPU topology here: %s" % (exc,))
+    return topo.devices[0]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_mosaic_compiles_for_v5e(case, v5e_device):
+    """The whole TPU compile, Mosaic's passes included, on the CPU: what a
+    kernel change should pass before it spends chip time."""
+    from jax.sharding import SingleDeviceSharding
+    _, fn, avals = case
+    on_chip = SingleDeviceSharding(v5e_device)
+    jax.jit(fn, in_shardings=(on_chip,) * len(avals)).trace(*avals) \
+        .lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_partitioned_trace_keeps_mosaic_kernels_out(monkeypatch):
+    """A jit XLA partitions by itself (shardings over a mesh) cannot hold
+    a Mosaic kernel — the lowering refuses it outside a shard_map.  The
+    trace scope the dp fused step and ShardedModule open resolves the
+    kernel flags to off there, so the 4-chip step lowers; and a program
+    bound for the cpu traces no compiled kernel on a TPU host."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.ops.nn import _pooling
+
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    assert pk.kernel_mode("bn") == "pallas"
+    assert dict(pk.kernel_signature("cpu"))["bn"] == "off"
+    with pk.trace_scope(platform="cpu"):
+        assert pk.kernel_mode("bn") == "off"
+    with pk.trace_scope(partitioned=True):
+        assert pk.kernel_mode("bn") == "off"
+        monkeypatch.setenv("MXNET_TPU_PALLAS_BN", "1")
+        assert pk.kernel_mode("bn") == "pallas"   # explicit: let it raise
+    dp = NamedSharding(Mesh(np.array(jax.devices()[:2]), ("dp",)), P("dp"))
+
+    def pool_grad(x):
+        return jax.grad(lambda v: jnp.sum(_pooling(
+            v, pool_type="max", kernel=(3, 3), stride=(2, 2),
+            pad=(1, 1)) ** 2))(x)
+
+    def scoped(x):
+        with pk.trace_scope(partitioned=True):
+            return pool_grad(x)
+
+    x = _aval((4, 8, 16, 16), "float32")
+    jax.jit(scoped, in_shardings=dp).trace(x).lower(
+        lowering_platforms=("tpu",))
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        jax.jit(pool_grad, in_shardings=dp).trace(x).lower(
+            lowering_platforms=("tpu",))

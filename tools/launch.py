@@ -33,6 +33,11 @@ def launch_local(args, command):
             "DMLC_NUM_WORKER": str(args.num_workers),
             "DMLC_WORKER_ID": str(rank),
         })
+        if args.num_workers > 1:
+            # a chip belongs to ONE process: N workers on one host are
+            # the cpu harness of a multi-host job, never N claimants of
+            # the local chip (a lone worker keeps the default backend)
+            env["JAX_PLATFORMS"] = "cpu"
         procs.append(subprocess.Popen(command, shell=True, env=env))
 
     def _kill(signum, frame):
