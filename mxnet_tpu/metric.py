@@ -14,6 +14,7 @@ from __future__ import annotations
 import numpy as np
 
 from .ndarray import NDArray
+from .observability import instrument as _instrument
 
 __all__ = [
     "EvalMetric", "CompositeEvalMetric", "Accuracy", "TopKAccuracy", "F1",
@@ -24,9 +25,13 @@ __all__ = [
 
 
 def _host(array):
-    """Bring one label/pred onto the host as a numpy array."""
+    """Bring one label/pred onto the host as a numpy array.  In the fit
+    loop this is where the host waits for the step: the ``metric:fetch``
+    phase ends as the outputs land, which is where the loop's tracker
+    first sees the device run dry (observability/instrument.py)."""
     if isinstance(array, NDArray):
-        return array.asnumpy()
+        with _instrument.phase("metric:fetch"):
+            return array.asnumpy()
     return np.asarray(array)
 
 

@@ -306,7 +306,8 @@ class BaseModule:
         Each step is decomposed into the telemetry components
         (data_wait / fwd_bwd_dispatch / update / metric / sync) as
         nested profiler spans + registry histograms — the per-step
-        breakdown `tools/traceview.py` tabulates.  Same lookahead
+        breakdown `tools/traceview.py` tabulates; what ``sync`` lumps
+        together is split into its ``sync:*`` phases.  Same lookahead
         contract as before: the NEXT batch is fetched mid-step so its
         host->device transfer (``prepare``) overlaps this step."""
         tic = time.time()
@@ -323,7 +324,8 @@ class BaseModule:
         nbatch = 0
         while batch is not None:
             if monitor is not None:
-                with tracker.component("sync"):
+                with tracker.component("sync"), \
+                        tracker.phase("sync:monitor"):
                     monitor.tic()
             with tracker.component("fwd_bwd_dispatch"):
                 self.forward_backward(batch)
@@ -333,7 +335,8 @@ class BaseModule:
                 upcoming = next(it, None)
             if upcoming is not None:
                 # start the next batch's transfer while the step executes
-                with tracker.component("sync"):
+                with tracker.component("sync"), \
+                        tracker.phase("sync:prepare"):
                     self.prepare(upcoming)
             pending_health = None
             if health_mon is not None:
@@ -343,14 +346,17 @@ class BaseModule:
                 # never changes the active program for the in-flight
                 # step (BucketingModule switches back), so the stashed
                 # vector is still this step's.
-                with tracker.component("sync"):
+                with tracker.component("sync"), \
+                        tracker.phase("sync:health"):
                     pending_health = self._capture_health()
             with tracker.component("metric"):
                 self.update_metric(eval_metric, batch.label)
             if monitor is not None:
-                with tracker.component("sync"):
+                with tracker.component("sync"), \
+                        tracker.phase("sync:monitor"):
                     monitor.toc_print()
-            with tracker.component("sync"):
+            with tracker.component("sync"), \
+                    tracker.phase("sync:callbacks"):
                 _each_callback(batch_end_callback, BatchEndParam(
                     epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
                     locals=locals()))
@@ -379,10 +385,12 @@ class BaseModule:
                 # monitor's callback snapshots here, strictly after its
                 # flight dump (black box first); schedule/preemption
                 # triggers also fire at this completed-step boundary
-                with tracker.component("sync"):
+                with tracker.component("sync"), \
+                        tracker.phase("sync:checkpoint"):
                     ckpt.on_step(self, epoch=epoch, batch=nbatch)
             batch = upcoming
             nbatch += 1
+        tracker.close()
         for name, val in eval_metric.get_name_value():
             self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
         self.logger.info("Epoch[%d] Time cost=%.3f",

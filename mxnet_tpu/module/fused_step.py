@@ -665,6 +665,63 @@ class FusedTrainStep:
             st_nd)
 
     def run(self, data_batch):
+        """One fused step.  The host work is named in phases of the fit
+        loop's tracker (``observability.instrument``): ``fused:refresh``,
+        ``fused:load``, ``fused:scalars``, ``fused:dispatch`` (the
+        tracker's in-flight mark), ``fused:scatter``."""
+        with _instrument.phase("fused:refresh"):
+            self._refresh()
+        if self.n_dev > 1:
+            self._run_dp(data_batch)
+            return
+        exe = self.exe
+
+        # load batch into the bound input buffers (device upload + dtype
+        # cast; the batch usually arrives host-side from the data pipeline)
+        def _load(name, arr):
+            dst = exe.arg_dict[name]
+            src = arr._h.array
+            if src.dtype != dst._h.array.dtype:
+                src = src.astype(dst._h.array.dtype)
+            dev = list(dst._h.array.devices())[0]
+            if list(src.devices())[0] != dev:
+                src = jax.device_put(src, dev)
+            dst._h.array = src
+            return src
+
+        with _instrument.phase("fused:load"):
+            loaded = [_load(name, arr) for name, arr
+                      in zip(self.data_names, data_batch.data)]
+            if self.label_names and data_batch.label:
+                loaded += [_load(name, arr) for name, arr
+                           in zip(self.label_names, data_batch.label)
+                           if name in exe.arg_dict]
+
+        with _instrument.phase("fused:scalars"):
+            lrs, wds, extras, opt_key = self._per_step_scalars()
+            other_vals = [exe.arg_dict[n]._h.array
+                          for n in self.other_names]
+            aux_vals = list(self._gaux)
+            keys = tuple(_random.next_key() for _ in range(exe._n_keys))
+            args = (self._masters, other_vals, self.states, aux_vals,
+                    self._residuals, keys, lrs, wds, extras, opt_key)
+            self._note_abstract(args)
+        res = self._dispatch(args, loaded, "fused_step")
+
+        with _instrument.phase("fused:scatter"):
+            outs, new_exec, new_aux = self._keep(res)
+            for n, v in zip(self.param_names, new_exec):
+                exe.arg_dict[n]._h.array = v
+                self._scattered[n] = v
+            for n, v in zip(self.prog.aux_names, new_aux):
+                exe.aux_dict[n]._h.array = v
+                self._scattered[n] = v
+            exe.outputs = [NDArray(o) for o in outs]
+
+    def _refresh(self):
+        """Rebind after a reshape, and re-derive master state where
+        ``set_params``/``init_params`` replaced the exec handles since
+        the last write-back (the staleness scans)."""
         module = self.module
         if module._exec_group.execs[0] is not self.exe:
             # a reshape rebuilt the executors: rebind to the live one,
@@ -686,8 +743,6 @@ class FusedTrainStep:
                     module._exec_group.execs[0].arg_dict[n]._h.array
         self.ran = True
         exe = self.exe
-        # refresh master state where set_params/init_params replaced the
-        # exec handles since our last write-back
         for j, n in enumerate(self.param_names):
             cur = exe.arg_dict[n]._h.array
             if self._scattered.get(n) is not cur:
@@ -697,59 +752,34 @@ class FusedTrainStep:
             cur = exe.aux_dict[n]._h.array
             if self._scattered.get(n) is not cur:
                 self._gaux[j] = self._to_global(np.asarray(cur))
-        if self.n_dev > 1:
-            self._run_dp(data_batch)
-            return
 
-        # load batch into the bound input buffers (device upload + dtype
-        # cast; the batch usually arrives host-side from the data pipeline)
-        def _load(name, arr):
-            dst = exe.arg_dict[name]
-            src = arr._h.array
-            if src.dtype != dst._h.array.dtype:
-                src = src.astype(dst._h.array.dtype)
-            dev = list(dst._h.array.devices())[0]
-            if list(src.devices())[0] != dev:
-                src = jax.device_put(src, dev)
-            dst._h.array = src
+    def _dispatch(self, args, uploads, oom_context):
+        """The step program's call, to its return: from here one more
+        step is in flight, finished when its first output reads ready
+        and not started before ``uploads`` have landed."""
+        with _instrument.phase("fused:dispatch", dispatches=True) as ph:
+            try:
+                res = self._step(*args)
+            except Exception as exc:
+                # OOM black box: RESOURCE_EXHAUSTED on the training step
+                # leaves the augmented flight dump behind before it kills
+                # the run (observability/memprof.py; no-op otherwise)
+                _memprof.maybe_record_oom(oom_context, exc)
+                raise
+            ph.watch(res[0][:1] or res[1][:1], uploads)
+        return res
 
-        for name, arr in zip(self.data_names, data_batch.data):
-            _load(name, arr)
-        if self.label_names and data_batch.label:
-            for name, arr in zip(self.label_names, data_batch.label):
-                if name in exe.arg_dict:
-                    _load(name, arr)
-
-        lrs, wds, extras, opt_key = self._per_step_scalars()
-        other_vals = [exe.arg_dict[n]._h.array for n in self.other_names]
-        aux_vals = list(self._gaux)
-        keys = tuple(_random.next_key() for _ in range(exe._n_keys))
-
-        args = (self._masters, other_vals, self.states, aux_vals,
-                self._residuals, keys, lrs, wds, extras, opt_key)
-        self._note_abstract(args)
-        try:
-            res = self._step(*args)
-        except Exception as exc:
-            # OOM black box: RESOURCE_EXHAUSTED on the training step
-            # leaves the augmented flight dump behind before it kills
-            # the run (observability/memprof.py; no-op otherwise)
-            _memprof.maybe_record_oom("fused_step", exc)
-            raise
+    def _keep(self, res):
+        """Take the step's results as the next step's state; returns
+        what is left to hand to the executors: (outputs, the parameters
+        in their storage dtype, the auxiliary states)."""
         outs, new_masters, new_states, new_aux, new_exec, new_res = res[:6]
         self.last_health = res[6] if self._health_on else None
-
         self._masters = list(new_masters)
         self.states = list(new_states)
         self._gaux = list(new_aux)
         self._residuals = list(new_res)
-        for n, v in zip(self.param_names, new_exec):
-            exe.arg_dict[n]._h.array = v
-            self._scattered[n] = v
-        for n, v in zip(self.prog.aux_names, new_aux):
-            exe.aux_dict[n]._h.array = v
-            self._scattered[n] = v
-        exe.outputs = [NDArray(o) for o in outs]
+        return outs, new_exec, new_aux
 
     def _per_step_scalars(self):
         opt = self.opt
@@ -796,6 +826,7 @@ class FusedTrainStep:
         batch_by_name = dict(zip(self.data_names, data_batch.data))
         if self.label_names and data_batch.label:
             batch_by_name.update(zip(self.label_names, data_batch.label))
+        uploads = []
 
         def global_input(name, is_batch):
             if is_batch and name in batch_by_name:
@@ -804,28 +835,24 @@ class FusedTrainStep:
                 if src.dtype != want:
                     src = src.astype(want)
                 # device_put reshards device arrays directly (no host hop)
-                return jax.device_put(src, self._sh_dp)
+                uploads.append(jax.device_put(src, self._sh_dp))
+                return uploads[-1]
             # non-batch graph input (fixed param, state): replicate the
             # bound value
             return jax.device_put(
                 np.asarray(exe.arg_dict[name]._h.array), self._sh_repl)
 
-        other_vals = [global_input(n, b)
-                      for n, b in zip(self.other_names,
-                                      self._other_is_batch)]
-        lrs, wds, extras, opt_key = self._per_step_scalars()
-        keys = tuple(_random.next_key() for _ in range(exe._n_keys))
-
-        args = (self._masters, other_vals, self.states, self._gaux,
-                self._residuals, keys, lrs, wds, extras, opt_key)
-        self._note_abstract(args)
-        try:
-            res = self._step(*args)
-        except Exception as exc:
-            _memprof.maybe_record_oom("fused_step_dp", exc)
-            raise
-        outs, new_masters, new_states, new_aux, new_exec, new_res = res[:6]
-        self.last_health = res[6] if self._health_on else None
+        with _instrument.phase("fused:load"):
+            other_vals = [global_input(n, b)
+                          for n, b in zip(self.other_names,
+                                          self._other_is_batch)]
+        with _instrument.phase("fused:scalars"):
+            lrs, wds, extras, opt_key = self._per_step_scalars()
+            keys = tuple(_random.next_key() for _ in range(exe._n_keys))
+            args = (self._masters, other_vals, self.states, self._gaux,
+                    self._residuals, keys, lrs, wds, extras, opt_key)
+            self._note_abstract(args)
+        res = self._dispatch(args, uploads, "fused_step_dp")
         if self._comm_plan is not None:
             # per-step wire accounting for the in-program collectives —
             # host-side, outside the traced body (the comm row in
@@ -833,28 +860,27 @@ class FusedTrainStep:
             # bench.py --comm-smoke read these)
             _instrument.note_comm_overlapped(self._comm_plan)
 
-        self._masters = list(new_masters)
-        self.states = list(new_states)
-        self._gaux = list(new_aux)
-        self._residuals = list(new_res)
-        # hand every exec its local replica shard so eval/save/get_params
-        # see the updated state with zero cross-device traffic
-        for k, exe_k in enumerate(self.module._exec_group.execs):
-            dev = self.devices[k]
-            for n, v in zip(self.param_names, new_exec):
-                shard = self._replica_shard(v, dev)
-                exe_k.arg_dict[n]._h.array = shard
-                if k == 0:
-                    self._scattered[n] = shard
-            for n, v in zip(self.prog.aux_names, new_aux):
-                shard = self._replica_shard(v, dev)
-                exe_k.aux_dict[n]._h.array = shard
-                if k == 0:
-                    self._scattered[n] = shard
-            # batch-carrying outs are dp-sharded: each exec's shard IS its
-            # batch slice; batchless outs arrive as full replicas
-            exe_k.outputs = [NDArray(self._replica_shard(o, dev))
-                             for o in outs]
+        with _instrument.phase("fused:scatter"):
+            outs, new_exec, new_aux = self._keep(res)
+            # hand every exec its local replica shard so eval/save/
+            # get_params see the updated state with zero cross-device
+            # traffic
+            for k, exe_k in enumerate(self.module._exec_group.execs):
+                dev = self.devices[k]
+                for n, v in zip(self.param_names, new_exec):
+                    shard = self._replica_shard(v, dev)
+                    exe_k.arg_dict[n]._h.array = shard
+                    if k == 0:
+                        self._scattered[n] = shard
+                for n, v in zip(self.prog.aux_names, new_aux):
+                    shard = self._replica_shard(v, dev)
+                    exe_k.aux_dict[n]._h.array = shard
+                    if k == 0:
+                        self._scattered[n] = shard
+                # batch-carrying outs are dp-sharded: each exec's shard IS
+                # its batch slice; batchless outs arrive as full replicas
+                exe_k.outputs = [NDArray(self._replica_shard(o, dev))
+                                 for o in outs]
 
     def _wrap_nd(self, arr, dev):
         return NDArray(self._replica_shard(arr, dev) if self.n_dev > 1

@@ -19,6 +19,7 @@ import numpy as np
 
 from ..context import cpu
 from ..observability import health as _health
+from ..observability import instrument as _instrument
 from ..initializer import Uniform, InitDesc
 from ..io import DataDesc
 from ..ndarray import zeros as nd_zeros
@@ -441,12 +442,10 @@ class Module(BaseModule):
                 self._fused_step = None
                 self._fused_pending = False
             else:
-                from .. import profiler as _profiler
-                # host-side span around the one-program dispatch
-                # (outside the jitted body: zero effect on tracing;
-                # no-op flag check while the profiler is stopped)
-                with _profiler.record_span("fused_train_step",
-                                           category="symbolic"):
+                # host-side span around the one-program dispatch, the
+                # parent of the fused:* phases inside run() (outside the
+                # jitted body: zero effect on tracing)
+                with _instrument.phase("fused_train_step"):
                     self._fused_step.run(data_batch)
                 self._fused_pending = True
                 self._params_dirty = True

@@ -11,7 +11,13 @@ exec-cache trace counters in ``make bench-smoke`` hold that line).
   as a child span of an enclosing ``step`` span and observed into
   fixed-bucket histograms.  The step span's extent is [first component
   start, last component end], so the components cover it up to pure
-  python glue.
+  python glue.  Inside the components, ``phase`` names where the work
+  happens (``fused:load``, ``sync:callbacks``; ``decode:*`` in
+  ``PagedTransformerDecoder.step``, which drives a tracker of its own);
+  every live component and phase is an ``mx:``-prefixed annotation on
+  the profiler's clock; the tracker counts the time the device had
+  nothing to run under each (``starved``) and keeps one record a step in
+  memory (``recent_steps``).
 - ``note_io_wait``: every ``DataIter.__next__`` reports how long the
   consumer waited for the batch (the numerator of the input-starvation
   ratio ``tools/traceview.py`` prints).
@@ -24,6 +30,7 @@ exec-cache trace counters in ``make bench-smoke`` hold that line).
 """
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import threading
@@ -35,10 +42,8 @@ from . import telemetry
 from . import tracing
 
 # device-memory gauge sampling cadence, in training steps (the
-# MXNET_TPU_MEM_SAMPLE_STEPS default; MEM_SAMPLE_INTERVAL is the
-# historical name, kept as an alias)
+# MXNET_TPU_MEM_SAMPLE_STEPS default)
 DEFAULT_MEM_SAMPLE_STEPS = 10
-MEM_SAMPLE_INTERVAL = DEFAULT_MEM_SAMPLE_STEPS
 _MEM_STEPS_ENV = "MXNET_TPU_MEM_SAMPLE_STEPS"
 _mem_env_warned = False
 
@@ -69,49 +74,114 @@ def mem_sample_steps():
 STEP_COMPONENTS = ("data_wait", "fwd_bwd_dispatch", "update", "metric",
                    "sync")
 
+# how many closed steps (iterations) each ring of ``recent_steps`` keeps
+RECENT_STEPS = 4096
 
-class _NoopComponent:
+TrackerNames = collections.namedtuple(
+    "TrackerNames", "series counters step glue components sample_memory")
+TrackerNames.__doc__ = """What one host loop calls its tracker's output:
+``series`` prefixes the histograms (``<series>.<component>_ms``,
+``.phase.<phase>_ms``, ``.total_ms``, ``.starved_ms``,
+``.starved.<name>_ms``), ``counters`` the counters (``<counters>.steps``,
+``.steps_run_ahead``); ``step`` names the enclosing span (Chrome event,
+``mx:<step>`` annotation), ``glue`` the starved time outside every
+component and phase; ``components`` are the top-level names
+``component()`` accepts."""
+
+FIT = TrackerNames("module.step", "module", "step", "step:glue",
+                   STEP_COMPONENTS, True)
+# ``decode:between_calls`` is the caller's loop between two ``step()``s
+DECODE = TrackerNames("serving.decode", "serving.decode", "decode:iter",
+                      "decode:between_calls", (), False)
+
+_recent = {}                # tracker pid -> deque of step records
+_tls = threading.local()    # .tracker: the one with a span open here
+
+
+def recent_steps(kind="train"):
+    """The last ``RECENT_STEPS`` step records of the trackers whose
+    ``pid`` is ``kind`` (``"train"``: ``BaseModule.fit``; ``"serving"``:
+    ``PagedTransformerDecoder.step``), oldest first.  One record a step:
+    ``{step, epoch, end_s, total_ms, components_ms, phases_ms,
+    starved_ms, starved_by_ms, ran_ahead}`` — ``end_s`` is the
+    ``time.perf_counter()`` instant the step's last component or phase
+    closed; ``starved_ms`` is None for a loop that notes no dispatch.
+    Kept in memory only; nothing on the hot path touches the disk."""
+    return list(_recent.get(kind, ()))
+
+
+def phase(name, dispatches=False, drains=False):
+    """A phase of whichever tracker has a span open on this thread (the
+    fit loop's, reached from ``FusedTrainStep.run``); the shared no-op
+    when there is none or both its sinks are off."""
+    tracker = getattr(_tls, "tracker", None)
+    if tracker is None:
+        return _NOOP_CM
+    return tracker.phase(name, dispatches, drains)
+
+
+class _NoopSpan:
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         return False
 
+    def watch(self, outputs, inputs=()):
+        pass
 
-_NOOP_CM = _NoopComponent()
+
+_NOOP_CM = _NoopSpan()
 
 
-class _Component:
-    """Times one component occurrence; accumulates into the tracker and
-    emits a ``step:<name>`` child span when the profiler is recording."""
+def _ms(ns):
+    return round(ns / 1e6, 4)
 
-    __slots__ = ("_tracker", "_name", "_t0")
 
-    def __init__(self, tracker, name):
+def _all_ready(arrays):
+    """Non-blocking: every array's buffer is there.  A deleted (donated)
+    array has been consumed, which is as good as ready."""
+    try:
+        return all(a.is_ready() for a in arrays)
+    except RuntimeError:
+        return True
+
+
+class _Span:
+    """One occurrence of a component or a phase: accumulates into the
+    tracker, is an ``mx:<name>`` annotation on the profiler's clock, and
+    emits a Chrome complete-event when ``mx.profiler`` is recording."""
+
+    __slots__ = ("_tracker", "name", "_key", "_dispatches", "_drains",
+                 "_t0", "_ann", "_parent", "_watch", "_inputs")
+
+    def __init__(self, tracker, name, key, dispatches, drains):
         self._tracker = tracker
-        self._name = name
+        self.name = name
+        self._key = key             # the component's bare name, or None
+        self._dispatches = dispatches
+        self._drains = drains
+        self._watch = self._inputs = ()
+
+    def watch(self, outputs, inputs=()):
+        """Inside a ``dispatches`` phase: the arrays whose ``is_ready()``
+        says the dispatched program has finished (``outputs``) and has
+        what it needs to start (``inputs``, uploads still in flight)."""
+        self._watch, self._inputs = tuple(outputs), tuple(inputs)
 
     def __enter__(self):
-        self._t0 = tracing.now_us()
-        if self._tracker._step_t0 is None:
-            self._tracker._step_t0 = self._t0
+        self._tracker._enter(self)
         return self
 
-    def __exit__(self, *exc):
-        t1 = tracing.now_us()
-        tracker = self._tracker
-        tracker._parts[self._name] += t1 - self._t0
-        tracker._last_end = t1
-        if tracing.is_recording():
-            tracing.emit_complete(
-                "step:" + self._name, self._t0, t1 - self._t0,
-                category="step", pid=tracker.pid,
-                args={"parent_id": tracker._step_span_id})
+    def __exit__(self, exc_type, exc, tb):
+        self._tracker._exit(self, exc_type is not None)
         return False
 
 
 class StepTracker:
-    """Per-step breakdown over one epoch of a training loop.
+    """Per-step breakdown of one host loop that feeds the device: one
+    epoch of ``BaseModule.fit`` (``names=FIT``), or the life of a
+    ``PagedTransformerDecoder`` (``names=DECODE``).
 
     Usage (the shape ``BaseModule._run_epoch`` drives)::
 
@@ -119,22 +189,60 @@ class StepTracker:
         with tracker.component("data_wait"):
             batch = next(it)
         with tracker.component("fwd_bwd_dispatch"):
-            module.forward_backward(batch)
+            module.forward_backward(batch)   # opens instrument.phase(..)
         ...
         tracker.step_end(nbatch)
 
     ``component`` calls may repeat within a step ("sync" does); the
-    durations accumulate.  ``step_end`` emits the enclosing ``step``
-    span (complete event spanning first-component-start to
-    last-component-end, with per-component millisecond args), feeds the
-    histograms, and samples the device-memory gauges every
+    durations accumulate.  A ``phase`` is a named stretch inside a
+    component (or straight under the step) where the work happens:
+    ``fused:load``, ``sync:callbacks``, ``decode:tables``.  Both are
+    ``mx:``-prefixed annotations on the profiler's clock, nested inside
+    ``mx:<step>``.  ``step_end`` emits the enclosing ``step`` span
+    (first-component-start to last-component-end, per-component
+    millisecond args), feeds the histograms, appends the step's record
+    to ``recent_steps`` and samples the device-memory gauges every
     ``MXNET_TPU_MEM_SAMPLE_STEPS`` steps (default 10).
+
+    **Device starvation.**  The tracker keeps the step programs in
+    flight: one more when a ``dispatches`` phase returns, none once the
+    newest one's watched output reads ready at a component or phase
+    boundary (a non-blocking ``is_ready()``: no sync is added) or a
+    ``drains`` phase (the host fetched the results) returns.  Time with
+    nothing in flight is *starved*: the device had nothing to run.  It
+    is charged to the innermost span open, or to ``names.glue`` between
+    spans.  Readiness is only seen at boundaries, so a starved stretch
+    opens at the first boundary after the device finished and — where
+    the dispatched program still waits for an upload — closes at the
+    last boundary that saw the upload unfinished: the figure is a lower
+    bound by at most the two spans the device's edges fell in.  A step
+    dispatched while an earlier one is in flight ran ahead
+    (``steps_run_ahead``): it adds no starved time, and the starved
+    figure is then a lower bound for a second reason — the host cannot
+    see the device drain and refill between the two.
     """
 
-    def __init__(self, epoch=0, pid="train"):
+    def __init__(self, epoch=0, pid="train", names=FIT,
+                 clock_ns=time.perf_counter_ns):
         self.epoch = epoch
         self.pid = pid
+        self.names = names
+        self._clock = clock_ns
+        # Chrome events carry wall-clock microseconds: one anchor maps
+        # the monotonic clock onto them, so a boundary reads one clock
+        self._wall0_us = tracing.now_us() - clock_ns() / 1e3
         self._mem_every = mem_sample_steps()
+        self._ring = _recent.setdefault(
+            pid, collections.deque(maxlen=RECENT_STEPS))
+        self._open = []             # spans open now, outermost first
+        self._mark = None           # the last boundary, ns
+        self._in_flight = collections.deque()   # watched outputs
+        self._launching = None      # uploads the newest dispatch awaits
+        self._device_seen = False   # a dispatch was noted: starved counts
+        self._starved = {}          # name -> ns, this step
+        self._starved_top = {}      # outermost span -> ns, this step
+        self._index = 0             # steps closed so far
+        self._outer = None          # the thread's tracker before this one
         self._resolve_handles()
         self._reset_step()
 
@@ -145,15 +253,24 @@ class StepTracker:
         instruments — same contract as the io/kv handle caches."""
         self._handle_key = (telemetry.registry_epoch(),
                             telemetry.enabled())
+        series, counters = self.names.series, self.names.counters
         # disabled telemetry hands back no-op instruments; component()
         # then short-circuits entirely unless the profiler is recording
         self._hists = {c: telemetry.histogram(
-            "module.step.%s_ms" % c,
-            help="per-step %s time" % c) for c in STEP_COMPONENTS}
+            "%s.%s_ms" % (series, c),
+            help="per-step %s time" % c) for c in self.names.components}
+        self._lazy_hists = {}       # phase and starved series, by name
         self._hist_total = telemetry.histogram(
-            "module.step.total_ms", help="measured step wall time")
+            series + ".total_ms", help="measured step wall time")
+        self._hist_starved = telemetry.histogram(
+            series + ".starved_ms",
+            help="per-step time the device had no step program to run "
+                 "(a lower bound, see steps_run_ahead)")
         self._steps = telemetry.counter(
-            "module.steps", help="training steps observed")
+            counters + ".steps", help="steps observed")
+        self._ran_ahead_total = telemetry.counter(
+            counters + ".steps_run_ahead",
+            help="steps dispatched while an earlier one was in flight")
         self._mem_gauge = telemetry.gauge(
             "device.live_bytes", help="live device memory (sampled)")
         self._peak_gauge = telemetry.gauge(
@@ -162,54 +279,202 @@ class StepTracker:
                  "memory_stats only)")
         self._telemetry_on = self._hist_total is not telemetry.NOOP
 
+    def _lazy_hist(self, kind, name):
+        hist = self._lazy_hists.get((kind, name))
+        if hist is None:
+            hist = self._lazy_hists[kind, name] = telemetry.histogram(
+                "%s.%s.%s_ms" % (self.names.series, kind, name))
+        return hist
+
     def _reset_step(self):
-        self._parts = {c: 0.0 for c in STEP_COMPONENTS}
+        self._parts = {c: 0 for c in self.names.components}
+        self._phases = {}
         self._step_t0 = None
         self._last_end = None
         self._step_span_id = None
+        self._step_ann = None
+        self._ran_ahead = False
+
+    def _live(self):
+        # both sinks off: the whole step costs one flag check per
+        # component (the module's zero-cost-when-disabled contract)
+        return self._telemetry_on or tracing.is_recording()
 
     def component(self, name):
-        if not (self._telemetry_on or tracing.is_recording()):
-            # both sinks off: the whole step costs one flag check per
-            # component (the module's zero-cost-when-disabled contract)
+        if not self._live():
             return _NOOP_CM
-        if self._step_span_id is None:
-            # allocate the step's span id lazily at first component so
-            # children can link to a parent that is emitted after them
+        return _Span(self, "step:" + name, name, False, False)
+
+    def phase(self, name, dispatches=False, drains=False):
+        if not self._live():
+            return _NOOP_CM
+        return _Span(self, name, None, dispatches, drains)
+
+    # -- boundaries ----------------------------------------------------------
+
+    def _boundary(self, now):
+        """Charge [last boundary, now] and look at the watched arrays."""
+        if self._device_seen:
+            if self._launching is not None:
+                # dispatched, but its upload was unfinished when last
+                # seen: starved for as long as it still is
+                if _all_ready(self._launching):
+                    self._launching = None
+                else:
+                    self._charge_starved(now - self._mark)
+            elif not self._in_flight:
+                self._charge_starved(now - self._mark)
+            # (nothing watched: only a ``drains`` phase clears it)
+            while self._in_flight and self._in_flight[0] \
+                    and _all_ready(self._in_flight[0]):
+                self._in_flight.popleft()
+        self._mark = now
+
+    def _charge_starved(self, ns):
+        if ns <= 0:
+            return
+        inner = self._open[-1].name if self._open else self.names.glue
+        top = self._open[0].name if self._open else self.names.glue
+        self._starved[inner] = self._starved.get(inner, 0) + ns
+        self._starved_top[top] = self._starved_top.get(top, 0) + ns
+
+    def _enter(self, span):
+        now = self._clock()
+        if self._step_t0 is None:
+            # the step opens at its first span; its id is allocated now
+            # so children can link to a parent emitted after them
+            self._step_t0 = now
             self._step_span_id = next(tracing._span_ids)
-        return _Component(self, name)
+            self._step_ann = tracing.annotation(self.names.step,
+                                                step=self._index)
+            self._step_ann.__enter__()
+        self._boundary(now)
+        if not self._open:
+            span._parent = self.names.step
+            self._outer = getattr(_tls, "tracker", None)
+            _tls.tracker = self
+        else:
+            span._parent = self._open[-1].name
+        self._open.append(span)
+        span._ann = tracing.annotation(span.name, step=self._index,
+                                       parent=span._parent)
+        span._ann.__enter__()
+        span._t0 = now
+
+    def _exit(self, span, failed):
+        now = self._clock()
+        self._boundary(now)
+        span._ann.__exit__(None, None, None)
+        self._open.pop()
+        if not self._open:
+            _tls.tracker = self._outer
+        dur = now - span._t0
+        if span._key is not None:
+            self._parts[span._key] += dur
+        else:
+            self._phases[span.name] = self._phases.get(span.name, 0) + dur
+        self._last_end = now
+        if not failed:
+            if span._dispatches:
+                self._dispatched(span)
+            if span._drains:
+                self._in_flight.clear()
+                self._launching = None
+        if tracing.is_recording():
+            args = {"parent_id": self._step_span_id}
+            if span._key is None:
+                args.update(parent=span._parent, step=self._index)
+            tracing.emit_complete(
+                span.name, self._wall0_us + span._t0 / 1e3, dur / 1e3,
+                category="step", pid=self.pid, args=args)
+
+    def _dispatched(self, span):
+        self._device_seen = True
+        if self._in_flight:
+            self._ran_ahead = True
+        elif span._inputs and not _all_ready(span._inputs):
+            self._launching = span._inputs
+        self._in_flight.append(span._watch)
+
+    # -- closing a step ------------------------------------------------------
+
+    def close(self):
+        """End the open step's ``mx:<step>`` annotation (``step_end``
+        does; the loop's owner calls it when the loop ends mid-step)."""
+        if self._step_ann is not None:
+            self._step_ann.__exit__(None, None, None)
+            self._step_ann = None
+
+    def cancel_step(self):
+        """Drop the open step unrecorded (a decoder iteration that found
+        nothing to run); starved time it saw goes to ``names.glue``."""
+        self.close()
+        if self._starved:
+            total = sum(self._starved.values())
+            self._starved = {self.names.glue: total}
+            self._starved_top = {self.names.glue: total}
+        self._reset_step()
 
     def step_end(self, nbatch):
-        """Close out the step.  Returns the per-component millisecond
-        breakdown (plus ``total``) so callers — the flight recorder —
-        can keep the last-N of them, or None when no component ran."""
-        if self._step_t0 is None:
-            return None
+        """Close out the step.  Returns its record (the one appended to
+        ``recent_steps``: per-component and per-phase milliseconds,
+        total, starved time by name) so callers — the flight recorder —
+        keep it, or None when no component ran."""
         if self._handle_key != (telemetry.registry_epoch(),
                                 telemetry.enabled()):
             self._resolve_handles()
-        dur = self._last_end - self._step_t0
-        args = {"span_id": self._step_span_id, "step": nbatch,
-                "epoch": self.epoch}
-        timings = {}
-        for c in STEP_COMPONENTS:
-            ms = self._parts[c] / 1e3
-            args[c + "_ms"] = timings[c] = round(ms, 4)
-            self._hists[c].observe(ms)
-        timings["total"] = round(dur / 1e3, 4)
-        self._hist_total.observe(dur / 1e3)
+        if self._step_t0 is None:
+            return None
+        self.close()
+        ms = _ms
+        total_ms = ms(self._last_end - self._step_t0)
+        record = {"step": nbatch, "epoch": self.epoch,
+                  "end_s": self._last_end / 1e9, "total_ms": total_ms,
+                  "components_ms": {c: ms(v)
+                                    for c, v in self._parts.items()},
+                  "phases_ms": {p: ms(v) for p, v in self._phases.items()},
+                  "starved_ms": None, "starved_by_ms": {},
+                  "ran_ahead": self._ran_ahead}
+        for c, v in record["components_ms"].items():
+            self._hists[c].observe(v)
+        for p, v in record["phases_ms"].items():
+            self._lazy_hist("phase", p).observe(v)
+        self._hist_total.observe(total_ms)
         self._steps.inc()
+        if self._ran_ahead:
+            self._ran_ahead_total.inc()
+        if self._device_seen:
+            record["starved_by_ms"] = {n: ms(v)
+                                       for n, v in self._starved.items()}
+            record["starved_ms"] = ms(sum(self._starved.values()))
+            self._hist_starved.observe(record["starved_ms"])
+            for n, v in record["starved_by_ms"].items():
+                self._lazy_hist("starved", n).observe(v)
+        self._ring.append(record)
         if tracing.is_recording():
-            tracing.emit_complete("step", self._step_t0, dur,
-                                  category="step", pid=self.pid,
-                                  args=args)
-        if nbatch % self._mem_every == 0 \
-                and (self._telemetry_on or tracing.is_recording()):
+            args = {"span_id": self._step_span_id, "step": nbatch,
+                    "epoch": self.epoch,
+                    "starved_ms": record["starved_ms"],
+                    "starved_by_ms": {n: ms(v) for n, v
+                                      in self._starved_top.items()}
+                    if self._device_seen else {},
+                    "ran_ahead": self._ran_ahead}
+            for c, v in record["components_ms"].items():
+                args[c + "_ms"] = v
+            tracing.emit_complete(
+                self.names.step, self._wall0_us + self._step_t0 / 1e3,
+                (self._last_end - self._step_t0) / 1e3,
+                category="step", pid=self.pid, args=args)
+        if self.names.sample_memory and nbatch % self._mem_every == 0 \
+                and self._live():
             # jax.live_arrays() is O(live arrays) — never pay it when
             # nobody is listening
             sample_device_memory(self._mem_gauge, self._peak_gauge)
+        self._starved = {}
+        self._starved_top = {}
+        self._index += 1
         self._reset_step()
-        return timings
+        return record
 
 
 # the most recent device-memory sample, host-side: flight-recorder

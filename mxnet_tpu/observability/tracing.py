@@ -18,6 +18,15 @@ buffer grown up:
 Recording is off until ``set_recording(True)`` (the profiler facade's
 ``profiler_set_state("run")``); every emit checks that flag first, so a
 non-profiled process pays one attribute read per callsite.
+
+**One clock with the device.**  Timestamps here are wall-clock; a
+``jax.profiler`` device trace counts from the start of its own session,
+so nothing stamped here can be laid beside a device gap.  ``annotation``
+is the bridge: every live span (and every live ``StepTracker`` component
+and phase) also opens a ``jax.profiler.TraceAnnotation`` named
+``"mx:" + name``, which lands on the ``/host:`` plane of the same
+``.xplane.pb`` as ``XLA Ops``, nested, on the profiler's clock.  With no
+profiling session open it is one atomic load.
 """
 from __future__ import annotations
 
@@ -25,9 +34,15 @@ import itertools
 import logging
 import os
 import threading
+import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import threads as _threads
-import time
+
+# the one prefix of everything the program itself puts on the profiler's
+# clock (the harness's own spans are "bench:")
+ANNOTATION_PREFIX = "mx:"
 
 _lock = _threads.package_lock("tracing._lock")
 _events = []
@@ -71,6 +86,15 @@ def now_us():
     """Trace timestamps are wall-clock microseconds (same clock as every
     pre-existing event in this buffer, so mixed dumps stay ordered)."""
     return time.time() * 1e6
+
+
+def annotation(name, **meta):
+    """A ``jax.profiler.TraceAnnotation`` named ``"mx:" + name``: a host
+    span in the device trace's own file and on its clock whenever a
+    profiling session is open (``mx.profiler.start_jax_trace``, the
+    benchmark's ``--trace 1``), an atomic load otherwise.  ``meta``
+    (step number, parent span) becomes the event's stats."""
+    return _TraceAnnotation(ANNOTATION_PREFIX + name, **meta)
 
 
 def is_recording():
@@ -133,11 +157,12 @@ class span:
     """Context manager recording one nested span on this thread's stack.
 
     Enter pushes; exit pops and emits a complete event carrying
-    ``span_id`` and (when nested) ``parent_id``.  When recording is off
+    ``span_id`` and (when nested) ``parent_id``; a live span is also an
+    ``mx:`` annotation on the profiler's clock.  When recording is off
     both directions are a single flag check."""
 
     __slots__ = ("name", "category", "pid", "args", "_t0", "_id",
-                 "_parent", "_live")
+                 "_parent", "_live", "_ann")
 
     def __init__(self, name, category="runtime", pid="cpu/0", args=None):
         self.name = name
@@ -153,6 +178,8 @@ class span:
         self._parent = stack[-1]._id if stack else 0
         self._id = next(_span_ids)
         stack.append(self)
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
         self._t0 = now_us()
         return self
 
@@ -160,6 +187,7 @@ class span:
         if not self._live:
             return False
         t1 = now_us()
+        self._ann.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()

@@ -52,6 +52,7 @@ from .. import context as _context
 from .. import threads as _threads
 from ..analysis import locksan as _locksan
 from ..base import MXNetError
+from ..observability import instrument as _instrument
 from ..observability import reqtrace as _reqtrace
 from ..observability import tracing
 from . import metrics
@@ -243,6 +244,8 @@ class PagedTransformerDecoder(SlotScheduler):
             self.num_layers, self.num_heads, self.head_dim,
             self.embed_dim, self.ffn_dim, self.vocab_size,
             self.slot_count, self.max_pages, self.page_size, donate)
+        self._tracker = _instrument.StepTracker(
+            pid="serving", names=_instrument.DECODE)
 
     # -- scheduling --------------------------------------------------------
 
@@ -331,9 +334,13 @@ class PagedTransformerDecoder(SlotScheduler):
         not the decoder), run the fixed-shape program, append/advance,
         register completed pages with the prefix cache, collect
         generated tokens, retire EOS streams.  Returns the number of
-        active slots run."""
+        active slots run.  The host work is named in phases of the
+        decoder's own tracker (``decode:admit``, ``decode:tables``,
+        ``decode:dispatch``, ``decode:fetch``, ``decode:commit``, under
+        ``decode:iter``): ``observability.instrument``."""
         overflow = []
-        with self._lock:
+        tracker = self._tracker
+        with tracker.phase("decode:admit"), self._lock:
             joins = self._admit_locked()
             batch = []
             for slot, stream in enumerate(self._slots):
@@ -351,52 +358,66 @@ class PagedTransformerDecoder(SlotScheduler):
         # COW pass OUTSIDE the scheduler lock: a clone dispatches a
         # device program (pool bookkeeping has its own lock); streams
         # seated in slots are only mutated by this stepping thread
-        active = []
-        tokens = np.zeros((self.slot_count,), np.int32)
-        positions = np.zeros((self.slot_count,), np.int32)
-        active_mask = np.zeros((self.slot_count,), bool)
-        tables = np.zeros((self.slot_count, self.max_pages), np.int32)
-        for slot, stream, need in batch:
-            try:
-                page, cloned = self.pool.ensure_private(
-                    stream.pages[need])
-            except Overloaded as exc:
-                with self._lock:
-                    self._shed(slot, stream, exc, overflow)
-                continue
-            if cloned:
-                stream.pages[need] = page
-            if stream.position < len(stream.prompt):
-                fed = stream.prompt[stream.position]   # prefill
-            else:
-                fed = stream.generated[-1]             # decode
-            tokens[slot] = fed
-            positions[slot] = stream.position
-            active_mask[slot] = True
-            tables[slot, :len(stream.pages)] = stream.pages
-            active.append((slot, stream, fed))
-        for stream, exc in overflow:
-            metrics.record_rejection("Overloaded")
-            stream._finish(exc)
-            _reqtrace.finish_rejected(stream.ctx, exc)
+        with tracker.phase("decode:tables"):
+            active = []
+            tokens = np.zeros((self.slot_count,), np.int32)
+            positions = np.zeros((self.slot_count,), np.int32)
+            active_mask = np.zeros((self.slot_count,), bool)
+            tables = np.zeros((self.slot_count, self.max_pages), np.int32)
+            for slot, stream, need in batch:
+                try:
+                    page, cloned = self.pool.ensure_private(
+                        stream.pages[need])
+                except Overloaded as exc:
+                    with self._lock:
+                        self._shed(slot, stream, exc, overflow)
+                    continue
+                if cloned:
+                    stream.pages[need] = page
+                if stream.position < len(stream.prompt):
+                    fed = stream.prompt[stream.position]   # prefill
+                else:
+                    fed = stream.generated[-1]             # decode
+                tokens[slot] = fed
+                positions[slot] = stream.position
+                active_mask[slot] = True
+                tables[slot, :len(stream.pages)] = stream.pages
+                active.append((slot, stream, fed))
+            for stream, exc in overflow:
+                metrics.record_rejection("Overloaded")
+                stream._finish(exc)
+                _reqtrace.finish_rejected(stream.ctx, exc)
         if not active:
+            tracker.cancel_step()
             return 0
         t_i0 = time.monotonic()
         with tracing.span("serving:paged_decode_step", category="serving",
                           pid="serving",
                           args={"active": len(active), "joins": joins}):
             _locksan.check_dispatch_clear("paged.step")
-            k_pool, v_pool, nxt, logits = self._step_fn(
-                self.pool.k_pool, self.pool.v_pool, self._params,
-                tokens, positions, active_mask, tables)
-            self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
-            nxt_host = np.asarray(nxt)
-            logits_host = np.asarray(logits)
+            with tracker.phase("decode:dispatch", dispatches=True):
+                k_pool, v_pool, nxt, logits = self._step_fn(
+                    self.pool.k_pool, self.pool.v_pool, self._params,
+                    tokens, positions, active_mask, tables)
+                self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+            # the results on the host: the device has run dry
+            with tracker.phase("decode:fetch", drains=True):
+                nxt_host = np.asarray(nxt)
+                logits_host = np.asarray(logits)
         t_i1 = time.monotonic()
         self.iterations += 1
+        with tracker.phase("decode:commit"):
+            leaves = self._commit(active, nxt_host, logits_host, t_i0, t_i1)
+            metrics.record_decode_step(len(active), joins, leaves)
+        tracker.step_end(self.iterations - 1)
+        return len(active)
+
+    def _commit(self, active, nxt_host, logits_host, t_i0, t_i1):
+        """Append/advance every stream that ran, offer filled pages to
+        the prefix cache, finish the streams that are done.  Returns how
+        many left."""
         pool_used = self.pool.pages_used()
         finished = []
-        leaves = 0
         with self._lock:
             for slot, stream, fed in active:
                 if stream.ctx is not None:
@@ -430,7 +451,6 @@ class PagedTransformerDecoder(SlotScheduler):
                     self._slots[slot] = None
                     pages_held = len(stream.pages)
                     self._release_stream_locked(stream)
-                    leaves += 1
                     finished.append((stream, pages_held))
         for stream, pages_held in finished:
             metrics.record_kv_stream_finished(pages_held)
@@ -438,8 +458,7 @@ class PagedTransformerDecoder(SlotScheduler):
             _reqtrace.finish(stream.ctx, status="ok",
                              steps=len(stream.generated),
                              prefix_pages=stream.prefix_pages)
-        metrics.record_decode_step(len(active), joins, leaves)
-        return len(active)
+        return len(finished)
 
     # -- warmup ------------------------------------------------------------
 

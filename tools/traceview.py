@@ -241,6 +241,22 @@ def step_breakdown(events):
                 comp[c]["total_ms"] += ms
     step_total = sum(steps)
     covered = sum(s["total_ms"] for s in comp.values())
+    # device starvation as the tracker measured it (the step event's
+    # args): time with no step program in flight, by the component it
+    # fell under ("glue": between components).  None on traces of a
+    # loop that notes no dispatch, or from before the tracker kept it
+    starved_ms, starved_by, ran_ahead = None, {}, 0
+    for e in events:
+        args = e.get("args") or {}
+        if e.get("ph") != "X" or e.get("cat") != "step" \
+                or e.get("name") != "step" \
+                or args.get("starved_ms") is None:
+            continue
+        starved_ms = (starved_ms or 0.0) + _fnum(args["starved_ms"], 0)
+        ran_ahead += 1 if args.get("ran_ahead") else 0
+        for name, ms in (args.get("starved_by_ms") or {}).items():
+            c = name.split(":", 1)[1] if name.startswith("step:") else name
+            starved_by[c] = starved_by.get(c, 0.0) + _fnum(ms, 0)
     return {
         "steps": len(steps),
         "step_total_ms": step_total,
@@ -249,6 +265,9 @@ def step_breakdown(events):
         "coverage": covered / step_total if step_total else 0.0,
         "starvation": (comp["data_wait"]["total_ms"] / step_total
                        if step_total else 0.0),
+        "starved_ms": starved_ms,
+        "starved_by": starved_by,
+        "ran_ahead": ran_ahead,
     }
 
 
@@ -1888,20 +1907,31 @@ def summarize(trace, top=15):
         lines.append("steps: %d   measured step time: %.3f ms total, "
                      "%.3f ms avg" % (bd["steps"], bd["step_total_ms"],
                                       bd["step_avg_ms"]))
-        lines.append("%-18s %7s %12s %12s %8s"
+        starved = bd["starved_ms"] is not None
+        lines.append("%-18s %7s %12s %12s %8s %12s"
                      % ("Component", "Calls", "Total(ms)", "Avg/step(ms)",
-                        "Step%"))
+                        "Step%", "Starved(ms)"))
         for c in STEP_COMPONENTS:
             s = bd["components"][c]
             share = (s["total_ms"] / bd["step_total_ms"] * 100.0
                      if bd["step_total_ms"] else 0.0)
-            lines.append("%-18s %7d %12.3f %12.3f %7.1f%%"
+            lines.append("%-18s %7d %12.3f %12.3f %7.1f%% %12s"
                          % (c, s["count"], s["total_ms"],
-                            s["total_ms"] / bd["steps"], share))
+                            s["total_ms"] / bd["steps"], share,
+                            "%.3f" % bd["starved_by"].get(c, 0.0)
+                            if starved else "-"))
         lines.append("component coverage of step time: %.1f%%"
                      % (bd["coverage"] * 100.0))
         lines.append("input starvation (data_wait / step): %.1f%%"
                      % (bd["starvation"] * 100.0))
+        if starved:
+            lines.append(
+                "device starved (no step program in flight): %.3f ms "
+                "total, %.3f ms/step (%.3f ms between components)%s"
+                % (bd["starved_ms"], bd["starved_ms"] / bd["steps"],
+                   bd["starved_by"].get("glue", 0.0),
+                   "; %d steps ran ahead: a lower bound" % bd["ran_ahead"]
+                   if bd["ran_ahead"] else ""))
 
     pb = pipeline_breakdown(events)
     if pb is not None:
