@@ -2255,7 +2255,7 @@ def kernel_smoke():
     on the CPU test backend, where every Pallas kernel runs through the
     interpreter (same kernel code path as the chip):
 
-    1. **direct parity** — pooling backward (max + avg, stride != kernel)
+    1. **direct parity** — max-pooling backward (stride != kernel)
        and the BN channel-sums epilogue match their XLA fallbacks on
        CPU-shaped inputs; int8 predict matches f32 predict to quant
        tolerance with identical argmax;
@@ -2291,14 +2291,13 @@ def kernel_smoke():
     parity = {}
     x = jnp.asarray(rng.randn(2, 4, 12, 14).astype(np.float32))
     from mxnet_tpu.ops.nn import _pool_core
-    for pool_type in ("max", "avg"):
-        cfg = (pool_type, (3, 3), (2, 2), (1, 1), "valid", True)
-        ref = jax.grad(lambda v: jnp.sum(_pool_core(*cfg, "off")(v) ** 2))(x)
-        got = jax.grad(
-            lambda v: jnp.sum(_pool_core(*cfg, "interpret")(v) ** 2))(x)
-        err = float(jnp.max(jnp.abs(got - ref)))
-        parity["pool_bwd_" + pool_type] = err
-        assert err < 1e-5, (pool_type, err)
+    cfg = ("max", (3, 3), (2, 2), (1, 1), "valid", True)  # avg/sum: no kernel
+    ref = jax.grad(lambda v: jnp.sum(_pool_core(*cfg, "off")(v) ** 2))(x)
+    got = jax.grad(
+        lambda v: jnp.sum(_pool_core(*cfg, "interpret")(v) ** 2))(x)
+    err = float(jnp.max(jnp.abs(got - ref)))
+    parity["pool_bwd_max"] = err
+    assert err < 1e-5, err
     s1, s2 = pk.bn_channel_sums(x, interpret=True)
     err = max(float(jnp.max(jnp.abs(s1 - jnp.sum(x, (0, 2, 3))))),
               float(jnp.max(jnp.abs(s2 - jnp.sum(x * x, (0, 2, 3))))))

@@ -387,12 +387,15 @@ register("Deconvolution", _deconvolution, input_names=("data", "weight", "bias")
                      no_bias=(pBool, True)))
 
 # ---------------------------------------------------------------------------
-# Pooling (ref: pooling-inl.h, pool.h) — lax.reduce_window forward; the
-# input gradient is either XLA's autodiff (select-and-scatter for max) or
-# the hand-scheduled Pallas kernel (ops/pallas_kernels.py, flag
-# MXNET_TPU_PALLAS_POOL) selected at trace time through a custom_vjp — so
-# the fused fwd_bwd program (module/fused_step.py, executor_cache.py)
-# picks the kernel up with no module-layer change.
+# Pooling (ref: pooling-inl.h, pool.h) — lax.reduce_window forward.  The
+# input gradient of avg/sum pooling is always XLA's transpose of the
+# reduce_window-add: a fan-out of dy that fuses into dx's consumer (a
+# broadcast for a window that covers the input).  For max pooling it is
+# either XLA's autodiff (select-and-scatter) or the hand-scheduled Pallas
+# kernel (ops/pallas_kernels.py, flag MXNET_TPU_PALLAS_POOL) selected at
+# trace time through a custom_vjp — so the fused fwd_bwd program
+# (module/fused_step.py, executor_cache.py) picks the kernel up with no
+# module-layer change.
 # ---------------------------------------------------------------------------
 
 def _pool_spatial_pads(spatial, kernel, stride, pad, convention):
@@ -457,15 +460,16 @@ def _pool_xla_forward(data, pool_type, kernel, stride, pad, convention,
 @_functools.lru_cache(maxsize=None)
 def _pool_core(pool_type, kernel, stride, pad, convention,
                count_include_pad, mode):
-    """Per-static-config pooling core.  mode 'off' returns the plain XLA
-    forward (autodiff = the parent program's select-and-scatter backward,
-    bit-identical to a build without the kernel); 'pallas'/'interpret'
-    wrap it in a custom_vjp whose backward is the recompute-argmax Pallas
-    kernel.  The forward saves the phase-major (s2d) input view as the
-    residual so the transpose fuses into the producer's epilogue."""
+    """Per-static-config pooling core.  avg/sum pooling, and max pooling
+    under mode 'off', return the plain XLA forward (autodiff = XLA's own
+    backward, bit-identical to a build without the kernel); max pooling
+    under 'pallas'/'interpret' wraps it in a custom_vjp whose backward is
+    the recompute-argmax Pallas kernel.  The forward saves the phase-major
+    (s2d) input view as the residual so the transpose fuses into the
+    producer's epilogue."""
     fwd_fn = lambda x: _pool_xla_forward(  # noqa: E731
         x, pool_type, kernel, stride, pad, convention, count_include_pad)
-    if mode == "off":
+    if mode == "off" or pool_type != "max":
         return fwd_fn
     from . import pallas_kernels as _pk
     interpret = True if mode == "interpret" else None
@@ -475,37 +479,20 @@ def _pool_core(pool_type, kernel, stride, pad, convention,
         return fwd_fn(x)
 
     def fwd(x):
-        out = fwd_fn(x)
-        if pool_type == "max":
-            oshape = _pool_out_shape(x.shape[2:], kernel, stride, pad,
-                                     convention)
-            xs = _pk.pool_s2d(x, kernel, stride, pad, oshape, -jnp.inf)
-        else:
-            xs = None  # avg/sum backward never reads x
+        oshape = _pool_out_shape(x.shape[2:], kernel, stride, pad,
+                                 convention)
+        xs = _pk.pool_s2d(x, kernel, stride, pad, oshape, -jnp.inf)
         # x rides along for its shape/dtype only; XLA DCEs the unused
         # residual (the make_loss precedent above)
-        return out, (x, xs)
+        return fwd_fn(x), (x, xs)
 
     def bwd(res, dy):
         x, xs = res
         oshape = _pool_out_shape(x.shape[2:], kernel, stride, pad,
                                  convention)
-        if pool_type == "max":
-            dx = _pk.max_pool_backward(xs, dy, x.shape, x.dtype, kernel,
-                                       stride, pad, oshape,
-                                       interpret=interpret)
-        else:
-            if pool_type == "sum":
-                div = jnp.ones(oshape, jnp.float32)
-            elif count_include_pad:
-                div = jnp.full(oshape, 1.0 / float(np.prod(kernel)),
-                               jnp.float32)
-            else:
-                div = 1.0 / _pool_window_counts(x.shape[2:], kernel,
-                                                stride, pad, convention)
-            dx = _pk.avg_pool_backward(dy, div, x.shape, x.dtype, kernel,
-                                       stride, pad, oshape,
-                                       interpret=interpret)
+        dx = _pk.max_pool_backward(xs, dy, x.shape, x.dtype, kernel,
+                                   stride, pad, oshape,
+                                   interpret=interpret)
         return (dx.astype(x.dtype),)
 
     core.defvjp(fwd, bwd)

@@ -451,7 +451,7 @@ def attention(q, k, v, causal=False, scale=None, kv_lens=None):
 
 
 # ---------------------------------------------------------------------------
-# Pooling backward (ref: pool.h unpool kernels; XLA's lowering is
+# Max-pooling backward (ref: pool.h unpool kernels; XLA's lowering is
 # select-and-scatter.11 = 423 us/step of the ResNet-50 train step,
 # ROOFLINE_r05.json).  Strategy: recompute-argmax over input tiles staged
 # through VMEM.  Stride-s pooling relates input lanes to output lanes at
@@ -563,39 +563,18 @@ def _max_pool_bwd_kernel(xs_ref, dy_ref, out_ref, acc_ref, *, taps, oh, ow):
     out_ref[:] = acc_ref[:].astype(out_ref.dtype)
 
 
-def _avg_pool_bwd_kernel(dy_ref, div_ref, out_ref, acc_ref, *, taps, oh,
-                         ow):
-    """avg/sum pooling backward never reads x: every tap of a window
-    takes the same cotangent share dy * div (div folds the window-count
-    divisor — per-position under count_include_pad=False)."""
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    c = dy_ref[:].astype(jnp.float32) * div_ref[:][None]
-    for plane, dh, dw in taps:
-        acc_ref[:, plane, dh:dh + oh, dw:dw + ow] += c
-    out_ref[:] = acc_ref[:].astype(out_ref.dtype)
-
-
 @functools.lru_cache(maxsize=512)
-def _pool_bwd_jitted(pool_type, rows, planes, hq, wq, oh, ow, taps, dtype,
-                     interpret):
+def _pool_bwd_jitted(rows, planes, hq, wq, oh, ow, taps, dtype, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     br = _pool_block_rows(rows)
     out_dtype = jnp.dtype(dtype)
-    if pool_type == "max":
-        kernel = functools.partial(_max_pool_bwd_kernel, taps=taps, oh=oh,
-                                   ow=ow)
-        in_specs = [
-            pl.BlockSpec((br, planes, hq, wq), lambda r: (r, 0, 0, 0)),
-            pl.BlockSpec((br, oh, ow), lambda r: (r, 0, 0)),
-        ]
-    else:
-        kernel = functools.partial(_avg_pool_bwd_kernel, taps=taps, oh=oh,
-                                   ow=ow)
-        in_specs = [
-            pl.BlockSpec((br, oh, ow), lambda r: (r, 0, 0)),
-            pl.BlockSpec((oh, ow), lambda r: (0, 0)),
-        ]
+    kernel = functools.partial(_max_pool_bwd_kernel, taps=taps, oh=oh,
+                               ow=ow)
+    in_specs = [
+        pl.BlockSpec((br, planes, hq, wq), lambda r: (r, 0, 0, 0)),
+        pl.BlockSpec((br, oh, ow), lambda r: (r, 0, 0)),
+    ]
 
     def run(*operands):
         with _enable_x64(False):
@@ -611,6 +590,7 @@ def _pool_bwd_jitted(pool_type, rows, planes, hq, wq, oh, ow, taps, dtype,
                     pltpu.VMEM((br, planes, hq, wq), jnp.float32)],
                 compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",)),
+                name="max_pool_bwd",
                 **({"interpret": interpret} if interpret is not None
                    else {}),
             )(*operands)
@@ -626,26 +606,10 @@ def max_pool_backward(xs, dy, x_shape, x_dtype, kernel, stride, pad,
     n, c = x_shape[:2]
     oh, ow = out_shape
     hq, wq, planes = _pool_geometry(kernel, stride, out_shape)
-    fn = _pool_bwd_jitted("max", n * c, planes, hq, wq, oh, ow,
+    fn = _pool_bwd_jitted(n * c, planes, hq, wq, oh, ow,
                           _pool_taps(kernel, stride),
                           str(jnp.dtype(x_dtype)), interpret)
     dxs = fn(xs, dy.reshape(n * c, oh, ow))
-    return _pool_s2d_inverse(dxs, x_shape, kernel, stride, pad, out_shape)
-
-
-def avg_pool_backward(dy, divisor, x_shape, x_dtype, kernel, stride, pad,
-                      out_shape, interpret=None):
-    """Input gradient of 2-D avg/sum pooling: ``divisor`` is the (OH, OW)
-    float32 map each cotangent is multiplied by — 1 for sum pooling,
-    1/prod(kernel) for avg, 1/valid-count under count_include_pad=False.
-    Never touches x."""
-    n, c = x_shape[:2]
-    oh, ow = out_shape
-    hq, wq, planes = _pool_geometry(kernel, stride, out_shape)
-    fn = _pool_bwd_jitted("avg", n * c, planes, hq, wq, oh, ow,
-                          _pool_taps(kernel, stride),
-                          str(jnp.dtype(x_dtype)), interpret)
-    dxs = fn(dy.reshape(n * c, oh, ow), divisor.astype(jnp.float32))
     return _pool_s2d_inverse(dxs, x_shape, kernel, stride, pad, out_shape)
 
 

@@ -22,7 +22,8 @@ import mxnet_tpu as mx
 from mxnet_tpu import executor_cache, serving
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops import quantize as quant
-from mxnet_tpu.ops.nn import _bn_train_core, _pool_core, _pooling
+from mxnet_tpu.ops.nn import (_bn_train_core, _pool_core, _pool_out_shape,
+                              _pooling)
 from mxnet_tpu.predict import Predictor
 
 
@@ -38,13 +39,39 @@ def _rng(seed=0):
 
 
 # ---------------------------------------------------------------------------
-# Pooling backward vs the XLA select-and-scatter oracle
+# Pooling backward: max = the Pallas kernel vs XLA's select-and-scatter;
+# avg/sum = XLA's own gradient (no kernel under any flag, PR 26) vs a
+# closed-form NumPy fan-out
 # ---------------------------------------------------------------------------
 
 def _pool_grad(mode, x, cfg):
     core = _pool_core(*cfg, mode)
     return jax.grad(
         lambda v: jnp.sum(core(v).astype(jnp.float32) ** 2))(x)
+
+
+def _np_fanout_grad(x, cfg):
+    """d/dx of sum(pool(x)^2) for avg/sum pooling, window by window in
+    float64: every valid tap of a window takes dy / divisor, the divisor
+    being 1 (sum), prod(kernel) (avg) or the window's count of non-padded
+    taps (avg, count_include_pad=False); 'full' windows clip past the
+    data."""
+    pool_type, kernel, stride, pad, convention, include_pad = cfg
+    x = np.asarray(x, np.float64)
+    h, w = x.shape[2:]
+    oh, ow = _pool_out_shape((h, w), kernel, stride, pad, convention)
+    dx = np.zeros_like(x)
+    for i in range(oh):
+        for j in range(ow):
+            h0, w0 = i * stride[0] - pad[0], j * stride[1] - pad[1]
+            hs = slice(max(h0, 0), min(h0 + kernel[0], h))
+            ws = slice(max(w0, 0), min(w0 + kernel[1], w))
+            valid = (hs.stop - hs.start) * (ws.stop - ws.start)
+            div = 1.0 if pool_type == "sum" else float(
+                np.prod(kernel) if include_pad else max(valid, 1))
+            out = x[:, :, hs, ws].sum((2, 3)) / div
+            dx[:, :, hs, ws] += (2.0 * out / div)[:, :, None, None]
+    return dx
 
 
 POOL_CASES = [
@@ -64,10 +91,44 @@ POOL_CASES = [
                          ids=["-".join(map(str, c)) for c in POOL_CASES])
 def test_pool_backward_matches_xla_oracle(case):
     x = jnp.asarray(_rng(1).randn(2, 3, 11, 13).astype(np.float32))
-    want = _pool_grad("off", x, case)       # XLA select-and-scatter path
-    got = _pool_grad(KERNEL_MODE, x, case)  # Pallas kernel path
+    got = _pool_grad(KERNEL_MODE, x, case)
+    if case[0] == "max":    # Pallas kernel vs XLA select-and-scatter
+        want = _pool_grad("off", x, case)
+    else:                   # XLA's reduce_window transpose vs closed form
+        want = _np_fanout_grad(x, case)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret", "pallas"])
+@pytest.mark.parametrize("pool_type", ["avg", "sum"])
+def test_avg_sum_pool_core_is_plain_xla(pool_type, mode):
+    """avg/sum pooling never wraps a custom_vjp, whatever the pool flag
+    resolves to: its gradient is XLA's transpose of reduce_window-add."""
+    core = _pool_core(pool_type, (3, 3), (2, 2), (1, 1), "valid", True, mode)
+    assert not hasattr(core, "defvjp")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_global_avg_pool_grad_is_broadcast(dtype, monkeypatch):
+    """The window covers the input: dx is dy / 49 on every tap, rounded
+    once — with the pool flag on, as the fused step on the chip has it."""
+    monkeypatch.setenv("MXNET_TPU_PALLAS_POOL", "1")
+    x = jnp.asarray(_rng(13).randn(2, 16, 7, 7)).astype(dtype)
+    dy = jnp.asarray(_rng(14).randn(2, 16, 1, 1)).astype(dtype)
+    out, vjp = jax.vjp(lambda v: _pooling(
+        v, pool_type="avg", kernel=(1, 1), global_pool=True), x)
+    assert out.shape == dy.shape and out.dtype == x.dtype
+    dx, = vjp(dy)
+    want = (np.asarray(dy, np.float32) / np.float32(49)).astype(dx.dtype)
+    assert dx.dtype == x.dtype
+    dx = np.asarray(dx)
+    assert np.array_equal(dx, np.broadcast_to(dx[:, :, :1, :1], x.shape))
+    if COMPILED:    # the chip's float32 divide is not correctly rounded
+        np.testing.assert_allclose(dx[:, :, :1, :1].astype(np.float32),
+                                   want.astype(np.float32), rtol=2e-7)
+    else:
+        assert np.array_equal(dx[:, :, :1, :1], want)
 
 
 def test_pool_backward_bf16():
@@ -215,8 +276,11 @@ def test_pool_backward_at_resnet50_shapes():
     from test_pallas_tpu_lowering import pool_configs
     for i, (shape, cfg) in enumerate(pool_configs()):
         x = jnp.asarray(_rng(i).randn(*shape), jnp.bfloat16)
-        want = _pool_grad("off", x, cfg).astype(jnp.float32)
         got = _pool_grad("pallas", x, cfg).astype(jnp.float32)
+        if cfg[0] == "max":
+            want = _pool_grad("off", x, cfg).astype(jnp.float32)
+        else:   # XLA on the chip vs the closed form
+            want = _np_fanout_grad(x, cfg)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-2, atol=2e-2, err_msg=str(shape))
 

@@ -131,6 +131,21 @@ def test_lowers_for_tpu(case):
     jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
 
 
+POOL_GRAD_CASES = [c for c in CASES if c[0].startswith("pool-")]
+
+
+@pytest.mark.parametrize("case", POOL_GRAD_CASES,
+                         ids=[c[0] for c in POOL_GRAD_CASES])
+def test_only_max_pooling_lowers_to_a_kernel(case):
+    """With the pool flag resolving to the compiled kernel, the gradient
+    at ResNet-50's max pool holds one Mosaic call and the one at its
+    global average pool holds none: that one is XLA's own (PR 26)."""
+    name, fn, avals = case
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == int(name.startswith("pool-max"))
+
+
 @pytest.fixture(scope="module")
 def v5e_device():
     """A compile-only v5e device: libtpu compiles for a named topology
