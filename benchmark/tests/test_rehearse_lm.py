@@ -1,0 +1,84 @@
+"""``qwen3next-train-s8k-b2`` rehearsed end to end at toy size on the CPU up
+to the result line, its fp8 control, and the four mistakes a share of an
+expert model invites, each of which has to come out as not correct."""
+import argparse
+import json
+
+import pytest
+
+from benchmark import harness, peaks, run
+from benchmark.references import lowprec, qwen3_next
+from benchmark.tests import toy_lm
+from benchmark.tools import calibrate_lm
+
+CELL = "toy-train-lm"
+
+
+@pytest.fixture(scope="module")
+def manifest(tmp_path_factory):
+    return toy_lm.make(tmp_path_factory.mktemp("toylm"))
+
+
+@pytest.fixture(autouse=True)
+def cpu_peak_row(monkeypatch):
+    monkeypatch.setitem(peaks.PEAKS, "cpu", dict(peaks.PEAKS["TPU v5 lite"]))
+
+
+def rehearse(manifest, trace=0, seed=2147483659):
+    args = argparse.Namespace(workload=CELL, seed=seed, seconds=1.0,
+                              trace=trace)
+    return run.run_cell(args, manifest_path=manifest, require_chip=False)
+
+
+@pytest.fixture(scope="module")
+def plain(manifest):
+    return rehearse(manifest)
+
+
+def test_end_to_end_line(plain, capsys):
+    result, checks, _ = plain
+    harness.emit(result, checks)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["metrics"]) == {"train_samples_per_s", "setup_s"}
+    assert set(line["compared"]) == set(toy_lm.LIMITS)
+
+
+def test_traced_line_reads_every_per_layer_metric(manifest, cpu_peak_row):
+    result, _, _ = rehearse(manifest, trace=1, seed=2500000001)
+    got = result["metrics"]
+    with open(manifest) as f:
+        want = {m["name"] for m in json.load(f)["per_layer"]}
+    assert want <= set(got), want - set(got)
+    assert got["fit_retraces_in_window"]["value"] == 0
+    # 4 layers, each half of a block (mixer, expert layer) a stage of its own
+    assert got["fit_recompute_blocks_per_step"]["value"] == 8
+    # 4 of 16 experts held: a quarter of the choices, give or take sampling
+    assert 15 < got["fit_moe_held_selection_share"]["value"] < 35
+    assert got["fit_moe_expert_load_max_over_mean"]["value"] >= 1
+    assert 0 < got["fit_step_mfu"]["value"]
+    assert result["correct"] is True
+
+
+def _instead_of_the_program(manifest, **how):
+    """The numbers compared when the reference, altered, stands where the
+    program stood, each beside its limit."""
+    numbers = calibrate_lm.readings(
+        harness.load_cell(CELL, manifest),
+        harness.find_chip(1, require_chip=False), 2147483659,
+        [("altered", how)])["altered"]
+    return {k: [numbers[k], v] for k, v in toy_lm.LIMITS.items()}
+
+
+def test_fp8_control_reads_above_the_program(manifest, plain):
+    control = _instead_of_the_program(
+        manifest, hooks=(lowprec.q_operand, lowprec.q_cotangent))
+    assert not harness.checks_ok(control), control
+    program = plain[1]
+    assert any(control[k][0] >= 3 * program[k][0] for k in toy_lm.LIMITS)
+
+
+@pytest.mark.parametrize("fault", qwen3_next.FAULTS)
+def test_a_planted_fault_is_not_correct(manifest, fault):
+    checks = _instead_of_the_program(manifest, fault=fault)
+    assert not harness.checks_ok(checks), checks

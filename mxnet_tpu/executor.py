@@ -30,6 +30,11 @@ from . import executor_cache
 from . import random as _random
 
 
+# MXNet's mirroring attribute (``MXNET_BACKWARD_DO_MIRROR``'s per-node form):
+# nodes that share a value of it are recomputed together in the backward pass
+MIRROR_STAGE = "__mirror_stage__"
+
+
 def _to_device(arr, dev):
     """Move `arr` to `dev` unless already there (single shared impl for
     every cross-device placement site in this file)."""
@@ -69,6 +74,7 @@ class _Program:
         # batch 0 — take their real shape from graph inference at bind
         # (the reference allocates by inferred shape via PlanMemory)
         self._shape_overrides = {}
+        self._unit_order = None
 
     def finalize_shapes(self, known_shapes):
         """Resolve 0-dim init-op shapes from inference given bound arg
@@ -97,10 +103,16 @@ class _Program:
 
     def evaluate(self, arg_map, aux_map, keys, train, tap=None):
         """Evaluate the graph given {name: jax.Array} maps.  Returns
-        (outputs, new_aux_map).  Pure — safe to jit/vjp."""
+        (outputs, new_aux_map).  Pure — safe to jit/vjp.
+
+        Nodes that carry one ``__mirror_stage__`` (MXNet's mirroring
+        attribute, set by a model's builder on the nodes of a block) are
+        evaluated together under ``jax.checkpoint``: a backward pass
+        keeps what enters the stage and recomputes the rest.  A graph
+        that sets no such attribute is evaluated node by node as ever."""
         env = {}
         new_aux = dict(aux_map)
-        key_iter = iter(keys)
+        key_of = dict(zip(self.rng_nodes, keys))
         for node in self.order:
             if node.is_var:
                 if node.name in arg_map:
@@ -109,7 +121,8 @@ class _Program:
                     env[(node, 0)] = aux_map[node.name]
                 else:
                     raise MXNetError("unbound variable %r" % node.name)
-                continue
+
+        def run(node, env, new_aux):
             op = get_op(node.op_name)
             attrs = op.normalize_attrs(node.attrs)
             if op.key_var_num_args and not attrs.get(op.key_var_num_args):
@@ -120,7 +133,7 @@ class _Program:
                 attrs["_train"] = train
             ins = [env[e] for e in node.inputs]
             if op.needs_rng:
-                ins = [next(key_iter)] + ins
+                ins = [key_of[node]] + ins
             out = op.impl(*ins, **attrs)
             if not isinstance(out, tuple):
                 out = (out,)
@@ -135,8 +148,94 @@ class _Program:
             if tap is not None:
                 for i in range(n_vis):
                     tap(node, i, out[i])
+
+        for unit in self._units():
+            if isinstance(unit, _Stage) and tap is None and train:
+                self._run_stage(unit, run, env, new_aux)
+            else:
+                for node in getattr(unit, "nodes", (unit,)):
+                    run(node, env, new_aux)
         outputs = [env[e] for e in self.entries]
         return outputs, new_aux
+
+    def _units(self):
+        """The op nodes in an order of evaluation, each mirror stage
+        gathered into one ``_Stage`` placed where its inputs are known."""
+        if self._unit_order is not None:
+            return self._unit_order
+        ops = [n for n in self.order if not n.is_var]
+        stage_of = {n: n.attrs.get(MIRROR_STAGE) for n in ops}
+        if not any(stage_of.values()):
+            self._unit_order = ops
+            return ops
+        stages = {}
+        for n in ops:
+            if stage_of[n]:
+                stages.setdefault(stage_of[n], _Stage(stage_of[n])) \
+                    .nodes.append(n)
+        unit_of = {n: stages[stage_of[n]] if stage_of[n] else n for n in ops}
+        units = list(dict.fromkeys(unit_of[n] for n in ops))
+        needs = {u: {unit_of[src] for n in getattr(u, "nodes", (u,))
+                     for src, _ in n.inputs if not src.is_var} - {u}
+                 for u in units}
+        order, done = [], set()
+        while len(order) < len(units):
+            ready = [u for u in units if u not in done and needs[u] <= done]
+            if not ready:
+                raise MXNetError(
+                    "mirror stages %s feed each other: a stage's nodes must "
+                    "need nothing that is computed from the stage itself"
+                    % sorted(s.name for s in units
+                             if isinstance(s, _Stage) and s not in done))
+            order.append(ready[0])
+            done.add(ready[0])
+        read_by = {}
+        for n in ops:
+            for e in n.inputs:
+                read_by.setdefault(e, []).append(n)
+        for stage in stages.values():
+            inside = set(stage.nodes)
+            stage.inputs = list(dict.fromkeys(
+                e for n in stage.nodes for e in n.inputs
+                if e[0] not in inside))
+            stage.outputs = [(n, i) for n in stage.nodes
+                             for i in range(n.num_outputs())
+                             if any(c not in inside
+                                    for c in read_by.get((n, i), ()))
+                             or (n, i) in self.entries]
+            stage.aux = [src.name for n in stage.nodes
+                         for in_idx in get_op(n.op_name).mutate_map
+                         for src in [n.inputs[in_idx][0]] if src.is_var]
+        self._unit_order = order
+        return order
+
+    @property
+    def mirror_stages(self):
+        """How many blocks a training pass of this graph recomputes."""
+        return sum(isinstance(u, _Stage) for u in self._units())
+
+    def _run_stage(self, stage, run, env, new_aux):
+        def body(ins):
+            local, aux = dict(zip(stage.inputs, ins)), {}
+            for name in stage.aux:
+                aux[name] = new_aux[name]
+            for node in stage.nodes:
+                run(node, local, aux)
+            return [local[e] for e in stage.outputs], aux
+
+        outs, aux = jax.checkpoint(body)([env[e] for e in stage.inputs])
+        env.update(zip(stage.outputs, outs))
+        new_aux.update(aux)
+
+
+class _Stage:
+    """The nodes of one ``__mirror_stage__``, in the graph's order, with
+    the entries that enter it and those that leave it."""
+
+    def __init__(self, name):
+        self.name = name
+        self.nodes = []
+        self.inputs = self.outputs = self.aux = None
 
 
 class Executor:
@@ -553,12 +652,19 @@ class Executor:
             req_of = dict(zip(arg_names, grad_req))
         else:
             req_of = {n: grad_req.get(n, "null") for n in arg_names}
+        # a gradient is stored by rebinding its handle, never written in
+        # place, so until then the zeros of one shape are ONE buffer (a
+        # half-billion-parameter model binds a gigabyte of zeros otherwise)
+        zero_grads = {}
         for name, shape, dt in zip(arg_names, arg_shapes, arg_types):
             dt = np_dtype(type_dict.get(name, dt or np.float32))
             a_ctx = ctx_of.get(name, ctx)
             arg_dict[name] = nd_zeros(shape, a_ctx, dtype=dt)
             if req_of.get(name, "null") != "null":
-                grad_dict[name] = nd_zeros(shape, a_ctx, dtype=dt)
+                key = (tuple(shape), str(dt), a_ctx)
+                if key not in zero_grads:
+                    zero_grads[key] = nd_zeros(shape, a_ctx, dtype=dt)
+                grad_dict[name] = NDArray(zero_grads[key]._h.array)
         for name, shape, dt in zip(aux_names, aux_shapes, aux_types):
             dt = np_dtype(type_dict.get(name, dt or np.float32))
             aux_dict[name] = nd_zeros(shape, ctx_of.get(name, ctx), dtype=dt)

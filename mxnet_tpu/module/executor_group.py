@@ -15,6 +15,7 @@ from ..base import MXNetError
 from ..io import DataDesc
 from ..ndarray import NDArray, zeros as nd_zeros, array, concatenate
 from ..executor import Executor
+from ..observability import instrument as _instrument
 
 
 def _split_input_slice(batch_size, work_load_list):
@@ -74,6 +75,7 @@ class DataParallelExecutorGroup:
         self.arg_names = symbol.list_arguments()
         self.aux_names = symbol.list_auxiliary_states()
         self.symbol = symbol
+        self._moe_counts = None
         self.contexts = contexts
         self.workload = workload or [1] * len(contexts)
         self.for_training = for_training
@@ -365,7 +367,20 @@ class DataParallelExecutorGroup:
                     out_grads_slice.append(grad.copyto(self.contexts[i]))
             exc.backward(out_grads=out_grads_slice if out_grads_slice else None)
 
+    def _moe_count_outputs(self):
+        """{output index: (first expert, experts held)} of the outputs a
+        model marks as its routing counts (``__moe_counts__`` on the head
+        node): they go to the counters, not to the metric."""
+        if self._moe_counts is None:
+            self._moe_counts = {
+                i: tuple(int(v) for v in
+                         node.attrs["__moe_counts__"].split(","))
+                for i, (node, _) in enumerate(self.symbol._entries)
+                if "__moe_counts__" in node.attrs}
+        return self._moe_counts
+
     def update_metric(self, eval_metric, labels):
+        counts = self._moe_count_outputs()
         for texec, islice in zip(self.execs, self.slices):
             labels_slice = []
             for label, axis in zip(labels, self.label_layouts or [0] * len(labels)):
@@ -376,7 +391,14 @@ class DataParallelExecutorGroup:
                     labels_slice.append(label)
                 else:
                     labels_slice.append(label)
-            eval_metric.update(labels_slice, texec.outputs)
+            if not counts:
+                eval_metric.update(labels_slice, texec.outputs)
+                continue
+            eval_metric.update(labels_slice, [
+                o for i, o in enumerate(texec.outputs) if i not in counts])
+            for i, (first, held) in counts.items():
+                _instrument.note_moe_counts(texec.outputs[i].asnumpy(),
+                                            first, held)
 
     def install_monitor(self, mon):
         for exe in self.execs:

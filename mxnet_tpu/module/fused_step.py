@@ -321,7 +321,7 @@ class FusedTrainStep:
                 return _step_body(*args)
 
         def _step_body(masters, other_vals, states, aux_vals, residuals,
-                       keys, lrs, wds, extras, opt_key):
+                       keys, lrs, wds, extras, opt_key, stored=None):
             # body runs only when jax (re)traces: counts real recompiles
             # of the fused step alongside the executor-cache counters
             _exec_cache.note_trace("fused_step", memprof_label,
@@ -340,8 +340,19 @@ class FusedTrainStep:
             # into the update's elementwise epilogue.  The update math is
             # value-identical: cast-then-update(f32) == the old
             # update(cast_vjp(g)) — the master path remains f32.
-            pvals = [m.astype(param_dtypes[j]) if mixed[j] else m
-                     for j, m in enumerate(masters)]
+            if stored is None:
+                pvals = [m.astype(param_dtypes[j]) if mixed[j] else m
+                         for j, m in enumerate(masters)]
+            else:
+                # the executor's storage-dtype copies of the mixed
+                # parameters ARE the masters cast: the last step wrote
+                # them (and _refresh re-derives a master wherever someone
+                # else replaced a copy).  Read, not recast, and donated,
+                # they become this step's copies in place: one copy a
+                # parameter instead of three at the peak
+                stored = iter(stored)
+                pvals = [next(stored) if mixed[j] else m
+                         for j, m in enumerate(masters)]
 
             if comm_plan is None:
                 def f(pv):
@@ -454,6 +465,10 @@ class FusedTrainStep:
         # compression residuals (4 — zero-length when not compressing)
         donate_idx = (0, 2, 4) if donate else ()
         self._last_abstract = None
+        # single device: the executor's storage-dtype copies ride along
+        # (argument 10, ``stored``) and are donated with the rest
+        self._spare_idx = [j for j in range(n_params) if mixed[j]] \
+            if donate and self.n_dev == 1 else []
 
         # persistent disk tier (program_cache.py): the step has no
         # executor-cache signature, so its key material is assembled
@@ -516,7 +531,9 @@ class FusedTrainStep:
             return _dispatch
 
         if self.n_dev == 1:
-            self._step_jit = jax.jit(_step, donate_argnums=donate_idx)
+            self._step_jit = jax.jit(
+                _step, donate_argnums=donate_idx
+                + ((10,) if self._spare_idx else ()))
             self._step = _wrap_step(self._step_jit)
             # identity of the arrays we last wrote into exec's dicts; a
             # mismatch means set_params/init_params replaced them and the
@@ -705,6 +722,9 @@ class FusedTrainStep:
             keys = tuple(_random.next_key() for _ in range(exe._n_keys))
             args = (self._masters, other_vals, self.states, aux_vals,
                     self._residuals, keys, lrs, wds, extras, opt_key)
+            if self._spare_idx:
+                args += ([exe.arg_dict[self.param_names[j]]._h.array
+                          for j in self._spare_idx],)
             self._note_abstract(args)
         res = self._dispatch(args, loaded, "fused_step")
 
@@ -767,6 +787,7 @@ class FusedTrainStep:
                 _memprof.maybe_record_oom(oom_context, exc)
                 raise
             ph.watch(res[0][:1] or res[1][:1], uploads)
+        _instrument.note_recompute_blocks(self.prog.mirror_stages)
         return res
 
     def _keep(self, res):
@@ -1025,9 +1046,13 @@ class FusedTrainStep:
                         .astype(self.master_dtypes[j]))
                     # pin: the restored f32 master is authoritative — the
                     # next run()'s staleness check must not re-derive it
-                    # from the half-width exec value
-                    self._scattered[n] = \
-                        self.module._exec_group.execs[0].arg_dict[n]._h.array
+                    # from the half-width exec value, and the step reads
+                    # the exec value: make it the master's cast
+                    handle = self.module._exec_group.execs[0].arg_dict[n]._h
+                    if self._spare_idx:
+                        handle.array = self._masters[j].astype(
+                            self.param_dtypes[j])
+                    self._scattered[n] = handle.array
             else:  # fused_v1: bare SGD momentum array
                 st = v
             cur_leaves = _state_leaves(self.states[j])

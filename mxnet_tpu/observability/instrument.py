@@ -833,3 +833,32 @@ def payload_nbytes(value):
             itemsize = 4
         total += n * itemsize
     return total
+
+
+# -- sparse experts and recomputation (models/qwen3_next.py) -------------------
+
+def note_recompute_blocks(blocks):
+    """``module.recompute.blocks``: the mirror stages (blocks evaluated
+    under ``jax.checkpoint``) of the step program just dispatched."""
+    if blocks:
+        telemetry.counter(
+            "module.recompute.blocks",
+            help="blocks the step programs recompute in their backward "
+                 "pass").inc(blocks)
+
+
+def note_moe_counts(counts, first_expert, experts_held):
+    """One step's routing, from the per-expert selection counts the step
+    program hands out ([layers, experts] or [experts]; read where the loss
+    is read, so no sync is added): ``module.moe.selections_total`` /
+    ``_held`` (all top-k choices, and those that fell on the experts held
+    here), ``module.moe.expert_load_max`` / ``_mean`` (tokens on the
+    busiest held expert and on the average one, summed over layers)."""
+    counts = np.asarray(counts, np.float64).reshape(-1, counts.shape[-1])
+    held = counts[:, first_expert:first_expert + experts_held]
+    for name, value in (("selections_total", counts.sum()),
+                        ("selections_held", held.sum()),
+                        ("expert_load_max", held.max(axis=1).sum()),
+                        ("expert_load_mean", held.mean(axis=1).sum())):
+        telemetry.counter("module.moe." + name).inc(float(value))
+

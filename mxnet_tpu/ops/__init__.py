@@ -7,6 +7,7 @@ from .registry import (  # noqa: F401
 from . import tensor  # noqa: F401
 from . import nn  # noqa: F401
 from . import attention  # noqa: F401
+from . import lm_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import rnn_op  # noqa: F401

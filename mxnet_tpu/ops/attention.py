@@ -24,6 +24,7 @@ off-path bitwise).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..observability import health as _health
@@ -52,8 +53,10 @@ def _sdpa(query, key, value, *rest, causal=False, scale=0.0,
           use_lengths=False):
     kv_lens = rest[0] if use_lengths else None
     _note_logit_bound(query, key, scale)
-    return _pk.attention(query, key, value, causal=causal,
-                         scale=(scale if scale else None), kv_lens=kv_lens)
+    with jax.named_scope("mx:attn"):
+        return _pk.attention(query, key, value, causal=causal,
+                             scale=(scale if scale else None),
+                             kv_lens=kv_lens)
 
 
 def _sdpa_infer_shape(in_shapes, attrs, out_shapes=None):

@@ -1,0 +1,467 @@
+"""Graph ops of the modern decoder block (ROADMAP M1, M3, M6): zero-centred
+RMSNorm, partial rotary positions, SwiGLU, causal depth-wise conv1d, the
+gated delta rule as a chunked scan, a dropless top-k expert layer that is
+told which experts it holds, and a per-sequence softmax cross-entropy.
+
+Each is a pure JAX function registered like every other op, so a model is
+ONE ``Symbol`` that ``Module.fit`` trains through the fused step
+(docs/qwen3_next.md).  Activations come in the storage dtype (bfloat16 in
+mixed precision); norms, the router's softmax, the decay and the loss are
+computed in float32 inside the ops and cast back.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .registry import register, pBool, pFloat, pInt, pStr
+
+_F32 = jnp.float32
+
+
+# -- RMSNorm ------------------------------------------------------------------
+
+def _rms_norm(data, gamma, eps=1e-6, zero_centered=True):
+    """``x / rms(x) * (1 + w)`` over the last axis (``w`` alone when not
+    zero-centred), in float32."""
+    x = data.astype(_F32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                      + _F32(eps))
+    w = gamma.astype(_F32)
+    return (y * (1.0 + w if zero_centered else w)).astype(data.dtype)
+
+
+def _last_dim_param_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    if in_shapes[0] is None:
+        return filled, [None]
+    filled[1] = (int(in_shapes[0][-1]),)
+    return filled, [tuple(in_shapes[0])]
+
+
+register("RMSNorm", _rms_norm, input_names=("data", "gamma"),
+         infer_shape=_last_dim_param_shape,
+         params={"eps": (pFloat, 1e-6), "zero_centered": (pBool, True)})
+
+
+# -- rotary positions -----------------------------------------------------------
+
+def _rotary_embedding(data, rotary_dim=0, base=10000.0):
+    """Rotate-half rotary positions on the first ``rotary_dim`` dims of
+    every head of ``[batch, seq, heads, head_dim]`` (all of them when 0);
+    the position of a row is its index along ``seq``."""
+    d = int(data.shape[-1])
+    rd = int(rotary_dim) or d
+    half = rd // 2
+    # graftlint: disable=GL003 — the angles depend on shapes and attributes
+    # alone: a float64 table made at trace time, a constant of the program
+    inv_freq = 1.0 / (float(base) ** (np.arange(0, rd, 2, dtype=np.float64)
+                                      / rd))
+    # graftlint: disable=GL003 — as above
+    ang = np.arange(int(data.shape[1]), dtype=np.float64)[:, None] \
+        * inv_freq[None, :]
+    cos = jnp.asarray(np.cos(ang), _F32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(ang), _F32)[None, :, None, :]
+    x = data.astype(_F32)
+    x1, x2, rest = x[..., :half], x[..., half:rd], x[..., rd:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                          axis=-1)
+    return out.astype(data.dtype)
+
+
+register("rotary_embedding", _rotary_embedding, num_inputs=1,
+         params={"rotary_dim": (pInt, 0), "base": (pFloat, 10000.0)})
+
+
+# -- SwiGLU -------------------------------------------------------------------
+
+def _swiglu(gate, up):
+    """``SiLU(gate) * up``."""
+    return (jax.nn.silu(gate.astype(_F32)) * up.astype(_F32)).astype(up.dtype)
+
+
+register("SwiGLU", _swiglu, input_names=("gate", "up"))
+
+
+# -- causal depth-wise conv1d -------------------------------------------------
+
+def _causal_conv1d(data, weight, kernel=4, activation="silu"):
+    """``y[t, c] = sum_j w[c, j] x[t - (kernel-1) + j, c]`` on ``[batch, seq,
+    channels]`` (zeros before the sequence, no bias), then SiLU unless
+    ``activation`` is ``'none'``."""
+    k = int(kernel)
+    x = data.astype(_F32)
+    w = weight.astype(_F32)
+    s = int(x.shape[1])
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, j:j + s, :] * w[:, j] for j in range(k))
+    if activation == "silu":
+        y = jax.nn.silu(y)
+    return y.astype(data.dtype)
+
+
+def _conv1d_infer_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    if in_shapes[0] is None:
+        return filled, [None]
+    filled[1] = (int(in_shapes[0][-1]), int(attrs["kernel"]))
+    return filled, [tuple(in_shapes[0])]
+
+
+register("causal_conv1d", _causal_conv1d, input_names=("data", "weight"),
+         infer_shape=_conv1d_infer_shape,
+         params={"kernel": (pInt, 4), "activation": (pStr, "silu")})
+
+
+# -- the gated delta rule, chunk-wise --------------------------------------------
+#
+# Per value head with state S in R^{dk x dv}, S_0 = 0:
+#   S' = gamma_t S_{t-1};  S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T;
+#   o_t = S_t^T q_t.
+# Within a chunk that starts from state S: with G_t the running product of
+# gamma, u_t = beta_t (v_t - G_t S^T k_t - sum_{j<t} (G_t/G_j)(k_j.k_t) u_j),
+# i.e. (I + L) U = V_beta - diag(beta G) K S with L strictly lower.  With
+# T = (I + L)^-1:  U = T V_beta - M (K S),  M = T diag(beta G);
+# o = diag(G) (Q S) + tril(Q K^T * D) U;  S_next = G_C S + K^T diag(G_C/G) U.
+# What a chunk needs beside q, k and the state is thus per value head a
+# [c, dv] matrix, two [c, c] matrices and three vectors — kept in the
+# operands' dtype (bfloat16 in mixed precision, as the products read them);
+# the decay, the solve and the carried state are float32.
+
+def _l2norm(x, eps=1e-6):
+    return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                         + _F32(eps))
+
+
+def _mm32(a, b):
+    return jnp.matmul(a, b, preferred_element_type=_F32)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(n):
+    """``(I - n)^{-1}`` of a strictly lower triangular (nilpotent) ``n``
+    over its last two axes: ``(I + n)(I + n^2)(I + n^4)...`` — log2(C)
+    batched products in place of a row-by-row substitution."""
+    c = int(n.shape[-1])
+    mm = lambda a, b: jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+    eye = jnp.eye(c, dtype=n.dtype)
+    inv, power, reach = eye + n, n, 2
+    while reach < c:
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
+        reach *= 2
+    return inv
+
+
+def _unit_lower_inverse_bwd(inv, d_inv):
+    # d(I - n)^-1 = T dn T: the products' factors are not kept
+    mm = lambda a, b: jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+    t = jnp.swapaxes(inv, -1, -2)
+    return (mm(mm(t, d_inv), t),)
+
+
+_unit_lower_inverse.defvjp(lambda n: (_unit_lower_inverse(n),) * 2,
+                           _unit_lower_inverse_bwd)
+
+
+def _chunk_local(q, k, v, g, beta):
+    """What every chunk computes from its own tokens alone, all chunks at
+    once.  q, k: [b, hk, n, c, dk]; v: [b, hk, r, n, c, dv] (``r`` value
+    heads to a key head); g (log decay), beta: [b, hk, r, n, c] float32.
+    Returns (u, m, qk, grow, shrink, g_all) as the header above names
+    them."""
+    c, cd = int(q.shape[-2]), v.dtype
+    gc = jnp.cumsum(g, axis=-1)
+    # graftlint: disable=GL003 — static index grids for the triangular masks
+    rows = np.arange(c)[:, None]
+    # graftlint: disable=GL003 — as above
+    cols = np.arange(c)[None, :]
+    diff = gc[..., :, None] - gc[..., None, :]
+    decay = jnp.exp(jnp.where(rows >= cols, diff, -jnp.inf))   # i >= j
+    kk = jnp.einsum("bhnid,bhnjd->bhnij", k, k,
+                    preferred_element_type=_F32)[:, :, None]
+    inv = _unit_lower_inverse(-jnp.where(
+        rows > cols, kk * decay * beta[..., :, None], 0.0))
+    u = _mm32(inv.astype(cd), (v * beta[..., None].astype(cd)))
+    m = inv * (beta * jnp.exp(gc))[..., None, :]
+    qk = jnp.einsum("bhnid,bhnjd->bhnij", q, k,
+                    preferred_element_type=_F32)[:, :, None] * decay
+    g_last = gc[..., -1:]
+    return (u.astype(cd), m.astype(cd), qk.astype(cd), jnp.exp(gc),
+            jnp.exp(g_last - gc), jnp.exp(g_last[..., 0]))
+
+
+def _chunk_step(state, xs):
+    """One chunk given the float32 state [b, hk, r, dk, dv] it starts from:
+    (next state, outputs [b, hk, r, c, dv])."""
+    q, k, u, m, qk, grow, shrink, g_all = xs
+    cd = u.dtype
+    s = state.astype(cd)
+    v_new = u.astype(_F32) - _mm32(m, _mm32(k[:, :, None], s).astype(cd))
+    out = grow[..., None] * _mm32(q[:, :, None], s) \
+        + _mm32(qk, v_new.astype(cd))
+    nxt = state * g_all[..., None, None] + jnp.einsum(
+        "bhcd,bhrce->bhrde", k, (v_new * shrink[..., None]).astype(cd),
+        preferred_element_type=_F32)
+    return nxt, out.astype(cd)
+
+
+def _split(x, axis, n):
+    """Cut ``axis`` (the sequence) into ``n`` chunks."""
+    return x.reshape(x.shape[:axis] + (n, x.shape[axis] // n)
+                     + x.shape[axis + 1:])
+
+
+def _scan_inputs(q, k, v, g, beta, chunk):
+    """(per-chunk operands with the chunk axis leading, the vjp of the
+    chunk-local part)."""
+    n = int(q.shape[2]) // chunk
+    args = (_split(q, 2, n), _split(k, 2, n), _split(v, 3, n),
+            _split(g, 3, n), _split(beta, 3, n))
+    local, pull = jax.vjp(_chunk_local, *args)
+    lead = lambda x, axis: jnp.moveaxis(x, axis, 0)
+    xs = (lead(args[0], 2), lead(args[1], 2)) \
+        + tuple(lead(x, 3) for x in local)
+    return xs, pull
+
+
+def _gdr_forward(q, k, v, g, beta, chunk):
+    """q, k: [b, hk, t, dk]; v: [b, hk, r, t, dv]; g, beta: [b, hk, r, t];
+    t a multiple of ``chunk``.  Returns (outputs [b, hk, r, t, dv], the
+    state each chunk starts from [n, b, hk, r, dk, dv], kept in the
+    operands' dtype)."""
+    xs, _ = _scan_inputs(q, k, v, g, beta, chunk)
+
+    def body(state, x):
+        nxt, out = _chunk_step(state, x)
+        return nxt, (state.astype(v.dtype), out)
+
+    start = jnp.zeros(v.shape[:3] + (q.shape[-1], v.shape[-1]), _F32)
+    _, (states, outs) = lax.scan(body, start, xs)
+    outs = jnp.moveaxis(outs, 0, 3)
+    return outs.reshape(v.shape), states
+
+
+@functools.lru_cache(maxsize=None)
+def _make_gdr(chunk):
+    @jax.custom_vjp
+    def gdr(q, k, v, g, beta):
+        return _gdr_forward(q, k, v, g, beta, chunk)[0]
+
+    def fwd(q, k, v, g, beta):
+        out, states = _gdr_forward(q, k, v, g, beta, chunk)
+        return out, (q, k, v, g, beta, states)
+
+    def bwd(res, d_out):
+        """Recomputes every chunk: the local part again in one piece with
+        its pull-back, then the chunks in reverse, each from the state it
+        started from, carrying the state's cotangent."""
+        q, k, v, g, beta, states = res
+        n = int(q.shape[2]) // chunk
+        xs, pull = _scan_inputs(q, k, v, g, beta, chunk)
+        d_outs = jnp.moveaxis(_split(d_out, 3, n), 3, 0)
+
+        def body(d_state, item):
+            state, x, d_o = item
+            _, step_vjp = jax.vjp(_chunk_step, state.astype(_F32), x)
+            d_prev, d_x = step_vjp((d_state, d_o))
+            return d_prev, d_x
+
+        zero = jnp.zeros(states.shape[1:], _F32)
+        _, d_xs = lax.scan(body, zero, (states, xs, d_outs), reverse=True)
+        d_q, d_k = (jnp.moveaxis(x, 0, 2) for x in d_xs[:2])
+        grads = list(pull(tuple(jnp.moveaxis(x, 0, 3) for x in d_xs[2:])))
+        grads[0], grads[1] = grads[0] + d_q, grads[1] + d_k
+        return tuple(x.reshape(r.shape) for x, r in
+                     zip(grads, (q, k, v, g, beta)))
+
+    gdr.defvjp(fwd, bwd)
+    return gdr
+
+
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
+    """The recurrence above for q, k [batch, key heads, seq, dk], v [batch,
+    key heads, r, seq, dv] (``r`` value heads share a key head) and float32
+    g (the log of the decay), beta [batch, key heads, r, seq], computed
+    chunk by chunk; any ``seq``: a tail chunk is padded with tokens that
+    leave the state as it is (beta 0, decay 1)."""
+    t = int(q.shape[2])
+    pad = (-t) % chunk
+    if pad:
+        at = lambda x, axis: jnp.pad(
+            x, [(0, pad if i == axis else 0) for i in range(x.ndim)])
+        q, k, v, g, beta = (at(q, 2), at(k, 2), at(v, 3), at(g, 3),
+                            at(beta, 3))
+    return _make_gdr(chunk)(q, k, v, g, beta)[:, :, :, :t]
+
+
+def _gated_delta_rule(query, key, value, a, b, A_log, dt_bias, chunk=64):
+    """Gated DeltaNet's token mixer on ``query``/``key`` [batch, seq, key
+    heads, dk], ``value`` [batch, seq, value heads, dv], ``a``/``b`` [batch,
+    seq, value heads]: q and k are L2-normalised over the head (q also
+    scaled by dk^-1/2), key head ``j`` serves value heads ``j*r .. (j+1)*r``,
+    ``beta = sigmoid(b)`` and the decay is ``exp(-exp(A_log) softplus(a +
+    dt_bias))``, in float32; the products read ``value``'s dtype."""
+    with jax.named_scope("mx:gdn"):
+        cd = value.dtype
+        bsz, t, hk, dk = query.shape
+        hv, dv = int(value.shape[2]), int(value.shape[3])
+        r = hv // hk
+        heads_first = lambda x: jnp.swapaxes(x, 1, 2)
+        q = heads_first(_l2norm(query.astype(_F32)) * _F32(dk ** -0.5))
+        k = heads_first(_l2norm(key.astype(_F32)))
+        grouped = lambda x: heads_first(x).reshape(
+            (bsz, hk, r, t) + x.shape[3:])
+        g = -jnp.exp(A_log.astype(_F32)) \
+            * jax.nn.softplus(a.astype(_F32) + dt_bias.astype(_F32))
+        out = chunked_gated_delta_rule(
+            q.astype(cd), k.astype(cd), grouped(value), grouped(g),
+            grouped(jax.nn.sigmoid(b.astype(_F32))), int(chunk))
+        return heads_first(out.reshape(bsz, hv, t, dv)).astype(cd)
+
+
+def _gdr_infer_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    v = in_shapes[2]
+    if v is None:
+        return filled, [None]
+    filled[3] = filled[4] = tuple(v[:3])
+    filled[5] = filled[6] = (int(v[2]),)
+    return filled, [tuple(v)]
+
+
+register("gated_delta_rule", _gated_delta_rule,
+         input_names=("query", "key", "value", "a", "b", "A_log", "dt_bias"),
+         infer_shape=_gdr_infer_shape, params={"chunk": (pInt, 64)})
+
+
+# -- dropless top-k experts, this chip's share ---------------------------------
+
+def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
+                 num_experts=1, num_hidden=0, experts_held=0, first_expert=0,
+                 top_k=1, norm_topk_prob=True):
+    """``sum_{e in top-k and held} p_e F_e(x)`` for tokens ``data`` [n, h],
+    ``F_e(x) = (SiLU(x W_gate,e) * x W_up,e) W_down,e``.
+
+    The router (``router_weight`` [num_experts, h]) scores ALL experts in
+    float32 and the top-k weights are renormalised over the k chosen
+    wherever those live; this op holds experts ``first_expert ..
+    first_expert + experts_held`` (weights [held, h, num_hidden] twice and
+    [held, num_hidden, h]) and computes their part alone — the rest is other
+    chips'.  Dropless: every (token, held expert) choice is computed, by
+    one grouped product over the choices sorted by expert.  Second output:
+    how many tokens chose each of the ``num_experts`` (no gradient)."""
+    with jax.named_scope("mx:moe"):
+        n, h = data.shape
+        held = int(experts_held) or int(num_experts)
+        k = int(top_k)
+        logits = jnp.matmul(data.astype(_F32), router_weight.astype(_F32).T)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = lax.top_k(probs, k)
+        if norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        chosen = lax.stop_gradient(top_e).reshape(-1)
+        counts = jnp.zeros((int(num_experts),), _F32).at[chosen].add(1.0)
+        local = chosen - int(first_expert)
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)       # the others sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        # the rows past the held groups are other chips': the grouped
+        # product leaves them UNWRITTEN on the TPU, in the backward pass
+        # too, so each of its operands and its result is selected, never
+        # scaled, to nought there (PERF.md, PR 27)
+        live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+        mine_only = lambda x: jnp.where(live, x, jnp.zeros((), x.dtype))
+        rows = mine_only(data[order // k])         # [n*k, h], by expert
+        mid = mine_only(_swiglu(lax.ragged_dot(rows, gate_weight, sizes),
+                                lax.ragged_dot(rows, up_weight, sizes)))
+        weight = jnp.where(mine, top_p.reshape(-1), 0.0)[order]
+        out = mine_only(lax.ragged_dot(mid, down_weight, sizes).astype(_F32)
+                        * weight[:, None]).astype(data.dtype)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype))
+        y = jnp.sum(out[back].reshape(n, k, h).astype(_F32), axis=1)
+        return y.astype(data.dtype), lax.stop_gradient(counts)
+
+
+def _moe_infer_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    x = in_shapes[0]
+    if x is None:
+        return filled, [None, None]
+    h, e, i = int(x[-1]), int(attrs["num_experts"]), int(attrs["num_hidden"])
+    held = int(attrs.get("experts_held") or 0) or e
+    filled[1] = (e, h)
+    filled[2] = filled[3] = (held, h, i)
+    filled[4] = (held, i, h)
+    return filled, [tuple(x), (e,)]
+
+
+register("moe_experts", _moe_experts, num_outputs=2,
+         input_names=("data", "router_weight", "gate_weight", "up_weight",
+                      "down_weight"),
+         infer_shape=_moe_infer_shape,
+         params={"num_experts": (pInt, 1), "num_hidden": (pInt, 0),
+                 "experts_held": (pInt, 0),
+                 "first_expert": (pInt, 0), "top_k": (pInt, 1),
+                 "norm_topk_prob": (pBool, True)})
+
+
+# -- softmax cross-entropy, one number a sequence ---------------------------------
+
+@jax.custom_vjp
+def _seq_ce(logits, label):
+    return _seq_ce_fwd(logits, label)[0]
+
+
+def _seq_ce_fwd(logits, label):
+    x = logits.astype(_F32)
+    idx = label.astype(jnp.int32)
+    lse = jax.nn.logsumexp(x, axis=-1)
+    picked = jnp.take_along_axis(x, idx[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - picked, axis=-1), (logits, label, lse)
+
+
+def _seq_ce_bwd(res, g):
+    """softmax minus one-hot, straight into the logits' dtype: no float32
+    copy of the probabilities is kept between the passes."""
+    logits, label, lse = res
+    idx = label.astype(jnp.int32)
+    scale = (g / logits.shape[-2]).astype(_F32)[..., None, None]
+    p = jnp.exp(logits.astype(_F32) - lse[..., None])
+    hot = idx[..., None] == jnp.arange(logits.shape[-1], dtype=jnp.int32)
+    d = ((p - hot.astype(_F32)) * scale).astype(logits.dtype)
+    if jnp.issubdtype(label.dtype, jnp.floating):
+        return d, jnp.zeros_like(label)
+    # graftlint: disable=GL003 — float0 is numpy's alone: an integer label's
+    # cotangent, never on the device
+    return d, np.zeros(label.shape, jax.dtypes.float0)
+
+
+_seq_ce.defvjp(_seq_ce_fwd, _seq_ce_bwd)
+
+
+def _sequence_cross_entropy(data, label):
+    """Mean over positions of ``-log softmax(data)[label]`` for ``data``
+    [batch, seq, vocab] and integer-valued ``label`` [batch, seq]: float32
+    [batch], whatever the logits' dtype."""
+    return _seq_ce(data, lax.stop_gradient(label))
+
+
+def _seq_ce_infer_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    d = in_shapes[0]
+    if d is None:
+        return filled, [None]
+    filled[1] = tuple(d[:-1])
+    return filled, [(int(d[0]),)]
+
+
+register("sequence_cross_entropy", _sequence_cross_entropy,
+         input_names=("data", "label"), infer_shape=_seq_ce_infer_shape,
+         infer_type=lambda in_dtypes, attrs: (list(in_dtypes), [np.float32]))

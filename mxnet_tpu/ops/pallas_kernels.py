@@ -32,6 +32,8 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None):
 
     ``kv_lens``: optional (B,) per-sequence valid KV length (the padding
     mask); keys at positions >= the length never receive weight."""
+    if q.shape[2] != k.shape[2]:
+        return _reference_attention_grouped(q, k, v, causal, scale, kv_lens)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     n_q, n_k = q.shape[1], k.shape[1]
     if causal:
@@ -43,6 +45,23 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None):
         s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None):
+    """The same for grouped-query attention: ``q`` has a multiple of the
+    K/V heads, and K/V head ``j`` serves query heads ``j*group ..
+    (j+1)*group`` — by index, no head is repeated in memory."""
+    b, n_q, h, d = q.shape
+    n_k, kv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, n_q, kv, h // kv, d)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((n_q, n_k), bool)), s, _NEG_INF)
+    if kv_lens is not None:
+        valid = jnp.arange(n_k)[None, :] < kv_lens.astype(jnp.int32)[:, None]
+        s = jnp.where(valid[:, None, None, None, :], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, n_q, h, d)
 
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
@@ -148,7 +167,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     otherwise.
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, kv = k.shape[1], k.shape[2]
+    if h % kv:
+        raise ValueError("flash_attention: %d query heads are no multiple "
+                         "of %d K/V heads" % (h, kv))
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
@@ -166,8 +188,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
 
     # layout: fold heads into batch, [BH, S, D]; pad to block multiples
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
     if sq_p != sq:
         qf = jnp.pad(qf, ((0, 0), (0, sq_p - sq), (0, 0)))
     if sk_p != sk:
@@ -189,7 +211,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     out = _flash_vjp_wrapped(qf, kf, vf, lens,
                              (b, h, sq_p, sk_p, d, str(jnp.dtype(q.dtype)),
                               causal, float(scale), bq, bk,
-                              interpret))
+                              interpret) + ((h // kv,) if h != kv else ()))
     out = out.reshape(b, h, sq_p, d)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
@@ -211,9 +233,10 @@ def _flash_vjp_fwd(qf, kf, vf, lens, meta):
 
 
 def _flash_vjp_bwd(meta, res, d_out):
-    b, h, sq, sk, d, dtype, causal, scale, block_q, block_k, interpret = meta
+    b, h, sq, sk, d, dtype, causal, scale, block_q, block_k, interpret = \
+        meta[:11]
     qf, kf, vf, lens, out, lse = res
-    fn = _flash_bwd_jitted(sq, sk, causal, scale, min(block_q, sq))
+    fn = _flash_bwd_jitted(sq, sk, causal, scale, min(block_q, sq), *meta[11:])
     dq, dk, dv = fn(qf, kf, vf, lens, out, lse, d_out)
     return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype),
             jnp.zeros_like(lens))
@@ -223,7 +246,11 @@ _flash_vjp_wrapped.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @functools.lru_cache(maxsize=512)
-def _flash_bwd_jitted(sq, sk, causal, scale, block_q):
+def _flash_bwd_jitted(sq, sk, causal, scale, block_q, group=1):
+    if group != 1:
+        return jax.jit(functools.partial(
+            _flash_bwd_grouped, sq=sq, sk=sk, causal=causal, scale=scale,
+            block_q=block_q, group=group))
     n_q = sq // block_q
 
     def bwd(qf, kf, vf, lens, out, lse, d_out):
@@ -287,9 +314,49 @@ def _flash_bwd_jitted(sq, sk, causal, scale, block_q):
     return jax.jit(bwd)
 
 
+def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
+                       scale, block_q, group):
+    """The blockwise backward for grouped-query attention: ``qf`` [B*H, Sq,
+    D] against ``kf``/``vf`` [B*KV, Sk, D].  The query side is viewed as
+    [B*KV, group, ...]; dk and dv sum over the group inside the products."""
+    bkv, d = kf.shape[0], kf.shape[2]
+    view = lambda x: x.reshape((bkv, group) + x.shape[1:])
+    qg, og, dog, lseg = view(qf), view(out), view(d_out), view(lse)
+    D = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
+    kv_len = view(lens)[:, 0].astype(jnp.int32)                # [B*KV]
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def body(i, carry):
+        dq_acc, dk_acc, dv_acc = carry
+        s = i * block_q
+        take = lambda x: jax.lax.dynamic_slice_in_dim(x, s, block_q, 2)
+        qb, dob, lseb, Db = take(qg), take(dog), take(lseg), take(D)
+        sij = jnp.einsum("bgqd,bkd->bgqk", qb, kf, **f32) * scale
+        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, sk), 1)
+        valid = cols[None, None] < kv_len[:, None, None, None]
+        if causal:
+            rows = s + jax.lax.broadcasted_iota(jnp.int32, (block_q, sk), 0)
+            valid &= (rows >= cols)[None, None]
+        p = jnp.where(valid, jnp.exp(jnp.where(valid, sij, _NEG_INF)
+                                     - lseb[..., None]), 0.0)
+        dp = jnp.einsum("bgqd,bkd->bgqk", dob, vf, **f32)
+        ds = p * (dp - Db[..., None])
+        dqb = jnp.einsum("bgqk,bkd->bgqd", ds, kf, **f32) * scale
+        dk_acc = dk_acc + jnp.einsum("bgqk,bgqd->bkd", ds, qb, **f32) * scale
+        dv_acc = dv_acc + jnp.einsum("bgqk,bgqd->bkd", p, dob, **f32)
+        return (jax.lax.dynamic_update_slice_in_dim(dq_acc, dqb, s, 2),
+                dk_acc, dv_acc)
+
+    dq, dk, dv = jax.lax.fori_loop(
+        0, sq // block_q, body,
+        (jnp.zeros(qg.shape, jnp.float32), jnp.zeros(kf.shape, jnp.float32),
+         jnp.zeros(vf.shape, jnp.float32)))
+    return dq.reshape(qf.shape), dk, dv
+
+
 @functools.lru_cache(maxsize=512)
 def _flash_jitted(b, h, sq, sk, d, dtype, causal, scale, block_q, block_k,
-                  interpret, with_lse=False):
+                  interpret, group=1, with_lse=False):
     n_q = sq // block_q
     n_kv = sk // block_k
     kernel = functools.partial(
@@ -302,18 +369,22 @@ def _flash_jitted(b, h, sq, sk, d, dtype, causal, scale, block_q, block_k,
         with _enable_x64(False):
             return _call_flash(kernel, qf, kf, vf, lens, b, h, sq, d, n_q,
                                n_kv, block_q, block_k,
-                               jnp.dtype(dtype), interpret, with_lse)
+                               jnp.dtype(dtype), interpret, with_lse, group)
 
     return jax.jit(run)
 
 
 def _call_flash(kernel, qf, kf, vf, lens, b, h, sq, d, n_q, n_kv, block_q,
-                block_k, dtype, interpret, with_lse):
+                block_k, dtype, interpret, with_lse, group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     # index maps see the scalar-prefetch ref as a trailing argument
     q_map = lambda bh, qi, ki, lens: (bh, qi, 0)  # noqa: E731
     kv_map = lambda bh, qi, ki, lens: (bh, ki, 0)  # noqa: E731
+    if group != 1:
+        # grouped-query attention: folded query head bh = batch*h + head
+        # reads K/V row batch*kv + head // group, which is bh // group
+        kv_map = lambda bh, qi, ki, lens: (bh // group, ki, 0)  # noqa: E731
     out_specs = [pl.BlockSpec((1, block_q, d), q_map)]
     out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), dtype)]
     if with_lse:

@@ -110,6 +110,102 @@ def test_flash_grads_match_reference(case):
             err_msg="d%s diverged" % name, **tol)
 
 
+# grouped-query attention: more query heads than K/V heads, each K/V head
+# reached by index (never repeated in memory)
+GQA_CASES = [
+    # (dtype, causal, with_lens, seq, query heads, K/V heads)
+    (jnp.float32, True, False, 16, 4, 2),
+    (jnp.float32, False, True, 13, 6, 2),
+    (jnp.float32, True, True, 24, 4, 1),
+    (jnp.bfloat16, True, False, 16, 8, 2),
+]
+GQA_IDS = ["%s-%s%s-s%d-h%dkv%d" % (np.dtype(c[0]).name,
+                                    "causal" if c[1] else "full",
+                                    "-lens" if c[2] else "", c[3], c[4], c[5])
+           for c in GQA_CASES]
+
+
+def _gqa_inputs(case, seed):
+    dtype, causal, with_lens, seq, heads, kv = case
+    q, _, _ = _qkv(2, seq, heads, 128, dtype, seed=seed)
+    _, k, v = _qkv(2, seq, kv, 128, dtype, seed=seed + 10)
+    lens = jnp.asarray([seq, max(1, seq - 5)], jnp.int32) \
+        if with_lens else None
+    return q, k, v, lens
+
+
+def _repeated(fn, group):
+    """The oracle: equal-heads attention on K/V heads repeated by hand."""
+    return lambda q, k, v: fn(q, jnp.repeat(k, group, axis=2),
+                              jnp.repeat(v, group, axis=2))
+
+
+@pytest.mark.parametrize("case", GQA_CASES, ids=GQA_IDS)
+def test_gqa_forward_matches_repeated_heads(case):
+    dtype, causal, _, _, heads, kv = case
+    q, k, v, lens = _gqa_inputs(case, seed=4)
+    scale = 1.0 / 128 ** 0.5
+    want = _repeated(lambda q_, k_, v_: pk._reference_attention(
+        q_, k_, v_, causal, scale, lens), heads // kv)(q, k, v)
+    for got in (pk._reference_attention(q, k, v, causal, scale, lens),
+                pk.flash_attention(q, k, v, causal=causal, use_pallas=True,
+                                   interpret=True, kv_lens=lens)):
+        assert got.dtype == q.dtype and got.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            **_tols(dtype))
+
+
+@pytest.mark.parametrize("case", GQA_CASES, ids=GQA_IDS)
+def test_gqa_grads_match_repeated_heads(case):
+    dtype, causal, _, _, heads, kv = case
+    q, k, v, lens = _gqa_inputs(case, seed=5)
+    scale = 1.0 / 128 ** 0.5
+    w = jnp.asarray(_rng(6).normal(0, 1, q.shape), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    want = grads(_repeated(lambda q_, k_, v_: pk._reference_attention(
+        q_, k_, v_, causal, scale, lens), heads // kv))
+    tol = {"rtol": 3e-2, "atol": 3e-2} if dtype == jnp.bfloat16 \
+        else {"rtol": 2e-4, "atol": 2e-4}
+    for fn in (lambda q_, k_, v_: pk._reference_attention(
+                   q_, k_, v_, causal, scale, lens),
+               lambda q_, k_, v_: pk.flash_attention(
+                   q_, k_, v_, causal=causal, use_pallas=True,
+                   interpret=True, kv_lens=lens)):
+        for g, r, name in zip(grads(fn), want, "qkv"):
+            assert g.dtype == r.dtype and g.shape == r.shape
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), np.asarray(r, np.float32),
+                err_msg="d%s diverged" % name, **tol)
+
+
+def test_equal_heads_program_is_what_it_was():
+    """With as many K/V heads as query heads nothing of the grouped path
+    is traced: no head index is divided, no group axis appears."""
+    q, k, v = _qkv(1, 16, 2, 128, seed=7)
+    text = jax.make_jaxpr(lambda *a: pk.flash_attention(
+        *a, causal=True, use_pallas=True, interpret=True))(q, k, v).pretty_print()
+    grouped = jax.make_jaxpr(lambda *a: pk.flash_attention(
+        *a, causal=True, use_pallas=True, interpret=True))(
+        q, k[:, :, :1], v[:, :, :1]).pretty_print()
+    assert text != grouped
+    assert pk._flash_jitted.__wrapped__.__defaults__[-2] == 1   # group
+    ref = jax.make_jaxpr(lambda *a: pk._reference_attention(
+        *a, True, 0.1))(q, k, v).pretty_print()
+    assert "bqhgd" not in ref and ref.count("dot_general") == 2
+
+
+def test_flash_refuses_heads_that_do_not_divide():
+    q, _, _ = _qkv(1, 16, 3, 128)
+    _, k, v = _qkv(1, 16, 2, 128)
+    with pytest.raises(ValueError, match="no multiple"):
+        pk.flash_attention(q, k, v, use_pallas=True, interpret=True)
+
+
 def test_attention_dispatch_falls_back_when_ineligible():
     """head_dim that is not lane-tiled (not a multiple of 128) must take
     the reference path bit-for-bit, whatever the flag says."""

@@ -191,6 +191,40 @@ def test_bf16_multi_precision_trains():
     assert fs._masters[j].dtype == np.float32
 
 
+def _bf16_run(monkeypatch, donate, tmp_path):
+    """Ten mixed-precision steps with a set_params and an optimizer-state
+    round trip in the middle; returns the masters' bytes."""
+    monkeypatch.setenv("MXNET_TPU_FUSED_DONATE", donate)
+    mx.random.seed(11)          # the initializer draws from the global key
+    mod, it = _bf16_mlp(True, seed=5)
+    fs = mod._fused_step
+    assert bool(fs._spare_idx) == (donate == "1")
+    it.reset()
+    for i, batch in enumerate(it):
+        mod.forward_backward(batch)
+        mod.update()
+        if i == 2:      # someone else replaces the stored copies
+            args, aux = mod.get_params()
+            mod.set_params({k: v * 0.5 for k, v in args.items()}, aux)
+        if i == 5:      # the restored float32 masters are authoritative
+            path = str(tmp_path / ("opt%s.states" % donate))
+            mod.save_optimizer_states(path)
+            mod.load_optimizer_states(path)
+    return [np.asarray(m).tobytes() for m in mod._fused_step._masters]
+
+
+def test_bf16_stored_copies_read_not_recast(monkeypatch, tmp_path):
+    """With donation on, a single-device step READS the executor's
+    storage-dtype copies of the mixed parameters (and hands them over to be
+    overwritten) where it otherwise casts the masters again: the same
+    numbers, bit for bit, through set_params and load_optimizer_states."""
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # cpu: donation is not implemented
+        on = _bf16_run(monkeypatch, "1", tmp_path)
+    assert on == _bf16_run(monkeypatch, "0", tmp_path)
+
+
 def test_bf16_consistency_with_f32():
     """check_consistency tier (ref fp16 pattern, SURVEY §4.2): the bf16
     net's forward agrees with the f32 net within bf16 tolerance."""

@@ -87,6 +87,20 @@ TESTED_ELSEWHERE = {
     "_contrib_bipartite_matching":
         "tests/test_operator.py (test_bipartite_matching)",
     "RNN": "tests/test_rnn.py",
+    # the decoder-block ops of ops/lm_ops.py: each against the plain
+    # reference (benchmark/references/qwen3_next.py), values and gradients
+    "RMSNorm": "tests/test_qwen3_next.py (test_rms_norm)",
+    "rotary_embedding":
+        "tests/test_qwen3_next.py (test_rotary_touches_the_first_dims_only)",
+    "SwiGLU": "tests/test_qwen3_next.py (the expert and DeltaNet layers)",
+    "causal_conv1d":
+        "tests/test_qwen3_next.py (test_causal_conv1d_sees_no_later_token)",
+    "gated_delta_rule":
+        "tests/test_qwen3_next.py (the chunked scan against the recurrence)",
+    "moe_experts": "tests/test_qwen3_next.py (the routed part, the shares)",
+    "sequence_cross_entropy":
+        "tests/test_qwen3_next.py "
+        "(test_sequence_cross_entropy_and_its_gradient)",
     "Custom": "tests/test_contrib_custom.py",
     "BatchNorm": "tests/test_module.py (train/eval aux semantics)",
     "Dropout": "tests/test_operator.py",
