@@ -14,11 +14,13 @@ import time
 import queue as _queue
 from collections import namedtuple
 
+import jax
 import numpy as np
 
 from . import threads as _threads
 from .base import MXNetError
 from .ndarray import NDArray, array
+from .ndarray.ndarray import host_view
 from .context import cpu
 from .observability.instrument import note_io_wait
 
@@ -358,7 +360,20 @@ def _init_data(data, allow_empty, default_name):
 
 
 class NDArrayIter(DataIter):
-    """Iterate over NDArray/numpy data (ref: io.py:541)."""
+    """Iterate over NDArray/numpy data (ref: io.py:541).
+
+    A batch that does not wrap around (``cursor + batch_size <=
+    num_data``) is handed out as a *view*: where a source lives in host
+    memory (numpy input, or an ``NDArray`` on the CPU backend) the
+    batch's arrays alias the source's rows, nothing is copied.  That is
+    safe under the MXNet surface because an ``NDArray`` never writes
+    through: ``__setitem__``, ``out=`` and the optimizers rebind the
+    handle to a new buffer, so a write to a batch leaves the source as
+    it was.  (The CPU backend takes a buffer as it is when it starts on
+    a 64-byte boundary and copies it otherwise: rows of a few bytes at
+    an odd offset.)  A source on an accelerator is sliced there, an h5py
+    dataset reads the window, and the wrap-around (``pad``) batch is
+    assembled as a copy."""
 
     def __init__(self, data, label=None, batch_size=1, shuffle=False,
                  last_batch_handle="pad", data_name="data",
@@ -424,10 +439,18 @@ class NDArrayIter(DataIter):
 
     @staticmethod
     def _rows(source, lo, hi):
-        """Slice [lo:hi) rows; h5py datasets read just that window."""
-        chunk = source[lo:hi]
-        return chunk if isinstance(chunk, NDArray) \
-            else array(np.asarray(chunk))
+        """Rows [lo:hi): a view of a source in host memory, a device
+        slice of one on an accelerator; h5py datasets read just that
+        window."""
+        if not isinstance(source, NDArray):
+            return array(np.asarray(source[lo:hi]))
+        host = host_view(source._h.array)
+        if host is None:
+            return source[lo:hi]
+        # the CPU backend takes an aligned numpy buffer as it is, and
+        # holds on to it (one that is not aligned it copies)
+        (dev,) = source._h.array.devices()
+        return NDArray(jax.device_put(host[lo:hi], dev), ctx=source._ctx)
 
     def _getdata(self, data_source):
         assert self.cursor < self.num_data, "DataIter needs reset."

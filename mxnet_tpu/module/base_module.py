@@ -307,9 +307,13 @@ class BaseModule:
         (data_wait / fwd_bwd_dispatch / update / metric / sync) as
         nested profiler spans + registry histograms — the per-step
         breakdown `tools/traceview.py` tabulates; what ``sync`` lumps
-        together is split into its ``sync:*`` phases.  Same lookahead
-        contract as before: the NEXT batch is fetched mid-step so its
-        host->device transfer (``prepare``) overlaps this step."""
+        together is split into its ``sync:*`` phases.  The lookahead:
+        once this step is dispatched the NEXT batch is fetched and
+        handed to ``prepare`` — ``Module.prepare`` starts its
+        host->device transfer there (``FusedTrainStep.stage``, phase
+        ``fused:stage``), so the copy runs under this step and the next
+        dispatch finds its inputs on the device.  The epoch's first
+        batch has no step to hide under and is loaded at dispatch."""
         tic = time.time()
         eval_metric.reset()
         tracker = StepTracker(epoch=epoch)

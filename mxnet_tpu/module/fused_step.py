@@ -39,6 +39,7 @@ from .. import program_cache as _program_cache
 from .. import random as _random
 from ..base import MXNetError
 from ..ndarray import NDArray
+from ..ndarray.ndarray import host_view
 from ..observability import health as _health
 from ..observability import instrument as _instrument
 from ..observability import memprof as _memprof
@@ -115,6 +116,8 @@ class FusedTrainStep:
         self.exe = module._exec_group.execs[0]
         self.opt = module._optimizer
         self.ran = False
+        # input name -> (the batch's array, its upload): see ``stage``
+        self._staged = {}
         exe = self.exe
         prog = exe._prog
         self.prog = prog
@@ -693,26 +696,13 @@ class FusedTrainStep:
             return
         exe = self.exe
 
-        # load batch into the bound input buffers (device upload + dtype
-        # cast; the batch usually arrives host-side from the data pipeline)
-        def _load(name, arr):
-            dst = exe.arg_dict[name]
-            src = arr._h.array
-            if src.dtype != dst._h.array.dtype:
-                src = src.astype(dst._h.array.dtype)
-            dev = list(dst._h.array.devices())[0]
-            if list(src.devices())[0] != dev:
-                src = jax.device_put(src, dev)
-            dst._h.array = src
-            return src
-
+        # the batch into the bound input buffers: staged a step ahead by
+        # ``stage``, or uploaded (and cast) now
         with _instrument.phase("fused:load"):
-            loaded = [_load(name, arr) for name, arr
-                      in zip(self.data_names, data_batch.data)]
-            if self.label_names and data_batch.label:
-                loaded += [_load(name, arr) for name, arr
-                           in zip(self.label_names, data_batch.label)
-                           if name in exe.arg_dict]
+            inputs = self._inputs(data_batch)
+            for name, arr in inputs.items():
+                exe.arg_dict[name]._h.array = arr
+            loaded = list(inputs.values())
 
         with _instrument.phase("fused:scalars"):
             lrs, wds, extras, opt_key = self._per_step_scalars()
@@ -737,6 +727,78 @@ class FusedTrainStep:
                 exe.aux_dict[n]._h.array = v
                 self._scattered[n] = v
             exe.outputs = [NDArray(o) for o in outs]
+
+    # -- the batch's way to the step's devices: one copy, the transfer ------
+
+    def _batch_inputs(self, data_batch):
+        """(name, array) of every input the step reads from a batch."""
+        pairs = list(zip(self.data_names, data_batch.data))
+        if self.label_names and data_batch.label:
+            pairs += zip(self.label_names, data_batch.label)
+        return [(n, a._h.array) for n, a in pairs if n in self.exe.arg_dict]
+
+    def _place(self, name, src):
+        """A batch's array as the step reads input ``name``: in the bound
+        dtype, on the bound buffer's device or split over the ``dp``
+        mesh — ``src`` itself where it is that already.  What lives in
+        host memory goes up from its numpy view: jax cuts a view, not a
+        copy, for each device, and the transfers are all that moves it
+        (an array on another accelerator is resharded device to
+        device)."""
+        bound = self.exe.arg_dict[name]._h.array
+        if src.dtype != bound.dtype:
+            src = src.astype(bound.dtype)
+        if self.n_dev > 1:
+            target = self._sh_dp
+            if src.sharding.is_equivalent_to(target, src.ndim):
+                return src
+        else:
+            target, = bound.devices()
+            if src.devices() == {target}:
+                return src
+        host = host_view(src)
+        return jax.device_put(src if host is None else host, target)
+
+    def stage(self, data_batch):
+        """Start the upload of the batch the next ``run`` will be handed
+        (``Module.prepare``, called by the fit loop once the step in
+        flight is dispatched, so the transfer runs under it): phase
+        ``fused:stage``.  One batch is held ahead, each upload beside
+        the array it was made from; ``run`` takes it when it is handed
+        that very array and drops it otherwise.  Nothing is staged of a
+        batch that is where the step reads it, or whose shapes are not
+        the bound ones (``forward_backward`` rebinds or retires the
+        step for such a batch)."""
+        with _instrument.phase("fused:stage"):
+            staged = {}
+            for name, src in self._batch_inputs(data_batch):
+                want = self._full_shape[name] if self.n_dev > 1 \
+                    else self.exe.arg_dict[name].shape
+                if tuple(src.shape) != tuple(want):
+                    staged = {}
+                    break
+                placed = self._place(name, src)
+                if placed is not src:
+                    staged[name] = (src, placed)
+            self._staged = staged
+
+    def _inputs(self, data_batch):
+        """name -> the batch's arrays as the step reads them: what
+        ``stage`` uploaded a step ahead where this is that batch, placed
+        now otherwise.  The stage is given up either way.  Counts the
+        step under ``module.input.staged`` or ``module.input.loaded``."""
+        staged, self._staged = self._staged, {}
+        inputs, ahead, late = {}, 0, 0
+        for name, src in self._batch_inputs(data_batch):
+            hit = staged.get(name)
+            if hit is not None and hit[0] is src:
+                inputs[name] = hit[1]
+                ahead += 1
+            else:
+                inputs[name] = self._place(name, src)
+                late += inputs[name] is not src
+        _instrument.note_step_input(staged=ahead > 0 and not late)
+        return inputs
 
     def _refresh(self):
         """Rebind after a reshape, and re-derive master state where
@@ -844,36 +906,21 @@ class FusedTrainStep:
         inserted by XLA from the shardings (replaces per-device executors
         + kvstore collective + per-device updater loop)."""
         exe = self.exe
-        batch_by_name = dict(zip(self.data_names, data_batch.data))
-        if self.label_names and data_batch.label:
-            batch_by_name.update(zip(self.label_names, data_batch.label))
-        uploads = []
-
-        def global_input(name, is_batch):
-            if is_batch and name in batch_by_name:
-                src = batch_by_name[name]._h.array
-                want = exe.arg_dict[name]._h.array.dtype
-                if src.dtype != want:
-                    src = src.astype(want)
-                # device_put reshards device arrays directly (no host hop)
-                uploads.append(jax.device_put(src, self._sh_dp))
-                return uploads[-1]
-            # non-batch graph input (fixed param, state): replicate the
-            # bound value
-            return jax.device_put(
-                np.asarray(exe.arg_dict[name]._h.array), self._sh_repl)
-
         with _instrument.phase("fused:load"):
-            other_vals = [global_input(n, b)
-                          for n, b in zip(self.other_names,
-                                          self._other_is_batch)]
+            inputs = self._inputs(data_batch)
+            # a non-batch graph input (fixed param, state) is the bound
+            # value, replicated
+            other_vals = [
+                inputs[n] if b and n in inputs else jax.device_put(
+                    np.asarray(exe.arg_dict[n]._h.array), self._sh_repl)
+                for n, b in zip(self.other_names, self._other_is_batch)]
         with _instrument.phase("fused:scalars"):
             lrs, wds, extras, opt_key = self._per_step_scalars()
             keys = tuple(_random.next_key() for _ in range(exe._n_keys))
             args = (self._masters, other_vals, self.states, self._gaux,
                     self._residuals, keys, lrs, wds, extras, opt_key)
             self._note_abstract(args)
-        res = self._dispatch(args, uploads, "fused_step_dp")
+        res = self._dispatch(args, list(inputs.values()), "fused_step_dp")
         if self._comm_plan is not None:
             # per-step wire accounting for the in-program collectives —
             # host-side, outside the traced body (the comm row in

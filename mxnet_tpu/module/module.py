@@ -609,4 +609,10 @@ class Module(BaseModule):
         return _health.combine(vecs, layout), layout
 
     def prepare(self, data_batch):
-        pass
+        """Start ``data_batch``, the one the next ``forward_backward``
+        will be handed, on its way to the fused step's devices while the
+        step in flight runs (``FusedTrainStep.stage``).  The general
+        path has nothing to do ahead of its dispatch."""
+        fused = getattr(self, "_fused_step", None)
+        if fused is not None:
+            fused.stage(data_batch)

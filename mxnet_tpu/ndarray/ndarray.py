@@ -430,6 +430,17 @@ def _wrap_array(arr, ctx=None):
     return NDArray(arr, ctx=ctx)
 
 
+def host_view(arr):
+    """The numpy view (zero-copy, read-only) of a jax array that lives
+    whole on one device of the CPU backend: host memory, which an
+    upload can read rows and shards of in place.  None for any other
+    array."""
+    devices = arr.devices()
+    if len(devices) != 1 or next(iter(devices)).platform != "cpu":
+        return None
+    return np.asarray(arr)
+
+
 # ---------------------------------------------------------------------------
 # Imperative dispatch (ref: MXImperativeInvokeEx -> Imperative::Invoke)
 # ---------------------------------------------------------------------------

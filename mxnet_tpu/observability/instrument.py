@@ -835,6 +835,20 @@ def payload_nbytes(value):
     return total
 
 
+# -- the fused step's input ----------------------------------------------------
+
+def note_step_input(staged):
+    """``module.input.staged``: the step took its batch from the upload
+    that ``Module.prepare`` started a step ahead (``fused:stage``);
+    ``module.input.loaded``: it placed the batch at dispatch
+    (``fused:load``) — the first step of an epoch, a loop that never
+    calls ``prepare``, a batch other than the staged one, or one that was
+    on the step's devices already."""
+    telemetry.counter(
+        "module.input.staged" if staged else "module.input.loaded",
+        help="fused steps by where their batch's upload began").inc()
+
+
 # -- sparse experts and recomputation (models/qwen3_next.py) -------------------
 
 def note_recompute_blocks(blocks):
