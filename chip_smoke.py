@@ -246,7 +246,7 @@ def train_phase(size, contexts, kvstore="local", clock=None):
         report.update(clock.since(mark))
 
     if len(contexts) > 1:
-        from mxnet_tpu.parallel import comm
+        from mxnet_tpu.module.fused_step import collective_counts
         execs = mod._exec_group.execs
         shard_devs = [next(iter(e.outputs[0]._h.array.devices()))
                       for e in execs]
@@ -254,7 +254,7 @@ def train_phase(size, contexts, kvstore="local", clock=None):
         assert {d.platform for d in shard_devs} == {platform}, shard_devs
         rows = [int(e.outputs[0].shape[0]) for e in execs]
         assert rows == [batch // len(contexts)] * len(contexts), rows
-        counts = comm.collective_counts(fused.compiled_hlo())
+        counts = collective_counts(fused.compiled_hlo())
         assert counts.get("all-reduce", 0) >= 1, counts
         report["shard_devices"] = [str(d) for d in shard_devs]
         report["collectives"] = counts

@@ -37,7 +37,7 @@ def get_symbol(network, num_layers, image_shape):
 
 def score(network, num_layers, dev, batch_size, image_shape="3,224,224",
           iters=20):
-    """Chained-fori_loop methodology (same as bench.py): iterations are
+    """Chained-fori_loop methodology: iterations are
     data-dependent, the window ends in a real host fetch, and the rate is
     the marginal between two window sizes — a timing that does not wait
     for the device measures the enqueue, not the work."""
@@ -83,16 +83,22 @@ def score(network, num_layers, dev, batch_size, image_shape="3,224,224",
     def run(n, *_args):
         return float(loop(n, arg_vals, aux_vals))  # real host fetch
 
-    # reuse the shared window-pair timing from bench.py (repo root is on
-    # sys.path above) so the two tools cannot drift methodologically
-    import bench as _bench
+    # marginal seconds per iteration between a small and a large window,
+    # median of paired marginals: the pair cancels the per-call cost
+    # (dispatch + fetch), the median its spikes in either direction
     iters = max(6, int(iters))
-    old_small, old_large = _bench.N_SMALL, _bench.N_LARGE
-    try:
-        _bench.N_SMALL, _bench.N_LARGE = max(2, iters // 5), iters
-        sec_per_iter = _bench._timed_windows(run, reps=5)
-    finally:
-        _bench.N_SMALL, _bench.N_LARGE = old_small, old_large
+    n_small = max(2, iters // 5)
+    run(2)  # warm (compile + caches)
+
+    def pair():
+        t0 = time.perf_counter()
+        run(n_small)
+        t1 = time.perf_counter()
+        run(iters)
+        t2 = time.perf_counter()
+        return ((t2 - t1) - (t1 - t0)) / (iters - n_small)
+
+    sec_per_iter = sorted(pair() for _ in range(5))[2]
     if sec_per_iter <= 0:
         raise RuntimeError(
             "non-positive marginal timing (%.3g s/iter): host too noisy "

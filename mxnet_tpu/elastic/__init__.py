@@ -7,20 +7,18 @@ checkpoint-based: preemption is the COMMON case at fleet scale, so the
 framework owns three pieces:
 
 - ``checkpoint.Checkpointer`` — atomic, sha256-manifested, last-K full
-  state snapshots (params, optimizer state *including the comm
-  error-feedback residuals*, data-iterator position, step counter,
-  flight-recorder lineage) on a step schedule
+  state snapshots (params, optimizer state, data-iterator position,
+  step counter, flight-recorder lineage) on a step schedule
   (``MXNET_TPU_CKPT_STEPS``), on health-monitor anomaly (black box
   first, then the snapshot), and on SIGTERM with a bounded-drain
   deadline;
 - ``resume.resume`` / ``resume.resume_fit`` — restore into a possibly
-  *re-factorized* mesh (surviving-worker count != original), warm-boot
-  compiled programs from the shared ``MXNET_TPU_PROGRAM_CACHE_DIR``
-  volume, and kick a fresh comm-bucket tuner pass for the new
-  factorization;
+  *re-factorized* mesh (surviving-worker count != original) and
+  warm-boot compiled programs from the shared
+  ``MXNET_TPU_PROGRAM_CACHE_DIR`` volume;
 - ``chaos`` — declarative fault plans (kill-at-step,
   checkpoint-corrupt, write-stall) that prove resumed runs match
-  uninterrupted ones (``bench.py --elastic-smoke``).
+  uninterrupted ones (``tests/test_elastic.py``).
 
 The epoch-granular legacy surface (``latest_checkpoint``,
 ``fit_elastic`` — resume-from-latest ``prefix-%04d.params``) lives on in

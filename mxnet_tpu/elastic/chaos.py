@@ -1,8 +1,8 @@
 """Deterministic fault injection for the elastic subsystem.
 
 A fault plan is declarative JSON — reviewable, replayable, env-shippable
-(``MXNET_TPU_CHAOS_PLAN``) — so the same plan drives a unit test, the
-8-device MULTICHIP dryrun harness, and ``bench.py --elastic-smoke``::
+(``MXNET_TPU_CHAOS_PLAN``) — so the same plan drives a unit test and a
+victim subprocess::
 
     [{"kind": "kill_at_step", "step": 22},
      {"kind": "corrupt_checkpoint", "at_step": 20},
@@ -103,8 +103,7 @@ class FaultPlan:
     @classmethod
     def from_env(cls):
         """The plan from ``MXNET_TPU_CHAOS_PLAN`` (None when unset) —
-        how ``bench.py --elastic-smoke`` ships a plan into its victim
-        subprocess."""
+        how a harness ships a plan into its victim subprocess."""
         raw = os.environ.get(PLAN_ENV, "").strip()
         return cls.from_json(raw) if raw else None
 
@@ -133,9 +132,8 @@ class FaultPlan:
 
 def corrupt_snapshot(snapshot_dir, artifact=PARAMS_FILE, nbytes=16):
     """Flip ``nbytes`` bytes at the middle of one snapshot artifact,
-    leaving the manifest untouched — the canonical injected corruption
-    (and the one ``bench.py --elastic-smoke``'s parent applies to the
-    newest snapshot between kill and resume).  Returns the path."""
+    leaving the manifest untouched — the canonical injected corruption.
+    Returns the path."""
     path = os.path.join(snapshot_dir, artifact)
     if artifact == MANIFEST_NAME:
         raise MXNetError("corrupt an artifact, not the manifest — a "

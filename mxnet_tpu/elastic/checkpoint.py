@@ -5,14 +5,13 @@ FULL training state:
 
 - ``params.ndarray`` — arg + aux params (``Module.save_params`` format);
 - ``optimizer.states`` — optimizer state via
-  ``Module.save_optimizer_states``, which on the fused path embeds the
-  PR 10 comm error-feedback residuals under ``__comm_residuals__``;
+  ``Module.save_optimizer_states``;
 - ``manifest.json`` — step/epoch/batch counters, the data-iterator
   position (the io_pipeline determinism root: a pure ``(seed, epoch,
   position)`` tuple reproduces the batch stream on resume), bound
   data/label shapes (so ``resume`` can bind without the iterator), the
-  comm signature and device count of the writing mesh, flight-recorder
-  lineage, and a sha256 + byte count per artifact.
+  device count of the writing mesh, flight-recorder lineage, and a
+  sha256 + byte count per artifact.
 
 Write protocol (the ``_build_rec_index`` contract, directory form):
 artifacts land in a pid+counter-suffixed temp directory, the manifest
@@ -481,7 +480,6 @@ class Checkpointer:
                 "label_shapes": _desc_list(
                     getattr(module, "_label_shapes", None)),
                 "n_dev": len(getattr(module, "_context", None) or []) or None,
-                "comm_signature": list(_comm_signature()),
                 "lineage": {
                     "flight_last_dump": recorder.last_dump_path,
                     "anomalies": recorder.anomaly_count(),
@@ -582,8 +580,3 @@ class Checkpointer:
                     continue
             return snap
         return None
-
-
-def _comm_signature():
-    from ..parallel import comm
-    return comm.comm_signature()

@@ -1,11 +1,8 @@
 """Resume from the newest usable snapshot, possibly on a different mesh.
 
-``resume(module, directory)`` restores params + optimizer state (comm
-error-feedback residuals included: bitwise at the original dp width,
-sum-merged when the surviving-worker count divides the original,
-dropped with a warning otherwise — ``parallel/comm.py
-reshard_residuals``) into an unbound module and reports what happened,
-including the warm-boot evidence: with ``MXNET_TPU_PROGRAM_CACHE_DIR``
+``resume(module, directory)`` restores params + optimizer state into
+an unbound module and reports what happened, including the warm-boot
+evidence: with ``MXNET_TPU_PROGRAM_CACHE_DIR``
 on a shared volume a replacement worker's bind restores its compiled
 programs from disk — ``expect_warm=True`` asserts zero backend compiles
 via the memprof build totals instead of hoping.
@@ -16,13 +13,8 @@ position (pure replay — the io_pipeline batch stream is a deterministic
 function of ``(seed, epoch, position)``), and continue ``fit`` to
 ``num_epoch``.  A run resumed this way is step-for-step the
 uninterrupted run: bitwise-equal final params at the original
-factorization, allclose across a re-factorization (``bench.py
---elastic-smoke`` proves both).
-
-On a RE-factorized mesh the comm bucket size tuned for the old
-factorization is stale; passing ``comm_measure`` (the
-``CommBucketTuner`` measure callable) runs a fresh tuner pass whose
-decision rides the flight recorder like every autotune record.
+factorization, allclose across a re-factorization
+(``tests/test_elastic.py``).
 """
 from __future__ import annotations
 
@@ -42,11 +34,10 @@ _log = _module_logger(__name__)
 class ResumeReport:
     """What ``resume`` did: the snapshot it chose, where training picks
     up (``begin_epoch`` + ``skip_batches`` into that epoch), whether
-    the mesh re-factorized, the warm-boot counters, and the comm-tuner
-    decision (None unless a re-factorization ran one)."""
+    the mesh re-factorized, and the warm-boot counters."""
 
     def __init__(self, snapshot, checkpointer, begin_epoch, skip_batches,
-                 refactorized, n_dev_from, n_dev_to, warm, comm_decision):
+                 refactorized, n_dev_from, n_dev_to, warm):
         self.snapshot = snapshot
         self.checkpointer = checkpointer
         self.step = snapshot.step
@@ -56,7 +47,6 @@ class ResumeReport:
         self.n_dev_from = n_dev_from
         self.n_dev_to = n_dev_to
         self.warm = warm
-        self.comm_decision = comm_decision
 
     def describe(self):
         return {"step": self.step, "begin_epoch": self.begin_epoch,
@@ -78,12 +68,12 @@ def _descs(records):
 
 def resume(module, directory=None, checkpointer=None, kvstore="local",
            optimizer="sgd", optimizer_params=(("learning_rate", 0.01),),
-           expect_warm=False, comm_measure=None, logger=None):
+           expect_warm=False, logger=None):
     """Restore ``module`` from the newest verified snapshot.
 
     The module may be completely fresh (same symbol): bind shapes come
     from the manifest, params from ``params.ndarray``, optimizer state
-    (momentum, f32 masters, comm residuals) from ``optimizer.states``.
+    (momentum, f32 masters) from ``optimizer.states``.
     Returns a :class:`ResumeReport`; raises :class:`SnapshotError` when
     no usable snapshot exists."""
     from .. import executor_cache
@@ -133,15 +123,11 @@ def resume(module, directory=None, checkpointer=None, kvstore="local",
     n_dev_from = snap.n_dev
     refactorized = n_dev_from is not None and n_dev_from != n_dev_to
 
-    comm_decision = None
     if refactorized:
         logger.warning(
-            "resuming into a re-factorized mesh: %s -> %s device(s); "
-            "optimizer state restored %s", n_dev_from, n_dev_to,
-            "with dp-resharded comm residuals where layouts allow"
-            if os.path.exists(states) else "without momentum")
-        if comm_measure is not None:
-            comm_decision = _retune_comm(comm_measure, logger)
+            "resuming into a re-factorized mesh: %s -> %s device(s), %s "
+            "optimizer state", n_dev_from, n_dev_to,
+            "with" if os.path.exists(states) else "without")
 
     position = snap.data_position
     consumed = position.get("consumed_batches") or 0
@@ -152,15 +138,13 @@ def resume(module, directory=None, checkpointer=None, kvstore="local",
     # preemption's snapshot still records the absolute data position
     ckpt.note_resume_position(begin_epoch, int(consumed))
     report = ResumeReport(snap, ckpt, begin_epoch, int(consumed),
-                          refactorized, n_dev_from, n_dev_to, warm,
-                          comm_decision)
+                          refactorized, n_dev_from, n_dev_to, warm)
     _flight.note_elastic({
         "kind": "resume", "from_step": snap.step,
         "snapshot": snap.directory, "begin_epoch": begin_epoch,
         "skip_batches": int(consumed), "refactorized": refactorized,
         "n_dev_from": n_dev_from, "n_dev_to": n_dev_to,
-        "warm": dict(warm),
-        "comm_retuned": comm_decision is not None})
+        "warm": dict(warm)})
     logger.info(
         "elastic resume from step %d (%s): epoch %d skip %d, "
         "%d device(s)%s; warm boot: %d restored / %d built / %d "
@@ -170,21 +154,6 @@ def resume(module, directory=None, checkpointer=None, kvstore="local",
         warm.get("restored", 0), warm.get("built", 0),
         warm.get("backend_compiles", 0))
     return report
-
-
-def _retune_comm(measure, logger):
-    """A fresh CommBucketTuner pass for the new factorization (the
-    ROADMAP autotune remainder): the bucket size tuned for the old
-    worker count is a stale incumbent once the interconnect fan-in
-    changed.  Honors ``MXNET_TPU_AUTOTUNE`` like every controller run
-    (``0`` disables, ``recommend`` logs only)."""
-    from ..observability import autotune
-    try:
-        return autotune.CommBucketTuner(measure).run()
-    except Exception:
-        logger.exception("post-resume comm-bucket tuner pass failed; "
-                         "keeping the checkpointed bucket size")
-        return None
 
 
 class _SkipFirstEpochIter(DataIter):
@@ -233,7 +202,7 @@ def resume_fit(module, train_data, num_epoch, directory=None,
                checkpointer=None, eval_data=None, kvstore="local",
                optimizer="sgd",
                optimizer_params=(("learning_rate", 0.01),),
-               expect_warm=False, comm_measure=None, **fit_kwargs):
+               expect_warm=False, **fit_kwargs):
     """``resume`` + continue ``fit`` to ``num_epoch``: restores state,
     re-attaches the checkpointer (step counter synced to the snapshot),
     fast-forwards ``train_data`` past the consumed batches of the
@@ -241,7 +210,7 @@ def resume_fit(module, train_data, num_epoch, directory=None,
     report = resume(module, directory=directory,
                     checkpointer=checkpointer, kvstore=kvstore,
                     optimizer=optimizer, optimizer_params=optimizer_params,
-                    expect_warm=expect_warm, comm_measure=comm_measure)
+                    expect_warm=expect_warm)
     # a resumed (often respawned) worker rejoins the fleet health
     # plane: the inherited MXNET_TPU_REQTRACE_CTX root routes its
     # shipped series into the same dir as the parent's (no-op when

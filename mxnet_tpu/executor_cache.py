@@ -30,7 +30,7 @@ so Executors constructed over the same signature share ONE entry holding:
 Trace counters increment inside the traced function bodies — a Python
 body only runs when jax actually (re)traces — so `stats()` reports real
 recompiles, not guesses, and a recompile regression shows up as a
-counter jump in `make bench-smoke` / the tests.
+counter jump in the tests.
 
 Config: `MXNET_TPU_EXEC_CACHE=0` disables sharing (each Executor builds
 a private program); `MXNET_TPU_EXEC_CACHE_SIZE` caps the LRU (default
@@ -154,7 +154,6 @@ def _signature(symbol, arg_dict, aux_dict, grad_names, platform, health):
     # retrace to enable, zero to disable, off-path program untouched) —
     # the op impls resolve the same modes at trace time (docs/kernels.md)
     from .ops import pallas_kernels as _pk
-    from .parallel import comm as _comm
     fp = symbol.structural_hash()
     arg_sig = tuple(sorted(
         (n, tuple(int(d) for d in a.shape), str(np.dtype(a.dtype)))
@@ -162,13 +161,8 @@ def _signature(symbol, arg_dict, aux_dict, grad_names, platform, health):
     aux_sig = tuple(sorted(
         (n, tuple(int(d) for d in a.shape), str(np.dtype(a.dtype)))
         for n, a in aux_dict.items()))
-    # the comm knobs (bucketed-overlap / 2-bit compression) key gradient-
-    # taking programs exactly like health/kernel flags: enable = one
-    # retrace, disable = zero (cached), off path bit-identical.
-    # Gradient-free binds never split — only training programs reduce.
-    comm_sig = _comm.comm_signature() if grad_names else ()
     return (fp, arg_sig, aux_sig, tuple(grad_names), platform,
-            bool(health), _pk.kernel_signature(platform), comm_sig)
+            bool(health), _pk.kernel_signature(platform))
 
 
 # -- retrace explainer --------------------------------------------------------
@@ -181,8 +175,7 @@ def _signature(symbol, arg_dict, aux_dict, grad_names, platform, health):
 
 # primary-cause priority: the most common/most actionable first
 _CAUSE_PRIORITY = ("shapes", "dtypes", "arg_names", "aux_names",
-                   "grad_names", "platform", "health", "kernel_flags",
-                   "comm_flags")
+                   "grad_names", "platform", "health", "kernel_flags")
 
 
 def _diff_shape_sig(prefix, old_sig, new_sig, causes, details):
@@ -242,13 +235,6 @@ def diff_signatures(old_key, new_key):
         causes.append("kernel_flags")
         details.append("kernel flags %s -> %s"
                        % (old_key[6], new_key[6]))
-    # keys minted before the comm component existed are 7-tuples:
-    # treat the missing slot as "overlap off"
-    old_comm = old_key[7] if len(old_key) > 7 else ()
-    new_comm = new_key[7] if len(new_key) > 7 else ()
-    if old_comm != new_comm:
-        causes.append("comm_flags")
-        details.append("comm flags %s -> %s" % (old_comm, new_comm))
     if not causes:
         return None, [], ""
     primary = next(c for c in _CAUSE_PRIORITY if c in causes)

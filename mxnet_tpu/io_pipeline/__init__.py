@@ -25,8 +25,8 @@ into a real pipeline:
   ``BucketingModule`` and the scoring loops consume the pipeline
   unchanged (``fit`` even accepts the Pipeline directly).
 
-Everything is host-side: the pipeline adds ZERO program retraces
-(asserted by ``bench.py --io-smoke``).  Knobs: ``MXNET_TPU_IO_WORKERS``,
+Everything is host-side: the pipeline adds ZERO program retraces.
+Knobs: ``MXNET_TPU_IO_WORKERS``,
 ``MXNET_TPU_IO_PREFETCH_DEPTH``, ``MXNET_TPU_IO_DOUBLE_BUFFER``
 (docs/env_vars.md); guide: docs/io_pipeline.md.
 """

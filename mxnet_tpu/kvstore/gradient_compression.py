@@ -8,20 +8,16 @@ Here the quantizer is a pure jitted function; the packed wire format is a
 uint8 array with 4 values/byte (the reference packs 16 per uint32 —
 same 2 bits/value density).
 
-Two consumers share the same wire format:
-
-- the ``GradientCompression`` class below — the kvstore's host-driven
-  mode (``set_gradient_compression``), residual keyed per parameter;
-- the in-program overlapped path (``parallel/comm.py``): the pure flat
-  functions ``quantize_flat`` / ``dequantize_flat`` /
-  ``dequantize_sum_flat`` run INSIDE the fused train-step program, with
-  the residual carried as extra (donated) optimizer state.
+The ``GradientCompression`` class below is the kvstore's host-driven
+mode (``set_gradient_compression``), residual keyed per parameter, over
+the pure flat functions ``quantize_flat`` / ``dequantize_flat`` /
+``dequantize_sum_flat``.
 
 Flat-length contract: the packed stream always covers ``ceil(n/4)``
 bytes.  ``_pack2`` owns the padding (codes for the pad lanes are 0 =
 "no update"), and every dequantizer slices back to the caller's ``n``
 — arbitrary gradient lengths round-trip (regression-tested in
-tests/test_comm_overlap.py).
+tests/test_gradient_compression.py).
 """
 from __future__ import annotations
 

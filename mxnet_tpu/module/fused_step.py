@@ -29,6 +29,7 @@ accumulate in float32.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,6 @@ from ..observability import instrument as _instrument
 from ..observability import memprof as _memprof
 from ..ops import pallas_kernels as _pallas_kernels
 from ..optimizer import _is_low_precision
-from ..parallel import comm as _comm
 
 
 # create_state-shaped pytrees are None / array / tuple-of-those — exactly
@@ -60,6 +60,16 @@ def _map2_state(fn, a, b):
 
 def _state_leaves(st):
     return jax.tree_util.tree_leaves(st)
+
+
+def collective_counts(hlo_text):
+    """Count collective ops in compiled-HLO text (async ``-start`` forms
+    counted once): what ``FusedTrainStep.compiled_hlo`` of a dp step
+    shows of the gradient all-reduce."""
+    return {name: len(re.findall(r"%s(?:-start)?\(" % re.escape(name),
+                                 hlo_text))
+            for name in ("all-reduce", "all-gather", "reduce-scatter",
+                         "collective-permute", "all-to-all")}
 
 
 class FusedStepUnsupported(MXNetError):
@@ -110,8 +120,7 @@ class FusedTrainStep:
             return False
         return True
 
-    def __init__(self, module, _carry_states=None, _carry_masters=None,
-                 _carry_residuals=None):
+    def __init__(self, module, _carry_states=None, _carry_masters=None):
         self.module = module
         self.exe = module._exec_group.execs[0]
         self.opt = module._optimizer
@@ -131,7 +140,10 @@ class FusedTrainStep:
             if module._label_shapes else []
         idx_of = {n: i for i, n in
                   enumerate(module._exec_group.param_names)}
-        self.param_idx = [idx_of.get(n, i)
+        # the optimizer's index of a parameter: Module keys idx2name (and
+        # so lr_mult / wd_mult) by ``position * n_dev + device``; the
+        # step is device 0's
+        self.param_idx = [idx_of.get(n, i) * self.n_dev
                           for i, n in enumerate(self.param_names)]
 
         # storage dtype per param, and the master dtype the update runs in
@@ -151,8 +163,7 @@ class FusedTrainStep:
             self._mesh = Mesh(np.array(self.devices), ("dp",))
             self._sh_repl = NamedSharding(self._mesh, P())
             self._sh_dp = NamedSharding(self._mesh, P("dp"))
-            # batch bookkeeping, needed both by the step body (overlap
-            # mode shard_maps the batch args) and the sharding specs
+            # batch bookkeeping for the sharding specs
             self._full_batch = int(module._data_shapes[0].shape[0])
             self._full_shape = {d.name: tuple(d.shape)
                                 for d in module._data_shapes}
@@ -165,34 +176,6 @@ class FusedTrainStep:
         else:
             self._mesh = None
             self._sh_repl = None
-
-        # -- overlapped gradient collectives (parallel/comm.py) ----------
-        # resolved at construction like the health flag: flipping either
-        # env knob takes effect on the next FusedTrainStep build, and the
-        # off path traces a program bit-identical to pre-flag builds.
-        self._comm_cfg = None
-        self._comm_plan = None
-        self._n_outs = None
-        self.overlap_off_reason = None
-        if self.n_dev == 1 and _comm.comm_config() is not None:
-            # nothing to overlap: there is no gradient collective
-            self.overlap_off_reason = "single-device"
-        if self.n_dev > 1:
-            cfg = _comm.comm_config()
-            if cfg is not None:
-                reason = self._overlap_gate(exe, prog)
-                if reason is None:
-                    self._comm_cfg = cfg
-                    self._comm_plan = _comm.CommPlan(
-                        [tuple(exe.arg_dict[n].shape)
-                         for n in self.param_names],
-                        self.param_dtypes, cfg)
-                else:
-                    self.overlap_off_reason = reason
-                    module.logger.warning(
-                        "gradient-collective overlap requested but "
-                        "unavailable for this program (%s); using the "
-                        "monolithic reduction", reason)
 
         def _to_global(arr):
             # never the default backend: the bound device (or dp mesh)
@@ -224,31 +207,6 @@ class FusedTrainStep:
         else:
             self.states = [self._init_state(j)
                            for j in range(len(self.param_names))]
-
-        # error-feedback residuals (2-bit compression only): one flat
-        # f32 vector per bucket PER SHARD (each data-parallel worker
-        # keeps its own quantization error — the reference kept one per
-        # key per worker, gradient_compression.h:52).  Stored dp-sharded
-        # and donated like momentum; dropped with a warning if a carried
-        # checkpoint no longer matches the bucket layout.
-        self._residuals = []
-        if self._comm_plan is not None and self._comm_plan.compress:
-            res_shapes = [(self.n_dev,) + s
-                          for s in self._comm_plan.residual_shapes()]
-            carried = None
-            if _carry_residuals is not None:
-                if [tuple(np.asarray(r).shape) for r in _carry_residuals] \
-                        == res_shapes:
-                    carried = _carry_residuals
-                else:
-                    module.logger.warning(
-                        "carried compression residuals do not match the "
-                        "current bucket layout; reinitializing to zero")
-            self._residuals = [
-                jax.device_put(np.asarray(carried[j], np.float32)
-                               if carried is not None
-                               else np.zeros(s, np.float32), self._sh_dp)
-                for j, s in enumerate(res_shapes)]
 
         # per-param extras width (bias-correction coefficients etc.) —
         # declared, not probed: fused_scalars needs _update_count to have
@@ -285,10 +243,7 @@ class FusedTrainStep:
         needs_rng = self._needs_rng
         health_on = self._health_on
         health_layout = self.health_layout
-        comm_plan = self._comm_plan
         mesh_ref = self._mesh
-        other_is_batch = self._other_is_batch if self.n_dev > 1 else []
-        n_outs = self._n_outs
 
         # Buffer donation lets XLA update masters and optimizer state in
         # place.  On by default where jax implements it (a TPU step):
@@ -303,8 +258,7 @@ class FusedTrainStep:
         # On the dp path the constructor's jax.eval_shape probe below
         # IS the step's one real trace — jax's jaxpr cache serves the
         # later jit lowering from it, so the body never re-runs at
-        # dispatch.  The probe therefore COUNTS as the retrace (the
-        # autotune comm tuner prices candidates on exactly this), but
+        # dispatch.  The probe therefore COUNTS as the retrace, but
         # must not arm a memprof build record: no compile follows the
         # probe directly (the real one attributes via aot_compile, or
         # never happens on a disk-restored warm boot), and a dangling
@@ -316,15 +270,14 @@ class FusedTrainStep:
         def _step(*args):
             # the ops resolve their kernel flags against what THIS step
             # is traced for: its devices' platform, and whether XLA
-            # partitions the graph by itself (the monolithic dp path —
-            # the overlap path evaluates per shard under shard_map)
+            # partitions the graph by itself (the dp path)
             with _pallas_kernels.trace_scope(
                     platform=self.devices[0].platform,
-                    partitioned=mesh_ref is not None and comm_plan is None):
+                    partitioned=mesh_ref is not None):
                 return _step_body(*args)
 
-        def _step_body(masters, other_vals, states, aux_vals, residuals,
-                       keys, lrs, wds, extras, opt_key, stored=None):
+        def _step_body(masters, other_vals, states, aux_vals, keys, lrs,
+                       wds, extras, opt_key, stored=None):
             # body runs only when jax (re)traces: counts real recompiles
             # of the fused step alongside the executor-cache counters
             _exec_cache.note_trace("fused_step", memprof_label,
@@ -336,8 +289,8 @@ class FusedTrainStep:
             # STORAGE-dtype parameter values, not the f32 masters.  The
             # old form (vjp through the master->bf16 cast) made the vjp
             # boundary materialize a full f32 copy of every gradient —
-            # pure HBM traffic (the convert_reduce_fusion.* family in
-            # ROOFLINE_r05.json).  Here activations AND gradients stay
+            # pure HBM traffic (a convert_reduce_fusion.* family in the
+            # device trace).  Here activations AND gradients stay
             # bf16 end-to-end; the one f32 cast per parameter happens at
             # the master-weight update below, where XLA fuses the convert
             # into the update's elementwise epilogue.  The update math is
@@ -357,76 +310,30 @@ class FusedTrainStep:
                 pvals = [next(stored) if mixed[j] else m
                          for j, m in enumerate(masters)]
 
-            if comm_plan is None:
-                def f(pv):
-                    amap = dict(arg_map)
-                    amap.update(zip(param_names, pv))
-                    outs, new_aux = prog_ref.evaluate(amap, aux_map, keys,
-                                                      True)
-                    return outs, [new_aux[n] for n in aux_names]
+            def f(pv):
+                amap = dict(arg_map)
+                amap.update(zip(param_names, pv))
+                outs, new_aux = prog_ref.evaluate(amap, aux_map, keys, True)
+                return outs, [new_aux[n] for n in aux_names]
 
-                if health_on:
-                    # attention-logit taps ride out of the vjp as
-                    # has_aux values (frame tracers must not leak out of
-                    # the linearization trace); topo order matches the
-                    # layout's tap slots
-                    def f_tapped(pv):
-                        with _health.collect_taps() as frame:
-                            result = f(pv)
-                        return result, list(frame)
+            if health_on:
+                # attention-logit taps ride out of the vjp as has_aux
+                # values (frame tracers must not leak out of the
+                # linearization trace); topo order matches the layout's
+                # tap slots
+                def f_tapped(pv):
+                    with _health.collect_taps() as frame:
+                        result = f(pv)
+                    return result, list(frame)
 
-                    (outs, new_aux), vjp_fn, taps = jax.vjp(
-                        f_tapped, pvals, has_aux=True)
-                else:
-                    taps = None
-                    (outs, new_aux), vjp_fn = jax.vjp(f, pvals)
-                heads = [jnp.ones_like(o) for o in outs]
-                zeros_aux = [jnp.zeros_like(a) for a in new_aux]
-                (grads,) = vjp_fn((heads, zeros_aux))
-                new_residuals = list(residuals)
+                (outs, new_aux), vjp_fn, taps = jax.vjp(
+                    f_tapped, pvals, has_aux=True)
             else:
-                # Overlapped path: the forward/backward runs PER SHARD
-                # under shard_map, so the gradients exist as explicit
-                # local partial sums and the cross-device reduction is
-                # OURS to schedule — one collective per reverse-autodiff
-                # bucket (optionally 2-bit compressed), barrier-chained
-                # so XLA cannot re-combine them into a tail all-reduce
-                # (parallel/comm.py).  Gated to aux-free, rng-free,
-                # batch-major-output programs, where per-shard evaluation
-                # is exactly the monolithic math up to reduction order.
-                from jax import shard_map
-                from jax.sharding import PartitionSpec as P
-
-                def _shard_fb(other_local, pvals_in, res_in):
-                    amap_l = dict(zip(other_names, other_local))
-
-                    def f(pv):
-                        amap = dict(amap_l)
-                        amap.update(zip(param_names, pv))
-                        outs, _ = prog_ref.evaluate(amap, {}, keys, True)
-                        return list(outs)
-
-                    outs, vjp_fn = jax.vjp(f, pvals_in)
-                    heads = [jnp.ones_like(o) for o in outs]
-                    (grads,) = vjp_fn(list(heads))
-                    red, new_res = _comm.reduce_buckets(
-                        list(grads), "dp", comm_plan,
-                        [r[0] for r in res_in])
-                    return outs, red, [r[None] for r in new_res]
-
-                n_res = len(comm_plan.residual_shapes())
-                outs, grads, new_residuals = shard_map(
-                    _shard_fb, mesh=mesh_ref,
-                    in_specs=([P("dp") if b else P()
-                               for b in other_is_batch],
-                              [P()] * n_params, [P("dp")] * n_res),
-                    out_specs=([P("dp")] * n_outs, [P()] * n_params,
-                               [P("dp")] * n_res),
-                    check_vma=False)(other_vals, pvals, residuals)
-                new_aux = []
-                # taps are not collectible through shard_map (the body
-                # runs per shard); the slots hold -1
                 taps = None
+                (outs, new_aux), vjp_fn = jax.vjp(f, pvals)
+            heads = [jnp.ones_like(o) for o in outs]
+            zeros_aux = [jnp.zeros_like(a) for a in new_aux]
+            (grads,) = vjp_fn((heads, zeros_aux))
 
             opt_keys = jax.random.split(opt_key, n_params) if needs_rng \
                 else [None] * n_params
@@ -460,16 +367,14 @@ class FusedTrainStep:
                                             update_ratio=ratio,
                                             taps=taps)
                 return (outs, new_masters, new_states, new_aux, new_exec,
-                        new_residuals, hvec)
-            return (outs, new_masters, new_states, new_aux, new_exec,
-                    new_residuals)
+                        hvec)
+            return outs, new_masters, new_states, new_aux, new_exec
 
-        # donation: masters (0), optimizer states (2), and the
-        # compression residuals (4 — zero-length when not compressing)
-        donate_idx = (0, 2, 4) if donate else ()
+        # donation: masters (0) and optimizer states (2)
+        donate_idx = (0, 2) if donate else ()
         self._last_abstract = None
         # single device: the executor's storage-dtype copies ride along
-        # (argument 10, ``stored``) and are donated with the rest
+        # (argument 9, ``stored``) and are donated with the rest
         self._spare_idx = [j for j in range(n_params) if mixed[j]] \
             if donate and self.n_dev == 1 else []
 
@@ -478,7 +383,7 @@ class FusedTrainStep:
         # here — everything the trace bakes in beyond the argument
         # shapes the per-call fingerprint already covers: the graph,
         # name/dtype layout, donation, the optimizer's traced constants,
-        # and the same health/kernel/comm flags that key entry programs.
+        # and the same health/kernel flags that key entry programs.
         def _disk_key():
             if not _program_cache.enabled():
                 return None
@@ -505,7 +410,6 @@ class FusedTrainStep:
                 tuple(str(d) for d in self.devices),
                 opt_fp,
                 _pallas_kernels.kernel_signature(self.devices[0].platform),
-                _comm.comm_signature(),
                 tuple(self._other_is_batch) if self.n_dev > 1 else ())
 
         def _wrap_step(jitted):
@@ -536,7 +440,7 @@ class FusedTrainStep:
         if self.n_dev == 1:
             self._step_jit = jax.jit(
                 _step, donate_argnums=donate_idx
-                + ((10,) if self._spare_idx else ()))
+                + ((9,) if self._spare_idx else ()))
             self._step = _wrap_step(self._step_jit)
             # identity of the arrays we last wrote into exec's dicts; a
             # mismatch means set_params/init_params replaced them and the
@@ -556,7 +460,6 @@ class FusedTrainStep:
         svals = [_map_state(lambda a: sds(a.shape, a.dtype), st)
                  for st in self.states]
         avals = [sds(a.shape, a.dtype) for a in self._gaux]
-        rvals = [sds(r.shape, r.dtype) for r in self._residuals]
         keys = tuple(_random.next_key() for _ in range(exe._n_keys))
         f32v = sds((n_params,), np.float32)
         exv = sds((n_params, max(n_extra, 1)), np.float32)
@@ -564,8 +467,8 @@ class FusedTrainStep:
         shape_probe["on"] = True
         try:
             outs_sd = jax.eval_shape(
-                _step, mvals, others, svals, avals, rvals, keys, f32v,
-                f32v, exv, kv)[0]
+                _step, mvals, others, svals, avals, keys, f32v, f32v, exv,
+                kv)[0]
         except (TypeError, ValueError, MXNetError) as exc:
             # a graph that bakes the PER-DEVICE batch into a shape attr
             # (Reshape(shape=(local_batch, ...))) cannot trace at the
@@ -577,8 +480,8 @@ class FusedTrainStep:
                          exe.arg_dict[n]._h.array.dtype)
                      for n in self.other_names]
             try:
-                jax.eval_shape(_step, mvals, local, svals, avals, rvals,
-                               keys, f32v, f32v, exv, kv)
+                jax.eval_shape(_step, mvals, local, svals, avals, keys,
+                               f32v, f32v, exv, kv)
             except Exception:
                 raise exc from None
             raise FusedStepUnsupported(
@@ -587,8 +490,7 @@ class FusedTrainStep:
         finally:
             shape_probe["on"] = False
         # XLA derives the gradient all-reduce from these shardings — the
-        # kvstore collective collapsed into the step program (monolithic
-        # mode) or scheduled per bucket by the shard_map body (overlap)
+        # kvstore collective collapsed into the step program
         state_sh = [_map_state(lambda a: repl, st) for st in self.states]
         out_sh = (
             [dp if (len(o.shape) >= 1 and o.shape[0] == full_batch)
@@ -596,8 +498,7 @@ class FusedTrainStep:
             [repl] * n_params,
             state_sh,
             [repl] * len(aux_names),
-            [repl] * n_params,
-            [dp] * len(self._residuals))
+            [repl] * n_params)
         if health_on:
             # the packed health vector is a global reduction: replicated
             out_sh = out_sh + (repl,)
@@ -608,7 +509,6 @@ class FusedTrainStep:
                 [dp if b else repl for b in self._other_is_batch],
                 state_sh,
                 [repl] * len(aux_names),
-                [dp] * len(self._residuals),
                 (repl,) * exe._n_keys,
                 repl, repl, repl, repl),
             out_shardings=out_sh,
@@ -616,54 +516,10 @@ class FusedTrainStep:
         self._step = _wrap_step(self._step_jit)
         self._scattered = {}
 
-    def _overlap_gate(self, exe, prog):
-        """Why the bucketed-overlap path cannot serve this program (None
-        when it can).  The overlap body evaluates the graph PER SHARD, so
-        it must be exactly the global math up to reduction order:
-
-        - auxiliary state (BatchNorm moving stats) is updated from batch
-          statistics — per-shard stats would change the training math,
-          so such programs keep the monolithic reduction;
-        - in-graph rng (dropout) draws a global-batch-shaped mask; a
-          per-shard trace would draw a different (shard-correlated) one;
-        - loss heads with batch-size-dependent gradient scale
-          (SoftmaxOutput normalization='batch'/'valid') divide by the
-          TRACED batch — per shard that is the local batch / local valid
-          count, so the psum would come out dp-times too large;
-        - every output must be batch-major so the shards concatenate
-          back into the monolithic program's outputs."""
-        if prog.aux_names:
-            return "auxiliary state (batch statistics need global-batch " \
-                   "semantics)"
-        if exe._n_keys:
-            return "in-graph rng"
-        for node in prog.order:
-            if node.attrs.get("normalization") in ("batch", "valid"):
-                return "batch-normalized loss gradient (%s " \
-                       "normalization=%r divides by the per-shard " \
-                       "batch)" % (node.op_name,
-                                   node.attrs["normalization"])
-        sds = jax.ShapeDtypeStruct
-        amap = {n: sds(tuple(a._h.array.shape), a._h.array.dtype)
-                for n, a in exe.arg_dict.items()}
-        try:
-            outs = jax.eval_shape(
-                lambda am: list(prog.evaluate(am, {}, (), True)[0]), amap)
-        except Exception as e:
-            return "output shape probe failed (%s)" % (e,)
-        local_b = int(exe.arg_dict[self.data_names[0]].shape[0])
-        if not all(len(o.shape) >= 1 and int(o.shape[0]) == local_b
-                   for o in outs):
-            return "non-batch-major outputs"
-        self._n_outs = len(outs)
-        return None
-
     def compiled_hlo(self):
         """Compiled-HLO text of the step program (None before the first
-        run).  The overlap acceptance evidence reads off it:
-        ``parallel.comm.collective_counts`` shows one all-reduce (or
-        all-gather, compressed) PER BUCKET instead of a combined tail
-        collective."""
+        run); ``collective_counts`` of it shows the all-reduce XLA
+        derived from the dp shardings."""
         if self._last_abstract is None:
             return None
         return self._step_jit.lower(*self._last_abstract).compile() \
@@ -710,8 +566,8 @@ class FusedTrainStep:
                           for n in self.other_names]
             aux_vals = list(self._gaux)
             keys = tuple(_random.next_key() for _ in range(exe._n_keys))
-            args = (self._masters, other_vals, self.states, aux_vals,
-                    self._residuals, keys, lrs, wds, extras, opt_key)
+            args = (self._masters, other_vals, self.states, aux_vals, keys,
+                    lrs, wds, extras, opt_key)
             if self._spare_idx:
                 args += ([exe.arg_dict[self.param_names[j]]._h.array
                           for j in self._spare_idx],)
@@ -811,13 +667,11 @@ class FusedTrainStep:
             # (same symbol, so the param list is unchanged)
             states = self.states
             masters = [np.asarray(m) for m in self._masters]
-            residuals = [np.asarray(r) for r in self._residuals] or None
             self.exe = module._exec_group.execs[0]
             self.__init__(module,
                           _carry_states=[_map_state(np.asarray, st)
                                          for st in states],
-                          _carry_masters=masters,
-                          _carry_residuals=residuals)
+                          _carry_masters=masters)
             # the carried masters are authoritative: stop the staleness
             # check below from re-deriving them off half-width storage
             for n in self.param_names:
@@ -856,12 +710,11 @@ class FusedTrainStep:
         """Take the step's results as the next step's state; returns
         what is left to hand to the executors: (outputs, the parameters
         in their storage dtype, the auxiliary states)."""
-        outs, new_masters, new_states, new_aux, new_exec, new_res = res[:6]
-        self.last_health = res[6] if self._health_on else None
+        outs, new_masters, new_states, new_aux, new_exec = res[:5]
+        self.last_health = res[5] if self._health_on else None
         self._masters = list(new_masters)
         self.states = list(new_states)
         self._gaux = list(new_aux)
-        self._residuals = list(new_res)
         return outs, new_exec, new_aux
 
     def _per_step_scalars(self):
@@ -918,15 +771,9 @@ class FusedTrainStep:
             lrs, wds, extras, opt_key = self._per_step_scalars()
             keys = tuple(_random.next_key() for _ in range(exe._n_keys))
             args = (self._masters, other_vals, self.states, self._gaux,
-                    self._residuals, keys, lrs, wds, extras, opt_key)
+                    keys, lrs, wds, extras, opt_key)
             self._note_abstract(args)
         res = self._dispatch(args, list(inputs.values()), "fused_step_dp")
-        if self._comm_plan is not None:
-            # per-step wire accounting for the in-program collectives —
-            # host-side, outside the traced body (the comm row in
-            # tools/traceview.py and the wire-bytes contract in
-            # bench.py --comm-smoke read these)
-            _instrument.note_comm_overlapped(self._comm_plan)
 
         with _instrument.phase("fused:scatter"):
             outs, new_exec, new_aux = self._keep(res)
@@ -992,16 +839,10 @@ class FusedTrainStep:
         f32 masters, under multi_precision)."""
         if updater is None:
             return
-        if self._residuals:
-            self.module.logger.warning(
-                "retiring the fused step drops the 2-bit compression "
-                "error-feedback residuals; the general path reduces "
-                "uncompressed gradients")
         for j, name in enumerate(self.param_names):
             idx = self.param_idx[j]
-            devs = self.devices if self.n_dev > 1 else [self.devices[0]]
-            for k, dev in enumerate(devs):
-                slot = idx * self.n_dev + k if self.n_dev > 1 else idx
+            for k, dev in enumerate(self.devices):
+                slot = idx + k
                 st_nd = _map_state(lambda a: self._wrap_nd(a, dev),
                                    self.states[j])
                 if self.mixed[j]:
@@ -1011,10 +852,6 @@ class FusedTrainStep:
                 updater.states_synced[slot] = True
 
     # -- optimizer-state checkpoint interop ---------------------------------
-    # reserved key for the compression residuals inside the fused_v2
-    # states dict; older loaders skip it (not a parameter name)
-    _RESIDUAL_KEY = "__comm_residuals__"
-
     def export_states(self):
         out = {}
         for j, name in enumerate(self.param_names):
@@ -1022,68 +859,13 @@ class FusedTrainStep:
             if self.mixed[j]:
                 entry["master"] = np.asarray(self._masters[j])
             out[name] = entry
-        if self._residuals:
-            out[self._RESIDUAL_KEY] = {
-                "signature": _comm.comm_signature(),
-                "buckets": [np.asarray(r) for r in self._residuals]}
         return out
 
-    def _load_residuals(self, comm_st):
-        """Restore checkpointed error-feedback residuals: bitwise when
-        the layout matches, dp-axis sum-merged when the checkpoint was
-        written by a larger factorization this mesh's dp width divides
-        (elastic resume onto surviving workers), dropped with a warning
-        otherwise — a residual applied under the wrong quantization
-        layout would inject noise, not correction."""
-        logger = self.module.logger
-        saved_sig = comm_st.get("signature")
-        cur_sig = _comm.comm_signature()
-        if saved_sig is not None and tuple(saved_sig) != tuple(cur_sig):
-            logger.warning(
-                "checkpointed compression residuals were written under "
-                "comm signature %s but the current configuration is %s; "
-                "dropping them (error feedback restarts from zero)",
-                tuple(saved_sig), tuple(cur_sig))
-            self._residuals = [
-                jax.device_put(np.zeros(tuple(r.shape), np.float32),
-                               self._sh_dp) for r in self._residuals]
-            return
-        buckets = [np.asarray(b, np.float32)
-                   for b in comm_st.get("buckets", [])]
-        want = [tuple(r.shape) for r in self._residuals]
-        if [b.shape for b in buckets] != want:
-            resharded, reason = (None, "bucket count changed") \
-                if len(buckets) != len(want) \
-                else _comm.reshard_residuals(buckets, self.n_dev)
-            if resharded is not None \
-                    and [r.shape for r in resharded] == want:
-                logger.info(
-                    "elastic resume: sum-merged compression residuals "
-                    "from dp=%d onto dp=%d (pending quantization error "
-                    "conserved)", buckets[0].shape[0], self.n_dev)
-                buckets = resharded
-            else:
-                logger.warning(
-                    "checkpointed compression residuals do not match "
-                    "the current bucket layout (%s vs %s%s); dropping "
-                    "them (error feedback restarts from zero)",
-                    [tuple(b.shape) for b in buckets], want,
-                    "; " + reason if reason else "")
-                self._residuals = [
-                    jax.device_put(np.zeros(s, np.float32), self._sh_dp)
-                    for s in want]
-                return
-        self._residuals = [jax.device_put(b, self._sh_dp)
-                           for b in buckets]
-
     def load_states(self, states):
-        comm_st = states.get(self._RESIDUAL_KEY) \
-            if isinstance(states, dict) else None
-        if comm_st is not None and self._residuals:
-            self._load_residuals(comm_st)
         for n, v in states.items():
             if n not in self.param_names:
-                continue  # __comm_residuals__ handled above
+                # e.g. the __comm_residuals__ entry of an older build's file
+                continue
             j = self.param_names.index(n)
             if isinstance(v, dict):  # fused_v2
                 st = v["state"]

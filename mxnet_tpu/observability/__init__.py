@@ -37,10 +37,9 @@ Three layers, lowest first:
   ``--fleet``; docs/observability.md §request-tracing).
 - ``autotune`` — the CONTROL half of the loop: controllers that turn
   the recorded signals above into bounded, auditable configuration
-  changes (comm bucket size, traffic-shaped serving buckets, io worker
-  counts) behind ``MXNET_TPU_AUTOTUNE=recommend|apply|0``, every
-  decision a structured record riding the flight recorder
-  (docs/autotune.md).
+  changes (traffic-shaped serving buckets, io worker counts) behind
+  ``MXNET_TPU_AUTOTUNE=recommend|apply|0``, every decision a structured
+  record riding the flight recorder (docs/autotune.md).
 - ``timeseries`` — the health plane's TREND layer: a bounded ring of
   timestamped registry snapshots (``MXNET_TPU_TS_INTERVAL_S``; sampler
   thread via ``threads.spawn``) with windowed signals — counter rates,
@@ -57,7 +56,7 @@ Three layers, lowest first:
 
 Every callsite stays OUTSIDE jitted bodies: instrumentation must never
 change a traced program (the exec-cache trace counters prove it adds
-zero recompiles — ``make bench-smoke`` asserts exactly that).
+zero recompiles — ``tests/test_step_phases.py`` asserts exactly that).
 """
 from __future__ import annotations
 

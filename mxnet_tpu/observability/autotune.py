@@ -8,12 +8,6 @@ configuration changes (the reference framework's profiler→operator-
 tuning feedback loop, SURVEY.md L2 + ``src/profiler/``, grown into
 fleet behavior):
 
-- :class:`CommBucketTuner` hill-climbs ``MXNET_TPU_COMM_BUCKET_MB``
-  from a measured per-candidate step cost under a hard RETRACE BUDGET.
-  Each candidate bucket size re-keys the gradient programs (the PR 10
-  cache-key contract: exactly one retrace per gradient program), so the
-  tuner counts spent retraces via ``executor_cache.watch_traces`` and
-  refuses to evaluate a new candidate once the budget is gone.
 - :class:`ServingBucketTuner` derives a TRAFFIC-SHAPED bucket set from
   the observed per-request row histogram (``serving.request_rows``,
   recorded at admission) via the shared log2-bucket quantile estimator
@@ -40,8 +34,8 @@ Safety rails, enforced rather than hoped for:
   recoverable from a flight dump (``tools/traceview.py --tuning``
   renders it; docs/autotune.md pins the schema).
 - A controller that cannot justify a change (insufficient samples,
-  budget exhausted, candidate == incumbent, footprint over capacity)
-  says so with a logged decision instead of acting.
+  candidate == incumbent, footprint over capacity) says so with a
+  logged decision instead of acting.
 """
 from __future__ import annotations
 
@@ -65,9 +59,8 @@ MODE_ENV = "MXNET_TPU_AUTOTUNE"
 #   recommend - report-only: the change the controller would make
 #   hold      - signals read, incumbent kept (in band / already optimal)
 #   reject    - candidate failed validation (e.g. footprint > capacity)
-#   stop      - the controller stopped before exploring (budget gone)
 #   skip      - not enough signal to decide (insufficient samples)
-ACTIONS = ("apply", "recommend", "hold", "reject", "stop", "skip")
+ACTIONS = ("apply", "recommend", "hold", "reject", "skip")
 
 _warned_mode = set()
 _log_lock = _threads.package_lock("autotune._log_lock")
@@ -113,7 +106,7 @@ def clear_decisions():
 
 
 class Controller:
-    """Base of the three tuners: mode resolution + the decision log.
+    """Base of the tuners: mode resolution + the decision log.
 
     ``mode`` precedence: the env kill switch (``MXNET_TPU_AUTOTUNE=0``)
     always wins; otherwise an explicit constructor ``mode=`` overrides
@@ -172,145 +165,7 @@ class Controller:
         return rec
 
 
-# -- 1. comm bucket size ------------------------------------------------------
-
-class CommBucketTuner(Controller):
-    """Hill-climb ``MXNET_TPU_COMM_BUCKET_MB`` under a retrace budget.
-
-    ``measure(bucket_mb) -> cost_ms`` is supplied by the caller and runs
-    with the env knob set to the candidate — typically a short training
-    window whose per-step wall time (which contains the exposed
-    ``comm.exposed_ms`` where the kvstore path is in play) is the cost.
-    The tuner wraps every call in ``executor_cache.watch_traces``: the
-    PR 10 cache-key contract prices each NEW candidate at exactly one
-    retrace per gradient program, and measuring the incumbent (whose
-    program the running job already compiled) at zero — so the budget
-    is spent on exploration only.  The budget gates STARTING a
-    candidate: nothing new is measured once ``spent >= budget``.  A
-    measurement window that retraces more than one program (several
-    gradient programs live, or a cold incumbent) can therefore finish
-    past the budget — the decision's ``cost.retraces`` records the
-    true spend, never a hoped-for one.
-
-    ``apply`` mode leaves the env set to the winner (the next
-    gradient-program bind picks it up — one more retrace, the applied
-    change itself); ``recommend`` restores the env exactly as found.
-    """
-
-    name = "comm_bucket"
-
-    def __init__(self, measure, budget=4, mode=None, start_mb=None,
-                 factor=2.0, min_mb=0.0625, max_mb=256.0,
-                 signal="step_cost_ms"):
-        super().__init__(mode=mode)
-        self._measure = measure
-        self._budget = int(budget)
-        self._start_mb = start_mb
-        self._factor = float(factor)
-        self._min_mb = float(min_mb)
-        self._max_mb = float(max_mb)
-        self._signal = signal
-        if self._factor <= 1.0:
-            raise ValueError("factor must be > 1")
-
-    def _resolve_start(self, comm):
-        if self._start_mb is not None:
-            return float(self._start_mb)
-        cur = comm.bucket_mb()
-        if isinstance(cur, (int, float)) and cur > 0:
-            return float(cur)
-        return float(comm.DEFAULT_BUCKET_MB)
-
-    def run(self):
-        if not self.active:
-            return None
-        from .. import executor_cache as _executor_cache
-        from ..parallel import comm as _comm
-        original = os.environ.get(_comm.BUCKET_ENV)
-        start = self._resolve_start(_comm)
-        spent = 0
-        costs = {}
-        trials = []
-        exhausted = False
-
-        def evaluate(mb):
-            nonlocal spent
-            os.environ[_comm.BUCKET_ENV] = "%g" % mb
-            with _executor_cache.watch_traces() as w:
-                cost = float(self._measure(mb))
-            retraces = w.total()
-            spent += retraces
-            costs[mb] = cost
-            trials.append({"bucket_mb": mb, "cost_ms": cost,
-                           "retraces": retraces})
-
-        try:
-            evaluate(start)
-            best = start
-            for direction in (self._factor, 1.0 / self._factor):
-                cur = best
-                moved = False
-                while True:
-                    nxt = min(self._max_mb,
-                              max(self._min_mb, cur * direction))
-                    if nxt == cur or nxt in costs:
-                        break
-                    if spent >= self._budget:
-                        exhausted = True
-                        break
-                    evaluate(nxt)
-                    if costs[nxt] < costs[cur]:
-                        cur = nxt
-                        moved = True
-                    else:
-                        break
-                if moved and costs[cur] < costs[best]:
-                    best = cur
-                    break  # climbed in this direction; local optimum found
-        finally:
-            # never leave a candidate's env behind uncommitted: the
-            # apply branch below re-sets it deliberately
-            if original is None:
-                os.environ.pop(_comm.BUCKET_ENV, None)
-            else:
-                os.environ[_comm.BUCKET_ENV] = original
-
-        stopped_blind = exhausted and len(trials) <= 1
-        applied = False
-        if stopped_blind:
-            action = "stop"
-            reason = ("retrace budget (%d) exhausted before any "
-                      "candidate beyond the incumbent could be measured"
-                      % self._budget)
-        else:
-            if self.mode == "apply":
-                os.environ[_comm.BUCKET_ENV] = "%g" % best
-                applied = True
-                action = "apply"
-            else:
-                action = "recommend"
-            reason = ("bucket %g MB has the lowest measured cost "
-                      "(%.3f ms) over %d candidate(s), %d/%d retraces "
-                      "spent%s"
-                      % (best, costs[best], len(trials), spent,
-                         self._budget,
-                         "; budget exhausted mid-climb" if exhausted
-                         else ""))
-        return self._record(
-            action,
-            inputs={"start_mb": start, "signal": self._signal,
-                    "env_before": original,
-                    "retrace_budget": self._budget},
-            candidates=trials,
-            decision={"bucket_mb": best if not stopped_blind else start,
-                      "cost_ms": costs.get(best),
-                      "budget_exhausted": exhausted,
-                      "applied": applied},
-            cost={"retraces": spent, "retrace_budget": self._budget},
-            reason=reason)
-
-
-# -- 2. serving bucket set ----------------------------------------------------
+# -- 1. serving bucket set ----------------------------------------------------
 
 def expected_padded_rows(rows_hist, buckets):
     """Estimated padding rows PER REQUEST if traffic shaped like
@@ -485,7 +340,7 @@ class ServingBucketTuner(Controller):
         return int(arg + sum(b * per_row for b in buckets))
 
 
-# -- 3. io-pipeline worker count ----------------------------------------------
+# -- 2. io-pipeline worker count ----------------------------------------------
 
 class IoWorkerTuner(Controller):
     """Recommend io-pipeline worker counts from the starvation ratio.

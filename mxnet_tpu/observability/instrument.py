@@ -2,7 +2,8 @@
 
 Everything here runs on the host, OUTSIDE jitted bodies — adding or
 removing instrumentation must never change a traced program (the
-exec-cache trace counters in ``make bench-smoke`` hold that line).
+exec-cache trace counters in ``tests/test_step_phases.py`` hold that
+line).
 
 - ``StepTracker``: the per-step breakdown behind ``BaseModule.fit``.
   Each training step decomposes into the five components a production
@@ -565,7 +566,7 @@ def note_io_wait(seconds):
 # consumer wait is the per-stage starvation signal (queue_wait), decode
 # and h2d histograms attribute where batch time goes, and h2d_ahead
 # counts uploads issued under the previous step's compute (the overlap
-# contract `bench.py --io-smoke` asserts on)
+# contract ``tests/test_io_pipeline.py`` asserts on)
 _pipe_cache = (None, None)
 
 
@@ -679,21 +680,11 @@ def disarm_pipeline_gauges(token):
 
 # -- gradient-collective (comm) accounting -----------------------------------
 #
-# Two kinds of gradient communication exist after the overlap work
-# (parallel/comm.py, docs/distributed.md):
-#
-# - EXPOSED: host-driven kvstore collectives (dist push/pull, tpu_ici
-#   push_pull) — the step waits on them, so their wall time is real
-#   exposed comm; recorded with bytes + latency + a ``comm:<op>`` span.
-# - OVERLAPPED: in-program bucketed collectives inside the fused train
-#   step — no host-observable latency (they ride under the backward),
-#   so only their per-step wire bytes are recorded, from the static
-#   CommPlan.
-#
-# ``comm.bytes_total`` sums both; ``comm.exposed_ms`` only ever grows
-# from the exposed path — a training setup whose exposed_ms is ~0 while
-# overlapped_bytes grows is the overlap win, and tools/traceview.py's
-# comm row prints exactly that comparison.
+# Host-driven kvstore collectives (dist push/pull, tpu_ici push_pull):
+# the step waits on them, so their wall time is real exposed comm;
+# recorded with bytes + latency + a ``comm:<op>`` span.  The fused
+# step's all-reduce is XLA's, inside the step program: the device trace
+# shows it (docs/distributed.md), nothing on the host does.
 _comm_cache = (None, None)
 
 
@@ -706,7 +697,7 @@ def _comm_handles():
             "bytes_total": telemetry.counter(
                 "comm.bytes_total",
                 help="gradient-collective payload bytes contributed by "
-                     "this worker (exposed + overlapped)"),
+                     "this worker"),
             "exposed_bytes": telemetry.counter(
                 "comm.exposed_bytes",
                 help="bytes moved by host-driven (exposed) collectives"),
@@ -714,41 +705,9 @@ def _comm_handles():
                 "comm.exposed_ms",
                 help="wall time the step spent blocked on exposed "
                      "collectives"),
-            "overlapped_bytes": telemetry.counter(
-                "comm.overlapped_bytes",
-                help="bytes moved by in-program bucketed collectives "
-                     "(overlapped with backward)"),
-            "compressed_saved_bytes": telemetry.counter(
-                "comm.compressed_saved_bytes",
-                help="f32-equivalent bytes NOT moved thanks to 2-bit "
-                     "compression"),
-            "steps": telemetry.counter(
-                "comm.steps", help="training steps with in-program "
-                                   "bucketed collectives"),
         }
         _comm_cache = (key, handles)
     return handles
-
-
-def note_comm_overlapped(plan):
-    """One fused-step dispatch with in-program bucketed collectives:
-    account the plan's wire bytes (host-side; zero traced-program
-    effect).  ``plan`` is a ``parallel.comm.CommPlan``.  The trace
-    counter carries the PER-STEP bytes (samples sum to the window's
-    total), so a trace window never inherits a prior session's
-    cumulative value."""
-    if not (telemetry.enabled() or tracing.is_recording()):
-        return
-    h = _comm_handles()
-    h["bytes_total"].inc(plan.wire_bytes)
-    h["overlapped_bytes"].inc(plan.wire_bytes)
-    h["steps"].inc()
-    if plan.compress:
-        h["compressed_saved_bytes"].inc(plan.grad_f32_bytes
-                                        - plan.wire_bytes)
-    if tracing.is_recording():
-        tracing.emit_counter("comm_overlapped_bytes", plan.wire_bytes,
-                             category="comm")
 
 
 def record_comm_exposed(op, nbytes, seconds, store_type):

@@ -21,7 +21,7 @@ unit XLA actually allocates for — the compiled program:
   flag on, the cached programs dispatch through :class:`ProfiledJit`,
   an AOT-managed twin of ``jax.jit`` (explicit trace → lower → compile
   via the SAME underlying jit object, so the jaxpr cache and the
-  retrace counters behave identically — ``bench.py --mem-smoke``
+  retrace counters behave identically — ``tests/test_memprof.py``
   asserts bitwise-equal counters on/off).  The compiled executable's
   ``memory_analysis()`` (argument / output / temp / generated-code
   bytes — XLA's own allocation plan) lands on the program record.
@@ -71,8 +71,8 @@ _listener_installed = False
 # monotonic totals (never reset by the ring bound): how many program
 # records were opened for real builds vs disk restores, and how many
 # backend-compile events landed on an armed record.  The persistent
-# program cache's warm-start verification (serving warmup, bench.py
-# --coldstart-smoke) asserts the "built"/"backend_compiles" deltas are
+# program cache's warm-start verification (serving warmup,
+# elastic resume) asserts the "built"/"backend_compiles" deltas are
 # ZERO across a warm window — the listener-verified form of "nothing
 # compiled".
 _totals = {"built": 0, "restored": 0, "backend_compiles": 0}

@@ -22,8 +22,7 @@ serving stack's hops:
 - segments are host-side dicts with monotonic-clock offsets from the
   request's origin.  NOTHING here touches a traced program: tracing on
   vs off leaves exec-cache counters and served bytes bitwise identical
-  (``bench.py --reqtrace-smoke`` + ``tests/test_reqtrace.py`` assert
-  exactly that).
+  (``tests/test_reqtrace.py`` asserts exactly that).
 
 Storage is two-tier, the production trade-off:
 

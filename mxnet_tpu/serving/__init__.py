@@ -36,7 +36,7 @@ Pieces (each its own module, composable without :class:`Server`):
 - typed rejections (``errors.py``), instrument names (``metrics.py``).
 
 See docs/serving.md for the architecture and the bucket/warmup/
-rejection contracts; ``bench.py --serve-smoke`` is the executable
+rejection contracts; ``tests/test_serving.py`` is the executable
 spec.
 """
 from __future__ import annotations

@@ -70,7 +70,7 @@ class Request:
         # request actually ran in.  Bitwise reproducibility is per
         # program SHAPE (XLA specializes row blocking per shape), so
         # replaying a response exactly requires replaying its bucket —
-        # bench.py --serve-smoke's oracle reads this.
+        # the tests' replay oracle reads this.
         self.dispatch_bucket = None
         # observability/reqtrace.py RequestContext (None when tracing
         # is off): the per-request waterfall every hop appends to

@@ -18,9 +18,10 @@ as the error result, then the loop continues.  Padding rows are zeros;
 the graph evaluates row-wise (no cross-row ops in inference graphs this
 serves), so real rows are bitwise-identical to any run of the SAME
 bucket shape — XLA specializes row blocking per program shape, so
-across shapes equality holds only up to float reassociation.  The
-serve-smoke asserts exactly that (each request replayed at its
-``dispatch_bucket`` through a plain Predictor, compared bitwise).
+across shapes equality holds only up to float reassociation.
+``tests/test_serving.py`` asserts exactly that (each request replayed
+at its ``dispatch_bucket`` through a plain Predictor, compared
+bitwise).
 """
 from __future__ import annotations
 

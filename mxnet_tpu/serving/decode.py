@@ -24,7 +24,7 @@ device-resident block pool:
   tokens — pool positions beyond the cursor, other streams' pages, and
   table zeros are all dropped by SELECT — so every served stream is
   bitwise-equal to decoding it alone (tests/test_kv_cache.py pins
-  this, bench.py --decode-smoke asserts it under open-loop traffic).
+  this).
 - Prefill is the same program fed one prompt token per iteration; the
   decode phase feeds the previous argmax (greedy).
 - **Prefix reuse + COW.**  ``submit`` probes the pool's prefix cache

@@ -1,9 +1,9 @@
 """Serving telemetry: one place that names every serving instrument.
 
 All instrumentation runs on the host, outside jitted bodies (the
-bench-smoke invariant: telemetry on vs off changes zero retrace
-counters).  Instruments resolve through the PR 3 registry factories at
-the call site — they return the shared no-op handle when
+invariant: telemetry on vs off changes zero retrace counters).
+Instruments resolve through the PR 3 registry factories at the call
+site — they return the shared no-op handle when
 ``MXNET_TPU_TELEMETRY=0``, and re-resolve automatically across
 ``telemetry.reset()`` because nothing is cached here.
 

@@ -71,10 +71,9 @@ class ServedModel:
         self.buckets = bucket_sizes(max_batch_size)
         self.max_batch_size = max_batch_size
         # declared per-model latency SLO (p99 target, ms): the contract
-        # the open-loop harness (bench.py --slo-smoke) and the traceview
-        # attainment table judge observed latency against.  None = no
-        # declared target; the env default covers fleets whose deploy
-        # config owns the number.
+        # the traceview attainment table judges observed latency
+        # against.  None = no declared target; the env default covers
+        # fleets whose deploy config owns the number.
         if slo_ms is None:
             env = os.environ.get("MXNET_TPU_SERVING_SLO_MS", "").strip()
             try:

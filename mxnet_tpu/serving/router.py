@@ -56,8 +56,7 @@ Determinism: every replica binds the same graph at the same bucket
 shapes, so all replicas dispatch the SAME cached program — a routed
 response is bitwise-identical to a plain ``predict.Predictor`` replay
 at its recorded ``dispatch_bucket`` no matter which replica served it
-(``tests/test_serving_fleet.py`` pins this; ``bench.py --slo-smoke``
-asserts it under open-loop load).
+(``tests/test_serving_fleet.py`` pins this).
 """
 from __future__ import annotations
 

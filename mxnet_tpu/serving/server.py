@@ -257,8 +257,7 @@ class Server:
         plus totals.  The deploy pipeline runs this once (CI, or the
         first replica); every later replica mounts the dir and boots
         through ``warmup(expect_warm=True)`` in seconds — the
-        cold-start economics story (docs/serving.md §prewarm,
-        ``bench.py --coldstart-smoke``)."""
+        cold-start economics story (docs/serving.md §prewarm)."""
         from .. import program_cache
         names = self.registry.names()
         if not names:
@@ -465,7 +464,7 @@ class Server:
         metrics.record_admitted(request.n_rows, model=model)
         # debug/verification handle: the queued Request (rows, deadline,
         # and — once dispatched — dispatch_bucket, the program shape the
-        # response came from; the serve-smoke bitwise oracle needs it)
+        # response came from; a bitwise replay oracle needs it)
         request.future.request = request
         return request.future
 

@@ -1,10 +1,8 @@
 """observability.autotune — telemetry-driven auto-tuning controllers.
 
-Pins the safety rails of docs/autotune.md in isolation (`bench.py
---tune-smoke` is the end-to-end version): the shared log2-bucket
+Pins the safety rails of docs/autotune.md: the shared log2-bucket
 quantile estimator at its bucket edges, the mode gate
-(``MXNET_TPU_AUTOTUNE=recommend|apply|0``), the comm tuner's retrace
-budget (exhausted -> stops with a logged decision), the serving tuner's
+(``MXNET_TPU_AUTOTUNE=recommend|apply|0``), the serving tuner's
 footprint-vs-capacity validation (over-capacity -> rejected, never
 staged) and warmup-boundary adoption (zero steady-state retraces), the
 io tuner's starvation band, the ``=0`` kill switch (zero new telemetry
@@ -22,7 +20,6 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import executor_cache, serving
 from mxnet_tpu.observability import autotune, flight_recorder, telemetry
-from mxnet_tpu.parallel import comm
 
 rng = np.random.RandomState(7)
 
@@ -33,8 +30,7 @@ FEAT = 6
 def _isolate(monkeypatch):
     """Each test owns the autotune mode and the knobs the controllers
     may set; the decision log and metrics registry start empty."""
-    for var in ("MXNET_TPU_AUTOTUNE", "MXNET_TPU_COMM_BUCKET_MB",
-                "MXNET_TPU_GRAD_COMPRESS", "MXNET_TPU_IO_WORKERS"):
+    for var in ("MXNET_TPU_AUTOTUNE", "MXNET_TPU_IO_WORKERS"):
         monkeypatch.delenv(var, raising=False)
     autotune.clear_decisions()
     telemetry.reset()
@@ -141,71 +137,6 @@ def test_constructor_mode_overrides_env(monkeypatch):
     assert autotune.IoWorkerTuner(mode="apply").mode == "apply"
     with pytest.raises(ValueError):
         autotune.IoWorkerTuner(mode="bogus")
-
-
-# -- CommBucketTuner -------------------------------------------------------
-
-def _comm_measure(costs):
-    """A measure stub priced like the real thing: one retrace per
-    candidate (the PR 10 cache-key contract), cost from a table."""
-    def measure(mb):
-        executor_cache.note_trace("fwd_bwd")
-        return costs[mb]
-    return measure
-
-
-def test_comm_tuner_climbs_to_minimum_and_restores_env(monkeypatch):
-    costs = {1.0: 10.0, 2.0: 6.0, 4.0: 3.0, 8.0: 7.0, 0.5: 11.0}
-    rec = autotune.CommBucketTuner(_comm_measure(costs), budget=4,
-                                   mode="recommend", start_mb=1.0).run()
-    assert rec["action"] == "recommend"
-    assert rec["decision"]["bucket_mb"] == 4.0
-    assert rec["cost"]["retraces"] <= 4
-    # recommend mode leaves the env exactly as found (unset)
-    assert comm.BUCKET_ENV not in os.environ
-    tried = [t["bucket_mb"] for t in rec["candidates"]]
-    assert tried == [1.0, 2.0, 4.0, 8.0]
-
-
-def test_comm_tuner_downhill_direction(monkeypatch):
-    costs = {4.0: 10.0, 8.0: 12.0, 2.0: 6.0, 1.0: 9.0}
-    rec = autotune.CommBucketTuner(_comm_measure(costs), budget=8,
-                                   mode="recommend", start_mb=4.0).run()
-    assert rec["decision"]["bucket_mb"] == 2.0
-
-
-def test_comm_tuner_apply_sets_env(monkeypatch):
-    costs = {1.0: 10.0, 2.0: 3.0, 4.0: 8.0, 0.5: 12.0}
-    rec = autotune.CommBucketTuner(_comm_measure(costs), budget=4,
-                                   mode="apply", start_mb=1.0).run()
-    assert rec["action"] == "apply"
-    assert rec["decision"]["applied"] is True
-    assert os.environ[comm.BUCKET_ENV] == "2"
-
-
-def test_comm_tuner_stops_at_retrace_budget(monkeypatch):
-    # every candidate improves, so only the budget can stop the climb
-    def measure(mb):
-        executor_cache.note_trace("fwd_bwd")
-        return 1.0 / mb
-    rec = autotune.CommBucketTuner(measure, budget=3, mode="recommend",
-                                   start_mb=1.0).run()
-    assert rec["decision"]["budget_exhausted"] is True
-    assert rec["cost"]["retraces"] == 3
-    assert len(rec["candidates"]) == 3  # incumbent + 2 explored
-
-
-def test_comm_tuner_budget_exhausted_before_exploring_stops(monkeypatch):
-    # the incumbent's own measurement spends the whole budget (a cold
-    # program): the tuner must stop with a logged decision and must NOT
-    # apply anything, even in apply mode
-    rec = autotune.CommBucketTuner(_comm_measure({1.0: 5.0}), budget=1,
-                                   mode="apply", start_mb=1.0).run()
-    assert rec["action"] == "stop"
-    assert rec["decision"]["budget_exhausted"] is True
-    assert rec["decision"]["applied"] is False
-    assert comm.BUCKET_ENV not in os.environ
-    assert autotune.decision_log()[-1]["action"] == "stop"
 
 
 # -- ServingBucketTuner ----------------------------------------------------
@@ -473,11 +404,7 @@ def test_disabled_autotune_is_inert_and_bitwise(monkeypatch):
     autotune.clear_decisions()
     monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "0")
 
-    def measure(mb):  # must never be called
-        raise AssertionError("disabled tuner called measure()")
-
     params = _tiny_fit()
-    assert autotune.CommBucketTuner(measure, budget=4).run() is None
     assert autotune.ServingBucketTuner().run(_StubModel()) is None
     assert autotune.IoWorkerTuner().run() is None
     for k in baseline:
@@ -485,7 +412,6 @@ def test_disabled_autotune_is_inert_and_bitwise(monkeypatch):
     assert autotune.decision_log() == []
     assert not [name for name in telemetry.snapshot()
                 if name.startswith("autotune.")]
-    assert comm.BUCKET_ENV not in os.environ
     assert "MXNET_TPU_IO_WORKERS" not in os.environ
 
 
@@ -494,15 +420,13 @@ def test_disabled_autotune_is_inert_and_bitwise(monkeypatch):
 def test_decisions_ride_the_flight_dump_and_traceview(tmp_path):
     autotune.IoWorkerTuner(mode="recommend").run(
         snapshot=_io_snapshot(200.0, 1000.0), current_workers=2, cores=8)
-    autotune.CommBucketTuner(_comm_measure({1.0: 4.0, 2.0: 6.0,
-                                            0.5: 7.0}),
-                             budget=4, mode="recommend",
-                             start_mb=1.0).run()
+    autotune.ServingBucketTuner(mode="recommend").run(
+        _StubModel(), rows_hist=_rows_hist([5] * 50 + [3] * 20))
     path = str(tmp_path / "flight.json")
     assert flight_recorder.dump(path=path, reason="test") == path
     doc = json.load(open(path))
     controllers = [r["controller"] for r in doc["tuning"]]
-    assert controllers == ["io_workers", "comm_bucket"]
+    assert controllers == ["io_workers", "serving_buckets"]
     # strict JSON all the way down (the flight contract)
     for rec in doc["tuning"]:
         json.dumps(rec, allow_nan=False)
@@ -510,9 +434,10 @@ def test_decisions_ride_the_flight_dump_and_traceview(tmp_path):
     tv = _load_traceview()
     stats = tv.tuning_stats(tv.tuning_records(doc))
     assert stats["decisions"] == 2
-    assert stats["by_controller"] == {"io_workers": 1, "comm_bucket": 1}
+    assert stats["by_controller"] == {"io_workers": 1,
+                                      "serving_buckets": 1}
     text = tv.summarize_tuning(doc["tuning"])
-    assert "comm_bucket" in text and "io_workers" in text
+    assert "serving_buckets" in text and "io_workers" in text
     assert tv.main(["--tuning", path]) == 0
     # a dump with no decisions exits 2 (the "autotune never ran" signal)
     empty = str(tmp_path / "empty.json")

@@ -17,7 +17,6 @@ from mxnet_tpu import elastic
 from mxnet_tpu.elastic import Checkpointer, PreemptedError, chaos
 from mxnet_tpu.elastic.checkpoint import (PARAMS_FILE, Snapshot,
                                           SnapshotError)
-from mxnet_tpu.parallel import comm as _comm
 
 
 def _net():
@@ -424,10 +423,6 @@ def test_chaos_corrupt_checkpoint_hook(tmp_path):
 
 # -- optimizer-state round trip across a mesh re-factorization ---------------
 
-_COMM_KNOBS = ("MXNET_TPU_COMM_BUCKET_MB", "MXNET_TPU_GRAD_COMPRESS",
-               "MXNET_TPU_GRAD_COMPRESS_THRESHOLD")
-
-
 def _dp_mlp():
     h = mx.sym.Activation(mx.sym.FullyConnected(
         mx.sym.var("data"), num_hidden=32, name="fc1"), act_type="relu")
@@ -447,88 +442,46 @@ def _dp_fit(n_dev, epochs=2):
     return mod
 
 
-@pytest.fixture
-def _compressed(monkeypatch):
-    monkeypatch.setenv("MXNET_TPU_COMM_BUCKET_MB", "0.001")
-    monkeypatch.setenv("MXNET_TPU_GRAD_COMPRESS", "2bit")
-    monkeypatch.setenv("MXNET_TPU_GRAD_COMPRESS_THRESHOLD", "0.05")
-    yield
+def _assert_states_bitwise(mod_a, mod_b):
+    """Momentum (and the f32 master, where a parameter has one) of
+    every parameter, bitwise."""
+    sa = mod_a._fused_step.export_states()
+    sb = mod_b._fused_step.export_states()
+    assert sorted(sa) == sorted(sb) == sorted(
+        mod_a._fused_step.param_names)
+    for name in sa:
+        assert np.array_equal(np.asarray(sa[name]["state"]),
+                              np.asarray(sb[name]["state"])), name
+        assert ("master" in sa[name]) == ("master" in sb[name])
+        if "master" in sa[name]:
+            assert np.array_equal(sa[name]["master"], sb[name]["master"])
 
 
-def _residuals(mod):
-    return [np.asarray(r) for r in mod._fused_step._residuals]
-
-
-def test_optimizer_roundtrip_dp8_to_dp8_bitwise(tmp_path, _compressed):
+def test_optimizer_roundtrip_dp8_to_dp8_bitwise(tmp_path):
     mod8 = _dp_fit(8)
-    res8 = _residuals(mod8)
-    assert res8 and any(np.abs(r).sum() > 0 for r in res8)
     path = str(tmp_path / "opt.states")
     mod8.save_optimizer_states(path)
     raw = pickle.load(open(path, "rb"))
     assert raw["format"] == "fused_v2"
-    assert "__comm_residuals__" in raw["states"]
+    assert sorted(raw["states"]) == sorted(mod8._fused_step.param_names)
 
     mod8b = _dp_fit(8, epochs=1)
     mod8b.load_optimizer_states(path)
-    for a, b in zip(_residuals(mod8b), res8):
-        assert np.array_equal(a, b)  # bitwise at equal factorization
-    # momentum too
-    sa = mod8._fused_step.export_states()
-    sb = mod8b._fused_step.export_states()
-    for name in ("fc1_weight", "fc2_weight"):
-        la = np.asarray(sa[name]["state"])
-        lb = np.asarray(sb[name]["state"])
-        assert np.array_equal(la, lb), name
+    _assert_states_bitwise(mod8, mod8b)
 
 
-def test_optimizer_roundtrip_dp8_to_dp4_sum_merges(tmp_path, _compressed):
+def test_optimizer_roundtrip_dp8_to_dp4_bitwise(tmp_path):
+    """The state is replicated over the dp mesh, so a narrower mesh
+    takes it as it is."""
     mod8 = _dp_fit(8)
-    res8 = _residuals(mod8)
     path = str(tmp_path / "opt.states")
     mod8.save_optimizer_states(path)
 
     mod4 = _dp_fit(4, epochs=1)
     mod4.load_optimizer_states(path)
-    want, reason = _comm.reshard_residuals(res8, 4)
-    assert reason is None
-    got = _residuals(mod4)
-    assert [r.shape for r in got] == [w.shape for w in want]
-    for a, b in zip(got, want):
-        assert np.array_equal(a, b)
-    # the pending quantization error is conserved across the merge
-    for a, b in zip(want, res8):
-        np.testing.assert_allclose(a.sum(axis=0), b.sum(axis=0),
-                                   rtol=1e-6, atol=1e-7)
-
-
-def test_optimizer_roundtrip_layout_change_warns_and_drops(
-        tmp_path, _compressed, monkeypatch, caplog):
-    mod8 = _dp_fit(8)
-    path = str(tmp_path / "opt.states")
-    mod8.save_optimizer_states(path)
-
-    monkeypatch.setenv("MXNET_TPU_COMM_BUCKET_MB", "0.002")
-    mod4 = _dp_fit(4, epochs=1)
-    import logging
-    with caplog.at_level(logging.WARNING, logger="mxnet_tpu"):
-        mod4.load_optimizer_states(path)
-    assert any("dropping them" in r.message for r in caplog.records)
-    assert all(np.abs(r).sum() == 0 for r in _residuals(mod4))
-
-
-def test_reshard_residuals_pure_function():
-    buckets = [np.arange(16, dtype=np.float32).reshape(8, 2)]
-    out, reason = _comm.reshard_residuals(buckets, 4)
-    assert reason is None
-    assert out[0].shape == (4, 2)
-    np.testing.assert_array_equal(out[0].sum(axis=0),
-                                  buckets[0].sum(axis=0))
-    # not divisible (including growing the mesh): declined with reason
-    out, reason = _comm.reshard_residuals(buckets, 3)
-    assert out is None and "divisible" in reason
-    out, reason = _comm.reshard_residuals(buckets, 16)
-    assert out is None
+    _assert_states_bitwise(mod8, mod4)
+    for st in mod4._fused_step.states:
+        assert len(st.devices()) == 4
 
 
 # -- review-hardening regressions --------------------------------------------

@@ -98,6 +98,9 @@ def test_pp_training_matches_sequential_module():
 
 
 def test_pp_forward_matches_and_scores():
+    # Xavier draws from numpy's global generator: unseeded, one start in
+    # ten leaves the accuracy at chance after 120 steps
+    mx.random.seed(1)
     rng = np.random.RandomState(1)
     X, y = _problem(rng)
     mesh = _mesh(dp=2, pp=2)

@@ -1,7 +1,6 @@
 """End-to-end request tracing (observability/reqtrace.py).
 
-Pins the contracts `bench.py --reqtrace-smoke` proves at traffic
-scale, in isolation:
+Pins its contracts:
 
 - a served request owns a CONTIGUOUS typed waterfall (queue ->
   assemble -> dispatch -> split on the single-process path; + route and

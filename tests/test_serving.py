@@ -6,8 +6,6 @@ assembly), typed rejections (oversized request, deadline expiry while
 queued, overload backpressure, unknown model, malformed payload),
 warmup's zero-recompile verification, graceful drain completing
 in-flight work, and the dispatch thread surviving model failures.
-`bench.py --serve-smoke` is the concurrent end-to-end version of the
-same contracts; these tests pin each behavior in isolation.
 """
 import time
 

@@ -1,8 +1,7 @@
 """mxnet_tpu.serving fleet tier — replica groups, router, continuous
 batching, SLO plumbing.
 
-Pins the contracts `bench.py --slo-smoke` proves at scale, in
-isolation:
+Pins its contracts:
 
 - weighted least-loaded routing actually shifts load away from a slow
   replica (injected latency skew);

@@ -1,7 +1,6 @@
 """Fleet health plane (observability/{timeseries,alerts,shipper}.py).
 
-Pins the contracts `bench.py --alert-smoke` proves at traffic scale,
-in isolation:
+Pins its contracts:
 
 - every instrument snapshot carries the registry generation token; a
   `telemetry.reset()` inside a window surfaces as a `resets` marker

@@ -302,11 +302,9 @@ def pipeline_breakdown(events):
 
 
 def comm_breakdown(events):
-    """Gradient-communication view (docs/distributed.md): EXPOSED comm
-    is the ``comm:*`` spans (kvstore collectives the step waits on);
-    OVERLAPPED comm is the ``comm_overlapped_bytes`` counter track the
-    fused step emits for its in-program bucketed collectives.  Returns
-    None when the trace carries neither."""
+    """Gradient-communication view (docs/distributed.md): the
+    ``comm:*`` spans, the kvstore collectives the step waits on.
+    Returns None when the trace carries none."""
     durations = span_durations(events)
     exposed = {"count": 0, "total_ms": 0.0, "bytes": 0}
     for e in events:
@@ -314,25 +312,11 @@ def comm_breakdown(events):
             exposed["count"] += 1
             exposed["total_ms"] += e.get("dur", 0) / 1e3
             exposed["bytes"] += int((e.get("args") or {}).get("bytes", 0))
-    overlapped_bytes = 0
-    overlapped_samples = 0
-    for e in events:
-        if e.get("ph") == "C" and e.get("name") == "comm_overlapped_bytes":
-            # per-step counter samples: they sum to the window's total
-            args = e.get("args") or {}
-            val = args.get("value", args.get("comm_overlapped_bytes", 0))
-            overlapped_bytes += _fnum(val, 0)
-            overlapped_samples += 1
-    if not exposed["count"] and not overlapped_samples:
+    if not exposed["count"]:
         return None
     steps = sum(1 for cat, name, ms in durations
                 if cat == "step" and name == "step") or None
-    return {
-        "exposed": exposed,
-        "overlapped_bytes": int(overlapped_bytes),
-        "overlapped_steps": overlapped_samples,
-        "steps": steps,
-    }
+    return {"exposed": exposed, "steps": steps}
 
 
 def instants(events):
@@ -670,14 +654,9 @@ def summarize_tuning(records, top=20):
         lines.append("%-18s %9d" % (a, stats["by_action"][a]))
     lines.append("")
     for r in records[-top:]:
-        cost = r.get("cost") or {}
-        head = "%-16s %-10s mode=%-9s" % (r.get("controller", "?"),
-                                          r.get("action", "?"),
-                                          r.get("mode", "?"))
-        budget = cost.get("retrace_budget")
-        if budget is not None:
-            head += " retraces %s/%s" % (cost.get("retraces", 0), budget)
-        lines.append(head)
+        lines.append("%-16s %-10s mode=%-9s" % (r.get("controller", "?"),
+                                                r.get("action", "?"),
+                                                r.get("mode", "?")))
         lines.append("  %s" % r.get("reason", ""))
         for cand in (r.get("candidates") or [])[:6]:
             lines.append("  candidate: %s" % json.dumps(cand,
@@ -728,8 +707,7 @@ def elastic_stats(records):
                             "refactorized": r.get("refactorized"),
                             "n_dev_from": r.get("n_dev_from"),
                             "n_dev_to": r.get("n_dev_to"),
-                            "warm": r.get("warm") or {},
-                            "comm_retuned": r.get("comm_retuned")})
+                            "warm": r.get("warm") or {}})
     return {"records": len(records), "by_kind": by_kind,
             "checkpoints": checkpoints,
             "last_checkpoint_step": (checkpoints[-1]["step"]
@@ -777,12 +755,10 @@ def summarize_elastic(records):
                         else "same factorization (%s device(s))"
                         % r["n_dev_to"]))
         lines.append("  warm boot: %s disk restore(s), %s built, %s "
-                     "backend compile(s), %s retrace(s)%s"
+                     "backend compile(s), %s retrace(s)"
                      % (warm.get("restored", 0), warm.get("built", 0),
                         warm.get("backend_compiles", 0),
-                        warm.get("traces", 0),
-                        "  [comm re-tuned]" if r.get("comm_retuned")
-                        else ""))
+                        warm.get("traces", 0)))
     return "\n".join(lines)
 
 
@@ -1954,25 +1930,11 @@ def summarize(trace, top=15):
         lines.append("== gradient communication ==")
         ex = cb["exposed"]
         steps = cb["steps"]
-        if ex["count"]:
-            per_step = " (%.3f ms/step)" % (ex["total_ms"] / steps) \
-                if steps else ""
-            lines.append("exposed:    %d collectives, %.3f ms total%s, %s"
-                         % (ex["count"], ex["total_ms"], per_step,
-                            _fmt_bytes(ex["bytes"])))
-        else:
-            lines.append("exposed:    none (no host-driven kvstore "
-                         "collectives)")
-        if cb["overlapped_steps"]:
-            per_step = cb["overlapped_bytes"] / cb["overlapped_steps"]
-            lines.append("overlapped: %s over %d steps (%s/step, "
-                         "in-program bucketed collectives — no exposed "
-                         "wall time)"
-                         % (_fmt_bytes(cb["overlapped_bytes"]),
-                            cb["overlapped_steps"], _fmt_bytes(per_step)))
-        else:
-            lines.append("overlapped: none (monolithic reduction or "
-                         "single device)")
+        per_step = " (%.3f ms/step)" % (ex["total_ms"] / steps) \
+            if steps else ""
+        lines.append("exposed:    %d collectives, %.3f ms total%s, %s"
+                     % (ex["count"], ex["total_ms"], per_step,
+                        _fmt_bytes(ex["bytes"])))
 
     inst = instants(events)
     if inst:
