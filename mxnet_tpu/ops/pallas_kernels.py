@@ -64,15 +64,31 @@ def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, n_q, h, d)
 
 
+def _last_kv_tile(q_idx, kv_len, block_q, block_k, causal):
+    """Index of the last K/V tile the q-block ``q_idx`` needs: the one that
+    holds key ``kv_len - 1`` and, under ``causal``, the one that holds the
+    block's last row's own position.  The kernel computes tiles up to it
+    and the K/V index map stops at it, so the tiles past it are neither
+    computed nor fetched.  Plain integer arithmetic: traced scalars in the
+    kernel and the index map, Python ints in the tests."""
+    last = (jnp.maximum(kv_len, 1) - 1) // block_k
+    if causal:
+        last = jnp.minimum(last, (q_idx * block_q + block_q - 1) // block_k)
+    return last
+
+
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
-                  causal, scale, block_q, block_k, n_kv_blocks,
+                  causal, scale, block_q, block_k, group, n_kv_blocks,
                   emit_lse):
-    """One (q-block, kv-block) grid step.  Grid = (BH, n_q, n_kv) with the
-    kv dimension innermost; m/l/acc scratch persists across kv steps of the
-    same q block (standard flash-attention accumulation).  ``len_ref`` is
-    the scalar-prefetched int32 [BH] vector of valid KV lengths (SMEM):
-    the padding mask, and the bound that makes block-padded sequences
-    exact."""
+    """One (q-block, kv-block) grid step.  Grid = (B*KV, n_q, n_kv) with
+    the kv dimension innermost; m/l/acc scratch persists across kv steps of
+    the same q block (standard flash-attention accumulation).  The q tile
+    holds ``block_q`` positions of ALL ``group`` query heads that share the
+    step's K/V head, flattened to ``group * block_q`` rows: one K/V tile is
+    read once for the whole group and both products see that many rows.
+    ``len_ref`` is the scalar-prefetched int32 [B*KV] vector of valid KV
+    lengths (SMEM): the padding mask, and the bound that makes block-padded
+    sequences exact."""
     if emit_lse:
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -82,6 +98,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
+    rows = group * block_q
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -90,16 +107,16 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     kv_len = len_ref[pl.program_id(0)]
-    # skip kv blocks entirely past the valid length; under causal, also
-    # blocks strictly above the diagonal — neither contributes weight
-    needed = kv_idx * block_k < kv_len
-    if causal:
-        needed &= kv_idx * block_k <= q_idx * block_q + (block_q - 1)
+    # tiles wholly past the valid length and, under causal, wholly above
+    # the diagonal contribute no weight: the body skips them here and the
+    # K/V index map (_call_flash) never moves to them
+    needed = (kv_len > 0) & (
+        kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0]                  # [block_q, d]
-        k = k_ref[0]                  # [block_k, d]
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])   # [group*block_q, d]
+        k = k_ref[0]                                  # [block_k, d]
         v = v_ref[0]
         # concrete f32 constants: the framework runs with x64 on and the
         # kernel must never see a 64-bit scalar (re-checked on jax 0.9: a
@@ -109,19 +126,24 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
                                 preferred_element_type=jnp.float32) \
             * jnp.float32(scale)
 
+        # a row's position is q_idx*block_q + (row mod block_q): the mask
+        # is one [block_q, block_k] tile that every head of the group
+        # shares, added as 0 / -1e30 (s - 1e30 rounds to -1e30 in float32)
         cols = kv_idx * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = cols < kv_len
         if causal:
-            rows = q_idx * block_q + jax.lax.broadcasted_iota(
+            pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            valid &= rows >= cols
-        s = jnp.where(valid, s, jnp.float32(_NEG_INF))
+            valid &= pos >= cols
+        bias = jnp.where(valid, jnp.float32(0.0), jnp.float32(_NEG_INF))
+        s = (s.reshape(group, block_q, block_k) + bias[None]).reshape(
+            rows, block_k)
 
-        # m/l scratch is lane-tiled [block_q, 128] (TPU min tile); the
+        # m/l scratch is lane-tiled [rows, 128] (TPU min tile); the
         # running stats live broadcast across lanes and are read back via
         # a 1-lane slice of the loaded value
-        m_prev = m_ref[:][:, :1]      # [block_q, 1]
+        m_prev = m_ref[:][:, :1]      # [rows, 1]
         l_prev = l_ref[:][:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -132,45 +154,122 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         lanes = m_ref.shape[1]
-        m_ref[:] = jnp.broadcast_to(m_new, (m_new.shape[0], lanes))
-        l_ref[:] = jnp.broadcast_to(l_new, (l_new.shape[0], lanes))
+        m_ref[:] = jnp.broadcast_to(m_new, (rows, lanes))
+        l_ref[:] = jnp.broadcast_to(l_new, (rows, lanes))
 
     @pl.when(kv_idx == n_kv_blocks - 1)
     def _finalize():
         l = l_ref[:][:, :1]
         l = jnp.where(l == 0, jnp.float32(1.0), l)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype).reshape(
+            o_ref.shape[1:])
         if emit_lse:
             # per-row log-sum-exp residual for the custom backward.
-            # Lane-broadcast [block_q, 128]: Mosaic requires the last two
+            # Lane-broadcast [rows, 128]: Mosaic requires the last two
             # block dims be 8/128-divisible, which rules out a compact
             # (1, block_q) layout; the 128x write only happens on the
             # DIFFERENTIATED forward (inference skips lse entirely)
             lse = m_ref[:][:, :1] + jnp.log(l)
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+            lse_ref[0] = jnp.broadcast_to(
+                lse, (rows, lse_ref.shape[-1])).reshape(lse_ref.shape[1:])
 
 
 def _round_up(n, m):
     return ((n + m - 1) // m) * m
 
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, use_pallas=None, interpret=None,
+def _sublanes(itemsize):
+    """Rows of one TPU tile for an element size: 8 of float32, 16 of
+    bfloat16."""
+    return 8 * max(1, 4 // itemsize)
+
+
+# What one grid step of the flash kernel may hold in VMEM by the plan's own
+# count, and the scoped limit the call asks Mosaic for.  The default scoped
+# limit (16 MiB on a v5e, of 128 MiB physical) is the binding one for the
+# large tiles, so the call states its own; the margin over the budget is
+# for what the count leaves out (Mosaic's own temporaries, semaphores).
+_FLASH_VMEM_BUDGET = 32 << 20
+_FLASH_VMEM_LIMIT = 48 << 20
+# past these a tile gains nothing: a step is already ~1 GFLOP against the
+# pipeline's ~0.4 us, K/V are re-read once per 2,048 query rows, and the
+# masked part of the diagonal tiles grows with both
+_FLASH_MAX_ROWS = 2048
+_FLASH_MAX_BLOCK_K = 1024
+
+
+def _flash_vmem_bytes(block_q, block_k, d, group, itemsize):
+    """VMEM one grid step holds, by operand: the double-buffered q, k, v,
+    out and log-sum-exp tiles, the three float32 scratch arrays, and the
+    float32 score and probability tiles with the probabilities' cast."""
+    rows = group * block_q
+    piped = 2 * (2 * rows * d * itemsize + 2 * block_k * d * itemsize
+                 + rows * 128 * 4)
+    scratch = rows * (128 + 128 + d) * 4
+    scores = rows * block_k * (4 + 4 + itemsize)
+    return piped + scratch + scores
+
+
+def _flash_plan(sq, sk, d, group, itemsize, causal):
+    """(block_q, block_k) for a [sq] x [sk] attention at head size ``d``
+    with ``group`` query heads a K/V head: the fewest equal tiles whose
+    step fits ``_FLASH_VMEM_BUDGET``, a q tile of at most
+    ``_FLASH_MAX_ROWS`` rows over the whole group and a K/V tile of at most
+    ``_FLASH_MAX_BLOCK_K`` keys (under ``causal``, neither side longer than
+    a quarter of the keys).  Tiles are sublane-aligned for the element
+    size (8 rows of float32, 16 of bfloat16) and 128-aligned where a
+    sequence takes several K/V tiles (keys lie on the score tile's lanes);
+    a sequence shorter than a cap is one tile of its own padded length, so
+    short and odd lengths are never padded past their alignment."""
+    sub = _sublanes(itemsize)
+
+    def block(n, cap, align):
+        if _round_up(n, sub) <= cap:
+            return _round_up(n, sub)
+        n_tiles = -(-n // cap)
+        return _round_up(-(-n // n_tiles), align)
+
+    cap_q = max(_FLASH_MAX_ROWS // group // sub * sub, sub)
+    cap_k = _FLASH_MAX_BLOCK_K
+    if causal:
+        # the masked part of the diagonal tiles is work thrown away, in
+        # proportion to (block_q + block_k) / keys: a tile side stays
+        # within a quarter of the keys, but not under 512, below which the
+        # per-step cost outweighs it (measured on the v5e, PERF.md PR 31)
+        cap_q, cap_k = (min(c, max(512, sk // 4)) for c in (cap_q, cap_k))
+    while True:
+        bq, bk = block(sq, cap_q, sub), block(sk, cap_k, 128)
+        if _flash_vmem_bytes(bq, bk, d, group, itemsize) \
+                <= _FLASH_VMEM_BUDGET or (cap_q == sub and cap_k == 128):
+            return bq, bk
+        # halve the longer side of the score tile
+        if (bk >= group * bq and cap_k > 128) or cap_q == sub:
+            cap_k //= 2
+        else:
+            cap_q = max(cap_q // 2 // sub * sub, sub)
+
+
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, use_pallas=None, interpret=None,
                     kv_lens=None):
-    """Blocked flash attention.  q/k/v: [batch, seq, heads, head_dim].
+    """Blocked flash attention.  q/k/v: [batch, seq, heads, head_dim];
+    ``k``/``v`` may hold fewer heads than ``q`` (grouped queries: K/V head
+    ``j`` serves query heads ``j*group .. (j+1)*group``).
 
     ``kv_lens``: optional (batch,) valid KV lengths — the padding mask.
     Sequences that do not tile evenly are block-padded internally and
     bounded by the same per-row length the padding mask uses, so any
     seq length is exact.  use_pallas=None auto-selects: the Pallas
     kernel on TPU backends for lane-tiled head dims, the XLA reference
-    otherwise.
+    otherwise.  ``block_q`` / ``block_k``: tile sizes, from
+    :func:`_flash_plan` unless given (the tests give them).
     """
     b, sq, h, d = q.shape
     sk, kv = k.shape[1], k.shape[2]
     if h % kv:
         raise ValueError("flash_attention: %d query heads are no multiple "
                          "of %d K/V heads" % (h, kv))
+    group = h // kv
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
@@ -180,10 +279,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if not use_pallas:
         return _reference_attention(q, k, v, causal, scale, kv_lens)
 
-    # block sizes: sublane-tiled (multiple of 8), never beyond the padded
-    # sequence; short/odd sequences round up to the next tile
-    bq = min(block_q, _round_up(sq, 8))
-    bk = min(block_k, _round_up(sk, 8))
+    # tile sizes: the plan's, or the caller's held to the same alignment
+    # and never beyond the padded sequence
+    itemsize = jnp.dtype(q.dtype).itemsize
+    sub = _sublanes(itemsize)
+    bq, bk = _flash_plan(sq, sk, d, group, itemsize, causal)
+    if block_q is not None:
+        bq = _round_up(min(block_q, sq), sub)
+    if block_k is not None:
+        bk = _round_up(min(block_k, sk), sub)
     sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
 
     # layout: fold heads into batch, [BH, S, D]; pad to block multiples
@@ -195,23 +299,23 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if sk_p != sk:
         kf = jnp.pad(kf, ((0, 0), (0, sk_p - sk), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, sk_p - sk), (0, 0)))
-    # per-row valid KV length, f32 [BH] (f32 so the custom_vjp can hand
-    # back an ordinary zero cotangent; the kernel reads it as int32 from
-    # SMEM).  Block padding and the user's padding mask are the same
+    # per-K/V-row valid KV length, f32 [B*KV] (f32 so the custom_vjp can
+    # hand back an ordinary zero cotangent; the kernel reads it as int32
+    # from SMEM).  Block padding and the user's padding mask are the same
     # bound to the kernel.
     if kv_lens is None:
         lens = jnp.full((b,), sk, jnp.float32)
     else:
         lens = jnp.clip(kv_lens.astype(jnp.float32), 0, sk)
-    lens = jnp.broadcast_to(lens[:, None], (b, h)).reshape(b * h)
+    lens = jnp.broadcast_to(lens[:, None], (b, kv)).reshape(b * kv)
 
     # dispatch through a jitted-callable cache: tracing a pallas_call is
     # hundreds of ms of host work, so eager per-call tracing would swamp
     # the kernel (measured 680 ms/call untraced vs 0.02 ms cached)
     out = _flash_vjp_wrapped(qf, kf, vf, lens,
-                             (b, h, sq_p, sk_p, d, str(jnp.dtype(q.dtype)),
-                              causal, float(scale), bq, bk,
-                              interpret) + ((h // kv,) if h != kv else ()))
+                             (b * kv, group, sq_p, sk_p, d,
+                              str(jnp.dtype(q.dtype)), causal, float(scale),
+                              bq, bk, interpret))
     out = out.reshape(b, h, sq_p, d)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
@@ -233,10 +337,13 @@ def _flash_vjp_fwd(qf, kf, vf, lens, meta):
 
 
 def _flash_vjp_bwd(meta, res, d_out):
-    b, h, sq, sk, d, dtype, causal, scale, block_q, block_k, interpret = \
-        meta[:11]
+    bkv, group, sq, sk, d, dtype, causal, scale, block_q, block_k, \
+        interpret = meta
     qf, kf, vf, lens, out, lse = res
-    fn = _flash_bwd_jitted(sq, sk, causal, scale, min(block_q, sq), *meta[11:])
+    # the backward walks q in blocks of its own: 128 rows wherever the
+    # forward's tile is a multiple of that, else the (short) tile itself
+    bwd_block = 128 if block_q % 128 == 0 else block_q
+    fn = _flash_bwd_jitted(sq, sk, causal, scale, bwd_block, group)
     dq, dk, dv = fn(qf, kf, vf, lens, out, lse, d_out)
     return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype),
             jnp.zeros_like(lens))
@@ -323,7 +430,7 @@ def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
     view = lambda x: x.reshape((bkv, group) + x.shape[1:])
     qg, og, dog, lseg = view(qf), view(out), view(d_out), view(lse)
     D = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
-    kv_len = view(lens)[:, 0].astype(jnp.int32)                # [B*KV]
+    kv_len = lens.astype(jnp.int32)                            # [B*KV]
     f32 = dict(preferred_element_type=jnp.float32)
 
     def body(i, carry):
@@ -355,64 +462,73 @@ def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
 
 
 @functools.lru_cache(maxsize=512)
-def _flash_jitted(b, h, sq, sk, d, dtype, causal, scale, block_q, block_k,
-                  interpret, group=1, with_lse=False):
-    n_q = sq // block_q
-    n_kv = sk // block_k
+def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
+                  block_k, interpret, with_lse=False):
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, n_kv_blocks=n_kv, emit_lse=with_lse)
+        block_k=block_k, group=group, n_kv_blocks=sk // block_k,
+        emit_lse=with_lse)
 
     def run(qf, kf, vf, lens):
         # the framework enables jax x64 globally (float64 NDArray API
         # parity); Mosaic rejects 64-bit types, so trace under 32-bit rules
         with _enable_x64(False):
-            return _call_flash(kernel, qf, kf, vf, lens, b, h, sq, d, n_q,
-                               n_kv, block_q, block_k,
-                               jnp.dtype(dtype), interpret, with_lse, group)
+            # folded query head b*h + kvh*group + g: [B*KV, group, S, D] is
+            # a view of [B*H, S, D], and so are the results' way back
+            out, lse = _call_flash(
+                kernel, qf.reshape(bkv, group, sq, d), kf, vf, lens,
+                block_q, block_k, causal, interpret, with_lse)
+            return (out.reshape(bkv * group, sq, d),
+                    lse.reshape(bkv * group, sq, 128) if with_lse else None)
 
     return jax.jit(run)
 
 
-def _call_flash(kernel, qf, kf, vf, lens, b, h, sq, d, n_q, n_kv, block_q,
-                block_k, dtype, interpret, with_lse, group=1):
+def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
+                interpret, with_lse):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    bkv, group, sq, d = qg.shape
+    n_q, n_kv = sq // block_q, kf.shape[1] // block_k
     # index maps see the scalar-prefetch ref as a trailing argument
-    q_map = lambda bh, qi, ki, lens: (bh, qi, 0)  # noqa: E731
-    kv_map = lambda bh, qi, ki, lens: (bh, ki, 0)  # noqa: E731
-    if group != 1:
-        # grouped-query attention: folded query head bh = batch*h + head
-        # reads K/V row batch*kv + head // group, which is bh // group
-        kv_map = lambda bh, qi, ki, lens: (bh // group, ki, 0)  # noqa: E731
-    out_specs = [pl.BlockSpec((1, block_q, d), q_map)]
-    out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), dtype)]
+    q_map = lambda g, qi, ki, lens: (g, 0, qi, 0)  # noqa: E731
+
+    def kv_map(g, qi, ki, lens):
+        # past the last tile the q-block needs the index stays where it
+        # is: the pipeline sees an unchanged block and issues no DMA
+        return (g, jnp.minimum(ki, _last_kv_tile(
+            qi, lens[g], block_q, block_k, causal)), 0)
+
+    out_specs = [pl.BlockSpec((1, group, block_q, d), q_map)]
+    out_shape = [jax.ShapeDtypeStruct(qg.shape, qg.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, block_q, 128), q_map))
+        out_specs.append(pl.BlockSpec((1, group, block_q, 128), q_map))
         out_shape.append(
-            jax.ShapeDtypeStruct((b * h, sq, 128), jnp.float32))
+            jax.ShapeDtypeStruct((bkv, group, sq, 128), jnp.float32))
+    rows = group * block_q
     res = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * h, n_q, n_kv),
+            grid=(bkv, n_q, n_kv),
             in_specs=[
-                pl.BlockSpec((1, block_q, d), q_map),
+                pl.BlockSpec((1, group, block_q, d), q_map),
                 pl.BlockSpec((1, block_k, d), kv_map),
                 pl.BlockSpec((1, block_k, d), kv_map),
             ],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, d), jnp.float32),
             ]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_LIMIT),
         name="flash_attn_fwd",
         **({"interpret": interpret} if interpret is not None else {}),
-    )(lens.astype(jnp.int32), qf, kf, vf)
+    )(lens.astype(jnp.int32), qg, kf, vf)
     return res if with_lse else (res[0], None)
 
 

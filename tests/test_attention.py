@@ -46,18 +46,37 @@ def _qkv(b, s, h, d, dtype=jnp.float32, seed=0):
 # ---------------------------------------------------------------------------
 
 ATTN_CASES = [
-    # (dtype, causal, with_lens, seq)
-    (jnp.float32, False, False, 16),
-    (jnp.float32, True, False, 16),
-    (jnp.float32, False, True, 16),
-    (jnp.float32, True, True, 13),    # odd length: block padding + mask
-    (jnp.bfloat16, False, False, 16),
-    (jnp.bfloat16, True, True, 16),
+    # (dtype, causal, with_lens, seq, block_q, block_k): None = planned
+    (jnp.float32, False, False, 16, None, None),
+    (jnp.float32, True, False, 16, None, None),
+    (jnp.float32, False, True, 16, None, None),
+    (jnp.float32, True, True, 13, None, None),   # odd: block padding + mask
+    (jnp.bfloat16, False, False, 16, None, None),
+    (jnp.bfloat16, True, True, 16, None, None),
+    # several q- and kv-tiles a sequence, block_q != block_k
+    (jnp.float32, True, True, 300, 64, 128),
+    (jnp.bfloat16, False, True, 256, 128, 64),
 ]
-ATTN_IDS = ["%s-%s%s-s%d" % (np.dtype(c[0]).name,
-                             "causal" if c[1] else "full",
-                             "-lens" if c[2] else "", c[3])
+
+
+def _blocks_id(bq, bk):
+    return "" if bq is None else "-q%dk%d" % (bq, bk)
+
+
+ATTN_IDS = ["%s-%s%s-s%d%s" % (np.dtype(c[0]).name,
+                               "causal" if c[1] else "full",
+                               "-lens" if c[2] else "", c[3],
+                               _blocks_id(*c[4:]))
             for c in ATTN_CASES]
+
+
+def _lens(seq, with_lens):
+    """Valid K/V lengths of a batch of two: the whole sequence, and one cut
+    by a few keys (a third of a long one, so whole K/V tiles lie past it)."""
+    if not with_lens:
+        return None
+    return jnp.asarray([seq, max(1, seq - 5) if seq < 64 else seq // 3],
+                       jnp.int32)
 
 
 def _tols(dtype):
@@ -67,14 +86,14 @@ def _tols(dtype):
 
 @pytest.mark.parametrize("case", ATTN_CASES, ids=ATTN_IDS)
 def test_flash_forward_matches_reference(case):
-    dtype, causal, with_lens, seq = case
+    dtype, causal, with_lens, seq, block_q, block_k = case
     q, k, v = _qkv(2, seq, 2, 128, dtype, seed=1)
-    lens = jnp.asarray([seq, max(1, seq - 5)], jnp.int32) \
-        if with_lens else None
+    lens = _lens(seq, with_lens)
     scale = 1.0 / 128 ** 0.5
     want = pk._reference_attention(q, k, v, causal, scale, lens)
     got = pk.flash_attention(q, k, v, causal=causal, use_pallas=True,
-                             interpret=True, kv_lens=lens)
+                             interpret=True, kv_lens=lens,
+                             block_q=block_q, block_k=block_k)
     assert got.dtype == q.dtype
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -83,10 +102,9 @@ def test_flash_forward_matches_reference(case):
 
 @pytest.mark.parametrize("case", ATTN_CASES, ids=ATTN_IDS)
 def test_flash_grads_match_reference(case):
-    dtype, causal, with_lens, seq = case
+    dtype, causal, with_lens, seq, block_q, block_k = case
     q, k, v = _qkv(2, seq, 2, 128, dtype, seed=2)
-    lens = jnp.asarray([seq, max(1, seq - 5)], jnp.int32) \
-        if with_lens else None
+    lens = _lens(seq, with_lens)
     scale = 1.0 / 128 ** 0.5
     w = jnp.asarray(_rng(3).normal(0, 1, q.shape), jnp.float32)
 
@@ -100,7 +118,7 @@ def test_flash_grads_match_reference(case):
         q_, k_, v_, causal, scale, lens))
     got = loss(lambda q_, k_, v_: pk.flash_attention(
         q_, k_, v_, causal=causal, use_pallas=True, interpret=True,
-        kv_lens=lens))
+        kv_lens=lens, block_q=block_q, block_k=block_k))
     tol = {"rtol": 3e-2, "atol": 3e-2} if dtype == jnp.bfloat16 \
         else {"rtol": 2e-4, "atol": 2e-4}
     for g, r, name in zip(got, want, "qkv"):
@@ -113,25 +131,43 @@ def test_flash_grads_match_reference(case):
 # grouped-query attention: more query heads than K/V heads, each K/V head
 # reached by index (never repeated in memory)
 GQA_CASES = [
-    # (dtype, causal, with_lens, seq, query heads, K/V heads)
-    (jnp.float32, True, False, 16, 4, 2),
-    (jnp.float32, False, True, 13, 6, 2),
-    (jnp.float32, True, True, 24, 4, 1),
-    (jnp.bfloat16, True, False, 16, 8, 2),
+    # (dtype, causal, with_lens, seq, query heads, K/V heads, head size,
+    #  block_q, block_k): None = planned
+    (jnp.float32, True, False, 16, 4, 2, 128, None, None),
+    (jnp.float32, False, True, 13, 6, 2, 128, None, None),
+    (jnp.float32, True, True, 24, 4, 1, 128, None, None),
+    (jnp.bfloat16, True, False, 16, 8, 2, 128, None, None),
+    # a group's heads in one q tile over SEVERAL q- and kv-tiles: forced
+    # small tiles, block_q != block_k, causal and full, lengths that leave
+    # whole tiles out, an odd length, head 256, the cell's 16 heads over 2
+    (jnp.float32, True, False, 512, 8, 2, 128, 64, 128),
+    (jnp.float32, False, True, 512, 8, 2, 128, 128, 64),
+    (jnp.float32, True, True, 333, 16, 2, 128, 32, 128),
+    (jnp.bfloat16, True, True, 512, 16, 2, 256, 64, 256),
+    (jnp.bfloat16, False, False, 512, 8, 2, 256, 128, 128),
+    # ... and the planned tiles where the plan itself takes several
+    (jnp.float32, True, False, 512, 8, 2, 128, None, None),
+    (jnp.float32, True, True, 1100, 8, 2, 128, None, None),
 ]
-GQA_IDS = ["%s-%s%s-s%d-h%dkv%d" % (np.dtype(c[0]).name,
-                                    "causal" if c[1] else "full",
-                                    "-lens" if c[2] else "", c[3], c[4], c[5])
-           for c in GQA_CASES]
+GQA_IDS = ["%s-%s%s-s%d-h%dkv%d-d%d%s" % (
+    np.dtype(c[0]).name, "causal" if c[1] else "full",
+    "-lens" if c[2] else "", c[3], c[4], c[5], c[6], _blocks_id(*c[7:]))
+    for c in GQA_CASES]
 
 
 def _gqa_inputs(case, seed):
-    dtype, causal, with_lens, seq, heads, kv = case
-    q, _, _ = _qkv(2, seq, heads, 128, dtype, seed=seed)
-    _, k, v = _qkv(2, seq, kv, 128, dtype, seed=seed + 10)
-    lens = jnp.asarray([seq, max(1, seq - 5)], jnp.int32) \
-        if with_lens else None
-    return q, k, v, lens
+    dtype, causal, with_lens, seq, heads, kv, d = case[:7]
+    q, _, _ = _qkv(2, seq, heads, d, dtype, seed=seed)
+    _, k, v = _qkv(2, seq, kv, d, dtype, seed=seed + 10)
+    return q, k, v, _lens(seq, with_lens)
+
+
+def _gqa_flash(case, lens):
+    dtype, causal = case[:2]
+    block_q, block_k = case[7:]
+    return lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=causal, use_pallas=True, interpret=True,
+        kv_lens=lens, block_q=block_q, block_k=block_k)
 
 
 def _repeated(fn, group):
@@ -142,14 +178,13 @@ def _repeated(fn, group):
 
 @pytest.mark.parametrize("case", GQA_CASES, ids=GQA_IDS)
 def test_gqa_forward_matches_repeated_heads(case):
-    dtype, causal, _, _, heads, kv = case
+    dtype, causal, _, _, heads, kv, d = case[:7]
     q, k, v, lens = _gqa_inputs(case, seed=4)
-    scale = 1.0 / 128 ** 0.5
+    scale = 1.0 / d ** 0.5
     want = _repeated(lambda q_, k_, v_: pk._reference_attention(
         q_, k_, v_, causal, scale, lens), heads // kv)(q, k, v)
     for got in (pk._reference_attention(q, k, v, causal, scale, lens),
-                pk.flash_attention(q, k, v, causal=causal, use_pallas=True,
-                                   interpret=True, kv_lens=lens)):
+                _gqa_flash(case, lens)(q, k, v)):
         assert got.dtype == q.dtype and got.shape == q.shape
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -158,45 +193,133 @@ def test_gqa_forward_matches_repeated_heads(case):
 
 @pytest.mark.parametrize("case", GQA_CASES, ids=GQA_IDS)
 def test_gqa_grads_match_repeated_heads(case):
-    dtype, causal, _, _, heads, kv = case
+    dtype, causal, _, _, heads, kv, d = case[:7]
     q, k, v, lens = _gqa_inputs(case, seed=5)
-    scale = 1.0 / 128 ** 0.5
+    scale = 1.0 / d ** 0.5
     w = jnp.asarray(_rng(6).normal(0, 1, q.shape), jnp.float32)
 
-    def grads(fn):
+    def grads(fn, args=(q, k, v)):
         return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
-                        argnums=(0, 1, 2))(q, k, v)
+                        argnums=(0, 1, 2))(*args)
 
+    # the oracle runs in float32 whatever the case's dtype: over hundreds
+    # of keys a bfloat16 autodiff is the noisier side of the comparison
     want = grads(_repeated(lambda q_, k_, v_: pk._reference_attention(
-        q_, k_, v_, causal, scale, lens), heads // kv))
-    tol = {"rtol": 3e-2, "atol": 3e-2} if dtype == jnp.bfloat16 \
-        else {"rtol": 2e-4, "atol": 2e-4}
+        q_, k_, v_, causal, scale, lens), heads // kv),
+        tuple(x.astype(jnp.float32) for x in (q, k, v)))
     for fn in (lambda q_, k_, v_: pk._reference_attention(
                    q_, k_, v_, causal, scale, lens),
-               lambda q_, k_, v_: pk.flash_attention(
-                   q_, k_, v_, causal=causal, use_pallas=True,
-                   interpret=True, kv_lens=lens)):
-        for g, r, name in zip(grads(fn), want, "qkv"):
-            assert g.dtype == r.dtype and g.shape == r.shape
+               _gqa_flash(case, lens)):
+        for g, x, r, name in zip(grads(fn), (q, k, v), want, "qkv"):
+            assert g.dtype == x.dtype and g.shape == r.shape
+            # bfloat16 keeps 8 bits: four of its roundings of the largest
+            # gradient; float32 to the products' own round-off
+            tol = {"rtol": 0, "atol": 4 * 2.0 ** -8 * float(jnp.abs(r).max())} \
+                if dtype == jnp.bfloat16 else {"rtol": 2e-4, "atol": 2e-4}
             np.testing.assert_allclose(
                 np.asarray(g, np.float32), np.asarray(r, np.float32),
                 err_msg="d%s diverged" % name, **tol)
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
 def test_equal_heads_program_is_what_it_was():
-    """With as many K/V heads as query heads nothing of the grouped path
-    is traced: no head index is divided, no group axis appears."""
+    """Equal heads are the group of one of the ONE forward kernel: the same
+    ``flash_attn_fwd`` call whose grid runs over batch x K/V heads and
+    whose q tile holds ``group`` heads — no second kernel, no switch.  The
+    XLA reference for equal heads still has no group axis."""
     q, k, v = _qkv(1, 16, 2, 128, seed=7)
-    text = jax.make_jaxpr(lambda *a: pk.flash_attention(
-        *a, causal=True, use_pallas=True, interpret=True))(q, k, v).pretty_print()
-    grouped = jax.make_jaxpr(lambda *a: pk.flash_attention(
-        *a, causal=True, use_pallas=True, interpret=True))(
-        q, k[:, :, :1], v[:, :, :1]).pretty_print()
-    assert text != grouped
-    assert pk._flash_jitted.__wrapped__.__defaults__[-2] == 1   # group
+
+    def call(k_, v_):
+        calls = _pallas_calls(jax.make_jaxpr(lambda *a: pk.flash_attention(
+            *a, causal=True, use_pallas=True, interpret=True))(
+            q, k_, v_).jaxpr)
+        assert len(calls) == 1
+        mapping = calls[0].params["grid_mapping"]
+        return (calls[0].params["name"], mapping.grid,
+                mapping.block_mappings[0].block_shape)
+
+    name, grid, q_block = call(k, v)
+    g_name, g_grid, g_q_block = call(k[:, :, :1], v[:, :, :1])
+    assert name == g_name == "flash_attn_fwd"
+    assert grid == (2, 1, 1) and g_grid == (1, 1, 1)
+    assert [int(getattr(x, "block_size", x)) for x in q_block] \
+        == [1, 1, 16, 128]
+    assert [int(getattr(x, "block_size", x)) for x in g_q_block] \
+        == [1, 2, 16, 128]
     ref = jax.make_jaxpr(lambda *a: pk._reference_attention(
         *a, True, 0.1))(q, k, v).pretty_print()
     assert "bqhgd" not in ref and ref.count("dot_general") == 2
+
+
+# the tile plan as a pure function (docs/kernels.md §flash-attention)
+
+def test_flash_plan_at_the_language_model_cells_shape():
+    """8,192 tokens, 16 query heads over 2 K/V heads of 256, bf16, causal,
+    batch 2: about a thousand grid steps where 128 x 128 tiles one query
+    head at a time took 131,072, inside the stated VMEM budget."""
+    bq, bk = pk._flash_plan(8192, 8192, 256, 8, 2, True)
+    assert 8192 % bq == 0 and 8192 % bk == 0
+    assert bq % 16 == 0 and bk % 128 == 0
+    steps = (2 * 2) * (8192 // bq) * (8192 // bk)
+    assert steps < 4096, (bq, bk, steps)
+    assert 8 * bq >= 1024 and bk >= 512
+    assert pk._flash_vmem_bytes(bq, bk, 256, 8, 2) <= pk._FLASH_VMEM_BUDGET \
+        <= pk._FLASH_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("seq", [16, 100, 256, 512, 4096])
+def test_flash_plan_tiles_divide_the_padded_length(seq, itemsize, group):
+    """Short and odd lengths keep the one or two tiles they always had:
+    the plan's tiles are sublane-aligned for the element size, never
+    longer than the sequence padded to that alignment, and divide it."""
+    sub = {2: 16, 4: 8}[itemsize]
+    padded = -(-seq // sub) * sub
+    for causal in (False, True):
+        bq, bk = pk._flash_plan(seq, seq, 128, group, itemsize, causal)
+        assert bq % sub == 0 and bk % sub == 0
+        assert bq <= padded and bk <= padded
+        assert padded % bq == 0 and padded % bk == 0
+        assert pk._flash_vmem_bytes(bq, bk, 128, group, itemsize) \
+            <= pk._FLASH_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 512),
+                                    (256, 1024)],
+                         ids=lambda b: "q%dk%d" % b)
+def test_kv_index_map_stops_at_the_last_needed_tile(blocks):
+    """The K/V index map of a causal grid, as plain Python: the mapped tile
+    never lies above the diagonal, and along a q-block's kv steps it
+    changes exactly once per needed tile (an unchanged index is no DMA)."""
+    bq, bk = blocks
+    seq = 2048
+    n_q, n_kv = seq // bq, seq // bk
+    for kv_len in (seq, 700):
+        for qi in range(n_q):
+            last = int(pk._last_kv_tile(qi, kv_len, bq, bk, True))
+            tiles = [min(ki, last) for ki in range(n_kv)]
+            # its first key is at or below the block's last row, and valid
+            assert all(t * bk <= qi * bq + bq - 1 and t * bk < kv_len
+                       for t in tiles)
+            needed = [ki for ki in range(n_kv)
+                      if ki * bk <= qi * bq + bq - 1 and ki * bk < kv_len]
+            assert needed == list(range(last + 1))
+            fetches = 1 + sum(a != b for a, b in zip(tiles, tiles[1:]))
+            assert fetches == len(needed)
+    # without a diagonal only the valid length bounds it
+    assert int(pk._last_kv_tile(0, seq, bq, bk, False)) == n_kv - 1
+    assert int(pk._last_kv_tile(0, 0, bq, bk, False)) == 0
 
 
 def test_flash_refuses_heads_that_do_not_divide():

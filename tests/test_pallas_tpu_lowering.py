@@ -5,7 +5,9 @@ TPU lowering (BlockSpec legality, the Mosaic MLIR emission) without a chip,
 so a block shape the TPU lowering refuses never reaches one again.  Shapes
 are the ones the chip runs: every BatchNorm and Pooling input of the
 ResNet-50 train step at batch 32, read off the symbol itself, and flash
-attention at head_dim 128, forward and gradient.
+attention at head_dim 128 and at the language-model cell's own shape
+(8,192 tokens, 16 query heads over 2 K/V heads of 256), forward and
+gradient.
 
 The lowering cannot see Mosaic's own compile (layout inference, unaligned
 slices).  ``-m slow`` adds it: libtpu compiles for a named v5e topology
@@ -59,13 +61,15 @@ def _resnet50_kernel_inputs():
 
 BN_SHAPES, POOLS = _resnet50_kernel_inputs()
 FLASH_CASES = [
-    # tests/test_pallas.py shapes ...
-    ((2, 256, 2, 128), "float32", False),
-    ((1, 256, 2, 128), "float32", True),
-    ((1, 512, 1, 128), "float32", True),
-    # ... an odd length (block padding), and one real one
-    ((1, 100, 2, 128), "bfloat16", True),
-    ((4, 4096, 16, 128), "bfloat16", True),
+    # (q shape, dtype, causal, K/V heads); tests/test_pallas.py shapes ...
+    ((2, 256, 2, 128), "float32", False, 2),
+    ((1, 256, 2, 128), "float32", True, 2),
+    ((1, 512, 1, 128), "float32", True, 1),
+    # ... an odd length (block padding), one real one, and the attention
+    # layer of `qwen3next-train-s8k-b2`: 16 query heads over 2 K/V heads
+    ((1, 100, 2, 128), "bfloat16", True, 2),
+    ((4, 4096, 16, 128), "bfloat16", True, 16),
+    ((2, 8192, 16, 256), "bfloat16", True, 2),
 ]
 
 
@@ -99,22 +103,25 @@ def _cases():
                     jax.grad(lambda v, core=core: jnp.sum(
                         core(v).astype(jnp.float32) ** 2)),
                     (_aval(shape, "bfloat16"),)))
-    for shape, dtype, causal in FLASH_CASES:
+    for shape, dtype, causal, kv_heads in FLASH_CASES:
         x = _aval(shape, dtype)
+        kv = _aval(shape[:2] + (kv_heads,) + shape[3:], dtype)
 
         def fwd(q, k, v, n=None, causal=causal):
             return pk.flash_attention(q, k, v, causal=causal,
                                       use_pallas=True, kv_lens=n)
 
-        tag = "%s-%s-%s" % (shape, dtype, "causal" if causal else "full")
-        out.append(("flash-fwd-" + tag, fwd, (x, x, x)))
+        tag = "%s%s-%s-%s" % (shape, "" if kv_heads == shape[2]
+                              else "kv%d" % kv_heads, dtype,
+                              "causal" if causal else "full")
+        out.append(("flash-fwd-" + tag, fwd, (x, kv, kv)))
         out.append(("flash-grad-" + tag,
                     jax.grad(lambda q, k, v, fwd=fwd: jnp.sum(
                         fwd(q, k, v).astype(jnp.float32) ** 2),
-                        argnums=(0, 1, 2)), (x, x, x)))
+                        argnums=(0, 1, 2)), (x, kv, kv)))
         # the padding-mask operand (scalar prefetch)
         out.append(("flash-lens-" + tag, fwd,
-                    (x, x, x, _aval(shape[:1], "int32"))))
+                    (x, kv, kv, _aval(shape[:1], "int32"))))
     return out
 
 
