@@ -1,0 +1,14 @@
+"""trinity-mini-26b-a3b-ep8-bf16 and its kin -> the program's objects."""
+from __future__ import annotations
+
+
+def symbol(cfg):
+    from mxnet_tpu import models
+    from .. import harness
+    if not hasattr(models, "trinity"):
+        # a checkout from before the model (the parent of the PR that added
+        # the cell): say so at once instead of failing somewhere inside
+        raise harness.Refused("this checkout's mxnet_tpu has no "
+                              "models.trinity: it cannot run %s"
+                              % cfg["name"])
+    return models.trinity.get_symbol(cfg, dtype=cfg["precision"]["compute"])

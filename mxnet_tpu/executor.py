@@ -13,7 +13,7 @@ match between forward and backward.
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,10 @@ from . import random as _random
 # MXNet's mirroring attribute (``MXNET_BACKWARD_DO_MIRROR``'s per-node form):
 # nodes that share a value of it are recomputed together in the backward pass
 MIRROR_STAGE = "__mirror_stage__"
+# nodes that carry this attribute are lowered under ``jax.named_scope`` of its
+# value: a model's builder names a group of plain nodes (a dense MLP) for the
+# device trace, as the ops that are one node name themselves (``mx:attn``)
+NAMED_SCOPE = "__named_scope__"
 
 
 def _to_device(arr, dev):
@@ -134,7 +138,9 @@ class _Program:
             ins = [env[e] for e in node.inputs]
             if op.needs_rng:
                 ins = [key_of[node]] + ins
-            out = op.impl(*ins, **attrs)
+            scope = node.attrs.get(NAMED_SCOPE)
+            with jax.named_scope(scope) if scope else nullcontext():
+                out = op.impl(*ins, **attrs)
             if not isinstance(out, tuple):
                 out = (out,)
             n_vis = node.num_outputs()
@@ -213,6 +219,33 @@ class _Program:
     def mirror_stages(self):
         """How many blocks a training pass of this graph recomputes."""
         return sum(isinstance(u, _Stage) for u in self._units())
+
+    def attention_pairs(self, shapes, dtypes):
+        """(computed, visible) (query, key) pairs of one training pass over
+        this graph's attention nodes at the bound argument ``shapes`` and
+        ``dtypes`` ({name: ...}), summed over nodes and heads apart:
+        ``ops.pallas_kernels.attention_pairs`` of each, resolved under the
+        caller's ``trace_scope``.  (0, 0) for a graph without attention."""
+        nodes = [n for n in self.order if not n.is_var and n.op_name in (
+            "scaled_dot_product_attention", "multi_head_attention")]
+        if not nodes:
+            return 0, 0
+        from .ops import pallas_kernels
+        at, kinds = self.symbol._infer(dict(shapes), dict(dtypes))
+        computed = visible = 0
+        for n in nodes:
+            attrs = get_op(n.op_name).normalize_attrs(n.attrs)
+            q, k = at[n.inputs[0]], at[n.inputs[1]]
+            if n.op_name == "multi_head_attention":
+                heads = int(attrs["num_heads"])
+                width = int(attrs["num_hidden"]) or int(q[-1])
+                q, k = ((int(s[0]), int(s[1]), heads, width // heads)
+                        for s in (q, k))
+            c, v = pallas_kernels.attention_pairs(
+                q, k, kinds[n.inputs[0]], bool(attrs["causal"]),
+                int(attrs.get("window") or 0))
+            computed, visible = computed + c, visible + v
+        return computed, visible
 
     def _run_stage(self, stage, run, env, new_aux):
         def body(ins):

@@ -19,31 +19,14 @@ backward pass (``recompute=False``: none does).
 from __future__ import annotations
 
 from .. import symbol as sym
-from ..executor import MIRROR_STAGE
+from ._lm import LMBuilder
 
 
 def is_attention(cfg, layer):
     return (layer + 1) % int(cfg["full_attention_interval"]) == 0
 
 
-class _Builder:
-    def __init__(self, cfg, dtype):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.eps = float(cfg["rms_norm_eps"])
-
-    def param(self, name):
-        return sym.var(name, dtype=self.dtype)
-
-    def dense(self, x, name, width):
-        return sym.FullyConnected(x, weight=self.param(name + "_weight"),
-                                  num_hidden=int(width), no_bias=True,
-                                  flatten=False, name=name)
-
-    def norm(self, x, name, zero_centered=True):
-        return sym.RMSNorm(x, gamma=self.param(name + "_gamma"), eps=self.eps,
-                           zero_centered=zero_centered, name=name)
-
+class _Builder(LMBuilder):
     def attention(self, x, p):
         cfg = self.cfg
         heads, kv, d = (int(cfg["num_attention_heads"]),
@@ -98,23 +81,10 @@ class _Builder:
     def moe(self, x, p):
         """(the layer's output, its per-expert selection counts)."""
         cfg = self.cfg
-        flat = sym.Reshape(x, shape=(-3, 0))
-        routed = sym.moe_experts(
-            flat, router_weight=self.param(p + "moe_router_weight"),
-            gate_weight=self.param(p + "moe_gate_weight"),
-            up_weight=self.param(p + "moe_up_weight"),
-            down_weight=self.param(p + "moe_down_weight"),
-            num_experts=int(cfg["router_num_experts"]),
-            num_hidden=int(cfg["moe_intermediate_size"]),
-            experts_held=int(cfg["num_experts"]),
-            first_expert=int(cfg.get("first_expert", 0)),
-            top_k=int(cfg["num_experts_per_tok"]),
-            norm_topk_prob=bool(cfg["norm_topk_prob"]), name=p + "moe")
-        width = int(cfg["shared_expert_intermediate_size"])
-        shared = self.dense(
-            sym.SwiGLU(self.dense(x, p + "shared_gate_proj", width),
-                       self.dense(x, p + "shared_up_proj", width)),
-            p + "shared_down_proj", cfg["hidden_size"])
+        routed = self.routed_experts(
+            x, p, norm_topk_prob=bool(cfg["norm_topk_prob"]))
+        shared = self.swiglu_mlp(x, p + "shared_",
+                                 int(cfg["shared_expert_intermediate_size"]))
         shared = sym.broadcast_mul(
             sym.sigmoid(self.dense(x, p + "shared_gate", 1)), shared)
         return sym.reshape_like(routed[0], x) + shared, routed[1]
@@ -123,8 +93,7 @@ class _Builder:
         """Each half of a block is one mirror stage: the backward pass keeps
         the block's input and ``h`` and recomputes either half alone."""
         p = "layer%d_" % layer
-        stage = lambda half: sym.AttrScope(
-            **({MIRROR_STAGE: p + half} if recompute else {}))
+        stage = lambda half: self.stage(p + half, recompute)
         with stage("mixer"):
             mix = self.attention if is_attention(self.cfg, layer) \
                 else self.delta_net
@@ -147,11 +116,4 @@ def get_symbol(cfg, dtype="float32", recompute=True):
     for layer in range(int(cfg["num_hidden_layers"])):
         x, c = build.block(x, layer, recompute)
         counts.append(c)
-    logits = build.dense(build.norm(x, "final_norm"), "lm_head",
-                         cfg["vocab_size"])
-    loss = sym.MakeLoss(sym.sequence_cross_entropy(
-        logits, sym.Variable("softmax_label"), name="ce"), name="loss")
-    counts = sym.BlockGrad(sym.stack(*counts, axis=0), name="moe_counts")
-    counts._set_attr(__moe_counts__="%d,%d" % (
-        int(cfg.get("first_expert", 0)), int(cfg["num_experts"])))
-    return sym.Group([loss, counts])
+    return build.outputs(x, counts)

@@ -125,6 +125,7 @@ class FusedTrainStep:
         self.exe = module._exec_group.execs[0]
         self.opt = module._optimizer
         self.ran = False
+        self._attn_pairs = None
         # input name -> (the batch's array, its upload): see ``stage``
         self._staged = {}
         exe = self.exe
@@ -704,7 +705,23 @@ class FusedTrainStep:
                 raise
             ph.watch(res[0][:1] or res[1][:1], uploads)
         _instrument.note_recompute_blocks(self.prog.mirror_stages)
+        _instrument.note_attention_pairs(*self._attention_pairs())
         return res
+
+    def _attention_pairs(self):
+        """The step program's attention schedules as (computed, visible)
+        pairs, worked out once from the shapes bound to device 0's
+        executor (its share of the batch, so times the devices)."""
+        if self._attn_pairs is None:
+            args = self.exe.arg_dict
+            with _pallas_kernels.trace_scope(
+                    platform=self.devices[0].platform,
+                    partitioned=self._mesh is not None):
+                pairs = self.prog.attention_pairs(
+                    {n: a.shape for n, a in args.items()},
+                    {n: a._h.array.dtype for n, a in args.items()})
+            self._attn_pairs = tuple(self.n_dev * p for p in pairs)
+        return self._attn_pairs
 
     def _keep(self, res):
         """Take the step's results as the next step's state; returns

@@ -808,7 +808,7 @@ def note_step_input(staged):
         help="fused steps by where their batch's upload began").inc()
 
 
-# -- sparse experts and recomputation (models/qwen3_next.py) -------------------
+# -- sparse experts, recomputation, attention (models/qwen3_next.py, trinity.py)
 
 def note_recompute_blocks(blocks):
     """``module.recompute.blocks``: the mirror stages (blocks evaluated
@@ -818,6 +818,23 @@ def note_recompute_blocks(blocks):
             "module.recompute.blocks",
             help="blocks the step programs recompute in their backward "
                  "pass").inc(blocks)
+
+
+def note_attention_pairs(computed, visible):
+    """``module.attn.pairs_computed`` / ``module.attn.pairs_visible``: the
+    (query, key) pairs whose score the attention nodes of the step program
+    just dispatched compute, masked or not, in their forward and backward
+    schedules, and the pairs their masks let through, once each way
+    (``_Program.attention_pairs``: static per program)."""
+    if visible:
+        telemetry.counter(
+            "module.attn.pairs_computed",
+            help="(query, key) scores the step programs' attention "
+                 "schedules compute, forward and backward").inc(computed)
+        telemetry.counter(
+            "module.attn.pairs_visible",
+            help="(query, key) pairs the attention masks let through, "
+                 "once each way").inc(visible)
 
 
 def note_moe_counts(counts, first_expert, experts_held):

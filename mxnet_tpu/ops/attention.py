@@ -11,7 +11,8 @@ S^2 materialization, recompute-based backward.
 Two ops:
 
 - ``scaled_dot_product_attention``: pre-split heads, q/k/v as
-  [batch, seq, heads, head_dim]; causal + padding masks.
+  [batch, seq, heads, head_dim] (K/V may hold fewer heads: grouped
+  queries); causal + padding masks, and a causal ``window``.
 - ``multi_head_attention``: fused qkv/out projections around the same
   core — one node carries the full attention block so the kernel flag
   (``MXNET_TPU_PALLAS_ATTN``) swaps the entire fast path at bind time.
@@ -50,13 +51,22 @@ def _note_logit_bound(q, k, scale):
 
 
 def _sdpa(query, key, value, *rest, causal=False, scale=0.0,
-          use_lengths=False):
+          use_lengths=False, window=0):
+    """``softmax(q k^T * scale + mask) v`` for q [batch, seq, heads,
+    head_dim] on k, v [batch, keys, kv heads, head_dim]; ``scale`` 0 means
+    ``1 / sqrt(head_dim)``.  ``causal``: key ``j`` is visible to query ``i``
+    iff ``j <= i``; ``window`` (needs ``causal``; 0 = none) narrows that to
+    ``0 <= i - j < window``, and the kernels neither fetch nor compute what
+    lies wholly outside it; ``use_lengths`` adds the (batch,) ``kv_length``
+    input, the padding mask."""
     kv_lens = rest[0] if use_lengths else None
     _note_logit_bound(query, key, scale)
-    with jax.named_scope("mx:attn"):
+    window = _pk.checked_window(window, causal, key.shape[1])
+    with jax.named_scope("mx:attn"), jax.named_scope(
+            "mx:attn:window" if window else "mx:attn:full"):
         return _pk.attention(query, key, value, causal=causal,
                              scale=(scale if scale else None),
-                             kv_lens=kv_lens)
+                             kv_lens=kv_lens, window=window)
 
 
 def _sdpa_infer_shape(in_shapes, attrs, out_shapes=None):
@@ -98,7 +108,7 @@ register("scaled_dot_product_attention", _sdpa,
          infer_shape=_sdpa_infer_shape, bidirectional_infer=True,
          infer_type=_sdpa_infer_type,
          params={"causal": (pBool, False), "scale": (pFloat, 0.0),
-                 "use_lengths": (pBool, False)})
+                 "use_lengths": (pBool, False), "window": (pInt, 0)})
 
 
 def _mha(query, key, value, q_weight, q_bias, k_weight, k_bias, v_weight,

@@ -342,28 +342,49 @@ register("gated_delta_rule", _gated_delta_rule,
 # -- dropless top-k experts, this chip's share ---------------------------------
 
 def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
-                 num_experts=1, num_hidden=0, experts_held=0, first_expert=0,
-                 top_k=1, norm_topk_prob=True):
+                 *rest, num_experts=1, num_hidden=0, experts_held=0,
+                 first_expert=0, top_k=1, norm_topk_prob=True,
+                 score_func="softmax", route_scale=1.0,
+                 use_expert_bias=False):
     """``sum_{e in top-k and held} p_e F_e(x)`` for tokens ``data`` [n, h],
     ``F_e(x) = (SiLU(x W_gate,e) * x W_up,e) W_down,e``.
 
     The router (``router_weight`` [num_experts, h]) scores ALL experts in
-    float32 and the top-k weights are renormalised over the k chosen
-    wherever those live; this op holds experts ``first_expert ..
-    first_expert + experts_held`` (weights [held, h, num_hidden] twice and
-    [held, num_hidden, h]) and computes their part alone — the rest is other
-    chips'.  Dropless: every (token, held expert) choice is computed, by
-    one grouped product over the choices sorted by expert.  Second output:
-    how many tokens chose each of the ``num_experts`` (no gradient)."""
+    float32 — ``score_func`` ``"softmax"`` over the experts, or
+    ``"sigmoid"`` of each logit alone — and takes the ``top_k`` by score;
+    with ``use_expert_bias`` by score plus ``expert_bias`` [num_experts],
+    which takes part in the choice alone, never in a weight, and gets no
+    gradient.  ``norm_topk_prob`` renormalises the chosen scores over the k
+    chosen wherever those live (plus 1e-20 under ``sigmoid``, whose scores
+    need not add up to anything), and ``route_scale`` scales them.  This op
+    holds experts ``first_expert .. first_expert + experts_held`` (weights
+    [held, h, num_hidden] twice and [held, num_hidden, h]) and computes
+    their part alone — the rest is other chips'.  Dropless: every (token,
+    held expert) choice is computed, by one grouped product over the
+    choices sorted by expert.  Second output: how many tokens chose each of
+    the ``num_experts`` (no gradient)."""
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError("moe_experts: score_func %r is neither 'softmax' "
+                         "nor 'sigmoid'" % (score_func,))
     with jax.named_scope("mx:moe"):
         n, h = data.shape
         held = int(experts_held) or int(num_experts)
         k = int(top_k)
         logits = jnp.matmul(data.astype(_F32), router_weight.astype(_F32).T)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = lax.top_k(probs, k)
+        sigmoid = score_func == "sigmoid"
+        probs = jax.nn.sigmoid(logits) if sigmoid \
+            else jax.nn.softmax(logits, axis=-1)
+        if use_expert_bias:
+            _, top_e = lax.top_k(
+                probs + lax.stop_gradient(rest[0].astype(_F32)), k)
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        else:
+            top_p, top_e = lax.top_k(probs, k)
         if norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total + _F32(1e-20) if sigmoid else total)
+        if float(route_scale) != 1.0:
+            top_p = top_p * _F32(route_scale)
         chosen = lax.stop_gradient(top_e).reshape(-1)
         counts = jnp.zeros((int(num_experts),), _F32).at[chosen].add(1.0)
         local = chosen - int(first_expert)
@@ -399,17 +420,23 @@ def _moe_infer_shape(in_shapes, attrs):
     filled[1] = (e, h)
     filled[2] = filled[3] = (held, h, i)
     filled[4] = (held, i, h)
+    if attrs.get("use_expert_bias") and len(filled) > 5:
+        filled[5] = (e,)
     return filled, [tuple(x), (e,)]
 
 
 register("moe_experts", _moe_experts, num_outputs=2,
          input_names=("data", "router_weight", "gate_weight", "up_weight",
-                      "down_weight"),
+                      "down_weight", "expert_bias"),
+         num_inputs=lambda attrs: 5 + bool(attrs.get("use_expert_bias")),
          infer_shape=_moe_infer_shape,
          params={"num_experts": (pInt, 1), "num_hidden": (pInt, 0),
                  "experts_held": (pInt, 0),
                  "first_expert": (pInt, 0), "top_k": (pInt, 1),
-                 "norm_topk_prob": (pBool, True)})
+                 "norm_topk_prob": (pBool, True),
+                 "score_func": (pStr, "softmax"),
+                 "route_scale": (pFloat, 1.0),
+                 "use_expert_bias": (pBool, False)})
 
 
 # -- softmax cross-entropy, one number a sequence ---------------------------------

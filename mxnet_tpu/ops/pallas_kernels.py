@@ -27,17 +27,29 @@ _enable_x64 = jax.enable_x64
 _NEG_INF = -1e30
 
 
-def _reference_attention(q, k, v, causal, scale, kv_lens=None):
+def _causal_mask(n_q, n_k, window=0):
+    """[n_q, n_k] bool: key ``j`` is visible to query ``i`` iff ``j <= i``
+    and, under a ``window``, ``i - j < window``."""
+    mask = jnp.tril(jnp.ones((n_q, n_k), bool))
+    if window:
+        mask &= ~jnp.tril(jnp.ones((n_q, n_k), bool), -int(window))
+    return mask
+
+
+def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0):
     """[B, S, H, D] exact attention — the fallback + test oracle.
 
     ``kv_lens``: optional (B,) per-sequence valid KV length (the padding
-    mask); keys at positions >= the length never receive weight."""
+    mask); keys at positions >= the length never receive weight.
+    ``window`` (needs ``causal``; 0 = none): a query sees its own position
+    and the ``window - 1`` before it."""
     if q.shape[2] != k.shape[2]:
-        return _reference_attention_grouped(q, k, v, causal, scale, kv_lens)
+        return _reference_attention_grouped(q, k, v, causal, scale, kv_lens,
+                                            window)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     n_q, n_k = q.shape[1], k.shape[1]
     if causal:
-        mask = jnp.tril(jnp.ones((n_q, n_k), bool))
+        mask = _causal_mask(n_q, n_k, window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     if kv_lens is not None:
         cols = jnp.arange(n_k)
@@ -47,7 +59,8 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None):
+def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None,
+                                 window=0):
     """The same for grouped-query attention: ``q`` has a multiple of the
     K/V heads, and K/V head ``j`` serves query heads ``j*group ..
     (j+1)*group`` — by index, no head is repeated in memory."""
@@ -56,7 +69,7 @@ def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None):
     qg = q.reshape(b, n_q, kv, h // kv, d)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones((n_q, n_k), bool)), s, _NEG_INF)
+        s = jnp.where(_causal_mask(n_q, n_k, window), s, _NEG_INF)
     if kv_lens is not None:
         valid = jnp.arange(n_k)[None, :] < kv_lens.astype(jnp.int32)[:, None]
         s = jnp.where(valid[:, None, None, None, :], s, _NEG_INF)
@@ -77,9 +90,19 @@ def _last_kv_tile(q_idx, kv_len, block_q, block_k, causal):
     return last
 
 
+def _first_kv_tile(q_idx, block_q, block_k, window):
+    """Index of the first K/V tile the q-block ``q_idx`` needs under a
+    causal ``window`` (0 = none: tile 0): the one that holds the key
+    ``window - 1`` before the block's first row.  The tiles before it are
+    neither computed nor fetched, as those past ``_last_kv_tile``."""
+    if not window:
+        return 0
+    return jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
+
+
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
                   causal, scale, block_q, block_k, group, n_kv_blocks,
-                  emit_lse):
+                  emit_lse, window=0):
     """One (q-block, kv-block) grid step.  Grid = (B*KV, n_q, n_kv) with
     the kv dimension innermost; m/l/acc scratch persists across kv steps of
     the same q block (standard flash-attention accumulation).  The q tile
@@ -108,10 +131,13 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
     kv_len = len_ref[pl.program_id(0)]
     # tiles wholly past the valid length and, under causal, wholly above
-    # the diagonal contribute no weight: the body skips them here and the
-    # K/V index map (_call_flash) never moves to them
+    # the diagonal (or wholly below the window) contribute no weight: the
+    # body skips them here and the K/V index map (_call_flash) never moves
+    # to them
     needed = (kv_len > 0) & (
         kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
+    if window:
+        needed &= kv_idx >= _first_kv_tile(q_idx, block_q, block_k, window)
 
     @pl.when(needed)
     def _compute():
@@ -136,6 +162,8 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
             pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             valid &= pos >= cols
+            if window:
+                valid &= pos - cols < window
         bias = jnp.where(valid, jnp.float32(0.0), jnp.float32(_NEG_INF))
         s = (s.reshape(group, block_q, block_k) + bias[None]).reshape(
             rows, block_k)
@@ -216,7 +244,11 @@ def _flash_plan(sq, sk, d, group, itemsize, causal):
     step fits ``_FLASH_VMEM_BUDGET``, a q tile of at most
     ``_FLASH_MAX_ROWS`` rows over the whole group and a K/V tile of at most
     ``_FLASH_MAX_BLOCK_K`` keys (under ``causal``, neither side longer than
-    a quarter of the keys).  Tiles are sublane-aligned for the element
+    a quarter of the keys).  A causal ``window`` does not narrow them: at
+    8,192 keys under a 2,048 window 256 x 1,024 tiles read 3.20 ms a call
+    on the v5e against 4.31 for 256 x 512, whose smaller overhang of the
+    band (1.25 against 1.40 computed pairs a visible one) does not pay for
+    twice the steps (PERF.md, PR 32).  Tiles are sublane-aligned for the element
     size (8 rows of float32, 16 of bfloat16) and 128-aligned where a
     sequence takes several K/V tiles (keys lie on the score tile's lanes);
     a sequence shorter than a cap is one tile of its own padded length, so
@@ -249,9 +281,18 @@ def _flash_plan(sq, sk, d, group, itemsize, causal):
             cap_q = max(cap_q // 2 // sub * sub, sub)
 
 
+def checked_window(window, causal, sk):
+    """``window`` as the kernels take it: 0 where it hides no key."""
+    window = int(window or 0)
+    if window < 0 or (window and not causal):
+        raise ValueError("attention: window %d needs causal=True and a "
+                         "positive width" % window)
+    return 0 if window >= sk else window
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, use_pallas=None, interpret=None,
-                    kv_lens=None):
+                    kv_lens=None, window=0):
     """Blocked flash attention.  q/k/v: [batch, seq, heads, head_dim];
     ``k``/``v`` may hold fewer heads than ``q`` (grouped queries: K/V head
     ``j`` serves query heads ``j*group .. (j+1)*group``).
@@ -259,7 +300,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     ``kv_lens``: optional (batch,) valid KV lengths — the padding mask.
     Sequences that do not tile evenly are block-padded internally and
     bounded by the same per-row length the padding mask uses, so any
-    seq length is exact.  use_pallas=None auto-selects: the Pallas
+    seq length is exact.  ``window`` (needs ``causal``; 0 = none): query
+    ``i`` sees keys ``i - window < j <= i``; the forward neither fetches
+    nor computes the K/V tiles wholly below the window and the backward
+    works on a band of keys (a query row with no visible key at all, which
+    only ``kv_lens`` can make, holds nothing meaningful and gives no
+    gradient).  use_pallas=None auto-selects: the Pallas
     kernel on TPU backends for lane-tiled head dims, the XLA reference
     otherwise.  ``block_q`` / ``block_k``: tile sizes, from
     :func:`_flash_plan` unless given (the tests give them).
@@ -270,6 +316,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         raise ValueError("flash_attention: %d query heads are no multiple "
                          "of %d K/V heads" % (h, kv))
     group = h // kv
+    window = checked_window(window, causal, sk)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
@@ -277,7 +324,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                       and d % 128 == 0        # lane-tiled head dim
                       and jnp.issubdtype(q.dtype, jnp.floating))
     if not use_pallas:
-        return _reference_attention(q, k, v, causal, scale, kv_lens)
+        return _reference_attention(q, k, v, causal, scale, kv_lens, window)
 
     # tile sizes: the plan's, or the caller's held to the same alignment
     # and never beyond the padded sequence
@@ -315,7 +362,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     out = _flash_vjp_wrapped(qf, kf, vf, lens,
                              (b * kv, group, sq_p, sk_p, d,
                               str(jnp.dtype(q.dtype)), causal, float(scale),
-                              bq, bk, interpret))
+                              bq, bk, interpret, window))
     out = out.reshape(b, h, sq_p, d)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
@@ -338,12 +385,10 @@ def _flash_vjp_fwd(qf, kf, vf, lens, meta):
 
 def _flash_vjp_bwd(meta, res, d_out):
     bkv, group, sq, sk, d, dtype, causal, scale, block_q, block_k, \
-        interpret = meta
+        interpret, window = meta
     qf, kf, vf, lens, out, lse = res
-    # the backward walks q in blocks of its own: 128 rows wherever the
-    # forward's tile is a multiple of that, else the (short) tile itself
-    bwd_block = 128 if block_q % 128 == 0 else block_q
-    fn = _flash_bwd_jitted(sq, sk, causal, scale, bwd_block, group)
+    fn = _flash_bwd_jitted(sq, sk, causal, scale, _flash_bwd_block(block_q),
+                           group, window)
     dq, dk, dv = fn(qf, kf, vf, lens, out, lse, d_out)
     return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype),
             jnp.zeros_like(lens))
@@ -352,12 +397,25 @@ def _flash_vjp_bwd(meta, res, d_out):
 _flash_vjp_wrapped.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _flash_bwd_block(block_q):
+    """The backward walks q in blocks of its own: 128 rows wherever the
+    forward's tile is a multiple of that, else the (short) tile itself."""
+    return 128 if block_q % 128 == 0 else block_q
+
+
+def _flash_bwd_band(sk, block_q, window):
+    """Keys a query block of the backward works on under a ``window``: the
+    ``window + block_q`` that hold every key its rows see, or 0 (all
+    ``sk`` of them, unsliced) where that is no fewer."""
+    return window + block_q if window and window + block_q < sk else 0
+
+
 @functools.lru_cache(maxsize=512)
-def _flash_bwd_jitted(sq, sk, causal, scale, block_q, group=1):
-    if group != 1:
+def _flash_bwd_jitted(sq, sk, causal, scale, block_q, group=1, window=0):
+    if group != 1 or window:
         return jax.jit(functools.partial(
             _flash_bwd_grouped, sq=sq, sk=sk, causal=causal, scale=scale,
-            block_q=block_q, group=group))
+            block_q=block_q, group=group, window=window))
     n_q = sq // block_q
 
     def bwd(qf, kf, vf, lens, out, lse, d_out):
@@ -422,35 +480,57 @@ def _flash_bwd_jitted(sq, sk, causal, scale, block_q, group=1):
 
 
 def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
-                       scale, block_q, group):
+                       scale, block_q, group, window=0):
     """The blockwise backward for grouped-query attention: ``qf`` [B*H, Sq,
     D] against ``kf``/``vf`` [B*KV, Sk, D].  The query side is viewed as
-    [B*KV, group, ...]; dk and dv sum over the group inside the products."""
+    [B*KV, group, ...]; dk and dv sum over the group inside the products.
+    Under a ``window`` a query block meets a band of ``window + block_q``
+    keys sliced from K and V where its rows' keys lie, and adds its dk, dv
+    into that band; without one it meets all ``sk`` keys, as ever."""
     bkv, d = kf.shape[0], kf.shape[2]
     view = lambda x: x.reshape((bkv, group) + x.shape[1:])
     qg, og, dog, lseg = view(qf), view(out), view(d_out), view(lse)
     D = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
     kv_len = lens.astype(jnp.int32)                            # [B*KV]
     f32 = dict(preferred_element_type=jnp.float32)
+    band = _flash_bwd_band(sk, block_q, window)
+    n_k = band or sk
 
     def body(i, carry):
         dq_acc, dk_acc, dv_acc = carry
         s = i * block_q
         take = lambda x: jax.lax.dynamic_slice_in_dim(x, s, block_q, 2)
         qb, dob, lseb, Db = take(qg), take(dog), take(lseg), take(D)
-        sij = jnp.einsum("bgqd,bkd->bgqk", qb, kf, **f32) * scale
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, sk), 1)
+        if band:
+            # the band ends with the block's last row, clamped to the keys
+            lo = jnp.clip(s + block_q - band, 0, sk - band)
+            kb, vb = (jax.lax.dynamic_slice_in_dim(x, lo, band, 1)
+                      for x in (kf, vf))
+            add = lambda acc, x: jax.lax.dynamic_update_slice_in_dim(
+                acc, jax.lax.dynamic_slice_in_dim(acc, lo, band, 1) + x,
+                lo, 1)
+        else:
+            kb, vb = kf, vf
+            add = lambda acc, x: acc + x
+        sij = jnp.einsum("bgqd,bkd->bgqk", qb, kb, **f32) * scale
+        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, n_k), 1)
+        if band:
+            cols = cols + lo
         valid = cols[None, None] < kv_len[:, None, None, None]
         if causal:
-            rows = s + jax.lax.broadcasted_iota(jnp.int32, (block_q, sk), 0)
-            valid &= (rows >= cols)[None, None]
+            rows = s + jax.lax.broadcasted_iota(jnp.int32, (block_q, n_k), 0)
+            seen = rows >= cols
+            if window:
+                seen &= rows - cols < window
+            valid &= seen[None, None]
         p = jnp.where(valid, jnp.exp(jnp.where(valid, sij, _NEG_INF)
                                      - lseb[..., None]), 0.0)
-        dp = jnp.einsum("bgqd,bkd->bgqk", dob, vf, **f32)
+        dp = jnp.einsum("bgqd,bkd->bgqk", dob, vb, **f32)
         ds = p * (dp - Db[..., None])
-        dqb = jnp.einsum("bgqk,bkd->bgqd", ds, kf, **f32) * scale
-        dk_acc = dk_acc + jnp.einsum("bgqk,bgqd->bkd", ds, qb, **f32) * scale
-        dv_acc = dv_acc + jnp.einsum("bgqk,bgqd->bkd", p, dob, **f32)
+        dqb = jnp.einsum("bgqk,bkd->bgqd", ds, kb, **f32) * scale
+        dk_acc = add(dk_acc,
+                     jnp.einsum("bgqk,bgqd->bkd", ds, qb, **f32) * scale)
+        dv_acc = add(dv_acc, jnp.einsum("bgqk,bgqd->bkd", p, dob, **f32))
         return (jax.lax.dynamic_update_slice_in_dim(dq_acc, dqb, s, 2),
                 dk_acc, dv_acc)
 
@@ -463,11 +543,11 @@ def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
 
 @functools.lru_cache(maxsize=512)
 def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
-                  block_k, interpret, with_lse=False):
+                  block_k, interpret, window=0, with_lse=False):
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, group=group, n_kv_blocks=sk // block_k,
-        emit_lse=with_lse)
+        emit_lse=with_lse, window=window)
 
     def run(qf, kf, vf, lens):
         # the framework enables jax x64 globally (float64 NDArray API
@@ -477,7 +557,7 @@ def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
             # a view of [B*H, S, D], and so are the results' way back
             out, lse = _call_flash(
                 kernel, qf.reshape(bkv, group, sq, d), kf, vf, lens,
-                block_q, block_k, causal, interpret, with_lse)
+                block_q, block_k, causal, interpret, with_lse, window)
             return (out.reshape(bkv * group, sq, d),
                     lse.reshape(bkv * group, sq, 128) if with_lse else None)
 
@@ -485,7 +565,7 @@ def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
 
 
 def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
-                interpret, with_lse):
+                interpret, with_lse, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bkv, group, sq, d = qg.shape
@@ -494,10 +574,15 @@ def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
     q_map = lambda g, qi, ki, lens: (g, 0, qi, 0)  # noqa: E731
 
     def kv_map(g, qi, ki, lens):
-        # past the last tile the q-block needs the index stays where it
-        # is: the pipeline sees an unchanged block and issues no DMA
-        return (g, jnp.minimum(ki, _last_kv_tile(
-            qi, lens[g], block_q, block_k, causal)), 0)
+        # past the last tile the q-block needs (and, under a window,
+        # before the first) the index stays where it is: the pipeline sees
+        # an unchanged block and issues no DMA
+        last = _last_kv_tile(qi, lens[g], block_q, block_k, causal)
+        tile = jnp.minimum(ki, last)
+        if window:
+            tile = jnp.maximum(tile, jnp.minimum(
+                _first_kv_tile(qi, block_q, block_k, window), last))
+        return (g, tile, 0)
 
     out_specs = [pl.BlockSpec((1, group, block_q, d), q_map)]
     out_shape = [jax.ShapeDtypeStruct(qg.shape, qg.dtype)]
@@ -613,10 +698,11 @@ def kernel_signature(platform=None):
     return tuple((k, kernel_mode(k, platform)) for k in sorted(_KERNEL_ENV))
 
 
-def attention(q, k, v, causal=False, scale=None, kv_lens=None):
+def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0):
     """Trace-time attention dispatch for the ``attn`` kernel family.
 
-    q/k/v: [batch, seq, heads, head_dim].  Resolves
+    q/k/v: [batch, seq, heads, head_dim]; ``window`` (needs ``causal``; 0 =
+    none) as :func:`flash_attention` takes it.  Resolves
     ``kernel_mode('attn')`` at TRACE time (the executor cache keys on the
     same resolution): ``off`` returns the plain XLA reference — no
     custom_vjp, so the off-path program is bit-identical to one that
@@ -626,15 +712,54 @@ def attention(q, k, v, causal=False, scale=None, kv_lens=None):
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    mode = kernel_mode("attn")
-    eligible = (q.shape[-1] % 128 == 0
-                and jnp.issubdtype(q.dtype, jnp.floating))
-    if mode == "off" or not eligible:
-        return _reference_attention(q, k, v, causal, float(scale), kv_lens)
+    window = checked_window(window, causal, k.shape[1])
+    mode = _flash_mode(q.shape[-1], q.dtype)
+    if mode is None:
+        return _reference_attention(q, k, v, causal, float(scale), kv_lens,
+                                    window)
     return flash_attention(q, k, v, causal=causal, scale=float(scale),
                            use_pallas=True,
                            interpret=(mode == "interpret") or None,
-                           kv_lens=kv_lens)
+                           kv_lens=kv_lens, window=window)
+
+
+def _flash_mode(head_dim, dtype):
+    """The resolved ``attn`` mode where :func:`attention` takes the flash
+    kernel for this head size and element type, else None (the XLA
+    reference)."""
+    mode = kernel_mode("attn")
+    eligible = head_dim % 128 == 0 and jnp.issubdtype(dtype, jnp.floating)
+    return mode if mode != "off" and eligible else None
+
+
+def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
+    """What one :func:`attention` node of these shapes is built to do, as
+    (computed, visible) counts of (query, key) pairs per head: the pairs
+    whose score its forward and its backward compute, masked or not (the
+    forward's needed tiles, the backward's query blocks against their band
+    or all keys; the XLA reference computes every pair both ways), and the
+    pairs the mask lets through, once each way.  Static: shapes, the tile
+    plan and the kernel mode of the enclosing :func:`trace_scope`; lengths
+    (``kv_lens``) are not known here."""
+    b, sq, _, d = (int(x) for x in q_shape)
+    sk, kv = int(k_shape[1]), int(k_shape[2])
+    group = int(q_shape[2]) // kv
+    window = checked_window(window, causal, sk)
+    if causal:
+        visible = sum(min(i, sk - 1) + 1 - (max(i - window + 1, 0)
+                                            if window else 0)
+                      for i in range(sq))
+    else:
+        visible = sq * sk
+    if _flash_mode(d, dtype) is None:
+        return 2 * b * sq * sk, 2 * b * visible
+    bq, bk = _flash_plan(sq, sk, d, group, jnp.dtype(dtype).itemsize, causal)
+    sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
+    tiles = sum(max(0, int(_last_kv_tile(qi, sk, bq, bk, causal)) + 1
+                    - int(_first_kv_tile(qi, bq, bk, window)))
+                for qi in range(sq_p // bq))
+    band = _flash_bwd_band(sk_p, _flash_bwd_block(bq), window)
+    return b * (bq * bk * tiles + sq_p * (band or sk_p)), 2 * b * visible
 
 
 # ---------------------------------------------------------------------------

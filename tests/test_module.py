@@ -24,6 +24,11 @@ def _separable(n=512, d=16, classes=4):
 
 
 def test_module_fit_learns():
+    # the initialiser and the shuffle draw from the process-wide generators,
+    # whose state is whatever the worker's earlier tests left: under xdist
+    # one run in a few ended at 0.898 of the 0.9 asked for
+    mx.random.seed(11)
+    np.random.seed(11)
     X, y = _separable()
     it = mx.io.NDArrayIter(X, y, batch_size=32, shuffle=True)
     mod = mx.mod.Module(_softmax_mlp(), context=mx.cpu())

@@ -70,6 +70,12 @@ FLASH_CASES = [
     ((1, 100, 2, 128), "bfloat16", True, 2),
     ((4, 4096, 16, 128), "bfloat16", True, 16),
     ((2, 8192, 16, 256), "bfloat16", True, 2),
+    # a fifth entry is a causal window: a short one over tiles that do not
+    # divide it, and the two kinds of layer of `trinity-mini-train-s8k-b1`,
+    # 32 query heads over 4 K/V heads with and without its 2,048 window
+    ((1, 512, 4, 128), "float32", True, 2, 100),
+    ((1, 8192, 32, 128), "bfloat16", True, 4, 2048),
+    ((1, 8192, 32, 128), "bfloat16", True, 4),
 ]
 
 
@@ -103,17 +109,20 @@ def _cases():
                     jax.grad(lambda v, core=core: jnp.sum(
                         core(v).astype(jnp.float32) ** 2)),
                     (_aval(shape, "bfloat16"),)))
-    for shape, dtype, causal, kv_heads in FLASH_CASES:
+    for shape, dtype, causal, kv_heads, *window in FLASH_CASES:
+        window = window[0] if window else 0
         x = _aval(shape, dtype)
         kv = _aval(shape[:2] + (kv_heads,) + shape[3:], dtype)
 
-        def fwd(q, k, v, n=None, causal=causal):
+        def fwd(q, k, v, n=None, causal=causal, window=window):
             return pk.flash_attention(q, k, v, causal=causal,
-                                      use_pallas=True, kv_lens=n)
+                                      use_pallas=True, kv_lens=n,
+                                      window=window)
 
-        tag = "%s%s-%s-%s" % (shape, "" if kv_heads == shape[2]
-                              else "kv%d" % kv_heads, dtype,
-                              "causal" if causal else "full")
+        tag = "%s%s-%s-%s%s" % (shape, "" if kv_heads == shape[2]
+                                else "kv%d" % kv_heads, dtype,
+                                "causal" if causal else "full",
+                                "-w%d" % window if window else "")
         out.append(("flash-fwd-" + tag, fwd, (x, kv, kv)))
         out.append(("flash-grad-" + tag,
                     jax.grad(lambda q, k, v, fwd=fwd: jnp.sum(
