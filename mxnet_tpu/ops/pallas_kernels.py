@@ -302,8 +302,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     bounded by the same per-row length the padding mask uses, so any
     seq length is exact.  ``window`` (needs ``causal``; 0 = none): query
     ``i`` sees keys ``i - window < j <= i``; the forward neither fetches
-    nor computes the K/V tiles wholly below the window and the backward
-    works on a band of keys (a query row with no visible key at all, which
+    nor computes the K/V tiles wholly below the window, nor do the
+    backward's two kernels (a query row with no visible key at all, which
     only ``kv_lens`` can make, holds nothing meaningful and gives no
     gradient).  use_pallas=None auto-selects: the Pallas
     kernel on TPU backends for lane-tiled head dims, the XLA reference
@@ -359,10 +359,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     # dispatch through a jitted-callable cache: tracing a pallas_call is
     # hundreds of ms of host work, so eager per-call tracing would swamp
     # the kernel (measured 680 ms/call untraced vs 0.02 ms cached)
+    # the backward's tiles: its own plan's, or the caller's where given
+    bwd = (bq, bk) if (block_q, block_k) != (None, None) else \
+        _flash_bwd_plan(sq_p, sk_p, bq, bk, d, group, itemsize, causal)
     out = _flash_vjp_wrapped(qf, kf, vf, lens,
-                             (b * kv, group, sq_p, sk_p, d,
-                              str(jnp.dtype(q.dtype)), causal, float(scale),
-                              bq, bk, interpret, window))
+                             ((b * kv, group, sq_p, sk_p, d,
+                               str(jnp.dtype(q.dtype)), causal, float(scale),
+                               bq, bk, interpret, window), bwd))
     out = out.reshape(b, h, sq_p, d)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
@@ -370,175 +373,346 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _flash_vjp_wrapped(qf, kf, vf, lens, meta):
     """Differentiable flash attention over [BH, S, D] operands: forward is
-    the Pallas kernel, backward is the standard flash backward computed
-    blockwise over q tiles from the saved row log-sum-exp (memory
-    O(block*S), no S^2 materialization — matching the kernel's point).
-    The undifferentiated primal skips the lse output entirely."""
-    out, _ = _flash_jitted(*meta, with_lse=False)(qf, kf, vf, lens)
+    the Pallas kernel, backward is the standard flash backward from the
+    saved row log-sum-exp as two more Pallas kernels (``flash_attn_bwd_dkv``
+    and ``flash_attn_bwd_dq``: no S^2 materialization, no tile the mask
+    hides).  The undifferentiated primal skips the lse output entirely."""
+    out, _ = _flash_jitted(*meta[0], with_lse=False)(qf, kf, vf, lens)
     return out
 
 
 def _flash_vjp_fwd(qf, kf, vf, lens, meta):
-    out, lse = _flash_jitted(*meta, with_lse=True)(qf, kf, vf, lens)
+    out, lse = _flash_jitted(*meta[0], with_lse=True)(qf, kf, vf, lens)
     return out, (qf, kf, vf, lens, out, lse[:, :, 0])
 
 
 def _flash_vjp_bwd(meta, res, d_out):
-    bkv, group, sq, sk, d, dtype, causal, scale, block_q, block_k, \
-        interpret, window = meta
+    (bkv, group, sq, sk, d, _, causal, scale, _, _, interpret, window), \
+        (block_q, block_k) = meta
     qf, kf, vf, lens, out, lse = res
-    fn = _flash_bwd_jitted(sq, sk, causal, scale, _flash_bwd_block(block_q),
-                           group, window)
+    fn = _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q,
+                           block_k, interpret, window)
     dq, dk, dv = fn(qf, kf, vf, lens, out, lse, d_out)
-    return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype),
-            jnp.zeros_like(lens))
+    return dq, dk, dv, jnp.zeros_like(lens)
 
 
 _flash_vjp_wrapped.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _flash_bwd_block(block_q):
-    """The backward walks q in blocks of its own: 128 rows wherever the
-    forward's tile is a multiple of that, else the (short) tile itself."""
-    return 128 if block_q % 128 == 0 else block_q
+def _first_q_tile(kv_idx, block_q, block_k, causal):
+    """Index of the first q tile that holds a query which sees a key of K/V
+    tile ``kv_idx``: under ``causal`` the one that holds the tile's first
+    key's own position, else tile 0.  The transpose of ``_last_kv_tile``:
+    the dk/dv kernel walks q tiles where the forward walks K/V tiles, and
+    neither computes nor fetches the q tiles before it.  Plain integer
+    arithmetic, as the forward's."""
+    return (kv_idx * block_k) // block_q if causal else 0
 
 
-def _flash_bwd_band(sk, block_q, window):
-    """Keys a query block of the backward works on under a ``window``: the
-    ``window + block_q`` that hold every key its rows see, or 0 (all
-    ``sk`` of them, unsliced) where that is no fewer."""
-    return window + block_q if window and window + block_q < sk else 0
+def _last_q_tile(kv_idx, kv_len, n_q, block_q, block_k, window):
+    """Index of the last q tile that holds a query which sees a key of K/V
+    tile ``kv_idx`` (the transpose of ``_first_kv_tile``): tile ``n_q - 1``
+    without a ``window``; under one, the tile that holds the query
+    ``window - 1`` past the tile's last VALID key (``kv_len`` bounds it)."""
+    if not window:
+        return n_q - 1
+    last_key = jnp.minimum(kv_idx * block_k + block_k,
+                           jnp.maximum(kv_len, 1)) - 1
+    return jnp.minimum((last_key + window - 1) // block_q, n_q - 1)
+
+
+def _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
+                    keys_first):
+    """The mask of one (q tile, K/V tile) pair as a float32 0 / -1e30 tile
+    that every head of the group shares: [block_q, block_k], or its
+    transpose where the keys lie on the sublanes (the dk/dv kernel)."""
+    shape = (block_k, block_q) if keys_first else (block_q, block_k)
+    cols = kv_idx * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0 if keys_first else 1)
+    valid = cols < kv_len
+    if causal:
+        pos = q_idx * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 if keys_first else 0)
+        valid &= pos >= cols
+        if window:
+            valid &= pos - cols < window
+    return jnp.where(valid, jnp.float32(0.0), jnp.float32(_NEG_INF))
+
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+
+
+def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                         dd_ref, dq_ref, acc_ref, lse_col, dd_col, *, causal,
+                         scale, block_q, block_k, group, n_kv_blocks, window):
+    """dq of one q tile: grid (B*KV, n_q, n_kv), the K/V tiles innermost
+    and walked as the forward walks them (``_first_kv_tile`` ..
+    ``_last_kv_tile``; the others neither computed nor fetched); dq
+    accumulates in float32 scratch and is written once.  The q tile holds
+    all ``group`` query heads of the K/V head, rows on the sublanes and
+    keys on the lanes as in the forward.  ``lse`` and D = rowsum(dO * O)
+    come as compact [1, rows] vectors and are turned once a q tile into
+    the lane-broadcast [rows, 128] columns the forward keeps its running
+    stats in."""
+    from jax.experimental import pallas as pl
+
+    kv_idx = pl.program_id(2)
+    q_idx = pl.program_id(1)
+    rows = group * block_q
+
+    @pl.when(kv_idx == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        column = lambda ref: jnp.transpose(  # noqa: E731
+            jnp.broadcast_to(ref[0, 0], (lse_col.shape[1], rows)))
+        lse_col[:] = column(lse_ref)
+        dd_col[:] = column(dd_ref)
+
+    kv_len = len_ref[pl.program_id(0)]
+    needed = (kv_len > 0) & (
+        kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
+    if window:
+        needed &= kv_idx >= _first_kv_tile(q_idx, block_q, block_k, window)
+
+    @pl.when(needed)
+    def _compute():
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        do = do_ref[0].reshape(rows, do_ref.shape[-1])
+        k, v = k_ref[0], v_ref[0]
+        f32 = dict(preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(q, k, _NT, **f32) * jnp.float32(scale) \
+            - lse_col[:][:, :1]
+        bias = _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k,
+                               causal, window, keys_first=False)
+        p = jnp.exp((s.reshape(group, block_q, block_k) + bias[None])
+                    .reshape(rows, block_k))
+        dp = jax.lax.dot_general(do, v, _NT, **f32)
+        ds = p * (dp - dd_col[:][:, :1])
+        acc_ref[:] += jax.lax.dot_general(ds.astype(k.dtype), k, _NN, **f32)
+
+    @pl.when(kv_idx == n_kv_blocks - 1)
+    def _finalize():
+        dq_ref[0] = (acc_ref[:] * jnp.float32(scale)).astype(
+            dq_ref.dtype).reshape(dq_ref.shape[1:])
+
+
+def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                          dd_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
+                          scale, block_q, block_k, group, n_q_blocks, window):
+    """dk and dv of one K/V tile: grid (B*KV, n_kv, n_q), the q tiles
+    innermost and walked from ``_first_q_tile`` to ``_last_q_tile`` (the
+    others neither computed nor fetched); dk and dv accumulate in float32
+    scratch across them and are written once.  The scores are computed
+    TRANSPOSED, keys on the sublanes and the ``group * block_q`` rows of
+    the q tile on the lanes: ``p.T @ dO`` and ``ds.T @ q`` are then plain
+    products that sum over the group inside the MXU, and ``lse`` and D come
+    as compact [1, rows] vectors that broadcast down the sublanes."""
+    from jax.experimental import pallas as pl
+
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(1)
+    rows = group * block_q
+
+    @pl.when(q_idx == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    kv_len = len_ref[pl.program_id(0)]
+    needed = (kv_idx * block_k < kv_len) \
+        & (q_idx >= _first_q_tile(kv_idx, block_q, block_k, causal)) \
+        & (q_idx <= _last_q_tile(kv_idx, kv_len, n_q_blocks, block_q,
+                                 block_k, window))
+
+    @pl.when(needed)
+    def _compute():
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        do = do_ref[0].reshape(rows, do_ref.shape[-1])
+        k, v = k_ref[0], v_ref[0]
+        f32 = dict(preferred_element_type=jnp.float32)
+        # one [block_k, block_q] tile, repeated along the lanes for each head
+        bias = jnp.concatenate([_flash_bwd_bias(
+            q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
+            keys_first=True)] * group, axis=1)
+        p = jnp.exp(jax.lax.dot_general(k, q, _NT, **f32)
+                    * jnp.float32(scale) - lse_ref[0, 0] + bias)
+        dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do, _NN, **f32)
+        dp = jax.lax.dot_general(v, do, _NT, **f32)
+        ds = p * (dp - dd_ref[0, 0])
+        dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q, _NN, **f32)
+
+    @pl.when(q_idx == n_q_blocks - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * jnp.float32(scale)).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# What one grid step of a backward kernel may hold in VMEM by the plan's own
+# count, and the scoped limit both calls ask Mosaic for (a v5e core has 128
+# MiB).  The count is generous (Mosaic reuses the score-sized temporaries it
+# lists as all live: a 96 MiB count compiled under this limit), so the
+# budget binds before the limit does.
+_FLASH_BWD_VMEM_BUDGET = 50 << 20
+_FLASH_BWD_VMEM_LIMIT = 64 << 20
+# measured on the v5e at both language-model cells' shapes (PERF.md, PR 33):
+# the backward wants K/V tiles HALF the forward's and q tiles twice as long,
+# up to the budget: 512 x 512 at head 128 x group 8 (with and without the
+# window), 256 x 512 at head 256 x group 8
+_FLASH_BWD_MAX_ROWS = 4096
+_FLASH_BWD_MAX_BLOCK_K = 512
+
+
+def _flash_bwd_vmem_bytes(block_q, block_k, d, group, itemsize):
+    """VMEM one grid step of the backward holds, the larger of its two
+    kernels by operand: the double-buffered q, dO, K and V tiles and the
+    compact ``lse`` and D rows (a [1, rows] block takes eight sublanes),
+    the double-buffered results and their float32 scratch (dq's with the
+    two lane-broadcast columns), and the float32 score, ``dp`` and ``ds``
+    tiles with the casts of ``p`` and ``ds``."""
+    rows = group * block_q
+    piped = 2 * (2 * rows * d + 2 * block_k * d) * itemsize \
+        + 2 * 2 * 8 * rows * 4
+    scores = rows * block_k * (3 * 4 + 2 * itemsize)
+    dq = 2 * rows * d * itemsize + rows * (d + 2 * 128) * 4
+    dkv = 2 * 2 * block_k * d * itemsize + 2 * block_k * d * 4
+    return piped + max(dq, dkv) + scores
+
+
+def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal):
+    """(block_q, block_k) of the backward's two kernels for operands padded
+    to ``sq`` x ``sk`` by the forward's tiles ``block_q`` x ``block_k``,
+    which the backward's must divide or multiply: the K/V tile is the
+    forward's halved down to ``_FLASH_BWD_MAX_BLOCK_K`` keys, the q tile
+    the longest run of the forward's q tiles that divides ``sq``, holds at
+    most ``_FLASH_BWD_MAX_ROWS`` rows over the group (under ``causal``, as
+    in the forward, no more than a quarter of the keys where that is over
+    512: the masked part of the diagonal tiles is work thrown away) and
+    fits ``_FLASH_BWD_VMEM_BUDGET`` by :func:`_flash_bwd_vmem_bytes`; where
+    not even one does, the forward's q tile halved until it fits.  A side
+    is never halved out of the forward's alignment (sublanes for q, 128
+    lanes for keys)."""
+    bk = block_k
+    while bk > _FLASH_BWD_MAX_BLOCK_K and bk % 256 == 0:
+        bk //= 2
+    fits = lambda bq: _flash_bwd_vmem_bytes(  # noqa: E731
+        bq, bk, d, group, itemsize) <= _FLASH_BWD_VMEM_BUDGET
+    n = sq // block_q
+    cap = _FLASH_BWD_MAX_ROWS // group
+    if causal:
+        cap = min(cap, max(512, sk // 4))
+    runs = [block_q * m for m in range(n, 0, -1) if n % m == 0
+            and block_q * m <= cap]
+    bq = next((r for r in runs if fits(r)), block_q)
+    while not fits(bq) and bq % (2 * _sublanes(itemsize)) == 0:
+        bq //= 2
+    return bq, bk
 
 
 @functools.lru_cache(maxsize=512)
-def _flash_bwd_jitted(sq, sk, causal, scale, block_q, group=1, window=0):
-    if group != 1 or window:
-        return jax.jit(functools.partial(
-            _flash_bwd_grouped, sq=sq, sk=sk, causal=causal, scale=scale,
-            block_q=block_q, group=group, window=window))
-    n_q = sq // block_q
+def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
+                      interpret, window=0):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n_q, n_kv = sq // block_q, sk // block_k
+    rows = group * block_q
+    static = dict(causal=causal, scale=scale, block_q=block_q,
+                  block_k=block_k, group=group, window=window)
+    extra = {"interpret": interpret} if interpret is not None else {}
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_FLASH_BWD_VMEM_LIMIT)
 
-    def bwd(qf, kf, vf, lens, out, lse, d_out):
-        # D_i = rowsum(dO_i * O_i), in f32: it enters ds by cancellation
-        # against dp, so bf16 rounding here would amplify
-        D = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                 # [BH, Sq]
-        kv_len = lens.astype(jnp.int32)                      # [BH]
+    # dq: the forward's grid and its clamped K/V index map
+    def kv_of_q(g, qi, ki, lens):
+        last = _last_kv_tile(qi, lens[g], block_q, block_k, causal)
+        tile = jnp.minimum(ki, last)
+        if window:
+            tile = jnp.maximum(tile, jnp.minimum(
+                _first_kv_tile(qi, block_q, block_k, window), last))
+        return (g, tile, 0)
 
-        def one_q_block(i):
-            s = i * block_q
-            qb = jax.lax.dynamic_slice_in_dim(qf, s, block_q, 1)
-            dob = jax.lax.dynamic_slice_in_dim(d_out, s, block_q, 1)
-            lseb = jax.lax.dynamic_slice_in_dim(lse, s, block_q, 1)
-            Db = jax.lax.dynamic_slice_in_dim(D, s, block_q, 1)
-            sij = jnp.einsum("bqd,bkd->bqk", qb, kf,
-                             preferred_element_type=jnp.float32) * scale
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, sk), 1)
-            valid = cols[None] < kv_len[:, None, None]       # [BH, bq, Sk]
-            if causal:
-                rows = s + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, sk), 0)
-                valid &= (rows >= cols)[None]
-            sij = jnp.where(valid, sij, _NEG_INF)
-            # explicit re-mask: a row with NO valid key has lse == m ==
-            # _NEG_INF and exp(s - lse) would resurrect every masked
-            # column as weight 1
-            p = jnp.where(valid, jnp.exp(sij - lseb[..., None]),
-                          0.0)                               # [BH, bq, Sk]
-            dp = jnp.einsum("bqd,bkd->bqk", dob, vf,
-                            preferred_element_type=jnp.float32)
-            ds = p * (dp - Db[..., None])
-            dqb = jnp.einsum("bqk,bkd->bqd", ds, kf,
-                             preferred_element_type=jnp.float32) * scale
-            dkb = jnp.einsum("bqk,bqd->bkd", ds, qb,
-                             preferred_element_type=jnp.float32) * scale
-            dvb = jnp.einsum("bqk,bqd->bkd", p, dob,
-                             preferred_element_type=jnp.float32)
-            return dqb, dkb, dvb
+    q_of_q = lambda g, qi, ki, lens: (g, 0, qi, 0)  # noqa: E731
+    row_of_q = lambda g, qi, ki, lens: (g, qi, 0, 0)  # noqa: E731
 
-        # accumulate dk/dv in the loop carry so only ONE full-size
-        # buffer per gradient exists (lax.map would stack n_q of them)
-        bh = qf.shape[0]
-        dkv_shape = (bh,) + kf.shape[1:]
+    # dk/dv: its transpose.  Past the last q tile that sees the K/V tile
+    # (and before the first) the index stays where it is: no DMA
+    def q_tile(g, ki, qi, lens):
+        last = _last_q_tile(ki, lens[g], n_q, block_q, block_k, window)
+        first = jnp.minimum(_first_q_tile(ki, block_q, block_k, causal), last)
+        return jnp.maximum(jnp.minimum(qi, last), first)
 
-        def body(i, carry):
-            dq_acc, dk_acc, dv_acc = carry
-            dqb, dkb, dvb = one_q_block(i)
-            dq_acc = jax.lax.dynamic_update_slice_in_dim(
-                dq_acc, dqb, i * block_q, 1)
-            return dq_acc, dk_acc + dkb, dv_acc + dvb
+    q_of_kv = lambda g, ki, qi, lens: (g, 0, q_tile(g, ki, qi, lens), 0)  # noqa: E731
+    row_of_kv = lambda g, ki, qi, lens: (g, q_tile(g, ki, qi, lens), 0, 0)  # noqa: E731
+    kv_of_kv = lambda g, ki, qi, lens: (g, ki, 0)  # noqa: E731
 
-        dq, dk, dv = jax.lax.fori_loop(
-            0, n_q, body,
-            (jnp.zeros(qf.shape, jnp.float32),
-             jnp.zeros(dkv_shape, jnp.float32),
-             jnp.zeros(dkv_shape, jnp.float32)))
-        return dq, dk, dv
+    def run(qf, kf, vf, lens, out, lse, d_out):
+        with _enable_x64(False):
+            # D_i = rowsum(dO_i * O_i), in f32: it enters ds by cancellation
+            # against dp, so bf16 rounding here would amplify
+            dd = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
+                         axis=-1)                               # [BH, Sq]
+            # a row with NO valid key has lse == -1e30, and exp(s - lse)
+            # would resurrect every masked column as weight 1: +1e30 there
+            # makes its p zero, and so its gradient
+            lse = jnp.where(lse > _NEG_INF / 2, lse, jnp.float32(-_NEG_INF))
+            grouped = lambda x: x.reshape((bkv, group, sq) + x.shape[2:])
+            # one q tile's rows (head-major, as the tile is flattened) as a
+            # [1, rows] vector: [B*KV, n_q, 1, rows]
+            row = lambda x: x.reshape(bkv, group, n_q, block_q).transpose(
+                0, 2, 1, 3).reshape(bkv, n_q, 1, rows)
+            lse, dd = row(lse), row(dd)
+            qg, dog = grouped(qf), grouped(d_out)
+            lens = lens.astype(jnp.int32)
+            q_block = (1, group, block_q, d)
+            kv_block = (1, block_k, d)
+            row_block = (1, 1, 1, rows)
+            dq = pl.pallas_call(
+                functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=n_kv,
+                                  **static),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(bkv, n_q, n_kv),
+                    in_specs=[
+                        pl.BlockSpec(q_block, q_of_q),
+                        pl.BlockSpec(kv_block, kv_of_q),
+                        pl.BlockSpec(kv_block, kv_of_q),
+                        pl.BlockSpec(q_block, q_of_q),
+                        pl.BlockSpec(row_block, row_of_q),
+                        pl.BlockSpec(row_block, row_of_q),
+                    ],
+                    out_specs=pl.BlockSpec(q_block, q_of_q),
+                    scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+                                    pltpu.VMEM((rows, 128), jnp.float32),
+                                    pltpu.VMEM((rows, 128), jnp.float32)]),
+                out_shape=jax.ShapeDtypeStruct(qg.shape, qf.dtype),
+                compiler_params=params, name="flash_attn_bwd_dq", **extra,
+            )(lens, qg, kf, vf, dog, lse, dd)
+            dk, dv = pl.pallas_call(
+                functools.partial(_flash_bwd_dkv_kernel, n_q_blocks=n_q,
+                                  **static),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(bkv, n_kv, n_q),
+                    in_specs=[
+                        pl.BlockSpec(q_block, q_of_kv),
+                        pl.BlockSpec(kv_block, kv_of_kv),
+                        pl.BlockSpec(kv_block, kv_of_kv),
+                        pl.BlockSpec(q_block, q_of_kv),
+                        pl.BlockSpec(row_block, row_of_kv),
+                        pl.BlockSpec(row_block, row_of_kv),
+                    ],
+                    out_specs=[pl.BlockSpec(kv_block, kv_of_kv),
+                               pl.BlockSpec(kv_block, kv_of_kv)],
+                    scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                                    pltpu.VMEM((block_k, d), jnp.float32)]),
+                out_shape=[jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+                           jax.ShapeDtypeStruct(vf.shape, vf.dtype)],
+                compiler_params=params, name="flash_attn_bwd_dkv", **extra,
+            )(lens, qg, kf, vf, dog, lse, dd)
+            return dq.reshape(qf.shape), dk, dv
 
-    return jax.jit(bwd)
-
-
-def _flash_bwd_grouped(qf, kf, vf, lens, out, lse, d_out, *, sq, sk, causal,
-                       scale, block_q, group, window=0):
-    """The blockwise backward for grouped-query attention: ``qf`` [B*H, Sq,
-    D] against ``kf``/``vf`` [B*KV, Sk, D].  The query side is viewed as
-    [B*KV, group, ...]; dk and dv sum over the group inside the products.
-    Under a ``window`` a query block meets a band of ``window + block_q``
-    keys sliced from K and V where its rows' keys lie, and adds its dk, dv
-    into that band; without one it meets all ``sk`` keys, as ever."""
-    bkv, d = kf.shape[0], kf.shape[2]
-    view = lambda x: x.reshape((bkv, group) + x.shape[1:])
-    qg, og, dog, lseg = view(qf), view(out), view(d_out), view(lse)
-    D = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
-    kv_len = lens.astype(jnp.int32)                            # [B*KV]
-    f32 = dict(preferred_element_type=jnp.float32)
-    band = _flash_bwd_band(sk, block_q, window)
-    n_k = band or sk
-
-    def body(i, carry):
-        dq_acc, dk_acc, dv_acc = carry
-        s = i * block_q
-        take = lambda x: jax.lax.dynamic_slice_in_dim(x, s, block_q, 2)
-        qb, dob, lseb, Db = take(qg), take(dog), take(lseg), take(D)
-        if band:
-            # the band ends with the block's last row, clamped to the keys
-            lo = jnp.clip(s + block_q - band, 0, sk - band)
-            kb, vb = (jax.lax.dynamic_slice_in_dim(x, lo, band, 1)
-                      for x in (kf, vf))
-            add = lambda acc, x: jax.lax.dynamic_update_slice_in_dim(
-                acc, jax.lax.dynamic_slice_in_dim(acc, lo, band, 1) + x,
-                lo, 1)
-        else:
-            kb, vb = kf, vf
-            add = lambda acc, x: acc + x
-        sij = jnp.einsum("bgqd,bkd->bgqk", qb, kb, **f32) * scale
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, n_k), 1)
-        if band:
-            cols = cols + lo
-        valid = cols[None, None] < kv_len[:, None, None, None]
-        if causal:
-            rows = s + jax.lax.broadcasted_iota(jnp.int32, (block_q, n_k), 0)
-            seen = rows >= cols
-            if window:
-                seen &= rows - cols < window
-            valid &= seen[None, None]
-        p = jnp.where(valid, jnp.exp(jnp.where(valid, sij, _NEG_INF)
-                                     - lseb[..., None]), 0.0)
-        dp = jnp.einsum("bgqd,bkd->bgqk", dob, vb, **f32)
-        ds = p * (dp - Db[..., None])
-        dqb = jnp.einsum("bgqk,bkd->bgqd", ds, kb, **f32) * scale
-        dk_acc = add(dk_acc,
-                     jnp.einsum("bgqk,bgqd->bkd", ds, qb, **f32) * scale)
-        dv_acc = add(dv_acc, jnp.einsum("bgqk,bgqd->bkd", p, dob, **f32))
-        return (jax.lax.dynamic_update_slice_in_dim(dq_acc, dqb, s, 2),
-                dk_acc, dv_acc)
-
-    dq, dk, dv = jax.lax.fori_loop(
-        0, sq // block_q, body,
-        (jnp.zeros(qg.shape, jnp.float32), jnp.zeros(kf.shape, jnp.float32),
-         jnp.zeros(vf.shape, jnp.float32)))
-    return dq.reshape(qf.shape), dk, dv
+    return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=512)
@@ -736,10 +910,11 @@ def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
     """What one :func:`attention` node of these shapes is built to do, as
     (computed, visible) counts of (query, key) pairs per head: the pairs
     whose score its forward and its backward compute, masked or not (the
-    forward's needed tiles, the backward's query blocks against their band
-    or all keys; the XLA reference computes every pair both ways), and the
-    pairs the mask lets through, once each way.  Static: shapes, the tile
-    plan and the kernel mode of the enclosing :func:`trace_scope`; lengths
+    needed tiles of the forward and of EACH of the backward's two kernels,
+    which both score the pairs of their tiles: a backward pair counts
+    twice; the XLA reference computes every pair both ways), and the pairs
+    the mask lets through, once each way.  Static: shapes, the tile plans
+    and the kernel mode of the enclosing :func:`trace_scope`; lengths
     (``kv_lens``) are not known here."""
     b, sq, _, d = (int(x) for x in q_shape)
     sk, kv = int(k_shape[1]), int(k_shape[2])
@@ -753,13 +928,19 @@ def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
         visible = sq * sk
     if _flash_mode(d, dtype) is None:
         return 2 * b * sq * sk, 2 * b * visible
-    bq, bk = _flash_plan(sq, sk, d, group, jnp.dtype(dtype).itemsize, causal)
-    sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
-    tiles = sum(max(0, int(_last_kv_tile(qi, sk, bq, bk, causal)) + 1
-                    - int(_first_kv_tile(qi, bq, bk, window)))
-                for qi in range(sq_p // bq))
-    band = _flash_bwd_band(sk_p, _flash_bwd_block(bq), window)
-    return b * (bq * bk * tiles + sq_p * (band or sk_p)), 2 * b * visible
+    itemsize = jnp.dtype(dtype).itemsize
+    bq, bk = _flash_plan(sq, sk, d, group, itemsize, causal)
+    sq_p = _round_up(sq, bq)
+
+    def scored(bq, bk):
+        tiles = sum(max(0, int(_last_kv_tile(qi, sk, bq, bk, causal)) + 1
+                        - int(_first_kv_tile(qi, bq, bk, window)))
+                    for qi in range(sq_p // bq))
+        return bq * bk * tiles
+
+    backward = scored(*_flash_bwd_plan(sq_p, _round_up(sk, bk), bq, bk, d,
+                                       group, itemsize, causal))
+    return b * (scored(bq, bk) + 2 * backward), 2 * b * visible
 
 
 # ---------------------------------------------------------------------------

@@ -261,6 +261,83 @@ def test_equal_heads_program_is_what_it_was():
     assert "bqhgd" not in ref and ref.count("dot_general") == 2
 
 
+def _primitives(jaxpr):
+    """Names of every primitive of a jaxpr, nested ones included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("heads,kv,window,with_lens", [
+    (2, 2, 0, False), (8, 1, 0, True), (8, 1, 48, False)],
+    ids=["group1", "group8-lens", "group8-window"])
+def test_the_backward_is_two_kernels_and_no_loop(heads, kv, window,
+                                                 with_lens):
+    """A differentiated call holds the forward's kernel and the backward's
+    two, ``flash_attn_bwd_dq`` and ``flash_attn_bwd_dkv`` (no name of theirs
+    holds the forward's: a metric finds the forward by it), whose grids are
+    each other's transpose, and no XLA loop.  A group of one is the same
+    pair of kernels."""
+    q, _, _ = _qkv(1, 256, heads, 128, seed=8)
+    _, k, v = _qkv(1, 256, kv, 128, seed=9)
+    lens = jnp.asarray([200], jnp.int32) if with_lens else None
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(pk.flash_attention(
+            *a, causal=True, use_pallas=True, interpret=True, window=window,
+            kv_lens=lens, block_q=64, block_k=128)), argnums=(0, 1, 2)))(
+        q, k, v).jaxpr
+    calls = {c.params["name"]: c.params["grid_mapping"].grid
+             for c in _pallas_calls(jaxpr)}
+    assert calls == {"flash_attn_fwd": (kv, 4, 2),
+                     "flash_attn_bwd_dq": (kv, 4, 2),
+                     "flash_attn_bwd_dkv": (kv, 2, 4)}
+    assert sum("flash_attn_fwd" in name for name in calls) == 1
+    assert not {"while", "scan"} & _primitives(jaxpr)
+
+
+@pytest.mark.parametrize("heads,kv", [(2, 2), (8, 1)],
+                         ids=["group1", "group8"])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "window"])
+def test_rows_that_see_no_key_take_no_gradient(heads, kv, window):
+    """Lengths that do not tile, one sequence cut short and one EMPTY: a
+    query row with no valid key (every row of the empty sequence; under a
+    window, the rows past the short one's reach) has p re-masked to 0 in
+    the backward: its dq is zero, it adds nothing to dk and dv, and
+    nothing is NaN, though its cotangent is not zero."""
+    seq, d = 333, 128
+    q, _, _ = _qkv(3, seq, heads, d, seed=11)
+    _, k, v = _qkv(3, seq, kv, d, seed=12)
+    lens = jnp.asarray([seq, 100, 0], jnp.int32)
+    w = jnp.asarray(_rng(13).normal(0, 1, q.shape), jnp.float32)
+    i = jnp.arange(seq)[:, None]
+    j = jnp.arange(seq)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    live = (seen[None] & (j[None] < lens[:, None, None])).any(-1)  # [B, S]
+    assert not bool(live[2].any()) and bool(live[0].all())
+    assert bool(live[1].all()) == (window == 0)
+
+    def grads(fn, weight):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda *a: pk.flash_attention(
+        *a, causal=True, use_pallas=True, interpret=True, kv_lens=lens,
+        window=window, block_q=32, block_k=128), w)
+    # the oracle is asked about the live rows only
+    want = grads(lambda *a: pk._reference_attention(
+        *a, True, 1.0 / d ** 0.5, lens, window), w * live[:, :, None, None])
+    for g, r, name in zip(got, want, "qkv"):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg="d%s diverged" % name)
+    dq = np.asarray(got[0])
+    assert not dq[~np.asarray(live)].any() and dq[np.asarray(live)].any()
+    assert not np.asarray(got[1])[2].any() and not np.asarray(got[2])[2].any()
+
+
 # the tile plan as a pure function (docs/kernels.md §flash-attention)
 
 def test_flash_plan_at_the_language_model_cells_shape():
@@ -293,6 +370,47 @@ def test_flash_plan_tiles_divide_the_padded_length(seq, itemsize, group):
         assert padded % bq == 0 and padded % bk == 0
         assert pk._flash_vmem_bytes(bq, bk, 128, group, itemsize) \
             <= pk._FLASH_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("d,want", [(128, (512, 512)), (256, (256, 512))],
+                         ids=["trinity-head128", "qwen3next-head256"])
+def test_backward_plan_at_the_language_model_cells_shapes(d, want):
+    """Both cells: 8,192 tokens, eight query heads a K/V head, bf16, causal.
+    The backward takes K/V tiles half the forward's and the longest q tile
+    its own budget lets it (measured on the v5e: PERF.md, PR 33)."""
+    fwd = pk._flash_plan(8192, 8192, d, 8, 2, True)
+    assert fwd == (256, 1024)
+    bq, bk = pk._flash_bwd_plan(8192, 8192, *fwd, d, 8, 2, True)
+    assert (bq, bk) == want
+    assert 8192 % bq == 0 and 8192 % bk == 0 and bk % 128 == 0
+    assert 8 * bq <= pk._FLASH_BWD_MAX_ROWS and bk <= pk._FLASH_BWD_MAX_BLOCK_K
+    assert pk._flash_bwd_vmem_bytes(bq, bk, d, 8, 2) \
+        <= pk._FLASH_BWD_VMEM_BUDGET <= pk._FLASH_BWD_VMEM_LIMIT
+    # one q tile more would not fit: the budget is what stops it
+    assert d == 128 or pk._flash_bwd_vmem_bytes(2 * bq, bk, d, 8, 2) \
+        > pk._FLASH_BWD_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("seq", [16, 100, 256, 1100, 4096])
+def test_backward_plan_tiles_divide_the_padded_length(seq, itemsize, group):
+    """The backward works on operands padded by the forward's tiles: its
+    own divide those lengths, keep the forward's alignment and fit its
+    budget; under ``causal`` a q tile stays within a quarter of the keys
+    (or 512)."""
+    sub = {2: 16, 4: 8}[itemsize]
+    for causal in (False, True):
+        fq, fk = pk._flash_plan(seq, seq, 128, group, itemsize, causal)
+        sq, sk = -(-seq // fq) * fq, -(-seq // fk) * fk
+        bq, bk = pk._flash_bwd_plan(sq, sk, fq, fk, 128, group, itemsize,
+                                    causal)
+        assert sq % bq == 0 and sk % bk == 0
+        assert bq % sub == 0 and (bk % 128 == 0 or bk == fk)
+        assert group * bq <= max(pk._FLASH_BWD_MAX_ROWS, group * fq)
+        assert not causal or bq <= max(512, sk // 4, fq)
+        assert pk._flash_bwd_vmem_bytes(bq, bk, 128, group, itemsize) \
+            <= pk._FLASH_BWD_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 512),

@@ -3,8 +3,8 @@ docs/trinity.md): query ``i`` sees keys ``i - window < j <= i``.
 
 The Pallas kernel through the interpreter (the code path the chip compiles)
 and the XLA reference against a float32 oracle written out by hand, forward
-and all three gradients; what the forward's K/V index map fetches and what
-the backward's band holds, as plain integers; and what an attention node is
+and all three gradients; what the forward's K/V index map and the
+backward's q index map fetch, as plain integers; and what an attention node is
 built to compute against what its mask lets through (the
 ``module.attn.pairs_*`` counters)."""
 import numpy as np
@@ -158,7 +158,7 @@ def test_a_window_needs_causal_and_a_positive_width():
             pk.attention(q, k, v, **kw)
 
 
-# -- what is fetched, and what the backward's band holds: plain integers ---------
+# -- what is fetched, forward and backward: plain integers -----------------------
 
 @pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 512),
                                     (256, 512), (256, 1024)],
@@ -185,16 +185,47 @@ def test_kv_index_map_keeps_to_the_window(blocks, window):
     assert pk._first_kv_tile(3, bq, bk, 0) == 0
 
 
-def test_backward_band_holds_every_key_a_block_sees():
-    sk, bq, window = 8192, 128, 2048
-    band = pk._flash_bwd_band(sk, bq, window)
-    assert band == window + bq and band % 128 == 0
-    for i in range(sk // bq):
-        lo = int(np.clip(i * bq + bq - band, 0, sk - band))
-        assert lo <= max(i * bq - window + 1, 0) and i * bq + bq <= lo + band
-    # no band where it would hold (nearly) all keys, or without a window
-    assert pk._flash_bwd_band(2100, 128, 2048) == 0
-    assert pk._flash_bwd_band(8192, 128, 0) == 0
+@pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 512),
+                                    (256, 512), (512, 512)],
+                         ids=lambda b: "q%dk%d" % b)
+@pytest.mark.parametrize("window", [0, 1, 100, 512, 2048])
+@pytest.mark.parametrize("kv_len", [2048, 700])
+def test_q_index_map_keeps_to_the_window(blocks, window, kv_len):
+    """The transpose of the test above, for the dk/dv kernel: the q tiles a
+    K/V tile computes are exactly those that hold a query which sees one of
+    its valid keys (every pair written out), and along its q steps the
+    mapped index changes once per such tile."""
+    bq, bk = blocks
+    seq = 2048
+    n_q, n_kv = seq // bq, seq // bk
+    i = np.arange(seq)[:, None]
+    j = np.arange(seq)[None, :]
+    seen = (j <= i) & (j < kv_len)
+    if window:
+        seen &= i - j < window
+    for ki in range(n_kv):
+        needed = [qi for qi in range(n_q)
+                  if seen[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()]
+        if ki * bk >= kv_len:     # the kernel's predicate skips the tile
+            assert not needed
+            continue
+        first = int(pk._first_q_tile(ki, bq, bk, True))
+        last = int(pk._last_q_tile(ki, kv_len, n_q, bq, bk, window))
+        assert list(range(first, last + 1)) == needed
+        tiles = [max(min(qi, last), min(first, last)) for qi in range(n_q)]
+        assert 1 + sum(a != b for a, b in zip(tiles, tiles[1:])) \
+            == len(needed)
+        # what the forward's q-blocks compute of this K/V tile, seen from
+        # the other side (the forward does not know the length's reach
+        # through the window, so it may compute more, never less)
+        fwd = [qi for qi in range(n_q)
+               if int(pk._first_kv_tile(qi, bq, bk, window)) <= ki
+               <= int(pk._last_kv_tile(qi, kv_len, bq, bk, True))]
+        assert set(needed) <= set(fwd)
+        assert kv_len < seq or needed == fwd
+    # without a diagonal every q tile sees every valid K/V tile
+    assert pk._first_q_tile(3, bq, bk, False) == 0
+    assert int(pk._last_q_tile(0, kv_len, n_q, bq, bk, 0)) == n_q - 1
 
 
 def test_flash_plan_at_the_window_cells_shape():
@@ -214,22 +245,29 @@ CELL_Q, CELL_K = (1, 8192, 32, D), (1, 8192, 4, D)
 
 def test_attention_pairs_of_the_cells_layers():
     """Visible: 14.7 M pairs a window layer, 33.6 M a full one, each way.
-    Computed on the chip: the forward's needed tiles and the backward's 64
-    blocks of 128 rows against their band (all keys without a window)."""
+    Computed on the chip: the forward's needed 256 x 1,024 tiles once, and
+    the backward's needed 512 x 512 tiles TWICE: its dq and its dk/dv kernel
+    each score the pairs of their tiles."""
     with pk.trace_scope(platform="tpu"):
         wc, wv = pk.attention_pairs(CELL_Q, CELL_K, jnp.bfloat16, True, 2048)
         fc, fv = pk.attention_pairs(CELL_Q, CELL_K, jnp.bfloat16, True)
     assert wv == 2 * (2048 * 2049 // 2 + 6144 * 2048) == 2 * 14_681_088
     assert fv == 2 * (8192 * 8193 // 2) == 2 * 33_558_528
     assert 4 * wv + fv == 184_565_760
+    assert pk._flash_bwd_plan(8192, 8192, 256, 1024, D, 8, 2, True) \
+        == (512, 512)
     fwd_full = 256 * 1024 * sum(i // 4 + 1 for i in range(32))
-    assert fc == fwd_full + 8192 * 8192
-    tiles = sum((256 * i + 255) // 1024 - max(256 * i - 2047, 0) // 1024 + 1
-                for i in range(32))
-    assert wc == 256 * 1024 * tiles + 8192 * (2048 + 128)
-    assert 1.4 < (4 * wc + fc) / (4 * wv + fv) < 1.6
-    # the window ignored both ways would read 2.8
-    assert 2.7 < 5 * fc / (4 * wv + fv) < 2.9
+    bwd_full = 512 * 512 * sum(i + 1 for i in range(16))
+    assert fc == fwd_full + 2 * bwd_full == 109_051_904
+    fwd_tiles = sum((256 * i + 255) // 1024 - max(256 * i - 2047, 0) // 1024
+                    + 1 for i in range(32))
+    bwd_tiles = sum(i - max(512 * i - 2047, 0) // 512 + 1 for i in range(16))
+    assert wc == 256 * 1024 * fwd_tiles + 2 * 512 * 512 * bwd_tiles
+    # one pass over the visible pairs each way would read 1; the forward's
+    # overhang and the backward's second scoring pass make it 1.8
+    assert 1.7 < (4 * wc + fc) / (4 * wv + fv) < 1.9
+    # the window ignored in all three kernels would read 3.0
+    assert 2.9 < 5 * fc / (4 * wv + fv) < 3.1
     # off the chip the XLA reference computes every pair both ways
     with pk.trace_scope(platform="cpu"):
         assert pk.attention_pairs(CELL_Q, CELL_K, jnp.bfloat16, True, 2048) \

@@ -71,7 +71,7 @@ def _max_rel(a, b):
 
 
 def test_flash_backward_matches_reference_vjp():
-    """The custom VJP (pallas forward + blockwise backward from saved LSE)
+    """The custom VJP (pallas forward + the two backward kernels from saved LSE)
     matches the XLA reference attention's autodiff gradients: to f32
     round-off through the interpreter, to the MXU's default-precision
     tolerance when both sides run compiled on the chip.  Zero-mean
