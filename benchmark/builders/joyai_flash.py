@@ -1,0 +1,15 @@
+"""joyai-llm-flash-48b-ep16-bf16 and its kin -> the program's objects."""
+from __future__ import annotations
+
+
+def symbol(cfg):
+    from mxnet_tpu import models
+    from .. import harness
+    if not hasattr(models, "joyai_flash"):
+        # a checkout from before the model (the parent of the PR that added
+        # the cell): say so at once instead of failing somewhere inside
+        raise harness.Refused("this checkout's mxnet_tpu has no "
+                              "models.joyai_flash: it cannot run %s"
+                              % cfg["name"])
+    return models.joyai_flash.get_symbol(cfg,
+                                         dtype=cfg["precision"]["compute"])

@@ -241,9 +241,14 @@ class _Program:
                 width = int(attrs["num_hidden"]) or int(q[-1])
                 q, k = ((int(s[0]), int(s[1]), heads, width // heads)
                         for s in (q, k))
+            widths = {}
+            if n.op_name == "scaled_dot_product_attention":
+                widths["v_width"] = int(at[n.inputs[2]][-1])
+                if attrs.get("use_shared_key"):
+                    widths["shared_width"] = int(at[n.inputs[-1]][-1])
             c, v = pallas_kernels.attention_pairs(
                 q, k, kinds[n.inputs[0]], bool(attrs["causal"]),
-                int(attrs.get("window") or 0))
+                int(attrs.get("window") or 0), **widths)
             computed, visible = computed + c, visible + v
         return computed, visible
 

@@ -1,8 +1,10 @@
-"""What the decoder language models share (``qwen3_next.py``, ``trinity.py``):
-parameters in the storage dtype, bias-free projections over ``[batch, seq,
-hidden]``, RMSNorm, a SwiGLU MLP, the expert layer's call, the mirror stage
-of half a block, and the tail that turns the last hidden state into the
-symbol's two outputs."""
+"""What the decoder language models share (``qwen3_next.py``, ``trinity.py``,
+``joyai_flash.py``): parameters in the storage dtype (one variable a name, so
+that two nodes may read one parameter and its gradient is the sum of both
+uses), bias-free projections over ``[batch, seq, hidden]``, RMSNorm, a SwiGLU
+MLP, the expert layer's call, the mirror stage of half a block, and the tail
+that turns the last hidden state (and, where a model has one, a second loss
+head on the same embedding and output matrix) into the symbol's outputs."""
 from __future__ import annotations
 
 from .. import symbol as sym
@@ -14,14 +16,26 @@ class LMBuilder:
         self.cfg = cfg
         self.dtype = dtype
         self.eps = float(cfg["rms_norm_eps"])
+        self._params = {}
 
     def param(self, name):
-        return sym.var(name, dtype=self.dtype)
+        """The one variable of this name: asked for twice, it is one
+        parameter read by two nodes."""
+        if name not in self._params:
+            self._params[name] = sym.var(name, dtype=self.dtype)
+        return self._params[name]
 
-    def dense(self, x, name, width):
-        return sym.FullyConnected(x, weight=self.param(name + "_weight"),
-                                  num_hidden=int(width), no_bias=True,
-                                  flatten=False, name=name)
+    def label(self):
+        """``softmax_label``, one variable whoever reads it."""
+        return self._params.setdefault("softmax_label",
+                                       sym.Variable("softmax_label"))
+
+    def dense(self, x, name, width, weight=None):
+        """``x W^T`` as the node ``name``, with its own matrix ``name`` +
+        ``_weight`` or, where ``weight`` names another node's, that one."""
+        return sym.FullyConnected(
+            x, weight=self.param((weight or name) + "_weight"),
+            num_hidden=int(width), no_bias=True, flatten=False, name=name)
 
     def norm(self, x, name, zero_centered=True):
         return sym.RMSNorm(x, gamma=self.param(name + "_gamma"), eps=self.eps,
@@ -65,20 +79,44 @@ class LMBuilder:
 
     @staticmethod
     def named(scope):
-        """The nodes built inside are lowered under ``jax.named_scope``."""
-        return sym.AttrScope(**{NAMED_SCOPE: scope})
+        """The nodes built inside are lowered under ``jax.named_scope``
+        (inside another such scope, under ``outer/scope``)."""
+        outer = sym.AttrScope.current().get(None).get(NAMED_SCOPE)
+        return sym.AttrScope(
+            **{NAMED_SCOPE: outer + "/" + scope if outer else scope})
 
-    def outputs(self, x, counts):
+    def token_loss(self, x, prefix="", shift=0):
+        """The cross-entropy, one mean a sequence, of the normed hidden
+        state ``x`` through THE output matrix (``lm_head_weight``, whichever
+        node asks) against ``softmax_label`` ``shift`` positions on: 0 is
+        the next token, 1 the one after it."""
+        logits = self.dense(x, prefix + "lm_head", self.cfg["vocab_size"],
+                            weight="lm_head")
+        return sym.sequence_cross_entropy(
+            logits, self.label(), shift=int(shift),
+            name=prefix + "ce")
+
+    def outputs(self, x, counts, second=None):
         """``Group([loss, expert selection counts])`` from the last block's
-        output: final norm, untied head, the next-token cross-entropy as one
-        mean a sequence under ``MakeLoss``, and the layers' counts stacked
-        without gradient and marked for ``Module.update_metric``."""
+        output through the final norm, ``x``: untied head, the next-token
+        cross-entropy as one mean a sequence under ``MakeLoss``, and the
+        layers' counts stacked without gradient and marked for
+        ``Module.update_metric``.
+
+        ``second = (weight, loss, counter names)`` adds a second head's loss
+        [batch] (``token_loss``) to the one that is trained, ``main + weight *
+        loss``, and a third output: the two parts stacked without gradient,
+        which ``update_metric`` adds to the two counters named."""
         cfg = self.cfg
-        logits = self.dense(self.norm(x, "final_norm"), "lm_head",
-                            cfg["vocab_size"])
-        loss = sym.MakeLoss(sym.sequence_cross_entropy(
-            logits, sym.Variable("softmax_label"), name="ce"), name="loss")
+        main = self.token_loss(x)
+        total = main if second is None else main + float(second[0]) * second[1]
+        loss = sym.MakeLoss(total, name="loss")
         counts = sym.BlockGrad(sym.stack(*counts, axis=0), name="moe_counts")
         counts._set_attr(__moe_counts__="%d,%d" % (
             int(cfg.get("first_expert", 0)), int(cfg["num_experts"])))
-        return sym.Group([loss, counts])
+        if second is None:
+            return sym.Group([loss, counts])
+        parts = sym.BlockGrad(sym.stack(main, second[1], axis=0),
+                              name="loss_parts")
+        parts._set_attr(__counters__=",".join(second[2]))
+        return sym.Group([loss, counts, parts])

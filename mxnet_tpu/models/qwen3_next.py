@@ -116,4 +116,4 @@ def get_symbol(cfg, dtype="float32", recompute=True):
     for layer in range(int(cfg["num_hidden_layers"])):
         x, c = build.block(x, layer, recompute)
         counts.append(c)
-    return build.outputs(x, counts)
+    return build.outputs(build.norm(x, "final_norm"), counts)

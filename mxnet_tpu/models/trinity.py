@@ -116,4 +116,4 @@ def get_symbol(cfg, dtype="float32", recompute=True):
         x, c = build.block(x, layer, kind, recompute)
         if c is not None:
             counts.append(c)
-    return build.outputs(x, counts)
+    return build.outputs(build.norm(x, "final_norm"), counts)

@@ -7,8 +7,10 @@ the reference (§2.5 of SURVEY.md).
 """
 from __future__ import annotations
 
+import functools
 import logging
 
+import jax
 import numpy as np
 
 from ..base import MXNetError
@@ -75,7 +77,7 @@ class DataParallelExecutorGroup:
         self.arg_names = symbol.list_arguments()
         self.aux_names = symbol.list_auxiliary_states()
         self.symbol = symbol
-        self._moe_counts = None
+        self._counter_notes = None
         self.contexts = contexts
         self.workload = workload or [1] * len(contexts)
         self.for_training = for_training
@@ -367,20 +369,28 @@ class DataParallelExecutorGroup:
                     out_grads_slice.append(grad.copyto(self.contexts[i]))
             exc.backward(out_grads=out_grads_slice if out_grads_slice else None)
 
-    def _moe_count_outputs(self):
-        """{output index: (first expert, experts held)} of the outputs a
-        model marks as its routing counts (``__moe_counts__`` on the head
-        node): they go to the counters, not to the metric."""
-        if self._moe_counts is None:
-            self._moe_counts = {
-                i: tuple(int(v) for v in
-                         node.attrs["__moe_counts__"].split(","))
-                for i, (node, _) in enumerate(self.symbol._entries)
-                if "__moe_counts__" in node.attrs}
-        return self._moe_counts
+    def _counter_outputs(self):
+        """{output index: what takes its value} of the outputs a model marks
+        for the counters and not for the metric: its routing counts
+        (``__moe_counts__`` = first expert, experts held, on the head node)
+        and rows that name their own counters (``__counters__``)."""
+        if self._counter_notes is None:
+            self._counter_notes = {}
+            for i, (node, _) in enumerate(self.symbol._entries):
+                if "__moe_counts__" in node.attrs:
+                    first, held = (int(v) for v in
+                                   node.attrs["__moe_counts__"].split(","))
+                    self._counter_notes[i] = functools.partial(
+                        _instrument.note_moe_counts, first_expert=first,
+                        experts_held=held)
+                elif "__counters__" in node.attrs:
+                    self._counter_notes[i] = functools.partial(
+                        _instrument.note_counter_rows,
+                        names=node.attrs["__counters__"].split(","))
+        return self._counter_notes
 
     def update_metric(self, eval_metric, labels):
-        counts = self._moe_count_outputs()
+        counts = self._counter_outputs()
         for texec, islice in zip(self.execs, self.slices):
             labels_slice = []
             for label, axis in zip(labels, self.label_layouts or [0] * len(labels)):
@@ -396,9 +406,11 @@ class DataParallelExecutorGroup:
                 continue
             eval_metric.update(labels_slice, [
                 o for i, o in enumerate(texec.outputs) if i not in counts])
-            for i, (first, held) in counts.items():
-                _instrument.note_moe_counts(texec.outputs[i].asnumpy(),
-                                            first, held)
+            # one transfer for all of them, once the loss is on the host
+            fetched = jax.device_get(
+                [texec.outputs[i]._h.array for i in counts])
+            for note, value in zip(counts.values(), fetched):
+                note(value)
 
     def install_monitor(self, mon):
         for exe in self.execs:

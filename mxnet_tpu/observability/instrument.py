@@ -837,6 +837,17 @@ def note_attention_pairs(computed, visible):
                  "once each way").inc(visible)
 
 
+def note_counter_rows(rows, names):
+    """One step's value of the counters a model's output names
+    (``__counters__``, read where the loss is read): row ``i`` of ``rows``,
+    averaged over what it holds (the batch), is added to counter
+    ``names[i]`` — ``module.lm.loss_main`` / ``module.lm.loss_mtp``, the two
+    parts of a loss with a second head, summed over steps."""
+    rows = np.asarray(rows, np.float64).reshape(len(names), -1)
+    for name, row in zip(names, rows):
+        telemetry.counter(name).inc(float(row.mean()))
+
+
 def note_moe_counts(counts, first_expert, experts_held):
     """One step's routing, from the per-expert selection counts the step
     program hands out ([layers, experts] or [experts]; read where the loss

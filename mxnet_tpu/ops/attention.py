@@ -12,7 +12,9 @@ Two ops:
 
 - ``scaled_dot_product_attention``: pre-split heads, q/k/v as
   [batch, seq, heads, head_dim] (K/V may hold fewer heads: grouped
-  queries); causal + padding masks, and a causal ``window``.
+  queries; v may be narrower or wider than q and k; a part of the key may
+  be one that all heads share); causal + padding masks, and a causal
+  ``window``.
 - ``multi_head_attention``: fused qkv/out projections around the same
   core — one node carries the full attention block so the kernel flag
   (``MXNET_TPU_PALLAS_ATTN``) swaps the entire fast path at bind time.
@@ -33,7 +35,7 @@ from . import pallas_kernels as _pk
 from .registry import register, pBool, pFloat, pInt
 
 
-def _note_logit_bound(q, k, scale):
+def _note_logit_bound(q, k, scale, k_shared=None):
     """Health tap: an upper bound on max|logit| for this node, by
     Cauchy-Schwarz — scale * max_row||q|| * max_row||k||.  O(BSHD), so
     it is uniform across kernel modes (the flash path never
@@ -45,48 +47,54 @@ def _note_logit_bound(q, k, scale):
     s = scale if scale else 1.0 / float(int(q.shape[-1])) ** 0.5
     qn = jnp.max(jnp.sqrt(jnp.sum(
         jnp.square(q.astype(jnp.float32)), axis=-1)))
-    kn = jnp.max(jnp.sqrt(jnp.sum(
-        jnp.square(k.astype(jnp.float32)), axis=-1)))
+    k2 = jnp.sum(jnp.square(k.astype(jnp.float32)), axis=-1)
+    if k_shared is not None:
+        k2 = k2 + jnp.sum(jnp.square(k_shared.astype(jnp.float32)),
+                          axis=-1)[:, :, None]
+    kn = jnp.max(jnp.sqrt(k2))
     _health.note_tap(jnp.float32(s) * qn * kn)
 
 
 def _sdpa(query, key, value, *rest, causal=False, scale=0.0,
-          use_lengths=False, window=0):
+          use_lengths=False, window=0, use_shared_key=False):
     """``softmax(q k^T * scale + mask) v`` for q [batch, seq, heads,
-    head_dim] on k, v [batch, keys, kv heads, head_dim]; ``scale`` 0 means
-    ``1 / sqrt(head_dim)``.  ``causal``: key ``j`` is visible to query ``i``
+    d_qk] on k [batch, keys, kv heads, d_qk] and v [batch, keys, kv heads,
+    d_v]: the result is [batch, seq, heads, d_v], and ``scale`` 0 means
+    ``1 / sqrt(d_qk)``.  ``causal``: key ``j`` is visible to query ``i``
     iff ``j <= i``; ``window`` (needs ``causal``; 0 = none) narrows that to
     ``0 <= i - j < window``, and the kernels neither fetch nor compute what
     lies wholly outside it; ``use_lengths`` adds the (batch,) ``kv_length``
-    input, the padding mask."""
+    input, the padding mask; ``use_shared_key`` adds ``key_shared`` [batch,
+    keys, d_s], a part of the key that every head shares: ``key`` is then
+    ``d_s`` narrower than ``query``, whose last ``d_s`` columns are scored
+    against it, and the kernels read it once instead of a copy a head."""
     kv_lens = rest[0] if use_lengths else None
-    _note_logit_bound(query, key, scale)
+    k_shared = rest[-1] if use_shared_key else None
+    _note_logit_bound(query, key, scale, k_shared)
     window = _pk.checked_window(window, causal, key.shape[1])
     with jax.named_scope("mx:attn"), jax.named_scope(
             "mx:attn:window" if window else "mx:attn:full"):
         return _pk.attention(query, key, value, causal=causal,
                              scale=(scale if scale else None),
-                             kv_lens=kv_lens, window=window)
+                             kv_lens=kv_lens, window=window,
+                             k_shared=k_shared)
 
 
 def _sdpa_infer_shape(in_shapes, attrs, out_shapes=None):
+    """k and v share batch, keys and heads but not their width: the output
+    is q's shape with v's last dim, and neither of k and v is healed from
+    the other."""
     filled = list(in_shapes)
     q, k, v = filled[0], filled[1], filled[2]
-    # k and v always share a shape — heal one from the other
-    if k is None and v is not None:
-        filled[1] = k = v
-    if v is None and k is not None:
-        filled[2] = v = k
     batch = None
-    for s in (q, k):
+    for s in (q, k, v):
         if s is not None and len(s) == 4 and int(s[0]) != 0:
             batch = int(s[0])
-    if attrs.get("use_lengths") and len(filled) > 3 and filled[3] is None \
-            and batch is not None:
+    if attrs.get("use_lengths") and filled[3] is None and batch is not None:
         filled[3] = (batch,)
-    if q is None:
+    if q is None or v is None:
         return filled, [None]
-    return filled, [tuple(q)]
+    return filled, [tuple(q[:-1]) + (v[-1],)]
 
 
 def _sdpa_infer_type(in_dtypes, attrs):
@@ -98,17 +106,22 @@ def _sdpa_infer_type(in_dtypes, attrs):
         if filled[i] is None:
             filled[i] = d
     # kv_length keeps its own dtype (an int/float index vector, never
-    # coerced to the activation dtype)
+    # coerced to the activation dtype); the shared key part takes the
+    # activations'
+    if attrs.get("use_shared_key") and filled[-1] is None:
+        filled[-1] = d
     return filled, [d]
 
 
 register("scaled_dot_product_attention", _sdpa,
-         input_names=("query", "key", "value", "kv_length"),
-         num_inputs=lambda attrs: 3 + bool(attrs.get("use_lengths")),
+         input_names=("query", "key", "value", "kv_length", "key_shared"),
+         num_inputs=lambda attrs: 3 + bool(attrs.get("use_lengths"))
+         + bool(attrs.get("use_shared_key")),
          infer_shape=_sdpa_infer_shape, bidirectional_infer=True,
          infer_type=_sdpa_infer_type,
          params={"causal": (pBool, False), "scale": (pFloat, 0.0),
-                 "use_lengths": (pBool, False), "window": (pInt, 0)})
+                 "use_lengths": (pBool, False), "window": (pInt, 0),
+                 "use_shared_key": (pBool, False)})
 
 
 def _mha(query, key, value, q_weight, q_bias, k_weight, k_bias, v_weight,

@@ -50,12 +50,16 @@ register("RMSNorm", _rms_norm, input_names=("data", "gamma"),
 
 # -- rotary positions -----------------------------------------------------------
 
-def _rotary_embedding(data, rotary_dim=0, base=10000.0):
-    """Rotate-half rotary positions on the first ``rotary_dim`` dims of
-    every head of ``[batch, seq, heads, head_dim]`` (all of them when 0);
-    the position of a row is its index along ``seq``."""
+def _rotary_embedding(data, rotary_dim=0, base=10000.0, interleaved=False,
+                      offset=0):
+    """Rotary positions on ``rotary_dim`` dims of every head of ``[batch,
+    seq, heads, head_dim]``, from dim ``offset`` on (all dims from there
+    when 0); the position of a row is its index along ``seq``.  Frequency
+    ``i`` turns the pair of dims ``(i, i + rotary_dim / 2)`` (rotate-half) or,
+    where ``interleaved``, the neighbours ``(2i, 2i + 1)``."""
     d = int(data.shape[-1])
-    rd = int(rotary_dim) or d
+    lo = int(offset)
+    rd = int(rotary_dim) or d - lo
     half = rd // 2
     # graftlint: disable=GL003 — the angles depend on shapes and attributes
     # alone: a float64 table made at trace time, a constant of the program
@@ -67,14 +71,23 @@ def _rotary_embedding(data, rotary_dim=0, base=10000.0):
     cos = jnp.asarray(np.cos(ang), _F32)[None, :, None, :]
     sin = jnp.asarray(np.sin(ang), _F32)[None, :, None, :]
     x = data.astype(_F32)
-    x1, x2, rest = x[..., :half], x[..., half:rd], x[..., rd:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                          axis=-1)
+    before, turned, rest = x[..., :lo], x[..., lo:lo + rd], x[..., lo + rd:]
+    if interleaved:
+        pairs = turned.reshape(turned.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                            axis=-1).reshape(turned.shape)]
+    else:
+        x1, x2 = turned[..., :half], turned[..., half:]
+        turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    parts = [p for p in (before, *turned, rest) if p.shape[-1]]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
     return out.astype(data.dtype)
 
 
 register("rotary_embedding", _rotary_embedding, num_inputs=1,
-         params={"rotary_dim": (pInt, 0), "base": (pFloat, 10000.0)})
+         params={"rotary_dim": (pInt, 0), "base": (pFloat, 10000.0),
+                 "interleaved": (pBool, False), "offset": (pInt, 0)})
 
 
 # -- SwiGLU -------------------------------------------------------------------
@@ -441,25 +454,42 @@ register("moe_experts", _moe_experts, num_outputs=2,
 
 # -- softmax cross-entropy, one number a sequence ---------------------------------
 
-@jax.custom_vjp
-def _seq_ce(logits, label):
-    return _seq_ce_fwd(logits, label)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _seq_ce(logits, label, shift):
+    return _seq_ce_fwd(logits, label, shift)[0]
 
 
-def _seq_ce_fwd(logits, label):
-    x = logits.astype(_F32)
+def _seq_ce_targets(label, shift):
+    """(target ids [.., seq], the float32 weight of each position or None
+    where all count alike, how many count): position ``i``'s target is
+    ``label[i + shift]``, and the last ``shift`` positions have none."""
     idx = label.astype(jnp.int32)
+    n = idx.shape[-1]
+    if not shift:
+        return idx, None, n
+    idx = jnp.concatenate([idx[..., shift:],
+                           jnp.zeros_like(idx[..., :shift])], axis=-1)
+    return idx, (jnp.arange(n) < n - shift).astype(_F32), n - shift
+
+
+def _seq_ce_fwd(logits, label, shift):
+    x = logits.astype(_F32)
+    idx, live, n = _seq_ce_targets(label, shift)
     lse = jax.nn.logsumexp(x, axis=-1)
     picked = jnp.take_along_axis(x, idx[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked, axis=-1), (logits, label, lse)
+    loss = jnp.mean(lse - picked, axis=-1) if live is None \
+        else jnp.sum((lse - picked) * live, axis=-1) / n
+    return loss, (logits, label, lse)
 
 
-def _seq_ce_bwd(res, g):
+def _seq_ce_bwd(shift, res, g):
     """softmax minus one-hot, straight into the logits' dtype: no float32
     copy of the probabilities is kept between the passes."""
     logits, label, lse = res
-    idx = label.astype(jnp.int32)
-    scale = (g / logits.shape[-2]).astype(_F32)[..., None, None]
+    idx, live, n = _seq_ce_targets(label, shift)
+    scale = (g / n).astype(_F32)[..., None, None]
+    if live is not None:
+        scale = scale * live[..., None]
     p = jnp.exp(logits.astype(_F32) - lse[..., None])
     hot = idx[..., None] == jnp.arange(logits.shape[-1], dtype=jnp.int32)
     d = ((p - hot.astype(_F32)) * scale).astype(logits.dtype)
@@ -473,11 +503,14 @@ def _seq_ce_bwd(res, g):
 _seq_ce.defvjp(_seq_ce_fwd, _seq_ce_bwd)
 
 
-def _sequence_cross_entropy(data, label):
+def _sequence_cross_entropy(data, label, shift=0):
     """Mean over positions of ``-log softmax(data)[label]`` for ``data``
     [batch, seq, vocab] and integer-valued ``label`` [batch, seq]: float32
-    [batch], whatever the logits' dtype."""
-    return _seq_ce(data, lax.stop_gradient(label))
+    [batch], whatever the logits' dtype.  Under ``shift`` position ``i`` is
+    held against ``label[i + shift]`` and the mean is over the ``seq -
+    shift`` positions that have such a target (a head that predicts further
+    ahead, on the same labels)."""
+    return _seq_ce(data, lax.stop_gradient(label), int(shift))
 
 
 def _seq_ce_infer_shape(in_shapes, attrs):
@@ -491,4 +524,5 @@ def _seq_ce_infer_shape(in_shapes, attrs):
 
 register("sequence_cross_entropy", _sequence_cross_entropy,
          input_names=("data", "label"), infer_shape=_seq_ce_infer_shape,
-         infer_type=lambda in_dtypes, attrs: (list(in_dtypes), [np.float32]))
+         infer_type=lambda in_dtypes, attrs: (list(in_dtypes), [np.float32]),
+         params={"shift": (pInt, 0)})

@@ -36,13 +36,21 @@ def _causal_mask(n_q, n_k, window=0):
     return mask
 
 
-def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0):
-    """[B, S, H, D] exact attention — the fallback + test oracle.
+def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0,
+                         k_shared=None):
+    """[B, S, H, D] exact attention — the fallback + test oracle.  ``v``
+    may be narrower or wider than ``q`` and ``k``: the result has its width.
 
     ``kv_lens``: optional (B,) per-sequence valid KV length (the padding
     mask); keys at positions >= the length never receive weight.
     ``window`` (needs ``causal``; 0 = none): a query sees its own position
-    and the ``window - 1`` before it."""
+    and the ``window - 1`` before it.  ``k_shared`` [B, S, d_s]: a part of
+    the key that every head shares, scored against the LAST ``d_s`` columns
+    of ``q``; here it is simply copied to every K/V head."""
+    if k_shared is not None:
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_shared[:, :, None, :].astype(k.dtype),
+            k.shape[:3] + k_shared.shape[-1:])], axis=-1)
     if q.shape[2] != k.shape[2]:
         return _reference_attention_grouped(q, k, v, causal, scale, kv_lens,
                                             window)
@@ -74,7 +82,8 @@ def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None,
         valid = jnp.arange(n_k)[None, :] < kv_lens.astype(jnp.int32)[:, None]
         s = jnp.where(valid[:, None, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, n_q, h, d)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, n_q, h,
+                                                          v.shape[-1])
 
 
 def _last_kv_tile(q_idx, kv_len, block_q, block_k, causal):
@@ -100,9 +109,38 @@ def _first_kv_tile(q_idx, block_q, block_k, window):
     return jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
 
 
-def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+
+
+def _tile_operands(q_ref, k_ref, ks_ref, rows):
+    """(q parts, key parts) of one (q tile, K/V tile) pair, matched: the
+    whole q tile as [rows, d] against the K/V head's key tile and, where
+    ``ks_ref`` holds a key part that all heads share, q's first columns
+    against the head's own keys and its remaining (last) columns against the
+    shared part.  The two widths are each lane-aligned where their sum (192
+    = 128 + 64) is not, so the parts are read as slices of the refs."""
+    if ks_ref is None:
+        return (q_ref[0].reshape(rows, q_ref.shape[-1]),), (k_ref[0],)
+    d_k = k_ref.shape[-1]
+    return ((q_ref[0, :, :, :d_k].reshape(rows, d_k),
+             q_ref[0, :, :, d_k:].reshape(rows, q_ref.shape[-1] - d_k)),
+            (k_ref[0], ks_ref[0]))
+
+
+def _tile_scores(qs, ks, keys_first=False):
+    """The raw float32 scores of :func:`_tile_operands`' parts, [rows, keys]
+    or its transpose: one product a part, summed."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    parts = [jax.lax.dot_general(k, q, _NT, **f32) if keys_first
+             else jax.lax.dot_general(q, k, _NT, **f32)
+             for q, k in zip(qs, ks)]
+    return sum(parts[1:], parts[0])
+
+
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest,
                   causal, scale, block_q, block_k, group, n_kv_blocks,
-                  emit_lse, window=0):
+                  emit_lse, window=0, shared=False):
     """One (q-block, kv-block) grid step.  Grid = (B*KV, n_q, n_kv) with
     the kv dimension innermost; m/l/acc scratch persists across kv steps of
     the same q block (standard flash-attention accumulation).  The q tile
@@ -111,7 +149,11 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     read once for the whole group and both products see that many rows.
     ``len_ref`` is the scalar-prefetched int32 [B*KV] vector of valid KV
     lengths (SMEM): the padding mask, and the bound that makes block-padded
-    sequences exact."""
+    sequences exact.  q and k share the score width, v and the output the
+    value width; ``shared`` adds ``ks_ref``, the key part all heads share
+    (:func:`_tile_scores`)."""
+    ks_ref = rest[0] if shared else None
+    o_ref, *rest = rest[bool(shared):]
     if emit_lse:
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -141,15 +183,12 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0].reshape(rows, q_ref.shape[-1])   # [group*block_q, d]
-        k = k_ref[0]                                  # [block_k, d]
-        v = v_ref[0]
+        v = v_ref[0]                                  # [block_k, d_v]
         # concrete f32 constants: the framework runs with x64 on and the
         # kernel must never see a 64-bit scalar (re-checked on jax 0.9: a
         # weak python float now survives the `_enable_x64(False)` window,
         # the explicit dtype costs nothing)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
+        s = _tile_scores(*_tile_operands(q_ref, k_ref, ks_ref, rows)) \
             * jnp.float32(scale)
 
         # a row's position is q_idx*block_q + (row mod block_q): the mask
@@ -226,21 +265,30 @@ _FLASH_MAX_ROWS = 2048
 _FLASH_MAX_BLOCK_K = 1024
 
 
-def _flash_vmem_bytes(block_q, block_k, d, group, itemsize):
+def _flash_vmem_bytes(block_q, block_k, d, group, itemsize, d_v=None):
     """VMEM one grid step holds, by operand: the double-buffered q, k, v,
     out and log-sum-exp tiles, the three float32 scratch arrays, and the
-    float32 score and probability tiles with the probabilities' cast."""
+    float32 score and probability tiles with the probabilities' cast.  ``d``
+    is the score width as VMEM holds it (:func:`_vmem_width`), ``d_v`` the
+    value width where it differs."""
     rows = group * block_q
-    piped = 2 * (2 * rows * d * itemsize + 2 * block_k * d * itemsize
-                 + rows * 128 * 4)
-    scratch = rows * (128 + 128 + d) * 4
+    d_v = d_v or d
+    piped = 2 * ((rows + block_k) * (d + d_v) * itemsize + rows * 128 * 4)
+    scratch = rows * (128 + 128 + d_v) * 4
     scores = rows * block_k * (4 + 4 + itemsize)
     return piped + scratch + scores
 
 
-def _flash_plan(sq, sk, d, group, itemsize, causal):
+def _vmem_width(d_k, d_shared=0):
+    """Lanes a q or key tile of score width ``d_k + d_shared`` takes in
+    VMEM: each part is padded to whole 128-lane tiles."""
+    return _round_up(d_k, 128) + (_round_up(d_shared, 128) if d_shared else 0)
+
+
+def _flash_plan(sq, sk, d, group, itemsize, causal, d_v=None):
     """(block_q, block_k) for a [sq] x [sk] attention at head size ``d``
-    with ``group`` query heads a K/V head: the fewest equal tiles whose
+    (the score width as VMEM holds it; values ``d_v`` wide where that
+    differs) with ``group`` query heads a K/V head: the fewest equal tiles whose
     step fits ``_FLASH_VMEM_BUDGET``, a q tile of at most
     ``_FLASH_MAX_ROWS`` rows over the whole group and a K/V tile of at most
     ``_FLASH_MAX_BLOCK_K`` keys (under ``causal``, neither side longer than
@@ -271,7 +319,7 @@ def _flash_plan(sq, sk, d, group, itemsize, causal):
         cap_q, cap_k = (min(c, max(512, sk // 4)) for c in (cap_q, cap_k))
     while True:
         bq, bk = block(sq, cap_q, sub), block(sk, cap_k, 128)
-        if _flash_vmem_bytes(bq, bk, d, group, itemsize) \
+        if _flash_vmem_bytes(bq, bk, d, group, itemsize, d_v) \
                 <= _FLASH_VMEM_BUDGET or (cap_q == sub and cap_k == 128):
             return bq, bk
         # halve the longer side of the score tile
@@ -292,10 +340,18 @@ def checked_window(window, causal, sk):
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, use_pallas=None, interpret=None,
-                    kv_lens=None, window=0):
+                    kv_lens=None, window=0, k_shared=None):
     """Blocked flash attention.  q/k/v: [batch, seq, heads, head_dim];
     ``k``/``v`` may hold fewer heads than ``q`` (grouped queries: K/V head
     ``j`` serves query heads ``j*group .. (j+1)*group``).
+
+    Two widths: the SCORE width is q's last dim, which k shares, and the
+    VALUE width is v's, which the result takes; they need not be equal.
+    ``k_shared`` [batch, keys, d_s] is a part of the key that every head
+    shares (latent attention's one rotary key): ``k`` is then ``d_s``
+    narrower than ``q``, whose last ``d_s`` columns are scored against it,
+    and the kernels read it once a tile instead of a copy a head.  ``scale``
+    defaults to ``1 / sqrt(score width)``.
 
     ``kv_lens``: optional (batch,) valid KV lengths — the padding mask.
     Sequences that do not tile evenly are block-padded internally and
@@ -311,26 +367,30 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     :func:`_flash_plan` unless given (the tests give them).
     """
     b, sq, h, d = q.shape
-    sk, kv = k.shape[1], k.shape[2]
+    sk, kv, d_k, d_v = k.shape[1], k.shape[2], k.shape[3], v.shape[3]
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
     if h % kv:
         raise ValueError("flash_attention: %d query heads are no multiple "
                          "of %d K/V heads" % (h, kv))
+    if d_k + d_s != d:
+        raise ValueError("flash_attention: q is %d wide, its keys %d%s"
+                         % (d, d_k, " + %d shared" % d_s if d_s else ""))
     group = h // kv
     window = checked_window(window, causal, sk)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
-        use_pallas = (on_tpu()
-                      and d % 128 == 0        # lane-tiled head dim
-                      and jnp.issubdtype(q.dtype, jnp.floating))
+        use_pallas = on_tpu() and _flash_eligible(d_k, d_v, d_s, q.dtype)
     if not use_pallas:
-        return _reference_attention(q, k, v, causal, scale, kv_lens, window)
+        return _reference_attention(q, k, v, causal, scale, kv_lens, window,
+                                    k_shared)
 
     # tile sizes: the plan's, or the caller's held to the same alignment
     # and never beyond the padded sequence
     itemsize = jnp.dtype(q.dtype).itemsize
     sub = _sublanes(itemsize)
-    bq, bk = _flash_plan(sq, sk, d, group, itemsize, causal)
+    wide = _vmem_width(d_k, d_s)
+    bq, bk = _flash_plan(sq, sk, wide, group, itemsize, causal, d_v)
     if block_q is not None:
         bq = _round_up(min(block_q, sq), sub)
     if block_k is not None:
@@ -339,13 +399,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     # layout: fold heads into batch, [BH, S, D]; pad to block multiples
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * kv, sk, d_k)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * kv, sk, d_v)
+    ksf = k_shared
     if sq_p != sq:
         qf = jnp.pad(qf, ((0, 0), (0, sq_p - sq), (0, 0)))
     if sk_p != sk:
-        kf = jnp.pad(kf, ((0, 0), (0, sk_p - sk), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, sk_p - sk), (0, 0)))
+        pad_keys = lambda x: jnp.pad(x, ((0, 0), (0, sk_p - sk), (0, 0)))
+        kf, vf = pad_keys(kf), pad_keys(vf)
+        ksf = None if ksf is None else pad_keys(ksf)
     # per-K/V-row valid KV length, f32 [B*KV] (f32 so the custom_vjp can
     # hand back an ordinary zero cotangent; the kernel reads it as int32
     # from SMEM).  Block padding and the user's padding mask are the same
@@ -361,39 +423,42 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     # the kernel (measured 680 ms/call untraced vs 0.02 ms cached)
     # the backward's tiles: its own plan's, or the caller's where given
     bwd = (bq, bk) if (block_q, block_k) != (None, None) else \
-        _flash_bwd_plan(sq_p, sk_p, bq, bk, d, group, itemsize, causal)
-    out = _flash_vjp_wrapped(qf, kf, vf, lens,
-                             ((b * kv, group, sq_p, sk_p, d,
+        _flash_bwd_plan(sq_p, sk_p, bq, bk, wide, group, itemsize, causal,
+                        d_v)
+    out = _flash_vjp_wrapped(qf, kf, vf, ksf, lens,
+                             ((b * kv, group, sq_p, sk_p, (d_k, d_v, d_s, kv),
                                str(jnp.dtype(q.dtype)), causal, float(scale),
                                bq, bk, interpret, window), bwd))
-    out = out.reshape(b, h, sq_p, d)[:, :, :sq]
+    out = out.reshape(b, h, sq_p, d_v)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash_vjp_wrapped(qf, kf, vf, lens, meta):
-    """Differentiable flash attention over [BH, S, D] operands: forward is
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash_vjp_wrapped(qf, kf, vf, ksf, lens, meta):
+    """Differentiable flash attention over [BH, S, D] operands (``ksf`` the
+    shared key part [B, S, d_s], or None; ``meta`` holds the widths as (own
+    key, value, shared key, K/V heads a batch row)): forward is
     the Pallas kernel, backward is the standard flash backward from the
     saved row log-sum-exp as two more Pallas kernels (``flash_attn_bwd_dkv``
     and ``flash_attn_bwd_dq``: no S^2 materialization, no tile the mask
     hides).  The undifferentiated primal skips the lse output entirely."""
-    out, _ = _flash_jitted(*meta[0], with_lse=False)(qf, kf, vf, lens)
+    out, _ = _flash_jitted(*meta[0], with_lse=False)(qf, kf, vf, ksf, lens)
     return out
 
 
-def _flash_vjp_fwd(qf, kf, vf, lens, meta):
-    out, lse = _flash_jitted(*meta[0], with_lse=True)(qf, kf, vf, lens)
-    return out, (qf, kf, vf, lens, out, lse[:, :, 0])
+def _flash_vjp_fwd(qf, kf, vf, ksf, lens, meta):
+    out, lse = _flash_jitted(*meta[0], with_lse=True)(qf, kf, vf, ksf, lens)
+    return out, (qf, kf, vf, ksf, lens, out, lse[:, :, 0])
 
 
 def _flash_vjp_bwd(meta, res, d_out):
-    (bkv, group, sq, sk, d, _, causal, scale, _, _, interpret, window), \
+    (bkv, group, sq, sk, widths, _, causal, scale, _, _, interpret, window), \
         (block_q, block_k) = meta
-    qf, kf, vf, lens, out, lse = res
-    fn = _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q,
+    qf, kf, vf, ksf, lens, out, lse = res
+    fn = _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
                            block_k, interpret, window)
-    dq, dk, dv = fn(qf, kf, vf, lens, out, lse, d_out)
-    return dq, dk, dv, jnp.zeros_like(lens)
+    dq, dk, dv, dks = fn(qf, kf, vf, ksf, lens, out, lse, d_out)
+    return dq, dk, dv, dks, jnp.zeros_like(lens)
 
 
 _flash_vjp_wrapped.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -439,13 +504,9 @@ def _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
     return jnp.where(valid, jnp.float32(0.0), jnp.float32(_NEG_INF))
 
 
-_NT = (((1,), (1,)), ((), ()))     # a @ b.T
-_NN = (((1,), (0,)), ((), ()))     # a @ b
-
-
 def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         dd_ref, dq_ref, acc_ref, lse_col, dd_col, *, causal,
-                         scale, block_q, block_k, group, n_kv_blocks, window):
+                         dd_ref, *rest, causal, scale, block_q, block_k, group,
+                         n_kv_blocks, window, shared=False):
     """dq of one q tile: grid (B*KV, n_q, n_kv), the K/V tiles innermost
     and walked as the forward walks them (``_first_kv_tile`` ..
     ``_last_kv_tile``; the others neither computed nor fetched); dq
@@ -454,9 +515,12 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     keys on the lanes as in the forward.  ``lse`` and D = rowsum(dO * O)
     come as compact [1, rows] vectors and are turned once a q tile into
     the lane-broadcast [rows, 128] columns the forward keeps its running
-    stats in."""
+    stats in.  Under ``shared`` dq's last columns come from the shared key
+    part, as the scores' second product did."""
     from jax.experimental import pallas as pl
 
+    ks_ref = rest[0] if shared else None
+    dq_ref, acc_ref, lse_col, dd_col = rest[bool(shared):]
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
     rows = group * block_q
@@ -477,19 +541,23 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        qs, keys = _tile_operands(q_ref, k_ref, ks_ref, rows)
         do = do_ref[0].reshape(rows, do_ref.shape[-1])
-        k, v = k_ref[0], v_ref[0]
+        v = v_ref[0]
         f32 = dict(preferred_element_type=jnp.float32)
-        s = jax.lax.dot_general(q, k, _NT, **f32) * jnp.float32(scale) \
-            - lse_col[:][:, :1]
+        s = _tile_scores(qs, keys) * jnp.float32(scale) - lse_col[:][:, :1]
         bias = _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k,
                                causal, window, keys_first=False)
         p = jnp.exp((s.reshape(group, block_q, block_k) + bias[None])
                     .reshape(rows, block_k))
         dp = jax.lax.dot_general(do, v, _NT, **f32)
-        ds = p * (dp - dd_col[:][:, :1])
-        acc_ref[:] += jax.lax.dot_general(ds.astype(k.dtype), k, _NN, **f32)
+        ds = (p * (dp - dd_col[:][:, :1])).astype(v.dtype)
+        if shared:
+            d_k = k_ref.shape[-1]
+            acc_ref[:, :d_k] += jax.lax.dot_general(ds, keys[0], _NN, **f32)
+            acc_ref[:, d_k:] += jax.lax.dot_general(ds, keys[1], _NN, **f32)
+        else:
+            acc_ref[:] += jax.lax.dot_general(ds, keys[0], _NN, **f32)
 
     @pl.when(kv_idx == n_kv_blocks - 1)
     def _finalize():
@@ -498,8 +566,8 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                          dd_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                          scale, block_q, block_k, group, n_q_blocks, window):
+                          dd_ref, *rest, causal, scale, block_q, block_k,
+                          group, n_q_blocks, window, shared=False):
     """dk and dv of one K/V tile: grid (B*KV, n_kv, n_q), the q tiles
     innermost and walked from ``_first_q_tile`` to ``_last_q_tile`` (the
     others neither computed nor fetched); dk and dv accumulate in float32
@@ -507,9 +575,16 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     TRANSPOSED, keys on the sublanes and the ``group * block_q`` rows of
     the q tile on the lanes: ``p.T @ dO`` and ``ds.T @ q`` are then plain
     products that sum over the group inside the MXU, and ``lse`` and D come
-    as compact [1, rows] vectors that broadcast down the sublanes."""
+    as compact [1, rows] vectors that broadcast down the sublanes.  Under
+    ``shared`` a third result, this K/V head's part of the shared key's
+    gradient in float32: the caller sums the heads' parts."""
     from jax.experimental import pallas as pl
 
+    if shared:
+        ks_ref, dk_ref, dv_ref, dks_ref, dk_acc, dv_acc, dks_acc = rest
+    else:
+        ks_ref = dks_ref = dks_acc = None
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     q_idx = pl.program_id(2)
     kv_idx = pl.program_id(1)
     rows = group * block_q
@@ -518,6 +593,8 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if shared:
+            dks_acc[:] = jnp.zeros_like(dks_acc)
 
     kv_len = len_ref[pl.program_id(0)]
     needed = (kv_idx * block_k < kv_len) \
@@ -527,25 +604,29 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        qs, keys = _tile_operands(q_ref, k_ref, ks_ref, rows)
         do = do_ref[0].reshape(rows, do_ref.shape[-1])
-        k, v = k_ref[0], v_ref[0]
+        v = v_ref[0]
         f32 = dict(preferred_element_type=jnp.float32)
         # one [block_k, block_q] tile, repeated along the lanes for each head
         bias = jnp.concatenate([_flash_bwd_bias(
             q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
             keys_first=True)] * group, axis=1)
-        p = jnp.exp(jax.lax.dot_general(k, q, _NT, **f32)
+        p = jnp.exp(_tile_scores(qs, keys, keys_first=True)
                     * jnp.float32(scale) - lse_ref[0, 0] + bias)
         dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do, _NN, **f32)
         dp = jax.lax.dot_general(v, do, _NT, **f32)
-        ds = p * (dp - dd_ref[0, 0])
-        dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q, _NN, **f32)
+        ds = (p * (dp - dd_ref[0, 0])).astype(v.dtype)
+        dk_acc[:] += jax.lax.dot_general(ds, qs[0], _NN, **f32)
+        if shared:
+            dks_acc[:] += jax.lax.dot_general(ds, qs[1], _NN, **f32)
 
     @pl.when(q_idx == n_q_blocks - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * jnp.float32(scale)).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        if shared:
+            dks_ref[0] = dks_acc[:] * jnp.float32(scale)
 
 
 # What one grid step of a backward kernel may hold in VMEM by the plan's own
@@ -563,23 +644,26 @@ _FLASH_BWD_MAX_ROWS = 4096
 _FLASH_BWD_MAX_BLOCK_K = 512
 
 
-def _flash_bwd_vmem_bytes(block_q, block_k, d, group, itemsize):
+def _flash_bwd_vmem_bytes(block_q, block_k, d, group, itemsize, d_v=None):
     """VMEM one grid step of the backward holds, the larger of its two
     kernels by operand: the double-buffered q, dO, K and V tiles and the
     compact ``lse`` and D rows (a [1, rows] block takes eight sublanes),
     the double-buffered results and their float32 scratch (dq's with the
     two lane-broadcast columns), and the float32 score, ``dp`` and ``ds``
-    tiles with the casts of ``p`` and ``ds``."""
+    tiles with the casts of ``p`` and ``ds``.  ``d`` and ``d_v`` as
+    :func:`_flash_vmem_bytes` takes them."""
     rows = group * block_q
-    piped = 2 * (2 * rows * d + 2 * block_k * d) * itemsize \
+    d_v = d_v or d
+    piped = 2 * (rows + block_k) * (d + d_v) * itemsize \
         + 2 * 2 * 8 * rows * 4
     scores = rows * block_k * (3 * 4 + 2 * itemsize)
     dq = 2 * rows * d * itemsize + rows * (d + 2 * 128) * 4
-    dkv = 2 * 2 * block_k * d * itemsize + 2 * block_k * d * 4
+    dkv = block_k * (d + d_v) * (2 * itemsize + 4)
     return piped + max(dq, dkv) + scores
 
 
-def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal):
+def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal,
+                    d_v=None):
     """(block_q, block_k) of the backward's two kernels for operands padded
     to ``sq`` x ``sk`` by the forward's tiles ``block_q`` x ``block_k``,
     which the backward's must divide or multiply: the K/V tile is the
@@ -596,7 +680,7 @@ def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal):
     while bk > _FLASH_BWD_MAX_BLOCK_K and bk % 256 == 0:
         bk //= 2
     fits = lambda bq: _flash_bwd_vmem_bytes(  # noqa: E731
-        bq, bk, d, group, itemsize) <= _FLASH_BWD_VMEM_BUDGET
+        bq, bk, d, group, itemsize, d_v) <= _FLASH_BWD_VMEM_BUDGET
     n = sq // block_q
     cap = _FLASH_BWD_MAX_ROWS // group
     if causal:
@@ -610,14 +694,18 @@ def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal):
 
 
 @functools.lru_cache(maxsize=512)
-def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
-                      interpret, window=0):
+def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
+                      block_k, interpret, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    d_k, d_v, d_s, kv_heads = widths
+    d = d_k + d_s
+    shared = bool(d_s)
     n_q, n_kv = sq // block_q, sk // block_k
     rows = group * block_q
     static = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k, group=group, window=window)
+                  block_k=block_k, group=group, window=window,
+                  shared=shared)
     extra = {"interpret": interpret} if interpret is not None else {}
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -645,8 +733,13 @@ def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
     q_of_kv = lambda g, ki, qi, lens: (g, 0, q_tile(g, ki, qi, lens), 0)  # noqa: E731
     row_of_kv = lambda g, ki, qi, lens: (g, q_tile(g, ki, qi, lens), 0, 0)  # noqa: E731
     kv_of_kv = lambda g, ki, qi, lens: (g, ki, 0)  # noqa: E731
+    # the shared key part has one row a batch row: every K/V head of it
+    # reads the same tile
+    shared_of_q = lambda g, qi, ki, lens: (  # noqa: E731
+        g // kv_heads,) + kv_of_q(g, qi, ki, lens)[1:]
+    shared_of_kv = lambda g, ki, qi, lens: (g // kv_heads, ki, 0)  # noqa: E731
 
-    def run(qf, kf, vf, lens, out, lse, d_out):
+    def run(qf, kf, vf, ksf, lens, out, lse, d_out):
         with _enable_x64(False):
             # D_i = rowsum(dO_i * O_i), in f32: it enters ds by cancellation
             # against dp, so bf16 rounding here would amplify
@@ -664,9 +757,11 @@ def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
             lse, dd = row(lse), row(dd)
             qg, dog = grouped(qf), grouped(d_out)
             lens = lens.astype(jnp.int32)
-            q_block = (1, group, block_q, d)
-            kv_block = (1, block_k, d)
+            q_block, do_block = ((1, group, block_q, w) for w in (d, d_v))
+            k_block, v_block, ks_block = ((1, block_k, w)
+                                          for w in (d_k, d_v, d_s))
             row_block = (1, 1, 1, rows)
+            more = (ksf,) if shared else ()
             dq = pl.pallas_call(
                 functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=n_kv,
                                   **static),
@@ -675,20 +770,20 @@ def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
                     grid=(bkv, n_q, n_kv),
                     in_specs=[
                         pl.BlockSpec(q_block, q_of_q),
-                        pl.BlockSpec(kv_block, kv_of_q),
-                        pl.BlockSpec(kv_block, kv_of_q),
-                        pl.BlockSpec(q_block, q_of_q),
+                        pl.BlockSpec(k_block, kv_of_q),
+                        pl.BlockSpec(v_block, kv_of_q),
+                        pl.BlockSpec(do_block, q_of_q),
                         pl.BlockSpec(row_block, row_of_q),
                         pl.BlockSpec(row_block, row_of_q),
-                    ],
+                    ] + [pl.BlockSpec(ks_block, shared_of_q)] * shared,
                     out_specs=pl.BlockSpec(q_block, q_of_q),
                     scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
                                     pltpu.VMEM((rows, 128), jnp.float32),
                                     pltpu.VMEM((rows, 128), jnp.float32)]),
                 out_shape=jax.ShapeDtypeStruct(qg.shape, qf.dtype),
                 compiler_params=params, name="flash_attn_bwd_dq", **extra,
-            )(lens, qg, kf, vf, dog, lse, dd)
-            dk, dv = pl.pallas_call(
+            )(lens, qg, kf, vf, dog, lse, dd, *more)
+            dk, dv, *dks = pl.pallas_call(
                 functools.partial(_flash_bwd_dkv_kernel, n_q_blocks=n_q,
                                   **static),
                 grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -696,34 +791,42 @@ def _flash_bwd_jitted(bkv, group, sq, sk, d, causal, scale, block_q, block_k,
                     grid=(bkv, n_kv, n_q),
                     in_specs=[
                         pl.BlockSpec(q_block, q_of_kv),
-                        pl.BlockSpec(kv_block, kv_of_kv),
-                        pl.BlockSpec(kv_block, kv_of_kv),
-                        pl.BlockSpec(q_block, q_of_kv),
+                        pl.BlockSpec(k_block, kv_of_kv),
+                        pl.BlockSpec(v_block, kv_of_kv),
+                        pl.BlockSpec(do_block, q_of_kv),
                         pl.BlockSpec(row_block, row_of_kv),
                         pl.BlockSpec(row_block, row_of_kv),
-                    ],
-                    out_specs=[pl.BlockSpec(kv_block, kv_of_kv),
-                               pl.BlockSpec(kv_block, kv_of_kv)],
-                    scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                                    pltpu.VMEM((block_k, d), jnp.float32)]),
+                    ] + [pl.BlockSpec(ks_block, shared_of_kv)] * shared,
+                    out_specs=[pl.BlockSpec(k_block, kv_of_kv),
+                               pl.BlockSpec(v_block, kv_of_kv)]
+                    + [pl.BlockSpec(ks_block, kv_of_kv)] * shared,
+                    scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32)
+                                    for w in (d_k, d_v) + (d_s,) * shared]),
                 out_shape=[jax.ShapeDtypeStruct(kf.shape, kf.dtype),
-                           jax.ShapeDtypeStruct(vf.shape, vf.dtype)],
+                           jax.ShapeDtypeStruct(vf.shape, vf.dtype)]
+                + [jax.ShapeDtypeStruct((bkv, sk, d_s), jnp.float32)] * shared,
                 compiler_params=params, name="flash_attn_bwd_dkv", **extra,
-            )(lens, qg, kf, vf, dog, lse, dd)
-            return dq.reshape(qf.shape), dk, dv
+            )(lens, qg, kf, vf, dog, lse, dd, *more)
+            if shared:
+                # the K/V heads' parts of the shared key's gradient, summed
+                dks = dks[0].reshape(-1, kv_heads, sk, d_s).sum(axis=1).astype(
+                    ksf.dtype)
+            return dq.reshape(qf.shape), dk, dv, dks if shared else None
 
     return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=512)
-def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
+def _flash_jitted(bkv, group, sq, sk, widths, dtype, causal, scale, block_q,
                   block_k, interpret, window=0, with_lse=False):
+    d_k, d_v, d_s, kv_heads = widths
+    d = d_k + d_s
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, group=group, n_kv_blocks=sk // block_k,
-        emit_lse=with_lse, window=window)
+        emit_lse=with_lse, window=window, shared=bool(d_s))
 
-    def run(qf, kf, vf, lens):
+    def run(qf, kf, vf, ksf, lens):
         # the framework enables jax x64 globally (float64 NDArray API
         # parity); Mosaic rejects 64-bit types, so trace under 32-bit rules
         with _enable_x64(False):
@@ -731,18 +834,21 @@ def _flash_jitted(bkv, group, sq, sk, d, dtype, causal, scale, block_q,
             # a view of [B*H, S, D], and so are the results' way back
             out, lse = _call_flash(
                 kernel, qf.reshape(bkv, group, sq, d), kf, vf, lens,
-                block_q, block_k, causal, interpret, with_lse, window)
-            return (out.reshape(bkv * group, sq, d),
+                block_q, block_k, causal, interpret, with_lse, window,
+                ksf, kv_heads)
+            return (out.reshape(bkv * group, sq, d_v),
                     lse.reshape(bkv * group, sq, 128) if with_lse else None)
 
     return jax.jit(run)
 
 
 def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
-                interpret, with_lse, window=0):
+                interpret, with_lse, window=0, ksf=None, kv_heads=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bkv, group, sq, d = qg.shape
+    d_k, d_v = kf.shape[-1], vf.shape[-1]
+    shared = ksf is not None
     n_q, n_kv = sq // block_q, kf.shape[1] // block_k
     # index maps see the scalar-prefetch ref as a trailing argument
     q_map = lambda g, qi, ki, lens: (g, 0, qi, 0)  # noqa: E731
@@ -758,8 +864,12 @@ def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
                 _first_kv_tile(qi, block_q, block_k, window), last))
         return (g, tile, 0)
 
-    out_specs = [pl.BlockSpec((1, group, block_q, d), q_map)]
-    out_shape = [jax.ShapeDtypeStruct(qg.shape, qg.dtype)]
+    # the shared key part has one row a batch row: every K/V head of it
+    # reads the same tile
+    shared_map = lambda g, qi, ki, lens: (  # noqa: E731
+        g // kv_heads,) + kv_map(g, qi, ki, lens)[1:]
+    out_specs = [pl.BlockSpec((1, group, block_q, d_v), q_map)]
+    out_shape = [jax.ShapeDtypeStruct(qg.shape[:3] + (d_v,), qg.dtype)]
     if with_lse:
         out_specs.append(pl.BlockSpec((1, group, block_q, 128), q_map))
         out_shape.append(
@@ -772,14 +882,14 @@ def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
             grid=(bkv, n_q, n_kv),
             in_specs=[
                 pl.BlockSpec((1, group, block_q, d), q_map),
-                pl.BlockSpec((1, block_k, d), kv_map),
-                pl.BlockSpec((1, block_k, d), kv_map),
-            ],
+                pl.BlockSpec((1, block_k, d_k), kv_map),
+                pl.BlockSpec((1, block_k, d_v), kv_map),
+            ] + [pl.BlockSpec((1, block_k, d - d_k), shared_map)] * shared,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((rows, 128), jnp.float32),
                 pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
+                pltpu.VMEM((rows, d_v), jnp.float32),
             ]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -787,7 +897,7 @@ def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
             vmem_limit_bytes=_FLASH_VMEM_LIMIT),
         name="flash_attn_fwd",
         **({"interpret": interpret} if interpret is not None else {}),
-    )(lens.astype(jnp.int32), qg, kf, vf)
+    )(lens.astype(jnp.int32), qg, kf, vf, *((ksf,) if shared else ()))
     return res if with_lse else (res[0], None)
 
 
@@ -872,52 +982,67 @@ def kernel_signature(platform=None):
     return tuple((k, kernel_mode(k, platform)) for k in sorted(_KERNEL_ENV))
 
 
-def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0):
+def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0,
+              k_shared=None):
     """Trace-time attention dispatch for the ``attn`` kernel family.
 
     q/k/v: [batch, seq, heads, head_dim]; ``window`` (needs ``causal``; 0 =
-    none) as :func:`flash_attention` takes it.  Resolves
+    none), the two widths and ``k_shared`` as :func:`flash_attention` takes
+    them.  Resolves
     ``kernel_mode('attn')`` at TRACE time (the executor cache keys on the
     same resolution): ``off`` returns the plain XLA reference — no
     custom_vjp, so the off-path program is bit-identical to one that
     never knew the kernel — while ``pallas``/``interpret`` route through
-    the flash kernel when the shape is eligible (lane-tiled head dim,
+    the flash kernel when the shape is eligible (lane-tiled widths,
     floating dtype) and fall back to the reference otherwise.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     window = checked_window(window, causal, k.shape[1])
-    mode = _flash_mode(q.shape[-1], q.dtype)
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
+    mode = _flash_mode(k.shape[-1], q.dtype, v.shape[-1], d_s)
     if mode is None:
         return _reference_attention(q, k, v, causal, float(scale), kv_lens,
-                                    window)
+                                    window, k_shared)
     return flash_attention(q, k, v, causal=causal, scale=float(scale),
                            use_pallas=True,
                            interpret=(mode == "interpret") or None,
-                           kv_lens=kv_lens, window=window)
+                           kv_lens=kv_lens, window=window, k_shared=k_shared)
 
 
-def _flash_mode(head_dim, dtype):
+def _flash_eligible(d_k, d_v, d_shared, dtype):
+    """Whether the flash kernels take these widths and element type: the
+    heads' own key width and the value width whole 128-lane tiles, a shared
+    key part whole or half ones."""
+    return d_k % 128 == 0 and d_v % 128 == 0 and d_shared % 64 == 0 \
+        and jnp.issubdtype(dtype, jnp.floating)
+
+
+def _flash_mode(d_k, dtype, d_v=None, d_shared=0):
     """The resolved ``attn`` mode where :func:`attention` takes the flash
-    kernel for this head size and element type, else None (the XLA
-    reference)."""
+    kernel for these widths (the heads' own keys', the values', the shared
+    key part's) and element type, else None (the XLA reference)."""
     mode = kernel_mode("attn")
-    eligible = head_dim % 128 == 0 and jnp.issubdtype(dtype, jnp.floating)
+    eligible = _flash_eligible(d_k, d_v or d_k, d_shared, dtype)
     return mode if mode != "off" and eligible else None
 
 
-def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
+def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0,
+                    v_width=None, shared_width=0):
     """What one :func:`attention` node of these shapes is built to do, as
     (computed, visible) counts of (query, key) pairs per head: the pairs
     whose score its forward and its backward compute, masked or not (the
     needed tiles of the forward and of EACH of the backward's two kernels,
     which both score the pairs of their tiles: a backward pair counts
     twice; the XLA reference computes every pair both ways), and the pairs
-    the mask lets through, once each way.  Static: shapes, the tile plans
+    the mask lets through, once each way.  ``k_shape``'s width is the heads'
+    own keys' (``shared_width`` less than q's where a key part is shared),
+    ``v_width`` the values' where it differs.  Static: shapes, the tile plans
     and the kernel mode of the enclosing :func:`trace_scope`; lengths
     (``kv_lens``) are not known here."""
-    b, sq, _, d = (int(x) for x in q_shape)
-    sk, kv = int(k_shape[1]), int(k_shape[2])
+    b, sq = int(q_shape[0]), int(q_shape[1])
+    sk, kv, d_k = (int(x) for x in k_shape[1:])
+    d, d_v = _vmem_width(d_k, shared_width), int(v_width or d_k)
     group = int(q_shape[2]) // kv
     window = checked_window(window, causal, sk)
     if causal:
@@ -926,10 +1051,10 @@ def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
                       for i in range(sq))
     else:
         visible = sq * sk
-    if _flash_mode(d, dtype) is None:
+    if _flash_mode(d_k, dtype, d_v, shared_width) is None:
         return 2 * b * sq * sk, 2 * b * visible
     itemsize = jnp.dtype(dtype).itemsize
-    bq, bk = _flash_plan(sq, sk, d, group, itemsize, causal)
+    bq, bk = _flash_plan(sq, sk, d, group, itemsize, causal, d_v)
     sq_p = _round_up(sq, bq)
 
     def scored(bq, bk):
@@ -939,7 +1064,7 @@ def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0):
         return bq * bk * tiles
 
     backward = scored(*_flash_bwd_plan(sq_p, _round_up(sk, bk), bq, bk, d,
-                                       group, itemsize, causal))
+                                       group, itemsize, causal, d_v))
     return b * (scored(bq, bk) + 2 * backward), 2 * b * visible
 
 

@@ -77,6 +77,15 @@ FLASH_CASES = [
     ((1, 8192, 32, 128), "bfloat16", True, 4, 2048),
     ((1, 8192, 32, 128), "bfloat16", True, 4),
 ]
+# score width != value width: (q shape, dtype, K/V heads, the heads' own key
+# width, the value width); what is left of q's width is a key part that all
+# heads share.  A small one, a plain wide-key one, and the latent attention of
+# `joyai-flash-train-s8k-b1`: 32 heads of 192 = 128 + 64 shared on values of 128
+FLASH_TWO_WIDTH_CASES = [
+    ((1, 256, 4, 192), "float32", 2, 128, 128),
+    ((1, 512, 4, 256), "bfloat16", 2, 256, 128),
+    ((1, 8192, 32, 192), "bfloat16", 32, 128, 128),
+]
 
 
 def pool_configs():
@@ -131,6 +140,22 @@ def _cases():
         # the padding-mask operand (scalar prefetch)
         out.append(("flash-lens-" + tag, fwd,
                     (x, kv, kv, _aval(shape[:1], "int32"))))
+    for shape, dtype, kv_heads, d_k, d_v in FLASH_TWO_WIDTH_CASES:
+        avals = [_aval(shape, dtype)] + [
+            _aval(shape[:2] + (kv_heads, w), dtype) for w in (d_k, d_v)]
+        if shape[3] > d_k:
+            avals.append(_aval(shape[:2] + (shape[3] - d_k,), dtype))
+
+        def fwd(q, k, v, ks=None):
+            return pk.flash_attention(q, k, v, causal=True, use_pallas=True,
+                                      k_shared=ks)
+
+        tag = "%skv%d-k%d-v%d-%s" % (shape, kv_heads, d_k, d_v, dtype)
+        out.append(("flash-fwd-" + tag, fwd, tuple(avals)))
+        out.append(("flash-grad-" + tag,
+                    jax.grad(lambda *a, fwd=fwd: jnp.sum(
+                        fwd(*a).astype(jnp.float32) ** 2),
+                        argnums=tuple(range(len(avals)))), tuple(avals)))
     return out
 
 
