@@ -125,7 +125,7 @@ class FusedTrainStep:
         self.exe = module._exec_group.execs[0]
         self.opt = module._optimizer
         self.ran = False
-        self._attn_pairs = None
+        self._schedule = None
         # input name -> (the batch's array, its upload): see ``stage``
         self._staged = {}
         exe = self.exe
@@ -705,23 +705,29 @@ class FusedTrainStep:
                 raise
             ph.watch(res[0][:1] or res[1][:1], uploads)
         _instrument.note_recompute_blocks(self.prog.mirror_stages)
-        _instrument.note_attention_pairs(*self._attention_pairs())
+        pairs, chunk_steps = self._schedule_counts()
+        _instrument.note_attention_pairs(*pairs)
+        _instrument.note_gdn_chunk_steps(*chunk_steps)
         return res
 
-    def _attention_pairs(self):
-        """The step program's attention schedules as (computed, visible)
-        pairs, worked out once from the shapes bound to device 0's
-        executor (its share of the batch, so times the devices)."""
-        if self._attn_pairs is None:
+    def _schedule_counts(self):
+        """What the step program's kernels are scheduled to do, static per
+        program: its attention as (computed, visible) pairs and its delta-rule
+        scans as (chunk steps, those in the Pallas kernels), worked out once
+        from the shapes bound to device 0's executor (its share of the batch,
+        so times the devices)."""
+        if self._schedule is None:
             args = self.exe.arg_dict
+            bound = ({n: a.shape for n, a in args.items()},
+                     {n: a._h.array.dtype for n, a in args.items()})
             with _pallas_kernels.trace_scope(
                     platform=self.devices[0].platform,
                     partitioned=self._mesh is not None):
-                pairs = self.prog.attention_pairs(
-                    {n: a.shape for n, a in args.items()},
-                    {n: a._h.array.dtype for n, a in args.items()})
-            self._attn_pairs = tuple(self.n_dev * p for p in pairs)
-        return self._attn_pairs
+                counts = (self.prog.attention_pairs(*bound),
+                          self.prog.gdn_chunk_steps(*bound))
+            self._schedule = tuple(tuple(self.n_dev * x for x in c)
+                                   for c in counts)
+        return self._schedule
 
     def _keep(self, res):
         """Take the step's results as the next step's state; returns

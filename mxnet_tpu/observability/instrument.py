@@ -837,6 +837,25 @@ def note_attention_pairs(computed, visible):
                  "once each way").inc(visible)
 
 
+def note_gdn_chunk_steps(steps, in_kernel):
+    """``module.gdn.chunk_steps`` / ``module.gdn.chunk_steps_in_kernel``: the
+    chunk steps (chunks x batch x key heads x passes: forward, forward again
+    where the block is recomputed, backward) of the ``gated_delta_rule``
+    nodes of the step program just dispatched, and those of them that run
+    inside the Pallas kernels ``gdn_scan_fwd`` / ``gdn_scan_bwd`` and not as
+    bodies of a ``lax.scan`` (``_Program.gdn_chunk_steps``: static per
+    program; 0 on the fallback)."""
+    if steps:
+        telemetry.counter(
+            "module.gdn.chunk_steps",
+            help="chunk steps of the step programs' delta-rule scans, "
+                 "forward, recomputed and backward").inc(steps)
+        telemetry.counter(
+            "module.gdn.chunk_steps_in_kernel",
+            help="chunk steps that run inside the Pallas scan "
+                 "kernels").inc(in_kernel)
+
+
 def note_counter_rows(rows, names):
     """One step's value of the counters a model's output names
     (``__counters__``, read where the loss is read): row ``i`` of ``rows``,

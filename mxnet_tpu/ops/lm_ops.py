@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import gdn_kernels
 from .registry import register, pBool, pFloat, pInt, pStr
 
 _F32 = jnp.float32
@@ -229,12 +230,18 @@ def _split(x, axis, n):
                      + x.shape[axis + 1:])
 
 
+def _chunks(q, k, v, g, beta, chunk):
+    """The operands cut into chunks: q, k [b, hk, n, c, dk]; v, g, beta [b,
+    hk, r, n, c, ...]."""
+    n = int(q.shape[2]) // chunk
+    return (_split(q, 2, n), _split(k, 2, n), _split(v, 3, n),
+            _split(g, 3, n), _split(beta, 3, n))
+
+
 def _scan_inputs(q, k, v, g, beta, chunk):
     """(per-chunk operands with the chunk axis leading, the vjp of the
     chunk-local part)."""
-    n = int(q.shape[2]) // chunk
-    args = (_split(q, 2, n), _split(k, 2, n), _split(v, 3, n),
-            _split(g, 3, n), _split(beta, 3, n))
+    args = _chunks(q, k, v, g, beta, chunk)
     local, pull = jax.vjp(_chunk_local, *args)
     lead = lambda x, axis: jnp.moveaxis(x, axis, 0)
     xs = (lead(args[0], 2), lead(args[1], 2)) \
@@ -242,11 +249,18 @@ def _scan_inputs(q, k, v, g, beta, chunk):
     return xs, pull
 
 
-def _gdr_forward(q, k, v, g, beta, chunk):
+def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
     """q, k: [b, hk, t, dk]; v: [b, hk, r, t, dv]; g, beta: [b, hk, r, t];
     t a multiple of ``chunk``.  Returns (outputs [b, hk, r, t, dv], the
-    state each chunk starts from [n, b, hk, r, dk, dv], kept in the
-    operands' dtype)."""
+    state each chunk starts from, kept in the operands' dtype: [n, b, hk, r,
+    dk, dv], or [b, hk, n, r, dk, dv] from the ``kernel``, which writes them
+    only where ``keep_states``; XLA drops the scan's by itself)."""
+    if kernel:
+        args = _chunks(q, k, v, g, beta, chunk)
+        outs, states = gdn_kernels.scan_fwd(
+            args[0], args[1], _chunk_local(*args), keep_states,
+            interpret=(kernel == "interpret") or None)
+        return outs.reshape(v.shape), states
     xs, _ = _scan_inputs(q, k, v, g, beta, chunk)
 
     def body(state, x):
@@ -260,13 +274,20 @@ def _gdr_forward(q, k, v, g, beta, chunk):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_gdr(chunk):
+def _make_gdr(chunk, kernel=None):
+    """The differentiable recurrence at one ``chunk``.  ``kernel``: None for
+    the ``lax.scan`` over chunks (the CPU path, the fallback, the tests'
+    oracle), ``"pallas"`` for ``ops/gdn_kernels.py``'s two kernels in its
+    place (``"interpret"``: the same through the Pallas interpreter, the
+    tests'); the chunk-local part and its pull-back are the same code either
+    way."""
     @jax.custom_vjp
     def gdr(q, k, v, g, beta):
-        return _gdr_forward(q, k, v, g, beta, chunk)[0]
+        return _gdr_forward(q, k, v, g, beta, chunk, kernel,
+                            keep_states=False)[0]
 
     def fwd(q, k, v, g, beta):
-        out, states = _gdr_forward(q, k, v, g, beta, chunk)
+        out, states = _gdr_forward(q, k, v, g, beta, chunk, kernel)
         return out, (q, k, v, g, beta, states)
 
     def bwd(res, d_out):
@@ -275,19 +296,29 @@ def _make_gdr(chunk):
         started from, carrying the state's cotangent."""
         q, k, v, g, beta, states = res
         n = int(q.shape[2]) // chunk
-        xs, pull = _scan_inputs(q, k, v, g, beta, chunk)
-        d_outs = jnp.moveaxis(_split(d_out, 3, n), 3, 0)
+        if kernel:
+            args = _chunks(q, k, v, g, beta, chunk)
+            local, pull = jax.vjp(_chunk_local, *args)
+            d_q, d_k, d_local = gdn_kernels.scan_bwd(
+                args[0], args[1], local, states, _split(d_out, 3, n),
+                interpret=(kernel == "interpret") or None)
+            grads = list(pull(d_local))
+        else:
+            xs, pull = _scan_inputs(q, k, v, g, beta, chunk)
+            d_outs = jnp.moveaxis(_split(d_out, 3, n), 3, 0)
 
-        def body(d_state, item):
-            state, x, d_o = item
-            _, step_vjp = jax.vjp(_chunk_step, state.astype(_F32), x)
-            d_prev, d_x = step_vjp((d_state, d_o))
-            return d_prev, d_x
+            def body(d_state, item):
+                state, x, d_o = item
+                _, step_vjp = jax.vjp(_chunk_step, state.astype(_F32), x)
+                d_prev, d_x = step_vjp((d_state, d_o))
+                return d_prev, d_x
 
-        zero = jnp.zeros(states.shape[1:], _F32)
-        _, d_xs = lax.scan(body, zero, (states, xs, d_outs), reverse=True)
-        d_q, d_k = (jnp.moveaxis(x, 0, 2) for x in d_xs[:2])
-        grads = list(pull(tuple(jnp.moveaxis(x, 0, 3) for x in d_xs[2:])))
+            zero = jnp.zeros(states.shape[1:], _F32)
+            _, d_xs = lax.scan(body, zero, (states, xs, d_outs),
+                               reverse=True)
+            d_q, d_k = (jnp.moveaxis(x, 0, 2) for x in d_xs[:2])
+            grads = list(pull(tuple(jnp.moveaxis(x, 0, 3)
+                                    for x in d_xs[2:])))
         grads[0], grads[1] = grads[0] + d_q, grads[1] + d_k
         return tuple(x.reshape(r.shape) for x, r in
                      zip(grads, (q, k, v, g, beta)))
@@ -301,7 +332,10 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
     key heads, r, seq, dv] (``r`` value heads share a key head) and float32
     g (the log of the decay), beta [batch, key heads, r, seq], computed
     chunk by chunk; any ``seq``: a tail chunk is padded with tokens that
-    leave the state as it is (beta 0, decay 1)."""
+    leave the state as it is (beta 0, decay 1).  The recurrence over the
+    chunks is ``ops/gdn_kernels.py``'s Pallas kernels where the program is
+    traced for a TPU and the shape is eligible (``gdn_kernels.mode``: no
+    knob), else a ``lax.scan``."""
     t = int(q.shape[2])
     pad = (-t) % chunk
     if pad:
@@ -309,7 +343,8 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
             x, [(0, pad if i == axis else 0) for i in range(x.ndim)])
         q, k, v, g, beta = (at(q, 2), at(k, 2), at(v, 3), at(g, 3),
                             at(beta, 3))
-    return _make_gdr(chunk)(q, k, v, g, beta)[:, :, :, :t]
+    kernel = gdn_kernels.mode(q.shape, v.shape, chunk, v.dtype)
+    return _make_gdr(chunk, kernel)(q, k, v, g, beta)[:, :, :, :t]
 
 
 def _gated_delta_rule(query, key, value, a, b, A_log, dt_bias, chunk=64):

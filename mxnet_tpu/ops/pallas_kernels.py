@@ -965,13 +965,25 @@ def kernel_mode(kind, platform=None):
     if val in ("0", "off", "false"):
         return "off"
     explicit = val in ("1", "on", "true", "interpret")
-    platform = platform or getattr(_trace_scope, "platform", None)
-    for_tpu = platform == "tpu" if platform else on_tpu()
-    if for_tpu:
+    if _traced_for_tpu(platform):
         if getattr(_trace_scope, "partitioned", False) and not explicit:
             return "off"
         return "pallas"
     return "interpret" if explicit else "off"
+
+
+def _traced_for_tpu(platform=None):
+    platform = platform or getattr(_trace_scope, "platform", None)
+    return platform == "tpu" if platform else on_tpu()
+
+
+def traced_for_unpartitioned_tpu():
+    """Whether the program being traced runs on a TPU (the enclosing
+    :func:`trace_scope`'s platform, else the process default backend) and
+    XLA does not partition it by itself: where a kernel with no flag of its
+    own (``ops/gdn_kernels.py``) engages."""
+    return _traced_for_tpu() and not getattr(_trace_scope, "partitioned",
+                                             False)
 
 
 def kernel_signature(platform=None):

@@ -7,7 +7,8 @@ are the ones the chip runs: every BatchNorm and Pooling input of the
 ResNet-50 train step at batch 32, read off the symbol itself, and flash
 attention at head_dim 128 and at the language-model cell's own shape
 (8,192 tokens, 16 query heads over 2 K/V heads of 256), forward and
-gradient.
+gradient; the delta-rule scan kernels (``ops/gdn_kernels.py``) at that
+cell's shape too.
 
 The lowering cannot see Mosaic's own compile (layout inference, unaligned
 slices).  ``-m slow`` adds it: libtpu compiles for a named v5e topology
@@ -24,6 +25,7 @@ import pytest
 
 from mxnet_tpu import models
 from mxnet_tpu.base import shape_attr
+from mxnet_tpu.ops import lm_ops
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.nn import _pool_core
 
@@ -156,6 +158,17 @@ def _cases():
                     jax.grad(lambda *a, fwd=fwd: jnp.sum(
                         fwd(*a).astype(jnp.float32) ** 2),
                         argnums=tuple(range(len(avals)))), tuple(avals)))
+    # the delta-rule scan kernels at the language-model cell's own shape:
+    # 2 x 8,192 tokens, 16 key heads of 128, 2 value heads of 128 each
+    gdr = lm_ops._make_gdr(64, "pallas")
+    heads = _aval((2, 16, 8192, 128), "bfloat16")
+    values = _aval((2, 16, 2, 8192, 128), "bfloat16")
+    gates = _aval((2, 16, 2, 8192), "float32")
+    gdn = (heads, heads, values, gates, gates)
+    out.append(("gdn-fwd", gdr, gdn))
+    out.append(("gdn-grad",
+                jax.grad(lambda *a: jnp.sum(gdr(*a).astype(jnp.float32) ** 2),
+                         argnums=(0, 1, 2, 3, 4)), gdn))
     return out
 
 

@@ -14,7 +14,8 @@ import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.executor import _Program
 from mxnet_tpu.observability import telemetry
-from mxnet_tpu.ops import lm_ops
+from mxnet_tpu.ops import gdn_kernels, lm_ops
+from mxnet_tpu.ops import pallas_kernels as pk
 
 from benchmark.references import qwen3_next as ref
 
@@ -94,15 +95,22 @@ def _delta_inputs(seq, seed=6, heads=(2, 4), dk=8, dv=8):
     return q, k, v, g, beta
 
 
+def _ops_layout(q, k, v, g, beta):
+    """The reference's operands as the op's scan takes them: heads first,
+    the value heads grouped by their key head."""
+    b, s, hk, _ = q.shape
+    first = lambda x: jnp.swapaxes(x, 1, 2)
+    group = lambda x: first(x).reshape((b, hk, v.shape[2] // hk, s)
+                                       + x.shape[3:])
+    return first(q), first(k), group(v), group(g), group(beta)
+
+
 def _chunked(q, k, v, g, beta, chunk=64):
     """The program's chunked scan on the reference's layout."""
-    b, s, hk, _ = q.shape
-    hv = v.shape[2]
-    first = lambda x: jnp.swapaxes(x, 1, 2)
-    group = lambda x: first(x).reshape((b, hk, hv // hk, s) + x.shape[3:])
-    out = lm_ops.chunked_gated_delta_rule(
-        first(q), first(k), group(v), group(g), group(beta), chunk)
-    return first(out.reshape(b, hv, s, -1))
+    b, s = q.shape[:2]
+    out = lm_ops.chunked_gated_delta_rule(*_ops_layout(q, k, v, g, beta),
+                                          chunk)
+    return jnp.swapaxes(out.reshape(b, v.shape[2], s, -1), 1, 2)
 
 
 def _token_by_token(q, k, v, g, beta):
@@ -127,6 +135,146 @@ def test_chunked_scan_backward_is_the_recurrence_s(seq, chunk):
                     argnums=range(5))(*args)
     for g, r, name in zip(got, want, ("q", "k", "v", "g", "beta")):
         _close(g, r, 2e-4), name
+
+
+# -- the scan as Pallas kernels (ops/gdn_kernels.py), in the interpreter ---------
+
+def _wide_inputs(seq, r, dtype, seed=80):
+    """Delta-rule operands at the kernels' widths (dk = dv = 128), on the
+    reference's layout: 2 key heads, ``r`` value heads each."""
+    q, k, v, g, beta = _delta_inputs(seq, seed, heads=(2, 2 * r), dk=128,
+                                     dv=128)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def _through(kernel, monkeypatch, *args):
+    """``_chunked`` with the recurrence over chunks as ``kernel`` says
+    (what ``gdn_kernels.mode`` would, steered here)."""
+    monkeypatch.setattr(gdn_kernels, "mode", lambda *a: kernel)
+    return _chunked(*args)
+
+
+@pytest.mark.parametrize("seq", [128, 100, 37])
+@pytest.mark.parametrize("r", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_scan_kernels_are_the_scan_and_the_recurrence(dtype, r, seq,
+                                                      monkeypatch):
+    """Outputs and all five gradients of the two kernels against the
+    ``lax.scan`` they replace (same precisions: equal to the rounding of
+    their sums) and against the token-by-token recurrence in float32."""
+    args = _wide_inputs(seq, r, dtype)
+    w = _normal(81, (BATCH, seq, 2 * r, 128))
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def both(fn, *a):
+        out, pull = jax.vjp(fn, *a)
+        return out, pull(w.astype(out.dtype))
+
+    out_k, g_k = both(lambda *a: _through("interpret", monkeypatch, *a),
+                      *args)
+    out_s, g_s = both(lambda *a: _through(None, monkeypatch, *a), *args)
+    out_r, g_r = both(_token_by_token, *(f32(x) for x in args))
+    assert out_k.dtype == jnp.dtype(dtype)
+    near, far = (1e-5, 2e-4) if dtype == "float32" else (1e-2, 3e-2)
+    _close(f32(out_k), f32(out_s), near)
+    _close(f32(out_k), out_r, far)
+    for got, scan, want, name in zip(g_k, g_s, g_r,
+                                     ("q", "k", "v", "g", "beta")):
+        assert got.dtype == scan.dtype, name
+        _close(f32(got), f32(scan), near), name
+        _close(f32(got), want, far), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_scan_kernel_saves_the_states_the_scan_saves(dtype):
+    args = _ops_layout(*_wide_inputs(256, 2, dtype))
+    _, kept = lm_ops._gdr_forward(*args, 64, kernel="interpret")
+    _, scanned = lm_ops._gdr_forward(*args, 64)
+    assert kept.dtype == scanned.dtype == jnp.dtype(dtype)
+    assert kept.shape == (BATCH, 2, 4, 2, 128, 128)     # [b, hk, n, r, ..]
+    _close(kept.astype(jnp.float32),
+           jnp.moveaxis(scanned, 0, 2).astype(jnp.float32),
+           1e-6 if dtype == "float32" else 1e-2)
+
+
+CELL_GDN = dict(query=(2, 8192, 16, 128), key=(2, 8192, 16, 128),
+                value=(2, 8192, 32, 128), a=(2, 8192, 32), b=(2, 8192, 32),
+                A_log=(32,), dt_bias=(32,))
+
+
+def _gdn_jaxpr(shapes, dtype, platform):
+    """The jaxpr text of the op's forward and gradient, traced for
+    ``platform`` at ``shapes`` (nothing runs)."""
+    avals = [jax.ShapeDtypeStruct(s, jnp.dtype(dtype))
+             for s in shapes.values()]
+
+    def grad(*a):
+        with pk.trace_scope(platform=platform):
+            return jax.grad(lambda *b: jnp.sum(lm_ops._gated_delta_rule(
+                *b, chunk=64).astype(jnp.float32)), argnums=range(7))(*a)
+
+    return str(jax.make_jaxpr(grad)(*avals))
+
+
+def test_a_tpu_program_at_the_cells_shape_holds_the_kernels_and_no_loop():
+    text = _gdn_jaxpr(CELL_GDN, "bfloat16", "tpu")
+    assert "name=gdn_scan_fwd" in text and "name=gdn_scan_bwd" in text
+    assert "scan[" not in text and "while[" not in text
+    loop = _gdn_jaxpr(CELL_GDN, "bfloat16", "cpu")
+    assert "pallas_call" not in loop and loop.count("scan[") == 2
+    # XLA partitions the program by itself: no Mosaic kernel can be in it
+    with pk.trace_scope(partitioned=True):
+        assert "pallas_call" not in _gdn_jaxpr(CELL_GDN, "bfloat16", "tpu")
+
+
+def test_a_narrow_head_falls_back_to_the_same_scan():
+    """dk = dv = 8 (the toy model's): a TPU program holds the program a CPU
+    one holds, and computes the same bits."""
+    narrow = {n: s[:3] + (8,) if len(s) == 4 else s
+              for n, s in CELL_GDN.items()}
+    narrow = {n: (2, 128) + s[2:] if len(s) > 1 else s
+              for n, s in narrow.items()}
+    assert _gdn_jaxpr(narrow, "float32", "tpu") \
+        == _gdn_jaxpr(narrow, "float32", "cpu")
+    args = _delta_inputs(100)
+    with pk.trace_scope(platform="tpu"):
+        as_tpu = _chunked(*args)
+    np.testing.assert_array_equal(np.asarray(as_tpu),
+                                  np.asarray(_chunked(*args)))
+
+
+@pytest.mark.parametrize("bh,r,n,itemsize", [
+    (32, 2, 128, 2),          # the cell: 2 x 16 key heads, 8,192 tokens
+    (32, 2, 128, 4), (6, 1, 3, 2), (7, 4, 1024, 2), (1, 2, 16, 4),
+    (32, 2, 1024, 2)])
+def test_gdn_plan_divides_the_grid_and_fits_its_budget(bh, r, n, itemsize):
+    heads = gdn_kernels._gdn_plan(bh, r, n, 64, 128, 128, itemsize)
+    assert heads and bh % heads == 0 and heads <= gdn_kernels._GDN_MAX_HEADS
+    assert gdn_kernels._gdn_vmem_bytes(heads, r, n, 64, 128, 128, itemsize) \
+        <= gdn_kernels._GDN_VMEM_BUDGET
+    more = [h for h in range(heads + 1, gdn_kernels._GDN_MAX_HEADS + 1)
+            if bh % h == 0]
+    assert all(gdn_kernels._gdn_vmem_bytes(h, r, n, 64, 128, 128, itemsize)
+               > gdn_kernels._GDN_VMEM_BUDGET for h in more)
+
+
+def test_gdn_kernels_take_whole_tiles_on_an_unpartitioned_tpu_only():
+    q, v = (2, 16, 8192, 128), (2, 16, 2, 8192, 128)
+    assert gdn_kernels.mode(q, v, 64, jnp.bfloat16) is None     # a CPU here
+    with pk.trace_scope(platform="tpu"):
+        assert gdn_kernels.mode(q, v, 64, jnp.bfloat16) == "pallas"
+        assert gdn_kernels.mode(q, v, 64, jnp.float32) == "pallas"
+        assert gdn_kernels.mode(q, v, 8, jnp.bfloat16) is None  # 16 sublanes
+        assert gdn_kernels.mode(q, v, 8, jnp.float32) == "pallas"
+        assert gdn_kernels.mode(q, v, 64, jnp.float16) is None
+        assert gdn_kernels.mode(q[:3] + (64,), v, 64, jnp.bfloat16) is None
+        assert gdn_kernels.mode(q, v[:4] + (192,), 64, jnp.bfloat16) is None
+        # a head's decay vectors stay resident: too long a sequence does not
+        long = 64 * 40000
+        assert gdn_kernels.mode(q[:2] + (long, 128), v[:3] + (long, 128), 64,
+                                jnp.bfloat16) is None
+        with pk.trace_scope(partitioned=True):
+            assert gdn_kernels.mode(q, v, 64, jnp.bfloat16) is None
 
 
 def test_gated_delta_rule_op_is_the_reference_layer():
@@ -356,6 +504,10 @@ def test_fit_trains_through_the_fused_step_like_three_adam_steps():
     assert 0.15 < held / (3 * 4 * 140 * 3) < 0.35       # 4 of 16 experts
     assert snap["module.moe.expert_load_max"]["value"] \
         >= snap["module.moe.expert_load_mean"]["value"]
+    # three delta-rule layers, two chunks of 2 x 2 key heads, three passes
+    # (forward, the recomputed forward, backward); a CPU program scans
+    assert snap["module.gdn.chunk_steps"]["value"] == 3 * (3 * 2 * 4 * 3)
+    assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 0
 
     p, m = dict(params), {n: jnp.zeros_like(a) for n, a in params.items()}
     v = dict(m)
@@ -372,3 +524,46 @@ def test_fit_trains_through_the_fused_step_like_three_adam_steps():
         moved = np.asarray(p[name] - params[name], np.float64)
         gap = np.asarray(got[name] - params[name], np.float64) - moved
         assert np.linalg.norm(gap) <= 0.05 * np.linalg.norm(moved), name
+
+
+WIDE = dict(CFG, linear_key_head_dim=128, linear_value_head_dim=128)
+
+
+def _one_fused_step(recompute=True):
+    """(the loss of one fused Adam step of the toy model at the kernels'
+    head widths, its parameters after it, the counters)."""
+    params = _params(WIDE, seed=90, scale=0.2)
+    xs, ys = _tokens(3, cfg=WIDE)
+    telemetry.reset()
+    mod = mx.mod.Module(models.qwen3_next.get_symbol(
+        WIDE, recompute=recompute), context=mx.cpu())
+    mod.fit(mx.io.NDArrayIter(xs, ys, batch_size=BATCH), num_epoch=1,
+            eval_metric="loss", optimizer="adam",
+            optimizer_params=dict(learning_rate=1e-2, epsilon=1e-3),
+            arg_params={n: mx.nd.NDArray(a) for n, a in params.items()})
+    assert mod._fused_step is not None and mod._fused_step.ran
+    after = dict(zip(mod._fused_step.param_names, mod._fused_step._masters))
+    return (float(mod.get_outputs()[0].asnumpy().mean()), after,
+            telemetry.snapshot())
+
+
+def test_chunk_step_counters_say_whether_the_kernels_engage(monkeypatch):
+    """``module.gdn.chunk_steps`` counts the scans' work; ``_in_kernel`` is 0
+    where the step program scans (any CPU program) and the same number where
+    it holds the kernels: here the interpreter's, steered in the test."""
+    loss, after, snap = _one_fused_step()
+    assert snap["module.gdn.chunk_steps"]["value"] == 3 * 2 * 4 * 3
+    assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 0
+    _, _, snap = _one_fused_step(recompute=False)       # no second forward
+    assert snap["module.gdn.chunk_steps"]["value"] == 2 * 2 * 4 * 3
+
+    real = gdn_kernels.mode
+    monkeypatch.setattr(pk, "traced_for_unpartitioned_tpu", lambda: True)
+    monkeypatch.setattr(gdn_kernels, "mode",
+                        lambda *a: real(*a) and "interpret")
+    loss_k, after_k, snap = _one_fused_step()
+    assert snap["module.gdn.chunk_steps"]["value"] == 3 * 2 * 4 * 3
+    assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 3 * 2 * 4 * 3
+    _close([loss_k], [loss], 1e-5)
+    for name in sorted(after):
+        _close(after_k[name], after[name], 1e-3), name
