@@ -13,7 +13,7 @@ match between forward and backward.
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +35,12 @@ from . import random as _random
 MIRROR_STAGE = "__mirror_stage__"
 # nodes that carry this attribute are lowered under ``jax.named_scope`` of its
 # value: a model's builder names a group of plain nodes (a dense MLP) for the
-# device trace, as the ops that are one node name themselves (``mx:attn``)
+# device trace, as the ops that are one node name themselves (``mx:attn``).
+# Every other node is lowered under ``mx:op:<its op>``, so that each op of a
+# compiled program says which mechanism it belongs to
+# (``FusedTrainStep.op_scopes``, docs/observability.md)
 NAMED_SCOPE = "__named_scope__"
+OP_SCOPE = "mx:op:"
 
 
 def _to_device(arr, dev):
@@ -138,8 +142,8 @@ class _Program:
             ins = [env[e] for e in node.inputs]
             if op.needs_rng:
                 ins = [key_of[node]] + ins
-            scope = node.attrs.get(NAMED_SCOPE)
-            with jax.named_scope(scope) if scope else nullcontext():
+            with jax.named_scope(node.attrs.get(NAMED_SCOPE)
+                                 or OP_SCOPE + node.op_name):
                 out = op.impl(*ins, **attrs)
             if not isinstance(out, tuple):
                 out = (out,)

@@ -37,6 +37,15 @@ class LMBuilder:
             x, weight=self.param((weight or name) + "_weight"),
             num_hidden=int(width), no_bias=True, flatten=False, name=name)
 
+    def embed(self, ids, name="embed"):
+        """The rows of THE embedding (``embed_weight``, whichever node
+        asks) for the token ids ``ids``."""
+        with self.named("mx:embed"):
+            return sym.Embedding(ids, weight=self.param("embed_weight"),
+                                 input_dim=int(self.cfg["vocab_size"]),
+                                 output_dim=int(self.cfg["hidden_size"]),
+                                 name=name)
+
     def norm(self, x, name, zero_centered=True):
         return sym.RMSNorm(x, gamma=self.param(name + "_gamma"), eps=self.eps,
                            zero_centered=zero_centered, name=name)
@@ -48,6 +57,12 @@ class LMBuilder:
             sym.SwiGLU(self.dense(x, p + "gate_proj", width),
                        self.dense(x, p + "up_proj", width)),
             p + "down_proj", self.cfg["hidden_size"])
+
+    def shared_expert(self, x, p, width):
+        """The expert every token takes, beside the routed ones: a SwiGLU
+        MLP under ``p`` + ``shared_``."""
+        with self.named("mx:moe:shared"):
+            return self.swiglu_mlp(x, p + "shared_", width)
 
     def routed_experts(self, x, p, **routing):
         """The ``moe_experts`` node of layer prefix ``p`` over the tokens of
@@ -90,11 +105,12 @@ class LMBuilder:
         state ``x`` through THE output matrix (``lm_head_weight``, whichever
         node asks) against ``softmax_label`` ``shift`` positions on: 0 is
         the next token, 1 the one after it."""
-        logits = self.dense(x, prefix + "lm_head", self.cfg["vocab_size"],
-                            weight="lm_head")
-        return sym.sequence_cross_entropy(
-            logits, self.label(), shift=int(shift),
-            name=prefix + "ce")
+        with self.named("mx:head"):
+            logits = self.dense(x, prefix + "lm_head",
+                                self.cfg["vocab_size"], weight="lm_head")
+            return sym.sequence_cross_entropy(
+                logits, self.label(), shift=int(shift),
+                name=prefix + "ce")
 
     def outputs(self, x, counts, second=None):
         """``Group([loss, expert selection counts])`` from the last block's

@@ -100,7 +100,7 @@ class _Builder(LMBuilder):
             use_expert_bias=True)
         width = int(cfg["moe_intermediate_size"]) * int(cfg["n_shared_experts"])
         return (sym.reshape_like(routed[0], x)
-                + self.swiglu_mlp(x, p + "shared_", width), routed[1])
+                + self.shared_expert(x, p, width), routed[1])
 
     def block(self, x, p, dense, recompute):
         """(the block's output, its counts or None).  Each half is one
@@ -116,12 +116,6 @@ class _Builder(LMBuilder):
             else:
                 out, counts = self.moe(u, p)
             return h + out, counts
-
-    def embed(self, ids, name):
-        return sym.Embedding(ids, weight=self.param("embed_weight"),
-                             input_dim=int(self.cfg["vocab_size"]),
-                             output_dim=int(self.cfg["hidden_size"]),
-                             name=name)
 
     def mtp(self, x, recompute):
         """(the module's loss [batch], its expert layer's counts) from the

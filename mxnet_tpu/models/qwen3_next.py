@@ -83,10 +83,11 @@ class _Builder(LMBuilder):
         cfg = self.cfg
         routed = self.routed_experts(
             x, p, norm_topk_prob=bool(cfg["norm_topk_prob"]))
-        shared = self.swiglu_mlp(x, p + "shared_",
-                                 int(cfg["shared_expert_intermediate_size"]))
-        shared = sym.broadcast_mul(
-            sym.sigmoid(self.dense(x, p + "shared_gate", 1)), shared)
+        with self.named("mx:moe:shared"):
+            shared = self.swiglu_mlp(
+                x, p + "shared_", int(cfg["shared_expert_intermediate_size"]))
+            shared = sym.broadcast_mul(
+                sym.sigmoid(self.dense(x, p + "shared_gate", 1)), shared)
         return sym.reshape_like(routed[0], x) + shared, routed[1]
 
     def block(self, x, layer, recompute):
@@ -109,9 +110,7 @@ def get_symbol(cfg, dtype="float32", recompute=True):
     """``Group([loss, expert selection counts])`` over ``data`` [batch, seq]
     token ids and ``softmax_label`` [batch, seq] next-token targets."""
     build = _Builder(cfg, dtype)
-    x = sym.Embedding(sym.Variable("data"), weight=build.param("embed_weight"),
-                      input_dim=int(cfg["vocab_size"]),
-                      output_dim=int(cfg["hidden_size"]), name="embed")
+    x = build.embed(sym.Variable("data"))
     counts = []
     for layer in range(int(cfg["num_hidden_layers"])):
         x, c = build.block(x, layer, recompute)

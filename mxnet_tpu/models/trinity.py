@@ -81,7 +81,7 @@ class _Builder(LMBuilder):
         width = int(cfg["moe_intermediate_size"]) \
             * int(cfg["num_shared_experts"])
         return (sym.reshape_like(routed[0], x)
-                + self.swiglu_mlp(x, p + "shared_", width), routed[1])
+                + self.shared_expert(x, p, width), routed[1])
 
     def block(self, x, layer, kind, recompute):
         """(the block's output, its counts or None).  Each half is one
@@ -106,9 +106,7 @@ def get_symbol(cfg, dtype="float32", recompute=True):
     """``Group([loss, expert selection counts])`` over ``data`` [batch, seq]
     token ids and ``softmax_label`` [batch, seq] next-token targets."""
     build = _Builder(cfg, dtype)
-    x = sym.Embedding(sym.Variable("data"), weight=build.param("embed_weight"),
-                      input_dim=int(cfg["vocab_size"]),
-                      output_dim=int(cfg["hidden_size"]), name="embed")
+    x = build.embed(sym.Variable("data"))
     if cfg.get("mup_enabled"):
         x = x * float(cfg["hidden_size"]) ** 0.5
     counts = []

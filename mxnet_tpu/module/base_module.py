@@ -25,6 +25,8 @@ from ..observability import flight_recorder as _flight
 from ..observability import health as _health
 from ..observability import instrument as _instrument
 from ..observability import memprof as _memprof
+from ..observability import telemetry as _telemetry
+from ..observability import tracing as _tracing
 from ..observability.instrument import StepTracker
 
 
@@ -205,11 +207,34 @@ class BaseModule:
                 force_init, begin_epoch, num_epoch, validation_metric,
                 monitor)
         finally:
+            self._capture_op_scopes()
             for it in owned_iters:
                 try:
                     it.close()
                 except Exception:
                     pass
+
+    def _capture_op_scopes(self):
+        """Under an open profiler session (and telemetry on), hand the fused
+        step's table from compiled op to ``mx:`` scope to ``instrument``, so
+        that whoever reads the trace can turn its device events into time by
+        mechanism after this module is gone
+        (``instrument.device_seconds_by_scope``).  Off the hot path: the
+        fit is over; with no session this is one attribute read."""
+        if not _tracing.device_trace_open() or not _telemetry.enabled():
+            return
+        step = getattr(self, "_fused_step", None)
+        if step is None or not step.ran:
+            return
+        try:
+            tic = time.time()
+            _instrument.capture_device_op_scopes(step._memprof_label,
+                                                 step.op_scopes())
+            self.logger.info("device trace open: the step program's op "
+                             "scopes captured in %.2f s", time.time() - tic)
+        except Exception:   # never take a finished fit down for a table
+            self.logger.warning("could not capture the step program's op "
+                                "scopes", exc_info=True)
 
     @staticmethod
     def _adapt_data(data, owned_iters, warm_start=True):

@@ -339,34 +339,37 @@ class FusedTrainStep:
             opt_keys = jax.random.split(opt_key, n_params) if needs_rng \
                 else [None] * n_params
             new_masters, new_states, new_exec = [], [], []
-            for j, (w, g) in enumerate(zip(masters, grads)):
-                if mixed[j]:
-                    # the ONLY master-precision cast on the gradient path
-                    g = g.astype(w.dtype)
-                ex = extras[j] if n_extra else ()
-                nw, nst = opt.fused_update(w, g, states[j], lrs[j], wds[j],
-                                           ex, key=opt_keys[j])
-                nw = nw.astype(w.dtype)
-                nst = _map2_state(lambda a, old: a.astype(old.dtype),
-                                  nst, states[j])
-                new_masters.append(nw)
-                new_states.append(nst)
-                new_exec.append(nw.astype(param_dtypes[j]) if mixed[j]
-                                else nw)
+            with jax.named_scope("mx:update"):
+                for j, (w, g) in enumerate(zip(masters, grads)):
+                    if mixed[j]:
+                        # the ONLY master-precision cast on the gradient
+                        # path
+                        g = g.astype(w.dtype)
+                    ex = extras[j] if n_extra else ()
+                    nw, nst = opt.fused_update(w, g, states[j], lrs[j],
+                                               wds[j], ex, key=opt_keys[j])
+                    nw = nw.astype(w.dtype)
+                    nst = _map2_state(lambda a, old: a.astype(old.dtype),
+                                      nst, states[j])
+                    new_masters.append(nw)
+                    new_states.append(nst)
+                    new_exec.append(nw.astype(param_dtypes[j]) if mixed[j]
+                                    else nw)
             if health_on:
                 # exact update/param ratio: the program holds old AND
                 # new masters, so |Δw|/|w| needs no host-side estimate
-                upd_sq = sum(jnp.sum(jnp.square(
-                    nw.astype(jnp.float32) - w.astype(jnp.float32)))
-                    for w, nw in zip(masters, new_masters))
-                par_sq = sum(jnp.sum(jnp.square(w.astype(jnp.float32)))
-                             for w in masters)
-                ratio = jnp.sqrt(upd_sq) / jnp.maximum(
-                    jnp.sqrt(par_sq), jnp.float32(1e-12))
-                hvec = _health.pack_summary(health_layout, outs, masters,
-                                            list(grads),
-                                            update_ratio=ratio,
-                                            taps=taps)
+                with jax.named_scope("mx:health"):
+                    upd_sq = sum(jnp.sum(jnp.square(
+                        nw.astype(jnp.float32) - w.astype(jnp.float32)))
+                        for w, nw in zip(masters, new_masters))
+                    par_sq = sum(jnp.sum(jnp.square(w.astype(jnp.float32)))
+                                 for w in masters)
+                    ratio = jnp.sqrt(upd_sq) / jnp.maximum(
+                        jnp.sqrt(par_sq), jnp.float32(1e-12))
+                    hvec = _health.pack_summary(health_layout, outs, masters,
+                                                list(grads),
+                                                update_ratio=ratio,
+                                                taps=taps)
                 return (outs, new_masters, new_states, new_aux, new_exec,
                         hvec)
             return outs, new_masters, new_states, new_aux, new_exec
@@ -374,6 +377,7 @@ class FusedTrainStep:
         # donation: masters (0) and optimizer states (2)
         donate_idx = (0, 2) if donate else ()
         self._last_abstract = None
+        self._hlo_text = self._op_scopes = None
         # single device: the executor's storage-dtype copies ride along
         # (argument 9, ``stored``) and are donated with the rest
         self._spare_idx = [j for j in range(n_params) if mixed[j]] \
@@ -519,12 +523,39 @@ class FusedTrainStep:
 
     def compiled_hlo(self):
         """Compiled-HLO text of the step program (None before the first
-        run); ``collective_counts`` of it shows the all-reduce XLA
-        derived from the dp shardings."""
+        run), lowered and compiled for it once and kept; its readers are
+        ``collective_counts`` (the all-reduce XLA derived from the dp
+        shardings) and ``op_scopes``."""
         if self._last_abstract is None:
             return None
-        return self._step_jit.lower(*self._last_abstract).compile() \
-            .as_text()
+        if self._hlo_text is None:
+            # JAX keys its persistent compile cache with the metadata
+            # stripped, so a hit may hand back an executable another build
+            # traced, with THAT build's scopes and lines in its text (the
+            # instructions and their names are the same either way).  For
+            # this one compile the metadata is part of the key: the text
+            # read here is this trace's own
+            key = "jax_compilation_cache_include_metadata_in_key"
+            before = getattr(jax.config, key)
+            jax.config.update(key, True)
+            try:
+                self._hlo_text = self._step_jit.lower(
+                    *self._last_abstract).compile().as_text()
+            finally:
+                jax.config.update(key, before)
+        return self._hlo_text
+
+    def op_scopes(self):
+        """{instruction name: {"path", "mechanism", "detail", "pass"}} for
+        every instruction of every computation of the compiled step (None
+        before the first run): which ``mx:`` scope each compiled op was
+        traced under, and in which pass (``instrument.scopes_of_hlo``).
+        A device trace names its events by these instructions, so this
+        table joined with the trace's seconds is device time by mechanism
+        (``instrument.device_seconds_by_scope``)."""
+        if self._op_scopes is None and self.compiled_hlo() is not None:
+            self._op_scopes = _instrument.scopes_of_hlo(self._hlo_text)
+        return self._op_scopes
 
     def _init_state(self, j):
         """create_state-shaped optimizer state in the master dtype, with

@@ -34,6 +34,7 @@ from __future__ import annotations
 import collections
 import logging
 import os
+import re
 import threading
 import time
 
@@ -792,6 +793,135 @@ def payload_nbytes(value):
             itemsize = 4
         total += n * itemsize
     return total
+
+
+# -- device time by mechanism: from compiled op to ``mx:`` scope -----------------
+#
+# Every op of a step program is traced under ``jax.named_scope`` tokens
+# ``mx:<mechanism>[:<detail>]`` (docs/observability.md §1), and XLA keeps the
+# scope path of every instruction it compiles (fusions by their root) in the
+# instruction's ``op_name``.  A device trace names its events by those same
+# instructions, so the table made here, joined with a trace's seconds per
+# event, is device time by mechanism.
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_SCOPE_TOKEN = re.compile(r"mx:[\w:.\-]+")
+_op_scopes = {}     # program label -> its table, kept past the program's life
+
+
+def scope_of_op_name(op_name):
+    """``{"path", "mechanism", "detail", "pass"}`` of one instruction's
+    ``op_name`` (``jit(_step)/transpose(jvp(mx:mtp/mx:moe))/mx:moe:gather/
+    gather``): ``path`` its ``mx:`` tokens joined by ``/``, ``detail`` the
+    LAST of them, ``mechanism`` that token's first two fields (``mx:moe`` of
+    ``mx:moe:gather``; both None with no token), ``pass`` ``"recomputed"``
+    under ``rematted_computation`` (the forward run again by
+    ``jax.checkpoint``), else ``"backward"`` under ``transpose(`` (JAX puts
+    it on the ops of a ``custom_vjp``'s backward rule too), else
+    ``"forward"``."""
+    tokens = _SCOPE_TOKEN.findall(op_name)
+    detail = tokens[-1] if tokens else None
+    return {"path": "/".join(tokens),
+            "mechanism": ":".join(detail.split(":")[:2]) if detail else None,
+            "detail": detail,
+            "pass": "recomputed" if "rematted_computation" in op_name
+            else "backward" if "transpose(" in op_name
+            else "forward"}
+
+
+def scopes_of_hlo(text):
+    """{instruction name: ``scope_of_op_name`` of it} for every instruction of
+    every computation in compiled-HLO ``text`` (``compile().as_text()``).
+    An instruction whose own ``op_name`` names no scope — a relayout copy or
+    an async copy XLA put in, the Mosaic call it makes of a grouped product,
+    a parameter — is what XLA made FOR a neighbour, and takes the row of the
+    nearest instruction of its computation that has a scope: the first one
+    that reads its result, through others like it, else the first it reads
+    (``"own": False`` marks such a row); with no such neighbour it maps to no
+    mechanism.  Instructions of one ``op_name`` share one row."""
+    rows, table, body = {}, {}, []
+
+    def nearest(name, edges):
+        """The row of the nearest instruction along ``edges`` that names a
+        scope itself, reached through those that do not."""
+        seen, queue = {name}, collections.deque([name])
+        while queue:
+            at = queue.popleft()
+            if table[at]["mechanism"] is not None \
+                    and table[at].get("own", True):
+                return table[at]
+            for nxt in edges.get(at, ()):
+                if nxt not in seen:
+                    seen.add(nxt)
+                    queue.append(nxt)
+        return None
+
+    def close():
+        """Resolve the scope-less instructions of the computation read."""
+        reads = {n: [o for o in ops if o in table] for n, ops in body}
+        read_by = {}
+        for n, ops in reads.items():
+            for o in ops:
+                read_by.setdefault(o, []).append(n)
+        for name, _ in body:
+            if table[name]["mechanism"] is None:
+                found = nearest(name, read_by) or nearest(name, reads)
+                if found:
+                    table[name] = dict(found, own=False)
+        del body[:]
+
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            if line.startswith("}"):
+                close()
+            continue
+        found = _HLO_OP_NAME.search(line, m.end())
+        op_name = found.group(1) if found else ""
+        if op_name not in rows:
+            rows[op_name] = scope_of_op_name(op_name)
+        table[m.group(1)] = rows[op_name]
+        body.append((m.group(1), _HLO_OPERAND.findall(
+            line, m.end(), found.start() if found else len(line))))
+    close()
+    return table
+
+
+def capture_device_op_scopes(label, table):
+    """Keep a program's ``op_scopes()`` under its ``label`` for
+    ``device_op_scopes`` (a later capture of the label replaces it): a plain
+    dict that outlives the program and the module that held it."""
+    if table:
+        _op_scopes[label] = table
+
+
+def device_op_scopes():
+    """The union of the captured tables: {instruction name: scope row}."""
+    merged = {}
+    for table in _op_scopes.values():
+        merged.update(table)
+    return merged
+
+
+def device_seconds_by_scope(op_seconds, scopes=None):
+    """Device seconds by mechanism: ``op_seconds`` {instruction name:
+    seconds} (a trace event's whole name will do: ``%fusion.4 = ...`` and
+    ``fusion.4 fusion`` both name ``fusion.4``) summed into rows
+    ``{"mechanism", "detail", "pass", "seconds"}`` through ``scopes`` (the
+    captured tables where not given).  A partition: the rows sum to the
+    input, and what no table names (an op of another program in the window)
+    lands in the row whose three fields are None."""
+    table = device_op_scopes() if scopes is None else scopes
+    sums = {}
+    for name, seconds in op_seconds.items():
+        row = table.get(name.lstrip("%").split(" ", 1)[0])
+        key = (row["mechanism"], row["detail"], row["pass"]) if row \
+            else (None, None, None)
+        sums[key] = sums.get(key, 0.0) + float(seconds)
+    return [{"mechanism": m, "detail": d, "pass": p, "seconds": s}
+            for (m, d, p), s in sums.items()]
 
 
 # -- the fused step's input ----------------------------------------------------

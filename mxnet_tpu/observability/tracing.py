@@ -97,6 +97,20 @@ def annotation(name, **meta):
     return _TraceAnnotation(ANNOTATION_PREFIX + name, **meta)
 
 
+def device_trace_open():
+    """Whether a ``jax.profiler`` session is open in this process
+    (``mx.profiler.start_jax_trace``, ``jax.profiler.start_trace``, the
+    benchmark's ``--trace 1``): the ONE place that asks.  JAX has no public
+    query, so its private session slot is read, and where that cannot be
+    read the session counts as open: the caller then does its work for a
+    trace that may not exist, never skips it for one that does."""
+    try:
+        from jax._src import profiler as _jax_profiler
+        return _jax_profiler._profile_state.profile_session is not None
+    except (ImportError, AttributeError):
+        return True
+
+
 def is_recording():
     return _recording
 

@@ -136,8 +136,9 @@ def _mha(query, key, value, q_weight, q_bias, k_weight, k_bias, v_weight,
     v = (jnp.matmul(value, v_weight.T) + v_bias).reshape(b, sk, h, -1)
     kv_lens = rest[0] if use_lengths else None
     _note_logit_bound(q, k, scale)
-    o = _pk.attention(q, k, v, causal=causal,
-                      scale=(scale if scale else None), kv_lens=kv_lens)
+    with jax.named_scope("mx:attn"):
+        o = _pk.attention(q, k, v, causal=causal,
+                          scale=(scale if scale else None), kv_lens=kv_lens)
     return jnp.matmul(o.reshape(b, sq, -1), out_weight.T) + out_bias
 
 

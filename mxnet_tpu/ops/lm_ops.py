@@ -146,6 +146,12 @@ register("causal_conv1d", _causal_conv1d, input_names=("data", "weight"),
 # operands' dtype (bfloat16 in mixed precision, as the products read them);
 # the decay, the solve and the carried state are float32.
 
+# the two parts of the op under its ``mx:gdn`` scope, in the forward and in
+# the backward rule alike (docs/observability.md: device time by mechanism)
+LOCAL_SCOPE = "mx:gdn:local"
+SCAN_SCOPE = "mx:gdn:scan"
+
+
 def _l2norm(x, eps=1e-6):
     return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
                          + _F32(eps))
@@ -242,7 +248,8 @@ def _scan_inputs(q, k, v, g, beta, chunk):
     """(per-chunk operands with the chunk axis leading, the vjp of the
     chunk-local part)."""
     args = _chunks(q, k, v, g, beta, chunk)
-    local, pull = jax.vjp(_chunk_local, *args)
+    with jax.named_scope(LOCAL_SCOPE):
+        local, pull = jax.vjp(_chunk_local, *args)
     lead = lambda x, axis: jnp.moveaxis(x, axis, 0)
     xs = (lead(args[0], 2), lead(args[1], 2)) \
         + tuple(lead(x, 3) for x in local)
@@ -257,9 +264,12 @@ def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
     only where ``keep_states``; XLA drops the scan's by itself)."""
     if kernel:
         args = _chunks(q, k, v, g, beta, chunk)
-        outs, states = gdn_kernels.scan_fwd(
-            args[0], args[1], _chunk_local(*args), keep_states,
-            interpret=(kernel == "interpret") or None)
+        with jax.named_scope(LOCAL_SCOPE):
+            local = _chunk_local(*args)
+        with jax.named_scope(SCAN_SCOPE):
+            outs, states = gdn_kernels.scan_fwd(
+                args[0], args[1], local, keep_states,
+                interpret=(kernel == "interpret") or None)
         return outs.reshape(v.shape), states
     xs, _ = _scan_inputs(q, k, v, g, beta, chunk)
 
@@ -268,7 +278,8 @@ def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
         return nxt, (state.astype(v.dtype), out)
 
     start = jnp.zeros(v.shape[:3] + (q.shape[-1], v.shape[-1]), _F32)
-    _, (states, outs) = lax.scan(body, start, xs)
+    with jax.named_scope(SCAN_SCOPE):
+        _, (states, outs) = lax.scan(body, start, xs)
     outs = jnp.moveaxis(outs, 0, 3)
     return outs.reshape(v.shape), states
 
@@ -298,11 +309,12 @@ def _make_gdr(chunk, kernel=None):
         n = int(q.shape[2]) // chunk
         if kernel:
             args = _chunks(q, k, v, g, beta, chunk)
-            local, pull = jax.vjp(_chunk_local, *args)
-            d_q, d_k, d_local = gdn_kernels.scan_bwd(
-                args[0], args[1], local, states, _split(d_out, 3, n),
-                interpret=(kernel == "interpret") or None)
-            grads = list(pull(d_local))
+            with jax.named_scope(LOCAL_SCOPE):
+                local, pull = jax.vjp(_chunk_local, *args)
+            with jax.named_scope(SCAN_SCOPE):
+                d_q, d_k, d_local = gdn_kernels.scan_bwd(
+                    args[0], args[1], local, states, _split(d_out, 3, n),
+                    interpret=(kernel == "interpret") or None)
         else:
             xs, pull = _scan_inputs(q, k, v, g, beta, chunk)
             d_outs = jnp.moveaxis(_split(d_out, 3, n), 3, 0)
@@ -314,11 +326,13 @@ def _make_gdr(chunk, kernel=None):
                 return d_prev, d_x
 
             zero = jnp.zeros(states.shape[1:], _F32)
-            _, d_xs = lax.scan(body, zero, (states, xs, d_outs),
-                               reverse=True)
+            with jax.named_scope(SCAN_SCOPE):
+                _, d_xs = lax.scan(body, zero, (states, xs, d_outs),
+                                   reverse=True)
             d_q, d_k = (jnp.moveaxis(x, 0, 2) for x in d_xs[:2])
-            grads = list(pull(tuple(jnp.moveaxis(x, 0, 3)
-                                    for x in d_xs[2:])))
+            d_local = tuple(jnp.moveaxis(x, 0, 3) for x in d_xs[2:])
+        with jax.named_scope(LOCAL_SCOPE):
+            grads = list(pull(d_local))
         grads[0], grads[1] = grads[0] + d_q, grads[1] + d_k
         return tuple(x.reshape(r.shape) for x, r in
                      zip(grads, (q, k, v, g, beta)))
@@ -414,47 +428,54 @@ def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
     if score_func not in ("softmax", "sigmoid"):
         raise ValueError("moe_experts: score_func %r is neither 'softmax' "
                          "nor 'sigmoid'" % (score_func,))
+    part = lambda name: jax.named_scope("mx:moe:" + name)
     with jax.named_scope("mx:moe"):
         n, h = data.shape
         held = int(experts_held) or int(num_experts)
         k = int(top_k)
-        logits = jnp.matmul(data.astype(_F32), router_weight.astype(_F32).T)
-        sigmoid = score_func == "sigmoid"
-        probs = jax.nn.sigmoid(logits) if sigmoid \
-            else jax.nn.softmax(logits, axis=-1)
-        if use_expert_bias:
-            _, top_e = lax.top_k(
-                probs + lax.stop_gradient(rest[0].astype(_F32)), k)
-            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
-        else:
-            top_p, top_e = lax.top_k(probs, k)
-        if norm_topk_prob:
-            total = jnp.sum(top_p, axis=-1, keepdims=True)
-            top_p = top_p / (total + _F32(1e-20) if sigmoid else total)
-        if float(route_scale) != 1.0:
-            top_p = top_p * _F32(route_scale)
-        chosen = lax.stop_gradient(top_e).reshape(-1)
-        counts = jnp.zeros((int(num_experts),), _F32).at[chosen].add(1.0)
-        local = chosen - int(first_expert)
-        mine = (local >= 0) & (local < held)
-        group = jnp.where(mine, local, held)       # the others sort last
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        # the rows past the held groups are other chips': the grouped
-        # product leaves them UNWRITTEN on the TPU, in the backward pass
-        # too, so each of its operands and its result is selected, never
-        # scaled, to nought there (PERF.md, PR 27)
-        live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+        with part("route"):
+            logits = jnp.matmul(data.astype(_F32),
+                                router_weight.astype(_F32).T)
+            sigmoid = score_func == "sigmoid"
+            probs = jax.nn.sigmoid(logits) if sigmoid \
+                else jax.nn.softmax(logits, axis=-1)
+            if use_expert_bias:
+                _, top_e = lax.top_k(
+                    probs + lax.stop_gradient(rest[0].astype(_F32)), k)
+                top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+            else:
+                top_p, top_e = lax.top_k(probs, k)
+            if norm_topk_prob:
+                total = jnp.sum(top_p, axis=-1, keepdims=True)
+                top_p = top_p / (total + _F32(1e-20) if sigmoid else total)
+            if float(route_scale) != 1.0:
+                top_p = top_p * _F32(route_scale)
+            chosen = lax.stop_gradient(top_e).reshape(-1)
+            counts = jnp.zeros((int(num_experts),), _F32).at[chosen].add(1.0)
+            local = chosen - int(first_expert)
+            mine = (local >= 0) & (local < held)
+            group = jnp.where(mine, local, held)   # the others sort last
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+            # the rows past the held groups are other chips': the grouped
+            # product leaves them UNWRITTEN on the TPU, in the backward pass
+            # too, so each of its operands and its result is selected, never
+            # scaled, to nought there (PERF.md, PR 27)
+            live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
         mine_only = lambda x: jnp.where(live, x, jnp.zeros((), x.dtype))
-        rows = mine_only(data[order // k])         # [n*k, h], by expert
-        mid = mine_only(_swiglu(lax.ragged_dot(rows, gate_weight, sizes),
-                                lax.ragged_dot(rows, up_weight, sizes)))
-        weight = jnp.where(mine, top_p.reshape(-1), 0.0)[order]
-        out = mine_only(lax.ragged_dot(mid, down_weight, sizes).astype(_F32)
-                        * weight[:, None]).astype(data.dtype)
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * k, dtype=order.dtype))
-        y = jnp.sum(out[back].reshape(n, k, h).astype(_F32), axis=1)
+        with part("gather"):
+            rows = mine_only(data[order // k])     # [n*k, h], by expert
+        with part("experts"):
+            mid = mine_only(_swiglu(lax.ragged_dot(rows, gate_weight, sizes),
+                                    lax.ragged_dot(rows, up_weight, sizes)))
+            weight = jnp.where(mine, top_p.reshape(-1), 0.0)[order]
+            out = mine_only(
+                lax.ragged_dot(mid, down_weight, sizes).astype(_F32)
+                * weight[:, None]).astype(data.dtype)
+        with part("scatter"):
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(n * k, dtype=order.dtype))
+            y = jnp.sum(out[back].reshape(n, k, h).astype(_F32), axis=1)
         return y.astype(data.dtype), lax.stop_gradient(counts)
 
 

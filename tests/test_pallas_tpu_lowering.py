@@ -262,3 +262,39 @@ def test_partitioned_trace_keeps_mosaic_kernels_out(monkeypatch):
     with pytest.raises(NotImplementedError, match="partitioned"):
         jax.jit(pool_grad, in_shardings=dp).trace(x).lower(
             lowering_platforms=("tpu",))
+
+
+@pytest.mark.slow
+def test_compiled_kernels_carry_their_scope_and_pass(v5e_device):
+    """The compiled text of a TPU program names each kernel's call by the
+    kernel, and its ``op_name`` carries the op's ``mx:`` scope and the pass:
+    what ``FusedTrainStep.op_scopes`` reads (docs/observability.md §1)."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.observability import instrument
+    from mxnet_tpu.ops import attention, lm_ops
+
+    def loss(q, k, v, qd, vd, a, log, bias):
+        with pk.trace_scope(platform="tpu"):
+            o = attention._sdpa(q, k, v, causal=True, window=128)
+            d = lm_ops._gated_delta_rule(qd, qd, vd, a, a, log, bias)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(d.astype(jnp.float32))
+
+    avals = [_aval((1, 512, 2, 128), "bfloat16")] * 3 \
+        + [_aval((1, 256, 2, 128), "bfloat16")] * 2 \
+        + [_aval((1, 256, 2), "bfloat16")] + [_aval((2,), "float32")] * 2
+    on_chip = SingleDeviceSharding(v5e_device)
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(8))),
+                   in_shardings=(on_chip,) * 8).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    table = instrument.scopes_of_hlo(text)
+    of = lambda kernel: {(r["mechanism"], r["detail"], r["pass"])
+                         for n, r in table.items() if n.startswith(kernel)}
+    assert of("flash_attn_fwd") == {("mx:attn", "mx:attn:window", "forward")}
+    for kernel in ("flash_attn_bwd_dq", "flash_attn_bwd_dkv"):
+        assert of(kernel) == {("mx:attn", "mx:attn:window", "backward")}
+    assert of("gdn_scan_fwd") == {("mx:gdn", "mx:gdn:scan", "forward")}
+    assert of("gdn_scan_bwd") == {("mx:gdn", "mx:gdn:scan", "backward")}
+    # (XLA merges the forward's chunk-local ops with the same ops of the
+    # backward's ``jax.vjp(_chunk_local)``: one of the two names survives)
+    assert "backward" in {r["pass"] for r in table.values()
+                          if r["detail"] == "mx:gdn:local"}
