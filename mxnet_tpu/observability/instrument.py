@@ -807,6 +807,9 @@ def payload_nbytes(value):
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+# instructions that run other computations: a trace's event of one spans the
+# events of what it calls
+_HLO_HOLDS = re.compile(r"\s(?:while|conditional|call)\(")
 _SCOPE_TOKEN = re.compile(r"mx:[\w:.\-]+")
 _op_scopes = {}     # program label -> its table, kept past the program's life
 
@@ -840,8 +843,11 @@ def scopes_of_hlo(text):
     nearest instruction of its computation that has a scope: the first one
     that reads its result, through others like it, else the first it reads
     (``"own": False`` marks such a row); with no such neighbour it maps to no
-    mechanism.  Instructions of one ``op_name`` share one row."""
-    rows, table, body = {}, {}, []
+    mechanism.  Instructions of one ``op_name`` share one row.  A ``while``,
+    a ``conditional`` or a ``call`` carries ``"holds": True``: a trace's
+    event of it spans the events of the computations it runs, whose seconds
+    are theirs (``device_seconds_by_scope`` leaves the holder's out)."""
+    rows, table, body, holders = {}, {}, [], []
 
     def nearest(name, edges):
         """The row of the nearest instruction along ``edges`` that names a
@@ -883,9 +889,13 @@ def scopes_of_hlo(text):
         if op_name not in rows:
             rows[op_name] = scope_of_op_name(op_name)
         table[m.group(1)] = rows[op_name]
-        body.append((m.group(1), _HLO_OPERAND.findall(
-            line, m.end(), found.start() if found else len(line))))
+        end = found.start() if found else len(line)
+        body.append((m.group(1), _HLO_OPERAND.findall(line, m.end(), end)))
+        if _HLO_HOLDS.search(line, m.end(), end):
+            holders.append(m.group(1))
     close()
+    for name in holders:
+        table[name] = dict(table[name], holds=True)
     return table
 
 
@@ -912,11 +922,15 @@ def device_seconds_by_scope(op_seconds, scopes=None):
     ``{"mechanism", "detail", "pass", "seconds"}`` through ``scopes`` (the
     captured tables where not given).  A partition: the rows sum to the
     input, and what no table names (an op of another program in the window)
-    lands in the row whose three fields are None."""
+    lands in the row whose three fields are None.  Left out of both: the
+    events of instructions that hold others (``"holds"``: a ``while`` loop's
+    event spans its body's, which are in the input themselves)."""
     table = device_op_scopes() if scopes is None else scopes
     sums = {}
     for name, seconds in op_seconds.items():
         row = table.get(name.lstrip("%").split(" ", 1)[0])
+        if row and row.get("holds"):
+            continue
         key = (row["mechanism"], row["detail"], row["pass"]) if row \
             else (None, None, None)
         sums[key] = sums.get(key, 0.0) + float(seconds)
@@ -1003,12 +1017,26 @@ def note_moe_counts(counts, first_expert, experts_held):
     is read, so no sync is added): ``module.moe.selections_total`` /
     ``_held`` (all top-k choices, and those that fell on the experts held
     here), ``module.moe.expert_load_max`` / ``_mean`` (tokens on the
-    busiest held expert and on the average one, summed over layers)."""
+    busiest held expert and on the average one, summed over layers).  And
+    what ``moe_experts`` moved for them: ``module.moe.rows_live`` (the held
+    choices), ``module.moe.rows_moved`` (the rounds each layer took times
+    the capacity of a round: the rows gathered, multiplied and added a
+    pass), ``module.moe.overflow_rounds`` (rounds beyond a layer's first:
+    0 while every layer's held choices fit one round)."""
+    from ..ops.lm_ops import moe_capacity, moe_rounds
     counts = np.asarray(counts, np.float64).reshape(-1, counts.shape[-1])
     held = counts[:, first_expert:first_expert + experts_held]
+    caps = [moe_capacity(total, experts_held, counts.shape[1])
+            for total in counts.sum(axis=1)]
+    rounds = [moe_rounds(live, cap)
+              for live, cap in zip(held.sum(axis=1), caps)]
     for name, value in (("selections_total", counts.sum()),
                         ("selections_held", held.sum()),
                         ("expert_load_max", held.max(axis=1).sum()),
-                        ("expert_load_mean", held.mean(axis=1).sum())):
+                        ("expert_load_mean", held.mean(axis=1).sum()),
+                        ("rows_live", held.sum()),
+                        ("rows_moved",
+                         sum(r * cap for r, cap in zip(rounds, caps))),
+                        ("overflow_rounds",
+                         sum(max(r - 1, 0) for r in rounds))):
         telemetry.counter("module.moe." + name).inc(float(value))
-

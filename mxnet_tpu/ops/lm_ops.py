@@ -403,6 +403,122 @@ register("gated_delta_rule", _gated_delta_rule,
 
 # -- dropless top-k experts, this chip's share ---------------------------------
 
+# A round of ``moe_experts`` holds this many times the choices a uniform
+# router would put on the held experts.  2: a layer at its expectation, or
+# up to twice it, takes one round, and moves an eighth of the rows where a
+# sixteenth of the experts is held.  One chip's share trains the router
+# towards the experts it holds (PERF.md, question 15: over a 51 s run layers
+# reach 2 to 15 times their expectation), and the rounds carry that: a layer
+# at 7 times takes four.  At 1 a layer at its expectation would already
+# take two rounds; at 4 every layer would move twice the rows it needs.
+_MOE_HEADROOM = 2
+_MOE_ROW_TILE = 1024            # a round is whole row tiles of the product
+
+
+def moe_capacity(choices, held, num_experts):
+    """The rows one round of ``moe_experts`` gathers, computes and combines,
+    of ``choices`` (tokens x top-k) routed over ``num_experts`` of which this
+    chip holds ``held``: ``_MOE_HEADROOM`` times the held experts' expected
+    share, in whole row tiles; all the choices where every expert is held
+    (``held`` 0 or ``num_experts``).  From shapes and attributes alone."""
+    choices, held, num_experts = int(choices), int(held), int(num_experts)
+    if not 0 < held < num_experts:
+        return choices
+    expected = -(-_MOE_HEADROOM * choices * held // num_experts)
+    return min(choices, -(-expected // _MOE_ROW_TILE) * _MOE_ROW_TILE)
+
+
+def moe_rounds(live, capacity):
+    """The rounds of ``capacity`` rows that ``live`` held choices take (a
+    number on the host, or a traced one)."""
+    return -(-live // capacity)
+
+
+def _moe_part(name):
+    return jax.named_scope("mx:moe:" + name)
+
+
+def _moe_round(r, k, cap, order, sizes, data, weight, gate_weight, up_weight,
+               down_weight):
+    """(tokens [cap], their weighted expert outputs [cap, h] in float32) of
+    the sorted choices ``r * cap .. (r + 1) * cap``.  ``order`` lists the
+    choices (token * k + slot) by held expert, other chips' last; ``sizes``
+    counts each held expert's; ``weight`` [n * k] is each choice's."""
+    lo = r * cap
+    window = lax.dynamic_slice(order, (lo,), (cap,))
+    tokens = window // k
+    ends = jnp.cumsum(sizes)
+    within = lambda edge: jnp.clip(edge, lo, lo + cap)
+    groups = within(ends) - within(ends - sizes)    # each expert's rows here
+    # the rows past the held groups are other chips': the grouped product
+    # leaves them UNWRITTEN on the TPU, in the backward pass too, so each of
+    # its operands and its result is selected, never scaled, to nought there
+    # (PERF.md, PR 27)
+    live = (lo + jnp.arange(cap) < ends[-1])[:, None]
+    mine_only = lambda x: jnp.where(live, x, jnp.zeros((), x.dtype))
+    with _moe_part("gather"):
+        rows = mine_only(data[tokens])              # [cap, h], by expert
+    with _moe_part("experts"):
+        mid = mine_only(_swiglu(lax.ragged_dot(rows, gate_weight, groups),
+                                lax.ragged_dot(rows, up_weight, groups)))
+        out = mine_only(lax.ragged_dot(mid, down_weight, groups)
+                        .astype(_F32)) * weight[window][:, None]
+    return tokens, out
+
+
+def _moe_in_rounds(cap, order, sizes, body, carry):
+    """``carry`` through ``body(r, carry)`` for every round of ``cap`` sorted
+    choices that the live ones reach, and no further; no loop where one
+    round holds every choice."""
+    if cap == order.shape[0]:
+        return body(0, carry)
+    return lax.fori_loop(0, moe_rounds(jnp.sum(sizes), cap), body, carry)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _moe_held(k, cap, order, sizes, data, weight, gate_weight, up_weight,
+              down_weight):
+    """``[n, h]``: each token's held choices, weighted and summed in float32
+    (cast once, to ``data``'s dtype), in rounds of ``cap`` sorted choices, as
+    many as the live ones reach: dropless at any load, and only the rows of
+    the rounds taken are moved.  ``order`` holds a whole number of rounds.
+    The backward takes the same rounds, each recomputed from the inputs and
+    pulled back, so what a round makes does not outlive it."""
+    def added(r, y):
+        tokens, out = _moe_round(r, k, cap, order, sizes, data, weight,
+                                 gate_weight, up_weight, down_weight)
+        with _moe_part("scatter"):
+            return y.at[tokens].add(out)
+    return _moe_in_rounds(cap, order, sizes, added,
+                          jnp.zeros(data.shape, _F32)).astype(data.dtype)
+
+
+def _moe_held_fwd(k, cap, *args):
+    return _moe_held(k, cap, *args), args
+
+
+def _moe_held_bwd(k, cap, args, d_y):
+    order, sizes, *into = args
+
+    def added(r, d):
+        _, pull, tokens = jax.vjp(
+            lambda *into: _moe_round(r, k, cap, order, sizes, *into)[::-1],
+            *into, has_aux=True)
+        with _moe_part("scatter"):
+            d_out = d_y[tokens].astype(_F32)    # what the sum's rows got
+        return jax.tree_util.tree_map(jnp.add, d, pull(d_out))
+
+    d = _moe_in_rounds(cap, order, sizes, added,
+                       tuple(jnp.zeros_like(x) for x in into))
+    # graftlint: disable=GL003 — float0 is numpy's alone: the cotangent of
+    # the integer operands, never on the device
+    none = lambda x: np.zeros(x.shape, jax.dtypes.float0)
+    return (none(order), none(sizes)) + d
+
+
+_moe_held.defvjp(_moe_held_fwd, _moe_held_bwd)
+
+
 def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
                  *rest, num_experts=1, num_hidden=0, experts_held=0,
                  first_expert=0, top_k=1, norm_topk_prob=True,
@@ -421,19 +537,22 @@ def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
     need not add up to anything), and ``route_scale`` scales them.  This op
     holds experts ``first_expert .. first_expert + experts_held`` (weights
     [held, h, num_hidden] twice and [held, num_hidden, h]) and computes
-    their part alone — the rest is other chips'.  Dropless: every (token,
-    held expert) choice is computed, by one grouped product over the
-    choices sorted by expert.  Second output: how many tokens chose each of
-    the ``num_experts`` (no gradient)."""
+    their part alone — the rest is other chips'.  The choices are sorted by
+    expert, the held ones first, and the first ``moe_capacity`` of them are
+    gathered, multiplied by one grouped product and added into their tokens
+    in float32; where more are live than that, further rounds of as many
+    follow (``_moe_held``).  Dropless: every (token, held expert) choice is
+    computed, whatever the load.  Second output: how many tokens chose each
+    of the ``num_experts`` (no gradient)."""
     if score_func not in ("softmax", "sigmoid"):
         raise ValueError("moe_experts: score_func %r is neither 'softmax' "
                          "nor 'sigmoid'" % (score_func,))
-    part = lambda name: jax.named_scope("mx:moe:" + name)
     with jax.named_scope("mx:moe"):
         n, h = data.shape
         held = int(experts_held) or int(num_experts)
         k = int(top_k)
-        with part("route"):
+        cap = moe_capacity(n * k, held, num_experts)
+        with _moe_part("route"):
             logits = jnp.matmul(data.astype(_F32),
                                 router_weight.astype(_F32).T)
             sigmoid = score_func == "sigmoid"
@@ -457,26 +576,11 @@ def _moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
             group = jnp.where(mine, local, held)   # the others sort last
             order = jnp.argsort(group, stable=True)
             sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-            # the rows past the held groups are other chips': the grouped
-            # product leaves them UNWRITTEN on the TPU, in the backward pass
-            # too, so each of its operands and its result is selected, never
-            # scaled, to nought there (PERF.md, PR 27)
-            live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
-        mine_only = lambda x: jnp.where(live, x, jnp.zeros((), x.dtype))
-        with part("gather"):
-            rows = mine_only(data[order // k])     # [n*k, h], by expert
-        with part("experts"):
-            mid = mine_only(_swiglu(lax.ragged_dot(rows, gate_weight, sizes),
-                                    lax.ragged_dot(rows, up_weight, sizes)))
-            weight = jnp.where(mine, top_p.reshape(-1), 0.0)[order]
-            out = mine_only(
-                lax.ragged_dot(mid, down_weight, sizes).astype(_F32)
-                * weight[:, None]).astype(data.dtype)
-        with part("scatter"):
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=order.dtype))
-            y = jnp.sum(out[back].reshape(n, k, h).astype(_F32), axis=1)
-        return y.astype(data.dtype), lax.stop_gradient(counts)
+            # whole rounds: what pads the last one lies past every live row
+            order = jnp.pad(order, (0, -(n * k) % cap))
+        y = _moe_held(k, cap, order, sizes, data, top_p.reshape(-1),
+                      gate_weight, up_weight, down_weight)
+        return y, lax.stop_gradient(counts)
 
 
 def _moe_infer_shape(in_shapes, attrs):

@@ -159,24 +159,57 @@ ENTRY %main (x: f32[2]) -> f32[2] {
     assert table["p"]["mechanism"] == table["n"]["mechanism"] == "mx:a"
 
 
+def test_a_loop_s_event_spans_its_body_s_and_is_left_out():
+    """A ``while`` is marked as holding other computations, and its seconds
+    (which a trace reports beside its body's own events) are summed
+    nowhere: the rows are the body's."""
+    text = """HloModule m
+%body (p: (s32[], f32[2])) -> (s32[], f32[2]) {
+  %p = (s32[], f32[2]{0}) parameter(0)
+  %g = f32[2]{0} get-tuple-element(%p), index=1
+  %n = f32[2]{0} negate(%g), metadata={op_name="jit(f)/mx:moe/while/body/mx:moe:gather/neg"}
+  ROOT %t = (s32[], f32[2]{0}) tuple(%p, %n)
+}
+ENTRY %main (x: (s32[], f32[2])) -> (s32[], f32[2]) {
+  %x = (s32[], f32[2]{0}) parameter(0)
+  %while.1 = (s32[], f32[2]{0}) while(%x), condition=%cond, body=%body, metadata={op_name="jit(f)/mx:moe/while"}
+  ROOT %copy.2 = (s32[], f32[2]{0}) copy(%while.1)
+}
+"""
+    table = instrument.scopes_of_hlo(text)
+    assert table["while.1"]["holds"] and table["while.1"]["detail"] == "mx:moe"
+    assert not any(row.get("holds") for name, row in table.items()
+                   if name != "while.1")
+    rows = instrument.device_seconds_by_scope(
+        {"while.1 while": 3.0, "n negate": 2.5, "copy.2 copy": 0.25}, table)
+    by = {(r["detail"], r["pass"]): r["seconds"] for r in rows}
+    assert by == {("mx:moe:gather", "forward"): 2.5,
+                  ("mx:moe", "forward"): 0.25}
+
+
 # -- every op of a step program -------------------------------------------------------
 
 EXPECTED = {
     "qwen3next": [("mx:attn", "mx:attn:full"), ("mx:gdn", "mx:gdn"),
                   ("mx:gdn", "mx:gdn:local"), ("mx:gdn", "mx:gdn:scan"),
-                  ("mx:moe", "mx:moe:route"), ("mx:moe", "mx:moe:gather"),
-                  ("mx:moe", "mx:moe:experts"), ("mx:moe", "mx:moe:scatter"),
-                  ("mx:moe", "mx:moe:shared"), ("mx:op", "mx:op:RMSNorm"),
+                  ("mx:moe", "mx:moe:route"), ("mx:moe", "mx:moe:shared"),
+                  ("mx:op", "mx:op:RMSNorm"),
                   ("mx:op", "mx:op:FullyConnected")],
+    # a norm reads the expert layer's output here, so its backward needs it
+    # and the stage runs the rounds again; the other two add it to the
+    # residual stream and recompute the routing alone
     "trinity": [("mx:attn", "mx:attn:window"), ("mx:attn", "mx:attn:full"),
                 ("mx:mlp", "mx:mlp"), ("mx:moe", "mx:moe:gather"),
                 ("mx:moe", "mx:moe:experts"), ("mx:moe", "mx:moe:scatter"),
                 ("mx:moe", "mx:moe:shared")],
     "joyai": [("mx:attn", "mx:attn:full"), ("mx:mla", "mx:mla"),
               ("mx:mlp", "mx:mlp"), ("mx:mtp", "mx:mtp"),
-              ("mx:moe", "mx:moe:gather"), ("mx:moe", "mx:moe:scatter"),
-              ("mx:moe", "mx:moe:shared")],
+              ("mx:moe", "mx:moe:route"), ("mx:moe", "mx:moe:shared")],
 }
+# the rounds of the expert layer: the backward runs each round's forward
+# itself and pulls it back (``lm_ops._moe_held``), all of it ``backward``
+ROUNDS = [("mx:moe", "mx:moe:gather"), ("mx:moe", "mx:moe:experts"),
+          ("mx:moe", "mx:moe:scatter")]
 # outside every mirror stage: never recomputed
 UNMIRRORED = [("mx:head", "mx:head"), ("mx:embed", "mx:embed")]
 
@@ -187,7 +220,8 @@ def test_every_instruction_maps_and_few_to_none(which, steps):
     text, table = step.compiled_hlo(), step.op_scopes()
     found = [m for m in map(INSTRUCTION.match, text.splitlines()) if m]
     assert len(found) > 100 and {m.group(1) for m in found} == set(table)
-    assert all(set(row) - {"own"} == {"path", "mechanism", "detail", "pass"}
+    assert all(set(row) - {"own", "holds"}
+               == {"path", "mechanism", "detail", "pass"}
                and row["pass"] in ("forward", "recomputed", "backward")
                for row in table.values())
     runs = [m.group(1) for m in found if m.group(2) not in FREE]
@@ -207,6 +241,8 @@ def test_each_mechanism_forward_and_backward_recomputed_in_stages(which,
     for key in EXPECTED[which]:
         assert seen[key] >= {"forward", "backward", "recomputed"}, \
             (key, seen[key])
+    for key in ROUNDS:
+        assert seen[key] >= {"forward", "backward"}, (key, seen[key])
     for key in UNMIRRORED:
         assert seen[key] == {"forward", "backward"}, (key, seen[key])
     assert seen["mx:update", "mx:update"] == {"forward"}
@@ -309,6 +345,56 @@ def test_the_program_is_the_same_with_the_scopes_stripped(which, steps,
     stripped = _opcodes(step.compiled_hlo())
     assert len(stripped) == len(with_scopes)
     assert collections.Counter(stripped) == collections.Counter(with_scopes)
+
+
+def test_the_resnet_step_holds_nothing_of_the_expert_layer(steps,
+                                                          monkeypatch):
+    """The cells that bypass the expert layer: no instruction of the ResNet
+    step lies under ``mx:moe``, and its program is the same, opcode for
+    opcode, with the expert layer's code taken away."""
+    with_experts = _opcodes(steps("resnet").compiled_hlo())
+    assert not any(row["mechanism"] == "mx:moe"
+                   for row in steps("resnet").op_scopes().values())
+
+    def gone(*args, **kwargs):
+        raise AssertionError("the ResNet step reached moe_experts")
+    for name in ("_moe_experts", "_moe_held", "_moe_round", "moe_capacity"):
+        monkeypatch.setattr(lm_ops, name, gone)
+    without = _opcodes(_fit_resnet()._fused_step.compiled_hlo())
+    assert without == with_experts
+
+
+def test_the_rounds_row_moves_keep_their_scopes_inside_the_loops():
+    """A layer of four rounds (tests/test_moe_rows.py's), forward and
+    backward under ``jax.checkpoint``: the forward's and the backward's
+    ``while`` bodies hold the row moves under ``mx:moe:gather`` and
+    ``mx:moe:scatter``, the backward's with the transposes (a scatter-add of
+    the rows' gradient under ``gather``, a gather of the output's gradient
+    under ``scatter``), all ``backward``: no round is ``recomputed``."""
+    import test_moe_rows as rows
+    x, w = rows._layer(3)
+    layer = jax.checkpoint(lambda x, w: lm_ops._moe_experts(
+        x, w["router"], w["gate"], w["up"], w["down"], num_experts=rows.E,
+        num_hidden=rows.I, experts_held=rows.HELD, first_expert=rows.FIRST,
+        top_k=rows.K)[0])
+    text = jax.jit(jax.grad(lambda x, w: jnp.sum(layer(x, w) ** 2),
+                            argnums=(0, 1))).lower(x, w).compile().as_text()
+    assert text.count(" while(") == 2
+    names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if "/while/body/" in n and "mx:moe:" in n]
+    seen = collections.defaultdict(set)
+    for n in names:
+        row = instrument.scope_of_op_name(n)
+        seen[row["detail"], row["pass"]].add(n.rsplit("/", 1)[-1])
+    assert "gather" in seen["mx:moe:gather", "forward"]
+    assert "scatter-add" in seen["mx:moe:scatter", "forward"]
+    assert {"gather", "scatter-add"} <= seen["mx:moe:gather", "backward"]
+    # the output's gradient is gathered; the round's own sum is dead there
+    assert "gather" in seen["mx:moe:scatter", "backward"]
+    assert "scatter-add" not in seen["mx:moe:scatter", "backward"]
+    assert seen["mx:moe:experts", "forward"] \
+        and seen["mx:moe:experts", "backward"]
+    assert not any(p == "recomputed" for _, p in seen)
 
 
 def test_compiled_hlo_is_compiled_once(steps, monkeypatch):

@@ -298,3 +298,34 @@ def test_compiled_kernels_carry_their_scope_and_pass(v5e_device):
     # backward's ``jax.vjp(_chunk_local)``: one of the two names survives)
     assert "backward" in {r["pass"] for r in table.values()
                           if r["detail"] == "mx:gdn:local"}
+
+
+@pytest.mark.slow
+def test_compiled_expert_layer_moves_no_array_of_all_the_choices(v5e_device):
+    """The expert layer of `qwen3next-train-s8k-b2` (16,384 tokens x top-10
+    over 512 experts, 32 held), forward and backward under
+    ``jax.checkpoint``, compiled for the v5e: no gather, scatter or select,
+    alone or in a fusion, makes an array of tokens x top-k rows of the
+    hidden or the expert width: the rows moved are a round's 20,480; and
+    both loops over the rounds are there."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    n, h, experts, held, k, width = 16384, 2048, 512, 32, 10, 512
+    cap = lm_ops.moe_capacity(n * k, held, experts)
+    assert cap == 20480
+    layer = jax.checkpoint(lambda x, r, g, u, d: lm_ops._moe_experts(
+        x, r, g, u, d, num_experts=experts, num_hidden=width,
+        experts_held=held, first_expert=held, top_k=k)[0])
+    avals = [_aval(s, "bfloat16") for s in (
+        (n, h), (experts, h), (held, h, width), (held, h, width),
+        (held, width, h))]
+    text = jax.jit(
+        jax.grad(lambda *a: jnp.sum(layer(*a).astype(jnp.float32) ** 2),
+                 argnums=(0, 1, 2, 3, 4)),
+        in_shardings=(SingleDeviceSharding(v5e_device),) * 5).trace(*avals) \
+        .lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert not re.search(r"\[%d,(%d|%d)\]" % (n * k, h, width), text)
+    assert not re.search(r"\[%d,%d,(%d|%d)\]" % (n, k, h, width), text)
+    assert re.search(r"(bf16|f32)\[%d,%d\]\S* gather\(" % (cap, h), text)
+    assert re.search(r"f32\[%d,%d\]\S* scatter\(" % (n, h), text)
+    assert text.count(" while(") == 2
