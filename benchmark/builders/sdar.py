@@ -1,0 +1,13 @@
+"""sdar-30b-a3b-ep8-bf16 and its kin -> the program's objects."""
+from __future__ import annotations
+
+
+def symbol(cfg):
+    from mxnet_tpu import models
+    from .. import harness
+    if not hasattr(models, "sdar"):
+        # a checkout from before the model (the parent of the PR that added
+        # the cell): say so at once instead of failing somewhere inside
+        raise harness.Refused("this checkout's mxnet_tpu has no "
+                              "models.sdar: it cannot run %s" % cfg["name"])
+    return models.sdar.get_symbol(cfg, dtype=cfg["precision"]["compute"])

@@ -250,6 +250,8 @@ class _Program:
                 widths["v_width"] = int(at[n.inputs[2]][-1])
                 if attrs.get("use_shared_key"):
                     widths["shared_width"] = int(at[n.inputs[-1]][-1])
+                widths["block_diffusion"] = int(
+                    attrs.get("block_diffusion") or 0)
             c, v = pallas_kernels.attention_pairs(
                 q, k, kinds[n.inputs[0]], bool(attrs["causal"]),
                 int(attrs.get("window") or 0), **widths)

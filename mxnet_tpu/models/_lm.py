@@ -1,10 +1,11 @@
 """What the decoder language models share (``qwen3_next.py``, ``trinity.py``,
-``joyai_flash.py``): parameters in the storage dtype (one variable a name, so
-that two nodes may read one parameter and its gradient is the sum of both
-uses), bias-free projections over ``[batch, seq, hidden]``, RMSNorm, a SwiGLU
-MLP, the expert layer's call, the mirror stage of half a block, and the tail
-that turns the last hidden state (and, where a model has one, a second loss
-head on the same embedding and output matrix) into the symbol's outputs."""
+``joyai_flash.py``, ``sdar.py``): parameters in the storage dtype (one
+variable a name, so that two nodes may read one parameter and its gradient is
+the sum of both uses), bias-free projections over ``[batch, seq, hidden]``,
+RMSNorm, a SwiGLU MLP, the expert layer's call, the mirror stage of half a
+block, and the tail that turns the last hidden state (and, where a model has
+one, a second loss head on the same embedding and output matrix) into the
+symbol's outputs."""
 from __future__ import annotations
 
 from .. import symbol as sym
@@ -123,16 +124,29 @@ class LMBuilder:
         [batch] (``token_loss``) to the one that is trained, ``main + weight *
         loss``, and a third output: the two parts stacked without gradient,
         which ``update_metric`` adds to the two counters named."""
-        cfg = self.cfg
         main = self.token_loss(x)
         total = main if second is None else main + float(second[0]) * second[1]
         loss = sym.MakeLoss(total, name="loss")
-        counts = sym.BlockGrad(sym.stack(*counts, axis=0), name="moe_counts")
-        counts._set_attr(__moe_counts__="%d,%d" % (
-            int(cfg.get("first_expert", 0)), int(cfg["num_experts"])))
+        counts = self.moe_counts(counts)
         if second is None:
             return sym.Group([loss, counts])
-        parts = sym.BlockGrad(sym.stack(main, second[1], axis=0),
-                              name="loss_parts")
-        parts._set_attr(__counters__=",".join(second[2]))
-        return sym.Group([loss, counts, parts])
+        return sym.Group([loss, counts, self.counter_rows(
+            (main, second[1]), second[2], "loss_parts")])
+
+    def moe_counts(self, counts):
+        """The expert layers' selection counts stacked without gradient and
+        marked for ``Module.update_metric`` (first expert, experts held)."""
+        out = sym.BlockGrad(sym.stack(*counts, axis=0), name="moe_counts")
+        cfg = self.cfg
+        out._set_attr(__moe_counts__="%d,%d" % (
+            int(cfg.get("first_expert", 0)), int(cfg["num_experts"])))
+        return out
+
+    @staticmethod
+    def counter_rows(rows, names, name):
+        """``rows`` (each [batch]) stacked without gradient as the node
+        ``name``, which ``update_metric`` adds, each row's mean over the
+        batch, to the counter of the same place in ``names``."""
+        out = sym.BlockGrad(sym.stack(*rows, axis=0), name=name)
+        out._set_attr(__counters__=",".join(names))
+        return out

@@ -13,8 +13,8 @@ Two ops:
 - ``scaled_dot_product_attention``: pre-split heads, q/k/v as
   [batch, seq, heads, head_dim] (K/V may hold fewer heads: grouped
   queries; v may be narrower or wider than q and k; a part of the key may
-  be one that all heads share); causal + padding masks, and a causal
-  ``window``.
+  be one that all heads share); causal + padding masks, a causal
+  ``window``, and the block-diffusion mask.
 - ``multi_head_attention``: fused qkv/out projections around the same
   core — one node carries the full attention block so the kernel flag
   (``MXNET_TPU_PALLAS_ATTN``) swaps the entire fast path at bind time.
@@ -56,7 +56,8 @@ def _note_logit_bound(q, k, scale, k_shared=None):
 
 
 def _sdpa(query, key, value, *rest, causal=False, scale=0.0,
-          use_lengths=False, window=0, use_shared_key=False):
+          use_lengths=False, window=0, use_shared_key=False,
+          block_diffusion=0):
     """``softmax(q k^T * scale + mask) v`` for q [batch, seq, heads,
     d_qk] on k [batch, keys, kv heads, d_qk] and v [batch, keys, kv heads,
     d_v]: the result is [batch, seq, heads, d_v], and ``scale`` 0 means
@@ -67,17 +68,23 @@ def _sdpa(query, key, value, *rest, causal=False, scale=0.0,
     input, the padding mask; ``use_shared_key`` adds ``key_shared`` [batch,
     keys, d_s], a part of the key that every head shares: ``key`` is then
     ``d_s`` narrower than ``query``, whose last ``d_s`` columns are scored
-    against it, and the kernels read it once instead of a copy a head."""
+    against it, and the kernels read it once instead of a copy a head.
+    ``block_diffusion`` (0 = none; takes no ``causal``, ``window`` or
+    lengths): the sequence is a noisy copy and a clean copy of ``seq / 2``
+    positions, in blocks of ``block_diffusion``, under the block-diffusion
+    mask (``pallas_kernels._bd_visible``)."""
     kv_lens = rest[0] if use_lengths else None
     k_shared = rest[-1] if use_shared_key else None
     _note_logit_bound(query, key, scale, k_shared)
     window = _pk.checked_window(window, causal, key.shape[1])
-    with jax.named_scope("mx:attn"), jax.named_scope(
-            "mx:attn:window" if window else "mx:attn:full"):
+    kind = "mx:attn:bd" if block_diffusion else (
+        "mx:attn:window" if window else "mx:attn:full")
+    with jax.named_scope("mx:attn"), jax.named_scope(kind):
         return _pk.attention(query, key, value, causal=causal,
                              scale=(scale if scale else None),
                              kv_lens=kv_lens, window=window,
-                             k_shared=k_shared)
+                             k_shared=k_shared,
+                             block_diffusion=block_diffusion)
 
 
 def _sdpa_infer_shape(in_shapes, attrs, out_shapes=None):
@@ -121,7 +128,8 @@ register("scaled_dot_product_attention", _sdpa,
          infer_type=_sdpa_infer_type,
          params={"causal": (pBool, False), "scale": (pFloat, 0.0),
                  "use_lengths": (pBool, False), "window": (pInt, 0),
-                 "use_shared_key": (pBool, False)})
+                 "use_shared_key": (pBool, False),
+                 "block_diffusion": (pInt, 0)})
 
 
 def _mha(query, key, value, q_weight, q_bias, k_weight, k_bias, v_weight,

@@ -52,12 +52,14 @@ register("RMSNorm", _rms_norm, input_names=("data", "gamma"),
 # -- rotary positions -----------------------------------------------------------
 
 def _rotary_embedding(data, rotary_dim=0, base=10000.0, interleaved=False,
-                      offset=0):
+                      offset=0, segments=1):
     """Rotary positions on ``rotary_dim`` dims of every head of ``[batch,
     seq, heads, head_dim]``, from dim ``offset`` on (all dims from there
-    when 0); the position of a row is its index along ``seq``.  Frequency
-    ``i`` turns the pair of dims ``(i, i + rotary_dim / 2)`` (rotate-half) or,
-    where ``interleaved``, the neighbours ``(2i, 2i + 1)``."""
+    when 0); the position of a row is its index along ``seq`` or, where the
+    sequence is ``segments`` equal parts (a noisy copy of a sequence and its
+    clean copy are two), its index within its part.  Frequency ``i`` turns
+    the pair of dims ``(i, i + rotary_dim / 2)`` (rotate-half) or, where
+    ``interleaved``, the neighbours ``(2i, 2i + 1)``."""
     d = int(data.shape[-1])
     lo = int(offset)
     rd = int(rotary_dim) or d - lo
@@ -66,8 +68,12 @@ def _rotary_embedding(data, rotary_dim=0, base=10000.0, interleaved=False,
     # alone: a float64 table made at trace time, a constant of the program
     inv_freq = 1.0 / (float(base) ** (np.arange(0, rd, 2, dtype=np.float64)
                                       / rd))
+    seq, parts = int(data.shape[1]), int(segments)
+    if seq % parts:
+        raise ValueError("rotary_embedding: %d rows are not %d equal parts"
+                         % (seq, parts))
     # graftlint: disable=GL003 — as above
-    ang = np.arange(int(data.shape[1]), dtype=np.float64)[:, None] \
+    ang = (np.arange(seq) % (seq // parts)).astype(np.float64)[:, None] \
         * inv_freq[None, :]
     cos = jnp.asarray(np.cos(ang), _F32)[None, :, None, :]
     sin = jnp.asarray(np.sin(ang), _F32)[None, :, None, :]
@@ -88,7 +94,8 @@ def _rotary_embedding(data, rotary_dim=0, base=10000.0, interleaved=False,
 
 register("rotary_embedding", _rotary_embedding, num_inputs=1,
          params={"rotary_dim": (pInt, 0), "base": (pFloat, 10000.0),
-                 "interleaved": (pBool, False), "offset": (pInt, 0)})
+                 "interleaved": (pBool, False), "offset": (pInt, 0),
+                 "segments": (pInt, 1)})
 
 
 # -- SwiGLU -------------------------------------------------------------------
@@ -614,63 +621,78 @@ register("moe_experts", _moe_experts, num_outputs=2,
 
 # -- softmax cross-entropy, one number a sequence ---------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _seq_ce(logits, label, shift):
-    return _seq_ce_fwd(logits, label, shift)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _seq_ce(logits, label, weight, shift):
+    return _seq_ce_fwd(logits, label, weight, shift)[0]
 
 
-def _seq_ce_targets(label, shift):
+def _seq_ce_targets(label, weight, shift):
     """(target ids [.., seq], the float32 weight of each position or None
-    where all count alike, how many count): position ``i``'s target is
-    ``label[i + shift]``, and the last ``shift`` positions have none."""
+    where all count alike, the count the sum is divided by): position
+    ``i``'s target is ``label[i + shift]``, and the last ``shift`` positions
+    have none; ``weight`` [.., seq] (or None) weighs each position."""
     idx = label.astype(jnp.int32)
     n = idx.shape[-1]
+    live = None if weight is None else weight.astype(_F32)
     if not shift:
-        return idx, None, n
+        return idx, live, n
     idx = jnp.concatenate([idx[..., shift:],
                            jnp.zeros_like(idx[..., :shift])], axis=-1)
-    return idx, (jnp.arange(n) < n - shift).astype(_F32), n - shift
+    keep = (jnp.arange(n) < n - shift).astype(_F32)
+    return idx, keep if live is None else live * keep, n - shift
 
 
-def _seq_ce_fwd(logits, label, shift):
+def _seq_ce_fwd(logits, label, weight, shift):
     x = logits.astype(_F32)
-    idx, live, n = _seq_ce_targets(label, shift)
+    idx, live, n = _seq_ce_targets(label, weight, shift)
     lse = jax.nn.logsumexp(x, axis=-1)
     picked = jnp.take_along_axis(x, idx[..., None], axis=-1)[..., 0]
     loss = jnp.mean(lse - picked, axis=-1) if live is None \
         else jnp.sum((lse - picked) * live, axis=-1) / n
-    return loss, (logits, label, lse)
+    return loss, (logits, label, weight, lse)
+
+
+def _no_cotangent(x):
+    """The zero cotangent of an input that gets no gradient: zeros of a
+    float, numpy's float0 of an integer (never on the device)."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.zeros_like(x)
+    # graftlint: disable=GL003 — float0 is numpy's alone: an integer input's
+    # cotangent, never on the device
+    return np.zeros(x.shape, jax.dtypes.float0)
 
 
 def _seq_ce_bwd(shift, res, g):
-    """softmax minus one-hot, straight into the logits' dtype: no float32
-    copy of the probabilities is kept between the passes."""
-    logits, label, lse = res
-    idx, live, n = _seq_ce_targets(label, shift)
+    """softmax minus one-hot, times each position's weight, straight into the
+    logits' dtype: no float32 copy of the probabilities is kept between the
+    passes.  The labels and the weights get no gradient."""
+    logits, label, weight, lse = res
+    idx, live, n = _seq_ce_targets(label, weight, shift)
     scale = (g / n).astype(_F32)[..., None, None]
     if live is not None:
         scale = scale * live[..., None]
     p = jnp.exp(logits.astype(_F32) - lse[..., None])
     hot = idx[..., None] == jnp.arange(logits.shape[-1], dtype=jnp.int32)
     d = ((p - hot.astype(_F32)) * scale).astype(logits.dtype)
-    if jnp.issubdtype(label.dtype, jnp.floating):
-        return d, jnp.zeros_like(label)
-    # graftlint: disable=GL003 — float0 is numpy's alone: an integer label's
-    # cotangent, never on the device
-    return d, np.zeros(label.shape, jax.dtypes.float0)
+    return (d, _no_cotangent(label),
+            None if weight is None else _no_cotangent(weight))
 
 
 _seq_ce.defvjp(_seq_ce_fwd, _seq_ce_bwd)
 
 
-def _sequence_cross_entropy(data, label, shift=0):
+def _sequence_cross_entropy(data, label, *rest, shift=0, use_weight=False):
     """Mean over positions of ``-log softmax(data)[label]`` for ``data``
     [batch, seq, vocab] and integer-valued ``label`` [batch, seq]: float32
     [batch], whatever the logits' dtype.  Under ``shift`` position ``i`` is
     held against ``label[i + shift]`` and the mean is over the ``seq -
     shift`` positions that have such a target (a head that predicts further
-    ahead, on the same labels)."""
-    return _seq_ce(data, lax.stop_gradient(label), int(shift))
+    ahead, on the same labels).  ``use_weight`` adds the input ``weight``
+    [batch, seq]: each position's term is multiplied by it (0 leaves the
+    position out) and the sum still divided by the positions, the weighted
+    mean a masked diffusion loss takes; the weight gets no gradient."""
+    weight = lax.stop_gradient(rest[0]) if use_weight else None
+    return _seq_ce(data, lax.stop_gradient(label), weight, int(shift))
 
 
 def _seq_ce_infer_shape(in_shapes, attrs):
@@ -678,11 +700,19 @@ def _seq_ce_infer_shape(in_shapes, attrs):
     d = in_shapes[0]
     if d is None:
         return filled, [None]
-    filled[1] = tuple(d[:-1])
+    filled[1:] = [tuple(d[:-1])] * (len(filled) - 1)
     return filled, [(int(d[0]),)]
 
 
+def _seq_ce_infer_type(in_dtypes, attrs):
+    filled = list(in_dtypes)
+    if len(filled) > 2 and filled[2] is None:
+        filled[2] = np.float32
+    return filled, [np.float32]
+
+
 register("sequence_cross_entropy", _sequence_cross_entropy,
-         input_names=("data", "label"), infer_shape=_seq_ce_infer_shape,
-         infer_type=lambda in_dtypes, attrs: (list(in_dtypes), [np.float32]),
-         params={"shift": (pInt, 0)})
+         input_names=("data", "label", "weight"),
+         num_inputs=lambda attrs: 2 + bool(attrs.get("use_weight")),
+         infer_shape=_seq_ce_infer_shape, infer_type=_seq_ce_infer_type,
+         params={"shift": (pInt, 0), "use_weight": (pBool, False)})

@@ -36,8 +36,56 @@ def _causal_mask(n_q, n_k, window=0):
     return mask
 
 
+def _bd_div(x, block):
+    """``x // block`` for non-negative ``x``: a shift where ``block`` is a
+    power of two (what a vector unit does without a divider)."""
+    if isinstance(x, (int, np.integer)) or block & (block - 1):
+        return x // block
+    return jax.lax.shift_right_logical(x, jnp.int32(block.bit_length() - 1))
+
+
+def _bd_visible(pos, cols, diffusion):
+    """Whether key ``cols`` is visible to query ``pos`` under the
+    block-diffusion mask ``diffusion = (block, half)``: positions below
+    ``half`` are the noisy copy of the sequence and the rest its clean copy,
+    both counted from 0 in blocks of ``block``.  A noisy query sees the noisy
+    keys of its own block and the clean keys of the blocks before it; a
+    clean query sees the clean keys of its block and those before it; no
+    query sees a noisy key of another block.  Plain integer arithmetic, on
+    arrays or scalars."""
+    block, half = diffusion
+    q_noisy, k_noisy = pos < half, cols < half
+    qb = _bd_div(jnp.where(q_noisy, pos, pos - half), block)
+    kb = _bd_div(jnp.where(k_noisy, cols, cols - half), block)
+    same = qb == kb
+    # boolean algebra alone: Mosaic selects no boolean vectors
+    return (k_noisy & q_noisy & same) \
+        | (~k_noisy & ((kb < qb) | (same & ~q_noisy)))
+
+
+def _bd_mask(n, block):
+    """[n, n] bool: the block-diffusion mask of ``n = 2 * half`` positions."""
+    pos = jnp.arange(n, dtype=jnp.int32)
+    return _bd_visible(pos[:, None], pos[None, :], (block, n // 2))
+
+
+def checked_diffusion(block, causal, window, kv_lens, sq, sk):
+    """``(block, half)`` as the kernels take the block-diffusion mask, or None
+    where ``block`` is 0.  The mask is a whole mask of its own: it takes no
+    causal flag, window or padding mask, and q and k are one sequence of
+    ``2 * half`` positions."""
+    block = int(block or 0)
+    if not block:
+        return None
+    if causal or window or kv_lens is not None or sq != sk or sk % 2:
+        raise ValueError(
+            "attention: block_diffusion takes one even sequence (%d queries, "
+            "%d keys) and no causal flag, window or kv_lens" % (sq, sk))
+    return block, sk // 2
+
+
 def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0,
-                         k_shared=None):
+                         k_shared=None, diffusion=None):
     """[B, S, H, D] exact attention — the fallback + test oracle.  ``v``
     may be narrower or wider than ``q`` and ``k``: the result has its width.
 
@@ -46,16 +94,20 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0,
     ``window`` (needs ``causal``; 0 = none): a query sees its own position
     and the ``window - 1`` before it.  ``k_shared`` [B, S, d_s]: a part of
     the key that every head shares, scored against the LAST ``d_s`` columns
-    of ``q``; here it is simply copied to every K/V head."""
+    of ``q``; here it is simply copied to every K/V head.  ``diffusion``
+    ``(block, half)``: the block-diffusion mask (:func:`_bd_visible`) in
+    place of any other."""
     if k_shared is not None:
         k = jnp.concatenate([k, jnp.broadcast_to(
             k_shared[:, :, None, :].astype(k.dtype),
             k.shape[:3] + k_shared.shape[-1:])], axis=-1)
     if q.shape[2] != k.shape[2]:
         return _reference_attention_grouped(q, k, v, causal, scale, kv_lens,
-                                            window)
+                                            window, diffusion)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     n_q, n_k = q.shape[1], k.shape[1]
+    if diffusion:
+        s = jnp.where(_bd_mask(n_k, diffusion[0])[None, None], s, _NEG_INF)
     if causal:
         mask = _causal_mask(n_q, n_k, window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
@@ -68,7 +120,7 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, window=0,
 
 
 def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None,
-                                 window=0):
+                                 window=0, diffusion=None):
     """The same for grouped-query attention: ``q`` has a multiple of the
     K/V heads, and K/V head ``j`` serves query heads ``j*group ..
     (j+1)*group`` — by index, no head is repeated in memory."""
@@ -76,6 +128,8 @@ def _reference_attention_grouped(q, k, v, causal, scale, kv_lens=None,
     n_k, kv = k.shape[1], k.shape[2]
     qg = q.reshape(b, n_q, kv, h // kv, d)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
+    if diffusion:
+        s = jnp.where(_bd_mask(n_k, diffusion[0]), s, _NEG_INF)
     if causal:
         s = jnp.where(_causal_mask(n_q, n_k, window), s, _NEG_INF)
     if kv_lens is not None:
@@ -109,6 +163,106 @@ def _first_kv_tile(q_idx, block_q, block_k, window):
     return jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
 
 
+# Under the block-diffusion mask a tile of one side needs the tiles of the
+# other side in TWO runs: a noisy q tile needs the noisy keys of its own
+# blocks and the clean keys of the blocks before them; a clean K/V tile is
+# seen by the noisy queries of the later blocks and by the clean queries of
+# its blocks and the later ones.  The kernels walk the first run and then the
+# second along their innermost grid axis, which is as long as the longest
+# pair of runs; a step past both stays on the last tile (no DMA) and computes
+# nothing.  Each run holds exactly the tiles with a visible pair, and a tile
+# that lies across the two halves is in the first run alone.  ``xp`` is
+# ``jnp`` for the traced scalars of a kernel or an index map, ``np`` for the
+# counts a plan makes in Python.
+
+def _bd_kv_runs(q_idx, block_q, block_k, diffusion, xp=jnp):
+    """(first tile, count, first tile, count) of the two runs of K/V tiles
+    that q tile ``q_idx`` needs: the noisy keys of the blocks its noisy rows
+    lie in, then the clean keys from the first one up to where its rows
+    stop seeing them."""
+    block, half = diffusion
+    r0 = q_idx * block_q
+    r1 = r0 + block_q - 1
+    noisy = r0 < half
+    last_noisy = xp.minimum(r1, half - 1)
+    n_lo = (r0 // block) * block // block_k
+    n_hi = (xp.minimum((last_noisy // block + 1) * block, half) - 1) // block_k
+    # clean keys half .. half + ends - 1: a noisy row sees the blocks before
+    # its own, a clean row its own too
+    ends = xp.maximum(
+        xp.where(noisy, (last_noisy // block) * block, 0),
+        xp.where(r1 >= half, xp.minimum(
+            (xp.maximum(r1 - half, 0) // block + 1) * block, half), 0))
+    c_lo = xp.where(noisy, xp.maximum(half // block_k, n_hi + 1),
+                    half // block_k)
+    c_hi = (half + ends - 1) // block_k
+    return (n_lo, xp.where(noisy, n_hi - n_lo + 1, 0), c_lo,
+            xp.where(ends > 0, xp.maximum(c_hi - c_lo + 1, 0), 0))
+
+
+def _bd_q_runs(kv_idx, kv_len, n_q, block_q, block_k, diffusion, xp=jnp):
+    """(first tile, count, first tile, count) of the two runs of q tiles that
+    see a key of K/V tile ``kv_idx`` (keys past ``kv_len`` are padding): the
+    noisy queries of its noisy keys' blocks and of the blocks after its first
+    clean key's, then the clean queries from that key's block on."""
+    block, half = diffusion
+    k0 = kv_idx * block_k
+    k1 = xp.minimum(k0 + block_k, kv_len) - 1
+    real = k0 < kv_len
+    noisy = real & (k0 < half)
+    clean = real & (k1 >= half)
+    # noisy queries of the noisy keys' own blocks
+    a_lo = (k0 // block) * block // block_q
+    a_hi = (xp.minimum((xp.minimum(k1, half - 1) // block + 1) * block, half)
+            - 1) // block_q
+    # ... and of the blocks after the first clean key's (a tile across the
+    # halves holds key half - 1, so the two ranges meet)
+    first = (xp.maximum(k0, half) - half) // block
+    after = (first + 1) * block
+    later = clean & (after < half)
+    lo1 = xp.where(noisy, xp.where(later, xp.minimum(a_lo, after // block_q),
+                                   a_lo), after // block_q)
+    hi1 = xp.where(later, (half - 1) // block_q, a_hi)
+    n1 = xp.where(noisy | later, hi1 - lo1 + 1, 0)
+    lo2 = (half + first * block) // block_q
+    lo2 = xp.where(n1 > 0, xp.maximum(lo2, hi1 + 1), lo2)
+    n2 = xp.where(clean, xp.maximum(n_q - lo2, 0), 0)
+    return lo1, n1, lo2, n2
+
+
+def _bd_step_tile(step, first1, n1, first2, n2, xp=jnp):
+    """The tile of grid step ``step`` over two runs: the first's, then the
+    second's, and past both the last one needed."""
+    last = xp.where(n2 > 0, first2 + n2 - 1, first1 + xp.maximum(n1 - 1, 0))
+    return xp.minimum(xp.where(step < n1, first1 + step, first2 + step - n1),
+                      last)
+
+
+def _kv_step(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
+             diffusion):
+    """(the K/V tile of grid step ``kv_idx`` of q tile ``q_idx``, whether the
+    step computes it) in the forward and dq: under causal the steps stop at
+    the diagonal's tile and under a window start at its first; under the
+    block-diffusion mask they walk the two runs of :func:`_bd_kv_runs`.
+    Tiles wholly past the valid length are not needed either."""
+    if diffusion:
+        runs = _bd_kv_runs(q_idx, block_q, block_k, diffusion)
+        return (_bd_step_tile(kv_idx, *runs),
+                (kv_len > 0) & (kv_idx < runs[1] + runs[3]))
+    needed = (kv_len > 0) & (
+        kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
+    if window:
+        needed &= kv_idx >= _first_kv_tile(q_idx, block_q, block_k, window)
+    return kv_idx, needed
+
+
+def _bd_steps(n_tiles, runs):
+    """The longest pair of runs over the ``n_tiles`` tiles of one side: the
+    length of the grid axis that walks the other side's tiles."""
+    counts = [runs(i) for i in range(n_tiles)]
+    return max(1, max(int(r[1] + r[3]) for r in counts))
+
+
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 _NN = (((1,), (0,)), ((), ()))     # a @ b
 
@@ -140,7 +294,7 @@ def _tile_scores(qs, ks, keys_first=False):
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest,
                   causal, scale, block_q, block_k, group, n_kv_blocks,
-                  emit_lse, window=0, shared=False):
+                  emit_lse, window=0, shared=False, diffusion=None):
     """One (q-block, kv-block) grid step.  Grid = (B*KV, n_q, n_kv) with
     the kv dimension innermost; m/l/acc scratch persists across kv steps of
     the same q block (standard flash-attention accumulation).  The q tile
@@ -151,7 +305,9 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest,
     lengths (SMEM): the padding mask, and the bound that makes block-padded
     sequences exact.  q and k share the score width, v and the output the
     value width; ``shared`` adds ``ks_ref``, the key part all heads share
-    (:func:`_tile_scores`)."""
+    (:func:`_tile_scores`).  Under ``diffusion`` (the block-diffusion mask)
+    the kv axis walks the two runs of :func:`_bd_kv_runs` and is
+    ``n_kv_blocks`` steps long."""
     ks_ref = rest[0] if shared else None
     o_ref, *rest = rest[bool(shared):]
     if emit_lse:
@@ -176,10 +332,8 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest,
     # the diagonal (or wholly below the window) contribute no weight: the
     # body skips them here and the K/V index map (_call_flash) never moves
     # to them
-    needed = (kv_len > 0) & (
-        kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
-    if window:
-        needed &= kv_idx >= _first_kv_tile(q_idx, block_q, block_k, window)
+    tile, needed = _kv_step(q_idx, kv_idx, kv_len, block_q, block_k, causal,
+                            window, diffusion)
 
     @pl.when(needed)
     def _compute():
@@ -194,12 +348,15 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest,
         # a row's position is q_idx*block_q + (row mod block_q): the mask
         # is one [block_q, block_k] tile that every head of the group
         # shares, added as 0 / -1e30 (s - 1e30 rounds to -1e30 in float32)
-        cols = kv_idx * block_k + jax.lax.broadcasted_iota(
+        cols = tile * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = cols < kv_len
-        if causal:
+        if causal or diffusion:
             pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
+        if diffusion:
+            valid &= _bd_visible(pos, cols, diffusion)
+        if causal:
             valid &= pos >= cols
             if window:
                 valid &= pos - cols < window
@@ -340,7 +497,7 @@ def checked_window(window, causal, sk):
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, use_pallas=None, interpret=None,
-                    kv_lens=None, window=0, k_shared=None):
+                    kv_lens=None, window=0, k_shared=None, block_diffusion=0):
     """Blocked flash attention.  q/k/v: [batch, seq, heads, head_dim];
     ``k``/``v`` may hold fewer heads than ``q`` (grouped queries: K/V head
     ``j`` serves query heads ``j*group .. (j+1)*group``).
@@ -361,7 +518,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     nor computes the K/V tiles wholly below the window, nor do the
     backward's two kernels (a query row with no visible key at all, which
     only ``kv_lens`` can make, holds nothing meaningful and gives no
-    gradient).  use_pallas=None auto-selects: the Pallas
+    gradient).  ``block_diffusion`` (0 = none; takes no ``causal``,
+    ``window`` or ``kv_lens``): q and k are the ``2 * half`` positions of a
+    noisy copy of a sequence and its clean copy, in blocks of that many
+    positions, and the block-diffusion mask (:func:`_bd_visible`) decides;
+    the three kernels walk the two runs of tiles that mask needs
+    (:func:`_bd_kv_runs`, :func:`_bd_q_runs`) and neither fetch nor compute
+    a tile it hides wholly.  use_pallas=None auto-selects: the Pallas
     kernel on TPU backends for lane-tiled head dims, the XLA reference
     otherwise.  ``block_q`` / ``block_k``: tile sizes, from
     :func:`_flash_plan` unless given (the tests give them).
@@ -377,20 +540,24 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                          % (d, d_k, " + %d shared" % d_s if d_s else ""))
     group = h // kv
     window = checked_window(window, causal, sk)
+    diffusion = checked_diffusion(block_diffusion, causal, window, kv_lens,
+                                  sq, sk)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
         use_pallas = on_tpu() and _flash_eligible(d_k, d_v, d_s, q.dtype)
     if not use_pallas:
         return _reference_attention(q, k, v, causal, scale, kv_lens, window,
-                                    k_shared)
+                                    k_shared, diffusion)
 
     # tile sizes: the plan's, or the caller's held to the same alignment
-    # and never beyond the padded sequence
+    # and never beyond the padded sequence.  The block-diffusion mask is
+    # planned as a causal one: each q tile needs about as many K/V tiles
     itemsize = jnp.dtype(q.dtype).itemsize
     sub = _sublanes(itemsize)
     wide = _vmem_width(d_k, d_s)
-    bq, bk = _flash_plan(sq, sk, wide, group, itemsize, causal, d_v)
+    tiled_as = causal or bool(diffusion)
+    bq, bk = _flash_plan(sq, sk, wide, group, itemsize, tiled_as, d_v)
     if block_q is not None:
         bq = _round_up(min(block_q, sq), sub)
     if block_k is not None:
@@ -423,12 +590,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     # the kernel (measured 680 ms/call untraced vs 0.02 ms cached)
     # the backward's tiles: its own plan's, or the caller's where given
     bwd = (bq, bk) if (block_q, block_k) != (None, None) else \
-        _flash_bwd_plan(sq_p, sk_p, bq, bk, wide, group, itemsize, causal,
+        _flash_bwd_plan(sq_p, sk_p, bq, bk, wide, group, itemsize, tiled_as,
                         d_v)
     out = _flash_vjp_wrapped(qf, kf, vf, ksf, lens,
                              ((b * kv, group, sq_p, sk_p, (d_k, d_v, d_s, kv),
                                str(jnp.dtype(q.dtype)), causal, float(scale),
-                               bq, bk, interpret, window), bwd))
+                               bq, bk, interpret, window, diffusion), bwd))
     out = out.reshape(b, h, sq_p, d_v)[:, :, :sq]
     return out.transpose(0, 2, 1, 3)
 
@@ -452,11 +619,11 @@ def _flash_vjp_fwd(qf, kf, vf, ksf, lens, meta):
 
 
 def _flash_vjp_bwd(meta, res, d_out):
-    (bkv, group, sq, sk, widths, _, causal, scale, _, _, interpret, window), \
-        (block_q, block_k) = meta
+    (bkv, group, sq, sk, widths, _, causal, scale, _, _, interpret, window,
+     diffusion), (block_q, block_k) = meta
     qf, kf, vf, ksf, lens, out, lse = res
     fn = _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
-                           block_k, interpret, window)
+                           block_k, interpret, window, diffusion)
     dq, dk, dv, dks = fn(qf, kf, vf, ksf, lens, out, lse, d_out)
     return dq, dk, dv, dks, jnp.zeros_like(lens)
 
@@ -487,7 +654,7 @@ def _last_q_tile(kv_idx, kv_len, n_q, block_q, block_k, window):
 
 
 def _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
-                    keys_first):
+                    keys_first, diffusion=None):
     """The mask of one (q tile, K/V tile) pair as a float32 0 / -1e30 tile
     that every head of the group shares: [block_q, block_k], or its
     transpose where the keys lie on the sublanes (the dk/dv kernel)."""
@@ -495,9 +662,12 @@ def _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
     cols = kv_idx * block_k + jax.lax.broadcasted_iota(
         jnp.int32, shape, 0 if keys_first else 1)
     valid = cols < kv_len
-    if causal:
+    if causal or diffusion:
         pos = q_idx * block_q + jax.lax.broadcasted_iota(
             jnp.int32, shape, 1 if keys_first else 0)
+    if diffusion:
+        valid &= _bd_visible(pos, cols, diffusion)
+    if causal:
         valid &= pos >= cols
         if window:
             valid &= pos - cols < window
@@ -506,10 +676,11 @@ def _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
 
 def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          dd_ref, *rest, causal, scale, block_q, block_k, group,
-                         n_kv_blocks, window, shared=False):
+                         n_kv_blocks, window, shared=False, diffusion=None):
     """dq of one q tile: grid (B*KV, n_q, n_kv), the K/V tiles innermost
     and walked as the forward walks them (``_first_kv_tile`` ..
-    ``_last_kv_tile``; the others neither computed nor fetched); dq
+    ``_last_kv_tile``, or the two runs of :func:`_bd_kv_runs` over
+    ``n_kv_blocks`` steps; the others neither computed nor fetched); dq
     accumulates in float32 scratch and is written once.  The q tile holds
     all ``group`` query heads of the K/V head, rows on the sublanes and
     keys on the lanes as in the forward.  ``lse`` and D = rowsum(dO * O)
@@ -534,10 +705,8 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dd_col[:] = column(dd_ref)
 
     kv_len = len_ref[pl.program_id(0)]
-    needed = (kv_len > 0) & (
-        kv_idx <= _last_kv_tile(q_idx, kv_len, block_q, block_k, causal))
-    if window:
-        needed &= kv_idx >= _first_kv_tile(q_idx, block_q, block_k, window)
+    tile, needed = _kv_step(q_idx, kv_idx, kv_len, block_q, block_k, causal,
+                            window, diffusion)
 
     @pl.when(needed)
     def _compute():
@@ -546,8 +715,9 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         v = v_ref[0]
         f32 = dict(preferred_element_type=jnp.float32)
         s = _tile_scores(qs, keys) * jnp.float32(scale) - lse_col[:][:, :1]
-        bias = _flash_bwd_bias(q_idx, kv_idx, kv_len, block_q, block_k,
-                               causal, window, keys_first=False)
+        bias = _flash_bwd_bias(q_idx, tile, kv_len, block_q, block_k,
+                               causal, window, keys_first=False,
+                               diffusion=diffusion)
         p = jnp.exp((s.reshape(group, block_q, block_k) + bias[None])
                     .reshape(rows, block_k))
         dp = jax.lax.dot_general(do, v, _NT, **f32)
@@ -567,11 +737,13 @@ def _flash_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                           dd_ref, *rest, causal, scale, block_q, block_k,
-                          group, n_q_blocks, window, shared=False):
+                          group, n_q_blocks, window, shared=False,
+                          diffusion=None, n_q_tiles=0):
     """dk and dv of one K/V tile: grid (B*KV, n_kv, n_q), the q tiles
-    innermost and walked from ``_first_q_tile`` to ``_last_q_tile`` (the
-    others neither computed nor fetched); dk and dv accumulate in float32
-    scratch across them and are written once.  The scores are computed
+    innermost and walked from ``_first_q_tile`` to ``_last_q_tile`` (or the
+    two runs of :func:`_bd_q_runs` of the ``n_q_tiles`` over ``n_q_blocks``
+    steps; the others neither computed nor fetched); dk and dv accumulate in
+    float32 scratch across them and are written once.  The scores are computed
     TRANSPOSED, keys on the sublanes and the ``group * block_q`` rows of
     the q tile on the lanes: ``p.T @ dO`` and ``ds.T @ q`` are then plain
     products that sum over the group inside the MXU, and ``lse`` and D come
@@ -585,11 +757,11 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     else:
         ks_ref = dks_ref = dks_acc = None
         dk_ref, dv_ref, dk_acc, dv_acc = rest
-    q_idx = pl.program_id(2)
+    step = pl.program_id(2)
     kv_idx = pl.program_id(1)
     rows = group * block_q
 
-    @pl.when(q_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -597,10 +769,17 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             dks_acc[:] = jnp.zeros_like(dks_acc)
 
     kv_len = len_ref[pl.program_id(0)]
-    needed = (kv_idx * block_k < kv_len) \
-        & (q_idx >= _first_q_tile(kv_idx, block_q, block_k, causal)) \
-        & (q_idx <= _last_q_tile(kv_idx, kv_len, n_q_blocks, block_q,
-                                 block_k, window))
+    if diffusion:
+        runs = _bd_q_runs(kv_idx, kv_len, n_q_tiles, block_q, block_k,
+                          diffusion)
+        q_idx = _bd_step_tile(step, *runs)
+        needed = step < runs[1] + runs[3]
+    else:
+        q_idx = step
+        needed = (kv_idx * block_k < kv_len) \
+            & (q_idx >= _first_q_tile(kv_idx, block_q, block_k, causal)) \
+            & (q_idx <= _last_q_tile(kv_idx, kv_len, n_q_blocks, block_q,
+                                     block_k, window))
 
     @pl.when(needed)
     def _compute():
@@ -611,7 +790,7 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         # one [block_k, block_q] tile, repeated along the lanes for each head
         bias = jnp.concatenate([_flash_bwd_bias(
             q_idx, kv_idx, kv_len, block_q, block_k, causal, window,
-            keys_first=True)] * group, axis=1)
+            keys_first=True, diffusion=diffusion)] * group, axis=1)
         p = jnp.exp(_tile_scores(qs, keys, keys_first=True)
                     * jnp.float32(scale) - lse_ref[0, 0] + bias)
         dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do, _NN, **f32)
@@ -621,7 +800,7 @@ def _flash_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if shared:
             dks_acc[:] += jax.lax.dot_general(ds, qs[1], _NN, **f32)
 
-    @pl.when(q_idx == n_q_blocks - 1)
+    @pl.when(step == n_q_blocks - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * jnp.float32(scale)).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -695,17 +874,27 @@ def _flash_bwd_plan(sq, sk, block_q, block_k, d, group, itemsize, causal,
 
 @functools.lru_cache(maxsize=512)
 def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
-                      block_k, interpret, window=0):
+                      block_k, interpret, window=0, diffusion=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     d_k, d_v, d_s, kv_heads = widths
     d = d_k + d_s
     shared = bool(d_s)
     n_q, n_kv = sq // block_q, sk // block_k
+    # the innermost axes: as many steps as the longest pair of runs where
+    # the block-diffusion mask decides (every key is valid there)
+    kv_steps, q_steps = n_kv, n_q
+    bd = {}
+    if diffusion:
+        kv_steps = _bd_steps(n_q, lambda qi: _bd_kv_runs(
+            qi, block_q, block_k, diffusion, np))
+        q_steps = _bd_steps(n_kv, lambda ki: _bd_q_runs(
+            ki, 2 * diffusion[1], n_q, block_q, block_k, diffusion, np))
+        bd = dict(diffusion=diffusion)
     rows = group * block_q
     static = dict(causal=causal, scale=scale, block_q=block_q,
                   block_k=block_k, group=group, window=window,
-                  shared=shared)
+                  shared=shared, **bd)
     extra = {"interpret": interpret} if interpret is not None else {}
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -713,6 +902,9 @@ def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
 
     # dq: the forward's grid and its clamped K/V index map
     def kv_of_q(g, qi, ki, lens):
+        if diffusion:
+            return (g, jnp.minimum(_bd_step_tile(ki, *_bd_kv_runs(
+                qi, block_q, block_k, diffusion)), n_kv - 1), 0)
         last = _last_kv_tile(qi, lens[g], block_q, block_k, causal)
         tile = jnp.minimum(ki, last)
         if window:
@@ -726,6 +918,9 @@ def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
     # dk/dv: its transpose.  Past the last q tile that sees the K/V tile
     # (and before the first) the index stays where it is: no DMA
     def q_tile(g, ki, qi, lens):
+        if diffusion:
+            return jnp.minimum(_bd_step_tile(qi, *_bd_q_runs(
+                ki, lens[g], n_q, block_q, block_k, diffusion)), n_q - 1)
         last = _last_q_tile(ki, lens[g], n_q, block_q, block_k, window)
         first = jnp.minimum(_first_q_tile(ki, block_q, block_k, causal), last)
         return jnp.maximum(jnp.minimum(qi, last), first)
@@ -763,11 +958,11 @@ def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
             row_block = (1, 1, 1, rows)
             more = (ksf,) if shared else ()
             dq = pl.pallas_call(
-                functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=n_kv,
+                functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=kv_steps,
                                   **static),
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
-                    grid=(bkv, n_q, n_kv),
+                    grid=(bkv, n_q, kv_steps),
                     in_specs=[
                         pl.BlockSpec(q_block, q_of_q),
                         pl.BlockSpec(k_block, kv_of_q),
@@ -784,11 +979,12 @@ def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
                 compiler_params=params, name="flash_attn_bwd_dq", **extra,
             )(lens, qg, kf, vf, dog, lse, dd, *more)
             dk, dv, *dks = pl.pallas_call(
-                functools.partial(_flash_bwd_dkv_kernel, n_q_blocks=n_q,
-                                  **static),
+                functools.partial(_flash_bwd_dkv_kernel, n_q_blocks=q_steps,
+                                  **dict(static, n_q_tiles=n_q) if bd
+                                  else static),
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
-                    grid=(bkv, n_kv, n_q),
+                    grid=(bkv, n_kv, q_steps),
                     in_specs=[
                         pl.BlockSpec(q_block, q_of_kv),
                         pl.BlockSpec(k_block, kv_of_kv),
@@ -818,13 +1014,19 @@ def _flash_bwd_jitted(bkv, group, sq, sk, widths, causal, scale, block_q,
 
 @functools.lru_cache(maxsize=512)
 def _flash_jitted(bkv, group, sq, sk, widths, dtype, causal, scale, block_q,
-                  block_k, interpret, window=0, with_lse=False):
+                  block_k, interpret, window=0, diffusion=None,
+                  with_lse=False):
     d_k, d_v, d_s, kv_heads = widths
     d = d_k + d_s
+    steps, bd = sk // block_k, {}
+    if diffusion:
+        steps = _bd_steps(sq // block_q, lambda qi: _bd_kv_runs(
+            qi, block_q, block_k, diffusion, np))
+        bd = dict(diffusion=diffusion)
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, group=group, n_kv_blocks=sk // block_k,
-        emit_lse=with_lse, window=window, shared=bool(d_s))
+        block_k=block_k, group=group, n_kv_blocks=steps,
+        emit_lse=with_lse, window=window, shared=bool(d_s), **bd)
 
     def run(qf, kf, vf, ksf, lens):
         # the framework enables jax x64 globally (float64 NDArray API
@@ -835,7 +1037,7 @@ def _flash_jitted(bkv, group, sq, sk, widths, dtype, causal, scale, block_q,
             out, lse = _call_flash(
                 kernel, qf.reshape(bkv, group, sq, d), kf, vf, lens,
                 block_q, block_k, causal, interpret, with_lse, window,
-                ksf, kv_heads)
+                ksf, kv_heads, diffusion, steps)
             return (out.reshape(bkv * group, sq, d_v),
                     lse.reshape(bkv * group, sq, 128) if with_lse else None)
 
@@ -843,13 +1045,15 @@ def _flash_jitted(bkv, group, sq, sk, widths, dtype, causal, scale, block_q,
 
 
 def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
-                interpret, with_lse, window=0, ksf=None, kv_heads=None):
+                interpret, with_lse, window=0, ksf=None, kv_heads=None,
+                diffusion=None, n_kv=None):
+    """``n_kv``: the steps of the kv axis (the K/V tiles where not given)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bkv, group, sq, d = qg.shape
     d_k, d_v = kf.shape[-1], vf.shape[-1]
     shared = ksf is not None
-    n_q, n_kv = sq // block_q, kf.shape[1] // block_k
+    n_q, n_kv = sq // block_q, n_kv or kf.shape[1] // block_k
     # index maps see the scalar-prefetch ref as a trailing argument
     q_map = lambda g, qi, ki, lens: (g, 0, qi, 0)  # noqa: E731
 
@@ -857,6 +1061,10 @@ def _call_flash(kernel, qg, kf, vf, lens, block_q, block_k, causal,
         # past the last tile the q-block needs (and, under a window,
         # before the first) the index stays where it is: the pipeline sees
         # an unchanged block and issues no DMA
+        if diffusion:
+            return (g, jnp.minimum(_bd_step_tile(ki, *_bd_kv_runs(
+                qi, block_q, block_k, diffusion)),
+                kf.shape[1] // block_k - 1), 0)
         last = _last_kv_tile(qi, lens[g], block_q, block_k, causal)
         tile = jnp.minimum(ki, last)
         if window:
@@ -995,12 +1203,12 @@ def kernel_signature(platform=None):
 
 
 def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0,
-              k_shared=None):
+              k_shared=None, block_diffusion=0):
     """Trace-time attention dispatch for the ``attn`` kernel family.
 
     q/k/v: [batch, seq, heads, head_dim]; ``window`` (needs ``causal``; 0 =
-    none), the two widths and ``k_shared`` as :func:`flash_attention` takes
-    them.  Resolves
+    none), the two widths, ``k_shared`` and ``block_diffusion`` as
+    :func:`flash_attention` takes them.  Resolves
     ``kernel_mode('attn')`` at TRACE time (the executor cache keys on the
     same resolution): ``off`` returns the plain XLA reference — no
     custom_vjp, so the off-path program is bit-identical to one that
@@ -1011,15 +1219,18 @@ def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     window = checked_window(window, causal, k.shape[1])
+    diffusion = checked_diffusion(block_diffusion, causal, window, kv_lens,
+                                  q.shape[1], k.shape[1])
     d_s = 0 if k_shared is None else k_shared.shape[-1]
     mode = _flash_mode(k.shape[-1], q.dtype, v.shape[-1], d_s)
     if mode is None:
         return _reference_attention(q, k, v, causal, float(scale), kv_lens,
-                                    window, k_shared)
+                                    window, k_shared, diffusion)
     return flash_attention(q, k, v, causal=causal, scale=float(scale),
                            use_pallas=True,
                            interpret=(mode == "interpret") or None,
-                           kv_lens=kv_lens, window=window, k_shared=k_shared)
+                           kv_lens=kv_lens, window=window, k_shared=k_shared,
+                           block_diffusion=block_diffusion)
 
 
 def _flash_eligible(d_k, d_v, d_shared, dtype):
@@ -1039,8 +1250,34 @@ def _flash_mode(d_k, dtype, d_v=None, d_shared=0):
     return mode if mode != "off" and eligible else None
 
 
+def bd_visible_pairs(seq, block):
+    """(query, key) pairs the block-diffusion mask lets through over ``seq
+    = 2 * half`` positions: a noisy row sees its own block's noisy keys and
+    the clean keys before its block, a clean row the clean keys up to its
+    block's end; ``block**2 * n**2 + half * block`` where ``n = half /
+    block`` blocks are whole."""
+    half = int(seq) // 2
+    starts = np.arange(0, half, int(block), dtype=np.int64)
+    sizes = np.minimum(starts + int(block), half) - starts
+    # a block's noisy rows and its clean rows each see starts + sizes keys
+    return int(2 * np.sum(sizes * (starts + sizes)))
+
+
+def _bd_pairs_scored(sq, sk, bq, bk, diffusion, keys_first=False):
+    """Pairs of the tiles a block-diffusion kernel computes over operands
+    padded to ``sq`` x ``sk``: the runs of K/V tiles of each q tile (the
+    forward, dq) or, ``keys_first``, of q tiles of each K/V tile (dk/dv)."""
+    if keys_first:
+        runs = [_bd_q_runs(ki, 2 * diffusion[1], sq // bq, bq, bk, diffusion,
+                           np) for ki in range(sk // bk)]
+    else:
+        runs = [_bd_kv_runs(qi, bq, bk, diffusion, np)
+                for qi in range(sq // bq)]
+    return bq * bk * sum(int(r[1] + r[3]) for r in runs)
+
+
 def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0,
-                    v_width=None, shared_width=0):
+                    v_width=None, shared_width=0, block_diffusion=0):
     """What one :func:`attention` node of these shapes is built to do, as
     (computed, visible) counts of (query, key) pairs per head: the pairs
     whose score its forward and its backward compute, masked or not (the
@@ -1051,12 +1288,29 @@ def attention_pairs(q_shape, k_shape, dtype, causal=False, window=0,
     own keys' (``shared_width`` less than q's where a key part is shared),
     ``v_width`` the values' where it differs.  Static: shapes, the tile plans
     and the kernel mode of the enclosing :func:`trace_scope`; lengths
-    (``kv_lens``) are not known here."""
+    (``kv_lens``) are not known here.  Under ``block_diffusion`` the three
+    kernels' tiles are counted each (dk/dv walks the transpose of the
+    forward's runs, tile for tile)."""
     b, sq = int(q_shape[0]), int(q_shape[1])
     sk, kv, d_k = (int(x) for x in k_shape[1:])
     d, d_v = _vmem_width(d_k, shared_width), int(v_width or d_k)
     group = int(q_shape[2]) // kv
     window = checked_window(window, causal, sk)
+    diffusion = checked_diffusion(block_diffusion, causal, window, None, sq,
+                                  sk)
+    if diffusion:
+        visible = bd_visible_pairs(sk, diffusion[0])
+        if _flash_mode(d_k, dtype, d_v, shared_width) is None:
+            return 2 * b * sq * sk, 2 * b * visible
+        itemsize = jnp.dtype(dtype).itemsize
+        bq, bk = _flash_plan(sq, sk, d, group, itemsize, True, d_v)
+        sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
+        bwd = _flash_bwd_plan(sq_p, sk_p, bq, bk, d, group, itemsize, True,
+                              d_v)
+        return b * (_bd_pairs_scored(sq_p, sk_p, bq, bk, diffusion)
+                    + _bd_pairs_scored(sq_p, sk_p, *bwd, diffusion)
+                    + _bd_pairs_scored(sq_p, sk_p, *bwd, diffusion,
+                                       keys_first=True)), 2 * b * visible
     if causal:
         visible = sum(min(i, sk - 1) + 1 - (max(i - window + 1, 0)
                                             if window else 0)

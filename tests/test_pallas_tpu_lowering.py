@@ -88,6 +88,13 @@ FLASH_TWO_WIDTH_CASES = [
     ((1, 512, 4, 256), "bfloat16", 2, 256, 128),
     ((1, 8192, 32, 192), "bfloat16", 32, 128, 128),
 ]
+# the block-diffusion mask: (q shape, dtype, K/V heads, block); a short one
+# whose tiles lie across the two halves, and `sdar-train-bd4-s8k-b1`'s
+# 16,384 positions (8,192 noisy + 8,192 clean), 32 query heads over 4
+FLASH_BD_CASES = [
+    ((1, 96, 2, 128), "float32", 1, 4),
+    ((1, 16384, 32, 128), "bfloat16", 4, 4),
+]
 
 
 def pool_configs():
@@ -158,6 +165,20 @@ def _cases():
                     jax.grad(lambda *a, fwd=fwd: jnp.sum(
                         fwd(*a).astype(jnp.float32) ** 2),
                         argnums=tuple(range(len(avals)))), tuple(avals)))
+    for shape, dtype, kv_heads, block in FLASH_BD_CASES:
+        x = _aval(shape, dtype)
+        kv = _aval(shape[:2] + (kv_heads,) + shape[3:], dtype)
+
+        def fwd(q, k, v, block=block):
+            return pk.flash_attention(q, k, v, use_pallas=True,
+                                      block_diffusion=block)
+
+        tag = "%skv%d-%s-bd%d" % (shape, kv_heads, dtype, block)
+        out.append(("flash-fwd-" + tag, fwd, (x, kv, kv)))
+        out.append(("flash-grad-" + tag,
+                    jax.grad(lambda q, k, v, fwd=fwd: jnp.sum(
+                        fwd(q, k, v).astype(jnp.float32) ** 2),
+                        argnums=(0, 1, 2)), (x, kv, kv)))
     # the delta-rule scan kernels at the language-model cell's own shape:
     # 2 x 8,192 tokens, 16 key heads of 128, 2 value heads of 128 each
     gdr = lm_ops._make_gdr(64, "pallas")
