@@ -259,21 +259,23 @@ class _Program:
         return computed, visible
 
     def gdn_chunk_steps(self, shapes, dtypes):
-        """(chunk steps, those of them in the Pallas kernels) of one training
-        pass over this graph's ``gated_delta_rule`` nodes at the bound
-        argument ``shapes`` and ``dtypes``: chunks x batch x key heads a
-        pass of a node, and a node makes two passes forward where its mirror
-        stage is recomputed, one where not, and one backward.  The second
-        number is the first's where ``ops.gdn_kernels.mode`` takes the
-        node's shape under the caller's ``trace_scope``, else 0.  (0, 0) for
-        a graph without such a node."""
+        """(chunk steps, those of them in the Pallas scan kernels, those
+        whose chunk-local part runs in the Pallas local kernels) of one
+        training pass over this graph's ``gated_delta_rule`` nodes at the
+        bound argument ``shapes`` and ``dtypes``: chunks x batch x key heads
+        a pass of a node, and a node makes two passes forward where its
+        mirror stage is recomputed, one where not, and one backward.  The
+        second number is the first's where ``ops.gdn_kernels.mode`` takes
+        the node's shape under the caller's ``trace_scope``, the third where
+        ``gdn_kernels.local_planned`` takes it too; else 0.  (0, 0, 0) for a
+        graph without such a node."""
         nodes = [n for n in self.order
                  if not n.is_var and n.op_name == "gated_delta_rule"]
         if not nodes:
-            return 0, 0
+            return 0, 0, 0
         from .ops import gdn_kernels
         at, kinds = self.symbol._infer(dict(shapes), dict(dtypes))
-        steps = in_kernel = 0
+        steps = in_kernel = local = 0
         for n in nodes:
             chunk = int(get_op(n.op_name).normalize_attrs(n.attrs)["chunk"])
             (b, t, hk, dk), value = at[n.inputs[0]], at[n.inputs[2]]
@@ -281,10 +283,12 @@ class _Program:
             passes = 3 if n.attrs.get(MIRROR_STAGE) else 2
             mine = passes * -(-int(t) // chunk) * int(b) * int(hk)
             steps += mine
-            if gdn_kernels.mode((b, hk, t, dk), (b, hk, r, t, value[3]),
-                                chunk, kinds[n.inputs[2]]):
+            q, v = (b, hk, t, dk), (b, hk, r, t, value[3])
+            if gdn_kernels.mode(q, v, chunk, kinds[n.inputs[2]]):
                 in_kernel += mine
-        return steps, in_kernel
+                if gdn_kernels.local_planned(q, v, chunk, kinds[n.inputs[2]]):
+                    local += mine
+        return steps, in_kernel, local
 
     def _run_stage(self, stage, run, env, new_aux):
         def body(ins):

@@ -744,7 +744,8 @@ class FusedTrainStep:
     def _schedule_counts(self):
         """What the step program's kernels are scheduled to do, static per
         program: its attention as (computed, visible) pairs and its delta-rule
-        scans as (chunk steps, those in the Pallas kernels), worked out once
+        scans as (chunk steps, those in the Pallas scan kernels, those whose
+        chunk-local part is in the Pallas local kernels), worked out once
         from the shapes bound to device 0's executor (its share of the batch,
         so times the devices)."""
         if self._schedule is None:

@@ -981,14 +981,17 @@ def note_attention_pairs(computed, visible):
                  "once each way").inc(visible)
 
 
-def note_gdn_chunk_steps(steps, in_kernel):
-    """``module.gdn.chunk_steps`` / ``module.gdn.chunk_steps_in_kernel``: the
-    chunk steps (chunks x batch x key heads x passes: forward, forward again
-    where the block is recomputed, backward) of the ``gated_delta_rule``
-    nodes of the step program just dispatched, and those of them that run
-    inside the Pallas kernels ``gdn_scan_fwd`` / ``gdn_scan_bwd`` and not as
-    bodies of a ``lax.scan`` (``_Program.gdn_chunk_steps``: static per
-    program; 0 on the fallback)."""
+def note_gdn_chunk_steps(steps, in_kernel, local_in_kernel):
+    """``module.gdn.chunk_steps`` / ``module.gdn.chunk_steps_in_kernel`` /
+    ``module.gdn.local_chunks_in_kernel``: the chunk steps (chunks x batch x
+    key heads x passes: forward, forward again where the block is
+    recomputed, backward) of the ``gated_delta_rule`` nodes of the step
+    program just dispatched, those of them that run inside the Pallas
+    kernels ``gdn_scan_fwd`` / ``gdn_scan_bwd`` and not as bodies of a
+    ``lax.scan``, and those whose chunk-local part runs inside
+    ``gdn_local_fwd`` / ``gdn_local_bwd`` and not as XLA's
+    ``_chunk_local`` (``_Program.gdn_chunk_steps``: static per program; 0
+    on the fallback)."""
     if steps:
         telemetry.counter(
             "module.gdn.chunk_steps",
@@ -998,6 +1001,10 @@ def note_gdn_chunk_steps(steps, in_kernel):
             "module.gdn.chunk_steps_in_kernel",
             help="chunk steps that run inside the Pallas scan "
                  "kernels").inc(in_kernel)
+        telemetry.counter(
+            "module.gdn.local_chunks_in_kernel",
+            help="chunk steps whose chunk-local part runs inside the "
+                 "Pallas local kernels").inc(local_in_kernel)
 
 
 def note_counter_rows(rows, names):

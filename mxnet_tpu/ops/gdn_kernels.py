@@ -1,14 +1,33 @@
-"""Pallas TPU kernels for the gated delta rule's recurrence over chunks
-(``ops/lm_ops.py``; docs/kernels.md).
+"""Pallas TPU kernels for the gated delta rule (``ops/lm_ops.py``;
+docs/kernels.md): the chunk-local algebra and the recurrence over chunks.
 
-The chunk-local algebra (``lm_ops._chunk_local``) is one piece over all
-chunks and stays with XLA.  What is left is a recurrence: every chunk reads
-the float32 state ``[dk, dv]`` of its value head, adds five small products
-and hands the state on.  As a ``lax.scan`` that is a dozen fusions a chunk
-with the state going through HBM between each pair of them; here it is one
-grid a pass, (key heads, chunks) with the chunk axis sequential, the state in
-a VMEM scratch for all chunks of a head and each chunk's operands read where
-they lie by the index map:
+The chunk-local algebra (``lm_ops._chunk_local``) needs a chunk's own
+tokens alone; as XLA ops over all chunks at once every intermediate is a
+float32 ``[chunks, c, c]`` array in HBM and the nilpotent inverse ten
+passes over them.  Here it is one grid a pass, (key heads, chunks), each
+step one chunk of a few key heads with everything in VMEM:
+
+- ``gdn_local_fwd``: reads q, k, v, g and beta where they lie and writes
+  ``_chunk_local``'s u, m, qk, grow and shrink in the layout the scan
+  kernels read.  A key head's ``r`` value heads share q and k, so their
+  ``[c, c]`` matrices stand side by side in one ``[c, r c]`` tile, and a
+  product by a block-diagonal matrix works on all of them at once.
+- ``gdn_local_bwd``: the same grid; recomputes the chunk (the inverse
+  included: the program keeps no residual for it) and emits what
+  ``jax.vjp(_chunk_local)`` emits, from the cotangents ``gdn_scan_bwd``
+  returns.
+
+The decays, the inverse and its pull-back stay float32 (the products inside
+the inverse at ``HIGHEST``, as XLA's are); operands meet the MXU in the
+compute dtype where ``_chunk_local`` casts them.
+
+What is left is a recurrence: every chunk reads the float32 state ``[dk,
+dv]`` of its value head, adds five small products and hands the state on.
+As a ``lax.scan`` that is a dozen fusions a chunk with the state going
+through HBM between each pair of them; here it is one grid a pass, (key
+heads, chunks) with the chunk axis sequential, the state in a VMEM scratch
+for all chunks of a head and each chunk's operands read where they lie by
+the index map:
 
 - ``gdn_scan_fwd``: ``lm_ops._chunk_step`` a grid step, from the first chunk
   to the last; emits the chunk's outputs and the state the chunk STARTED
@@ -66,14 +85,32 @@ def _gdn_vmem_bytes(heads, r, n, c, dk, dv, itemsize):
     return heads * (2 * (per_chunk + results + vectors) + scratch)
 
 
-def _gdn_plan(bh, r, n, c, dk, dv, itemsize):
+def _local_vmem_bytes(heads, r, n, c, dk, dv, itemsize):
+    """VMEM one grid step of ``gdn_local_bwd`` (the larger of the two local
+    kernels) holds for ``heads`` key heads: the double-buffered per-chunk
+    operands and results, the decay vectors and their cotangents (resident a
+    head), and the float32 ``[c, r c]`` tiles the chunk's algebra keeps
+    live, counted as 32 of them."""
+    lanes = lambda w: _pk._round_up(w, 128)
+    per_chunk = (2 * c * lanes(dk) + 2 * r * c * lanes(dv)
+                 + 2 * r * c * lanes(c)) * itemsize \
+        + c * lanes(r * c) * 4                # the inverses
+    results = (2 * c * lanes(dk) + r * c * lanes(dv)) * itemsize
+    vectors = 6 * r * _pk._round_up(n, 8) * lanes(c) * 4
+    work = 32 * c * lanes(r * c) * 4
+    return heads * (2 * (per_chunk + results + vectors) + work)
+
+
+def _gdn_plan(bh, r, n, c, dk, dv, itemsize, vmem=_gdn_vmem_bytes):
     """Key heads a grid step takes for ``bh`` (batch x key heads) rows of
     ``n`` chunks of ``c`` tokens, ``r`` value heads a key head: the most, up
-    to ``_GDN_MAX_HEADS``, that divide ``bh`` and fit ``_GDN_VMEM_BUDGET``;
-    None where not even one head fits (the decay vectors of a head's ``n``
-    chunks stay resident: a very long sequence falls back to the scan)."""
+    to ``_GDN_MAX_HEADS``, that divide ``bh`` and whose ``vmem`` count (the
+    scan kernels' by default, :func:`_local_vmem_bytes` for the local ones)
+    fits ``_GDN_VMEM_BUDGET``; None where not even one head fits (the decay
+    vectors of a head's ``n`` chunks stay resident: a very long sequence
+    falls back to XLA)."""
     for heads in range(min(_GDN_MAX_HEADS, bh), 0, -1):
-        if bh % heads == 0 and _gdn_vmem_bytes(
+        if bh % heads == 0 and vmem(
                 heads, r, n, c, dk, dv, itemsize) <= _GDN_VMEM_BUDGET:
             return heads
     return None
@@ -106,17 +143,38 @@ def mode(q_shape, v_shape, chunk, dtype):
     return "pallas" if planned else None
 
 
+def local_planned(q_shape, v_shape, chunk, dtype):
+    """Whether, where :func:`mode` takes the recurrence, the chunk-local
+    part runs in ``gdn_local_fwd`` / ``gdn_local_bwd`` too: the shape has a
+    plan of theirs (else it stays ``lm_ops._chunk_local`` in XLA).  The
+    shape alone decides."""
+    b, hk, t, dk = (int(x) for x in q_shape)
+    r, dv = int(v_shape[2]), int(v_shape[-1])
+    return _gdn_plan(b * hk, r, -(-t // chunk), chunk, dk, dv,
+                     jnp.dtype(dtype).itemsize, _local_vmem_bytes) is not None
+
+
 def _dot(a, b, dims=_NN):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=_F32)
 
 
+def _dot_f32(a, b, dims=_NN):
+    """A float32 product at float32 precision (``HIGHEST``: Mosaic's fp32
+    contract precision, XLA's six bfloat16 passes)."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=_F32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
 def _eye(c):
-    return jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) \
-        == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return _iota((c, c), 0) == _iota((c, c), 1)
 
 
 def _last_column(rows, c):
-    return jax.lax.broadcasted_iota(jnp.int32, (rows, c), 1) == c - 1
+    return _iota((rows, c), 1) == c - 1
 
 
 def _picked(x, mask, axis):
@@ -128,6 +186,297 @@ def _picked(x, mask, axis):
     both, and this is one of each."""
     return jnp.sum(jnp.where(mask, jnp.broadcast_to(x, mask.shape),
                              _F32(0.0)), axis=axis, keepdims=True)
+
+
+# -- the chunk-local algebra: one chunk of a key head a grid step ------------------
+#
+# The r value heads of a key head share q and k, so their [c, c] matrices
+# stand side by side in one [c, r c] tile (value head j in lanes j c ..
+# (j + 1) c).  A product by the block-diagonal [r c, r c] matrix of such a
+# tile multiplies each of them by its own, r at a time on the MXU; a
+# product by a 0/1 placement matrix moves a [c, c] matrix into or out of its
+# block, exactly (one term a sum).
+
+def _dot_split(a, b, dims):
+    """A float32 cotangent ``a`` by an operand ``b`` in the compute dtype:
+    ``a`` as the sum of two parts in ``b``'s dtype, a product each (what XLA
+    does with the float32 operand, to about 16 bits)."""
+    hi = a.astype(b.dtype)
+    if hi.dtype == a.dtype:
+        return _dot(a, b, dims)
+    return _dot(hi, b, dims) + _dot((a - hi.astype(_F32)).astype(b.dtype),
+                                    b, dims)
+
+
+class _Tiles:
+    """The masks of a ``[c, r c]`` tile: ``blocks[j]`` (value head j's
+    lanes), ``rows``, and ``cols`` (the column within a block)."""
+
+    def __init__(self, c, r):
+        self.c, self.r = c, r
+        self.rows, lanes = _iota((c, r * c), 0), _iota((c, r * c), 1)
+        self.blocks = [(lanes >= j * c) & (lanes < (j + 1) * c)
+                       for j in range(r)]
+        self.cols = lanes
+        for j in range(1, r):
+            self.cols = jnp.where(self.blocks[j], lanes - j * c, self.cols)
+        self.eye = self.rows == self.cols
+
+    def spread(self, columns):
+        """r columns ``[c, 1]`` as one tile, column j across block j."""
+        out = jnp.broadcast_to(columns[0], self.eye.shape)
+        for col, block in zip(columns[1:], self.blocks[1:]):
+            out = jnp.where(block, jnp.broadcast_to(col, block.shape), out)
+        return out
+
+    def by_column(self, tile):
+        """``[1, r c]``: the value a tile made by :meth:`spread` holds on
+        row ``i`` of block j, at column ``i`` of block j."""
+        return _picked(tile, self.eye, 0)
+
+    def column(self, row, j):
+        """``[c, 1]``: block j of a ``[1, r c]`` row, as a column."""
+        return _picked(row, self.eye & self.blocks[j], 1)
+
+    def block_sum(self, tile, j):
+        """``[c, 1]``: each row of a tile summed over block j."""
+        return _picked(tile, self.blocks[j], 1)
+
+    def diagonal(self, tile):
+        """The ``[r c, r c]`` block-diagonal matrix of a tile's blocks."""
+        if self.r == 1:
+            return tile
+        return jnp.concatenate([jnp.where(b, tile, _F32(0.0))
+                                for b in self.blocks], axis=0)
+
+    def fold(self, x):
+        """The sum of the ``r`` row blocks of ``[r c, ...]``."""
+        c, out = self.c, x[:self.c]
+        for j in range(1, self.r):
+            out = out + x[j * c:(j + 1) * c]
+        return out
+
+    def placement(self, j, dtype):
+        """``[c, r c]`` in ``dtype``: ``x @ P`` puts a ``[., c]`` matrix
+        into block j, ``t @ P.T`` takes block j out of a tile."""
+        return jnp.where(self.eye & self.blocks[j], _F32(1.0),
+                         _F32(0.0)).astype(dtype)
+
+    def moved(self, a, b, dims, dtype):
+        """A placement product, exact: one term a sum (float32 operands at
+        float32 precision)."""
+        if jnp.dtype(dtype) == jnp.dtype(_F32):
+            return _dot_f32(a, b, dims)
+        return _dot(a, b, dims)
+
+
+def _unit_lower_inverse(nils, tiles):
+    """``(I - n)^{-1}`` of the r strictly lower ``[c, c]`` matrices side by
+    side in each tile of ``nils`` (one a key head): ``sum_{j < c} n^j`` by
+    doubling, ``s <- s + s p`` and ``p <- p p`` in ONE float32 product of
+    ``[s; p]`` by ``p``'s block diagonal: six products of the pair where
+    ``lm_ops._unit_lower_inverse`` takes ten of each matrix.  The key
+    heads' chains go in step, so that their products stand side by side."""
+    c = tiles.c
+    eye = jnp.where(tiles.eye, _F32(1.0), _F32(0.0))
+    ss = [eye + n for n in nils]
+    ps = [_dot_f32(n, tiles.diagonal(n)) for n in nils]     # n^2
+    reach = 2
+    while reach < c:
+        if 2 * reach < c:
+            both = [_dot_f32(jnp.concatenate([s, p], axis=0),
+                             tiles.diagonal(p)) for s, p in zip(ss, ps)]
+            ss = [s + x[:c] for s, x in zip(ss, both)]
+            ps = [x[c:] for x in both]
+        else:
+            ss = [s + _dot_f32(s, tiles.diagonal(p)) for s, p in zip(ss, ps)]
+        reach *= 2
+    return ss
+
+
+class _Chunk:
+    """``lm_ops._chunk_local`` of one chunk of one key head in float32 up to
+    the inverse, the r value heads side by side: from q, k ``[c, dk]`` and v
+    ``[c, dv]`` (r of them) in the compute dtype and the rows g, beta ``[1,
+    c]`` (r of each).  Attributes as the backward needs them: ``gc`` (r
+    columns of the in-chunk cumulative log decay), the tiles ``gc_cols`` /
+    ``beta_cols`` (row i of block j: value head j's at token i), the rows
+    ``gc_row`` / ``beta_row`` (column l of block j: its at token l),
+    ``decay``, ``kk``, ``qk`` (before the decay), ``nil`` (the strictly
+    lower matrices :func:`_unit_lower_inverse` inverts) and ``vb`` (v times
+    beta, stacked ``[r c, dv]``)."""
+
+    def __init__(self, tiles, q, k, vs, gs, betas):
+        c, r, cd = tiles.c, tiles.r, k.dtype
+        eye = _eye(c)
+        self.on_or_below = _iota((c, c), 0) >= _iota((c, c), 1)
+        # the cumulative sum as a masked one: float32, like jnp.cumsum's
+        self.gc = [jnp.sum(jnp.where(self.on_or_below,
+                                     jnp.broadcast_to(g, (c, c)), _F32(0.0)),
+                           axis=1, keepdims=True) for g in gs]
+        self.gc_cols = tiles.spread(self.gc)
+        self.gc_row = tiles.by_column(self.gc_cols)
+        self.betas = [_picked(b, eye, 1) for b in betas]
+        self.beta_cols = tiles.spread(self.betas)
+        self.beta_row = tiles.by_column(self.beta_cols)
+        self.lower = tiles.rows >= tiles.cols
+        self.decay = jnp.exp(jnp.where(self.lower,
+                                       self.gc_cols - self.gc_row, -jnp.inf))
+        # q k^T and k k^T at once, r copies side by side
+        self.k_stack = jnp.concatenate([k] * r, axis=0) if r > 1 else k
+        both = _dot(jnp.concatenate([q, k], axis=0), self.k_stack, _NT)
+        self.qk, self.kk = both[:c], both[c:]
+        self.strict = tiles.rows > tiles.cols
+        self.nil = -jnp.where(self.strict,
+                              self.kk * self.decay * self.beta_cols,
+                              _F32(0.0))
+        vb = [(v.astype(_F32) * b.astype(cd).astype(_F32)).astype(cd)
+              for v, b in zip(vs, self.betas)]
+        self.vb = jnp.concatenate(vb, axis=0) if r > 1 else vb[0]
+
+    def row(self, j):
+        """``[1, c]``: value head j's cumulative log decay as a row."""
+        return _picked(self.gc[j], _eye(self.gc[j].shape[0]), 0)
+
+
+def _last(col):
+    """``[1, 1]``: the last entry of a column ``[c, 1]``."""
+    return _picked(col, _iota(col.shape, 0) == col.shape[0] - 1, 0)
+
+
+def _chunks_of(refs, tiles, heads, i):
+    """The :class:`_Chunk` of each of a grid step's key heads, from the
+    refs q, k, v, g, beta (the decay vectors resident: chunk ``i``'s row)."""
+    from jax.experimental import pallas as pl
+    q_ref, k_ref, v_ref, g_ref, beta_ref = refs
+    at = lambda ref, h: [ref[h, j, pl.ds(i, 1), :] for j in range(tiles.r)]
+    return [_Chunk(tiles, q_ref[h, 0], k_ref[h, 0],
+                   [v_ref[h, j, 0] for j in range(tiles.r)], at(g_ref, h),
+                   at(beta_ref, h)) for h in range(heads)]
+
+
+def _local_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, m_ref,
+                      qk_ref, grow_ref, shrink_ref, *inv_ref, heads, r, c):
+    """One chunk of ``heads`` key heads: ``lm_ops._chunk_local``.  The
+    decay vectors of all chunks of a head stay resident (in and out) and are
+    indexed by the chunk here.  ``inv_ref``, where given, takes the
+    inverses as they stand side by side (for :func:`_local_bwd_kernel`)."""
+    from jax.experimental import pallas as pl
+    i = pl.program_id(1)
+    tiles = _Tiles(c, r)
+    chunks = _chunks_of((q_ref, k_ref, v_ref, g_ref, beta_ref), tiles,
+                        heads, i)
+    invs = _unit_lower_inverse([ch.nil for ch in chunks], tiles)
+    for h, (ch, inv) in enumerate(zip(chunks, invs)):
+        cd = ch.vb.dtype
+        if inv_ref:
+            inv_ref[0][h, 0] = inv
+        scale = ch.beta_row * jnp.exp(ch.gc_row)        # beta exp(gc), by column
+        mats = jnp.concatenate([(inv * scale).astype(cd),
+                                (ch.qk * ch.decay).astype(cd)], axis=0)
+        for j in range(r):
+            mine = jnp.where(tiles.blocks[j], inv, _F32(0.0)).astype(cd)
+            u_ref[h, j, 0] = _dot(mine, ch.vb).astype(cd)
+            both = tiles.moved(mats, tiles.placement(j, cd), _NT, cd)
+            m_ref[h, j, 0] = both[:c].astype(cd)
+            qk_ref[h, j, 0] = both[c:].astype(cd)
+            gc = ch.row(j)
+            grow_ref[h, j, pl.ds(i, 1), :] = jnp.exp(gc)
+            shrink_ref[h, j, pl.ds(i, 1), :] = jnp.exp(
+                _last(ch.gc[j]) - gc)
+
+
+def _local_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, du_ref,
+                      dm_ref, dqk_ref, dgrow_ref, dshrink_ref, dq_ref, dk_ref,
+                      dv_ref, dg_ref, dbeta_ref, *, heads, r, c):
+    """One chunk of ``heads`` key heads: the chunk again up to its inverse,
+    which ``inv_ref`` hands in, then the transpose of
+    :func:`_local_fwd_kernel`'s algebra.  Cotangents are float32 and meet
+    the MXU in the compute dtype where the forward's operands did; the
+    inverse's pull-back ``T^T dT T^T`` is float32 at float32 precision."""
+    from jax.experimental import pallas as pl
+    i = pl.program_id(1)
+    tiles = _Tiles(c, r)
+    eye = _eye(c)
+    at = lambda ref, h, j: ref[h, j, pl.ds(i, 1), :]
+    chunks = _chunks_of((q_ref, k_ref, v_ref, g_ref, beta_ref), tiles,
+                        heads, i)
+    d_invs, parts = [], []
+    for h, ch in enumerate(chunks):
+        cd = ch.vb.dtype
+        inv = inv_ref[h, 0]
+        inv_c = inv.astype(cd)
+        grow_row = jnp.exp(ch.gc_row)
+        scale = ch.beta_row * grow_row
+        # the cotangents of m and qk, side by side
+        dmq = None
+        for j in range(r):
+            put = tiles.moved(jnp.concatenate([dm_ref[h, j, 0],
+                                               dqk_ref[h, j, 0]], axis=0),
+                              tiles.placement(j, cd), _NN, cd)
+            dmq = put if dmq is None else dmq + put
+        dm, dqk = dmq[:c], dmq[c:]
+        # m = inv * scale (by column)
+        d_inv = dm * scale
+        d_scale = jnp.sum(dm * inv, axis=0, keepdims=True)
+        # u_j = inv_j (v_j beta_j)
+        d_beta = []
+        for j in range(r):
+            du = du_ref[h, j, 0]
+            d_inv = d_inv + jnp.where(tiles.blocks[j],
+                                      _dot(du, ch.vb, _NT), _F32(0.0))
+            d_vb = _dot(inv_c, du, _TN)[j * c:(j + 1) * c].astype(cd) \
+                .astype(_F32)
+            beta_c = ch.betas[j].astype(cd).astype(_F32)
+            v = v_ref[h, j, 0].astype(_F32)
+            dv_ref[h, j, 0] = (d_vb * beta_c).astype(cd)
+            d_beta.append(jnp.sum(d_vb * v, axis=1,
+                                  keepdims=True).astype(cd).astype(_F32))
+        d_invs.append(d_inv)
+        parts.append((dqk, d_scale, scale, grow_row, d_beta))
+    # inv = (I - n)^-1: dn = inv^T d_inv inv^T, r at a time, heads in step
+    diags = [tiles.diagonal(inv_ref[h, 0]) for h in range(heads)]
+    rights = [_dot_f32(d, diag, _NT) for d, diag in zip(d_invs, diags)]
+    d_nils = [tiles.fold(_dot_f32(diag, tiles.diagonal(x), _TN))
+              for diag, x in zip(diags, rights)]
+    for h, (ch, d_nil, part) in enumerate(zip(chunks, d_nils, parts)):
+        dqk, d_scale, scale, grow_row, d_beta = part
+        cd = ch.vb.dtype
+        q, k = q_ref[h, 0], k_ref[h, 0]
+        # n = -where(strict, kk * decay * beta (by row), 0)
+        w = jnp.where(ch.strict, -d_nil, _F32(0.0))
+        d_kkd = w * ch.beta_cols
+        d_kk = d_kkd * ch.decay
+        d_decay = d_kkd * ch.kk + dqk * ch.qk
+        d_beta_cols = w * (ch.kk * ch.decay)
+        # qk = (q k^T) * decay; q k^T and k k^T read q and k, r copies
+        lhs = jnp.concatenate([dqk * ch.decay, d_kk], axis=0)   # [2c, r c]
+        by_k = _dot_split(lhs, ch.k_stack, _NN)            # d_qk k, d_kk k
+        by_rows = tiles.fold(_dot_split(
+            lhs, jnp.concatenate([q, k], axis=0), _TN))    # d_qk^T q + d_kk^T k
+        dq_ref[h, 0] = by_k[:c].astype(cd)
+        dk_ref[h, 0] = (by_k[c:] + by_rows).astype(cd)
+        # decay = exp(gc_i - gc_l) on and below the diagonal
+        d_diff = jnp.where(ch.lower, d_decay * ch.decay, _F32(0.0))
+        d_gc_row = d_scale * scale - jnp.sum(d_diff, axis=0, keepdims=True)
+        d_beta_row = d_scale * grow_row
+        for j in range(r):
+            gc = ch.gc[j]
+            d_gc = tiles.block_sum(d_diff, j) + tiles.column(d_gc_row, j)
+            # grow = exp(gc); shrink = exp(gc_last - gc)
+            d_gc = d_gc + _picked(at(dgrow_ref, h, j), eye, 1) * jnp.exp(gc)
+            d_sh = _picked(at(dshrink_ref, h, j), eye, 1) \
+                * jnp.exp(_last(gc) - gc)
+            d_gc = d_gc - d_sh + jnp.where(
+                _iota((c, 1), 0) == c - 1,
+                jnp.sum(d_sh, axis=0, keepdims=True), _F32(0.0))
+            # gc = cumsum(g): g's cotangent sums gc's from its token on
+            dg_ref[h, j, pl.ds(i, 1), :] = jnp.sum(jnp.where(
+                ch.on_or_below, jnp.broadcast_to(d_gc, (c, c)), _F32(0.0)),
+                axis=0, keepdims=True)
+            d_b = d_beta[j] + tiles.block_sum(d_beta_cols, j) \
+                + tiles.column(d_beta_row, j)
+            dbeta_ref[h, j, pl.ds(i, 1), :] = _picked(d_b, eye, 0)
 
 
 def _fwd_kernel(q_ref, k_ref, u_ref, m_ref, qk_ref, grow_ref, shrink_ref,
@@ -366,3 +715,115 @@ def scan_bwd(q, k, local, states, d_out, interpret=None):
     return back(d_q, q), back(d_k, k), tuple(
         back(x, like) for x, like in zip(d_local, local)) \
         + (jnp.zeros_like(g_all),)
+
+
+def _local_planned(q, v):
+    """((b * hk, r, n, c, dk, dv), key heads a grid step) of the local
+    kernels' operands q ``[b, hk, n, c, dk]`` and v ``[b, hk, r, n, c,
+    dv]``."""
+    b, hk, n, c, dk = q.shape
+    dims = (b * hk, int(v.shape[2]), n, c, dk, int(v.shape[-1]))
+    heads = _gdn_plan(*dims, jnp.dtype(v.dtype).itemsize, _local_vmem_bytes)
+    if not heads:
+        raise ValueError("gdn_local: no plan for %d rows of %d chunks at "
+                         "%d x %d" % (dims[0], n, dk, dims[-1]))
+    return dims, heads
+
+
+def _local_specs(heads, r, n, c, dk, dv):
+    """:func:`_specs` of the local kernels (chunk ``i`` at grid step ``i``)
+    with ``inverses``: a chunk's inverses side by side, ``[b * hk, n, c, r
+    c]`` float32."""
+    from jax.experimental import pallas as pl
+    spec = _specs(heads, r, n, c, dk, dv, lambda i: i)
+    spec["inverses"] = pl.BlockSpec((heads, 1, c, r * c),
+                                    lambda h, i: (h, i, 0, 0))
+    return spec
+
+
+@functools.lru_cache(maxsize=128)
+def _local_fwd_jitted(bh, r, n, c, dk, dv, dtype, heads, interpret,
+                      keep_inverse):
+    from jax.experimental import pallas as pl
+    spec = _local_specs(heads, r, n, c, dk, dv)
+    extra = {"interpret": interpret} if interpret is not None else {}
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct((bh,) + s, dt)
+
+    def run(q, k, v, g, beta):
+        with _pk._enable_x64(False):
+            return pl.pallas_call(
+                functools.partial(_local_fwd_kernel, heads=heads, r=r, c=c),
+                grid=(bh // heads, n),
+                in_specs=[spec["qk_rows"], spec["qk_rows"], spec["values"],
+                          spec["vectors"], spec["vectors"]],
+                out_specs=[spec["values"], spec["square"], spec["square"],
+                           spec["vectors"], spec["vectors"]]
+                + [spec["inverses"]] * keep_inverse,
+                out_shape=[shape(r, n, c, dv), shape(r, n, c, c),
+                           shape(r, n, c, c), shape(r, n, c, dt=_F32),
+                           shape(r, n, c, dt=_F32)]
+                + [shape(n, c, r * c, dt=_F32)] * keep_inverse,
+                compiler_params=_params(), name="gdn_local_fwd", **extra,
+            )(q, k, v, g, beta)
+
+    return jax.jit(run)
+
+
+@functools.lru_cache(maxsize=128)
+def _local_bwd_jitted(bh, r, n, c, dk, dv, dtype, heads, interpret):
+    from jax.experimental import pallas as pl
+    spec = _local_specs(heads, r, n, c, dk, dv)
+    extra = {"interpret": interpret} if interpret is not None else {}
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct((bh,) + s, dt)
+
+    def run(q, k, v, g, beta, inv, d_u, d_m, d_qk, d_grow, d_shrink):
+        with _pk._enable_x64(False):
+            return pl.pallas_call(
+                functools.partial(_local_bwd_kernel, heads=heads, r=r, c=c),
+                grid=(bh // heads, n),
+                in_specs=[spec["qk_rows"], spec["qk_rows"], spec["values"],
+                          spec["vectors"], spec["vectors"], spec["inverses"],
+                          spec["values"], spec["square"], spec["square"],
+                          spec["vectors"], spec["vectors"]],
+                out_specs=[spec["qk_rows"], spec["qk_rows"], spec["values"],
+                           spec["vectors"], spec["vectors"]],
+                out_shape=[shape(n, c, dk), shape(n, c, dk),
+                           shape(r, n, c, dv), shape(r, n, c, dt=_F32),
+                           shape(r, n, c, dt=_F32)],
+                compiler_params=_params(), name="gdn_local_bwd", **extra,
+            )(q, k, v, g, beta, inv, d_u, d_m, d_qk, d_grow, d_shrink)
+
+    return jax.jit(run)
+
+
+def local_fwd(q, k, v, g, beta, keep_inverse=False, interpret=None):
+    """``lm_ops._chunk_local`` in ``gdn_local_fwd``: q, k ``[b, hk, n, c,
+    dk]`` and v ``[b, hk, r, n, c, dv]`` in the compute dtype, g, beta
+    ``[b, hk, r, n, c]`` float32.  Returns (``_chunk_local``'s (u, m, qk,
+    grow, shrink, g_all), ``g_all`` being ``grow``'s last column; the
+    chunks' inverses for :func:`local_bwd`, ``[b * hk, n, c, r c]`` float32
+    with a key head's side by side, or None unless ``keep_inverse``)."""
+    dims, heads = _local_planned(q, v)
+    fn = _local_fwd_jitted(*dims, jnp.dtype(v.dtype), heads, interpret,
+                           bool(keep_inverse))
+    u, m, qk, grow, shrink, *inv = fn(*(_flat(x) for x in (q, k, v, g, beta)))
+    u, m, qk, grow, shrink = (x.reshape(q.shape[:2] + x.shape[1:])
+                              for x in (u, m, qk, grow, shrink))
+    return (u, m, qk, grow, shrink, grow[..., -1]), \
+        inv[0] if inv else None
+
+
+def local_bwd(q, k, v, g, beta, inv, d_local, interpret=None):
+    """The cotangents of :func:`local_fwd`'s operands (what
+    ``jax.vjp(_chunk_local)`` pulls back) in ``gdn_local_bwd``, from the
+    inverses ``local_fwd`` kept and the cotangents of (u, m, qk, grow,
+    shrink, g_all) as :func:`scan_bwd` returns them: ``g_all``'s inside
+    ``grow``'s, so its own place is not read."""
+    dims, heads = _local_planned(q, v)
+    fn = _local_bwd_jitted(*dims, jnp.dtype(v.dtype), heads, interpret)
+    d_u, d_m, d_qk, d_grow, d_shrink = d_local[:5]
+    out = fn(*(_flat(x) for x in (q, k, v, g, beta)), inv,
+             *(_flat(x) for x in (d_u.astype(v.dtype), d_m.astype(v.dtype),
+                                  d_qk.astype(v.dtype), d_grow, d_shrink)))
+    return tuple(x.reshape(like.shape)
+                 for x, like in zip(out, (q, k, v, g, beta)))

@@ -263,6 +263,26 @@ def _scan_inputs(q, k, v, g, beta, chunk):
     return xs, pull
 
 
+def _local_part(args, kernel, keep_inverse=True):
+    """(``local``, its pull-back) of the ``kernel`` path on the chunked
+    operands: ``gdn_kernels.local_fwd`` / ``local_bwd`` where the shape has
+    their plan (``gdn_kernels.local_planned``), else ``_chunk_local`` in
+    XLA.  The backward rule's call keeps the chunks' inverses for
+    ``local_bwd``; the differentiated forward's asks the same, so that XLA
+    merges the call the mirror stage recomputes with it (a forward that
+    nothing differentiates does not write them)."""
+    q, v = args[0], args[2]
+    b, hk, n, c, dk = q.shape
+    interpret = (kernel == "interpret") or None
+    if not gdn_kernels.local_planned((b, hk, n * c, dk), v.shape, c,
+                                     v.dtype):
+        return jax.vjp(_chunk_local, *args)
+    local, inv = gdn_kernels.local_fwd(*args, keep_inverse=keep_inverse,
+                                       interpret=interpret)
+    return local, functools.partial(gdn_kernels.local_bwd, *args, inv,
+                                    interpret=interpret)
+
+
 def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
     """q, k: [b, hk, t, dk]; v: [b, hk, r, t, dv]; g, beta: [b, hk, r, t];
     t a multiple of ``chunk``.  Returns (outputs [b, hk, r, t, dv], the
@@ -272,7 +292,7 @@ def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
     if kernel:
         args = _chunks(q, k, v, g, beta, chunk)
         with jax.named_scope(LOCAL_SCOPE):
-            local = _chunk_local(*args)
+            local, _ = _local_part(args, kernel, keep_states)
         with jax.named_scope(SCAN_SCOPE):
             outs, states = gdn_kernels.scan_fwd(
                 args[0], args[1], local, keep_states,
@@ -294,11 +314,12 @@ def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
 @functools.lru_cache(maxsize=None)
 def _make_gdr(chunk, kernel=None):
     """The differentiable recurrence at one ``chunk``.  ``kernel``: None for
-    the ``lax.scan`` over chunks (the CPU path, the fallback, the tests'
-    oracle), ``"pallas"`` for ``ops/gdn_kernels.py``'s two kernels in its
-    place (``"interpret"``: the same through the Pallas interpreter, the
-    tests'); the chunk-local part and its pull-back are the same code either
-    way."""
+    the ``lax.scan`` over chunks and ``_chunk_local`` in XLA (the CPU path,
+    the fallback, the tests' oracle), ``"pallas"`` for ``ops/gdn_kernels.py``'s
+    scan kernels in its place and, where the shape has their plan, its
+    local kernels in place of ``_chunk_local`` and its pull-back
+    (``"interpret"``: the same through the Pallas interpreter, the
+    tests')."""
     @jax.custom_vjp
     def gdr(q, k, v, g, beta):
         return _gdr_forward(q, k, v, g, beta, chunk, kernel,
@@ -317,7 +338,7 @@ def _make_gdr(chunk, kernel=None):
         if kernel:
             args = _chunks(q, k, v, g, beta, chunk)
             with jax.named_scope(LOCAL_SCOPE):
-                local, pull = jax.vjp(_chunk_local, *args)
+                local, pull = _local_part(args, kernel)
             with jax.named_scope(SCAN_SCOPE):
                 d_q, d_k, d_local = gdn_kernels.scan_bwd(
                     args[0], args[1], local, states, _split(d_out, 3, n),
@@ -354,9 +375,10 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
     g (the log of the decay), beta [batch, key heads, r, seq], computed
     chunk by chunk; any ``seq``: a tail chunk is padded with tokens that
     leave the state as it is (beta 0, decay 1).  The recurrence over the
-    chunks is ``ops/gdn_kernels.py``'s Pallas kernels where the program is
+    chunks, and the chunk-local part where the shape has the local kernels'
+    plan, are ``ops/gdn_kernels.py``'s Pallas kernels where the program is
     traced for a TPU and the shape is eligible (``gdn_kernels.mode``: no
-    knob), else a ``lax.scan``."""
+    knob), else a ``lax.scan`` after ``_chunk_local``."""
     t = int(q.shape[2])
     pad = (-t) % chunk
     if pad:

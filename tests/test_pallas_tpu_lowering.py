@@ -7,8 +7,8 @@ are the ones the chip runs: every BatchNorm and Pooling input of the
 ResNet-50 train step at batch 32, read off the symbol itself, and flash
 attention at head_dim 128 and at the language-model cell's own shape
 (8,192 tokens, 16 query heads over 2 K/V heads of 256), forward and
-gradient; the delta-rule scan kernels (``ops/gdn_kernels.py``) at that
-cell's shape too.
+gradient; the delta-rule scan and chunk-local kernels
+(``ops/gdn_kernels.py``) at that cell's shape too.
 
 The lowering cannot see Mosaic's own compile (layout inference, unaligned
 slices).  ``-m slow`` adds it: libtpu compiles for a named v5e topology
@@ -25,7 +25,7 @@ import pytest
 
 from mxnet_tpu import models
 from mxnet_tpu.base import shape_attr
-from mxnet_tpu.ops import lm_ops
+from mxnet_tpu.ops import gdn_kernels, lm_ops
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.nn import _pool_core
 
@@ -190,6 +190,19 @@ def _cases():
     out.append(("gdn-grad",
                 jax.grad(lambda *a: jnp.sum(gdr(*a).astype(jnp.float32) ** 2),
                          argnums=(0, 1, 2, 3, 4)), gdn))
+    # the two chunk-local kernels alone at that shape: 128 chunks of 64
+    chunks = _aval((2, 16, 128, 64, 128), "bfloat16")
+    per_chunk = _aval((2, 16, 2, 128, 64, 128), "bfloat16")
+    decays = _aval((2, 16, 2, 128, 64), "float32")
+    squares = _aval((2, 16, 2, 128, 64, 64), "bfloat16")
+    inverses = _aval((32, 128, 64, 128), "float32")
+    local = (chunks, chunks, per_chunk, decays, decays)
+    out.append(("gdn-local-fwd", lambda *a: gdn_kernels.local_fwd(
+        *a, keep_inverse=True), local))
+    out.append(("gdn-local-bwd", lambda q, k, v, g, b, inv, *d:
+                gdn_kernels.local_bwd(q, k, v, g, b, inv, d),
+                local + (inverses, per_chunk, squares, squares, decays,
+                         decays)))
     return out
 
 
@@ -315,10 +328,11 @@ def test_compiled_kernels_carry_their_scope_and_pass(v5e_device):
         assert of(kernel) == {("mx:attn", "mx:attn:window", "backward")}
     assert of("gdn_scan_fwd") == {("mx:gdn", "mx:gdn:scan", "forward")}
     assert of("gdn_scan_bwd") == {("mx:gdn", "mx:gdn:scan", "backward")}
-    # (XLA merges the forward's chunk-local ops with the same ops of the
-    # backward's ``jax.vjp(_chunk_local)``: one of the two names survives)
-    assert "backward" in {r["pass"] for r in table.values()
-                          if r["detail"] == "mx:gdn:local"}
+    assert of("gdn_local_bwd") == {("mx:gdn", "mx:gdn:local", "backward")}
+    # the backward rule calls ``gdn_local_fwd`` too, to recompute the chunks
+    # (where a mirror stage recomputes the forward, XLA merges the two)
+    assert of("gdn_local_fwd") == {("mx:gdn", "mx:gdn:local", "forward"),
+                                   ("mx:gdn", "mx:gdn:local", "backward")}
 
 
 @pytest.mark.slow

@@ -149,8 +149,11 @@ def _wide_inputs(seq, r, dtype, seed=80):
 
 def _through(kernel, monkeypatch, *args):
     """``_chunked`` with the recurrence over chunks as ``kernel`` says
-    (what ``gdn_kernels.mode`` would, steered here)."""
+    (what ``gdn_kernels.mode`` would, steered here) and the chunk-local part
+    XLA's ``_chunk_local`` either way: the scan kernels alone
+    (tests/test_gdn_local.py holds the local kernels)."""
     monkeypatch.setattr(gdn_kernels, "mode", lambda *a: kernel)
+    monkeypatch.setattr(gdn_kernels, "local_planned", lambda *a: False)
     return _chunked(*args)
 
 
@@ -218,10 +221,13 @@ def _gdn_jaxpr(shapes, dtype, platform):
 
 def test_a_tpu_program_at_the_cells_shape_holds_the_kernels_and_no_loop():
     text = _gdn_jaxpr(CELL_GDN, "bfloat16", "tpu")
-    assert "name=gdn_scan_fwd" in text and "name=gdn_scan_bwd" in text
+    for kernel in ("gdn_scan_fwd", "gdn_scan_bwd", "gdn_local_fwd",
+                   "gdn_local_bwd"):
+        assert "name=%s" % kernel in text, kernel
     assert "scan[" not in text and "while[" not in text
     loop = _gdn_jaxpr(CELL_GDN, "bfloat16", "cpu")
     assert "pallas_call" not in loop and loop.count("scan[") == 2
+    assert "gdn_local" not in loop
     # XLA partitions the program by itself: no Mosaic kernel can be in it
     with pk.trace_scope(partitioned=True):
         assert "pallas_call" not in _gdn_jaxpr(CELL_GDN, "bfloat16", "tpu")
@@ -508,6 +514,7 @@ def test_fit_trains_through_the_fused_step_like_three_adam_steps():
     # (forward, the recomputed forward, backward); a CPU program scans
     assert snap["module.gdn.chunk_steps"]["value"] == 3 * (3 * 2 * 4 * 3)
     assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 0
+    assert snap["module.gdn.local_chunks_in_kernel"]["value"] == 0
 
     p, m = dict(params), {n: jnp.zeros_like(a) for n, a in params.items()}
     v = dict(m)
@@ -548,12 +555,14 @@ def _one_fused_step(recompute=True):
 
 
 def test_chunk_step_counters_say_whether_the_kernels_engage(monkeypatch):
-    """``module.gdn.chunk_steps`` counts the scans' work; ``_in_kernel`` is 0
-    where the step program scans (any CPU program) and the same number where
-    it holds the kernels: here the interpreter's, steered in the test."""
+    """``module.gdn.chunk_steps`` counts the scans' work; ``_in_kernel`` and
+    ``local_chunks_in_kernel`` are 0 where the step program scans (any CPU
+    program) and the same number where it holds the scan and the local
+    kernels: here the interpreter's, steered in the test."""
     loss, after, snap = _one_fused_step()
     assert snap["module.gdn.chunk_steps"]["value"] == 3 * 2 * 4 * 3
     assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 0
+    assert snap["module.gdn.local_chunks_in_kernel"]["value"] == 0
     _, _, snap = _one_fused_step(recompute=False)       # no second forward
     assert snap["module.gdn.chunk_steps"]["value"] == 2 * 2 * 4 * 3
 
@@ -564,6 +573,8 @@ def test_chunk_step_counters_say_whether_the_kernels_engage(monkeypatch):
     loss_k, after_k, snap = _one_fused_step()
     assert snap["module.gdn.chunk_steps"]["value"] == 3 * 2 * 4 * 3
     assert snap["module.gdn.chunk_steps_in_kernel"]["value"] == 3 * 2 * 4 * 3
+    assert snap["module.gdn.local_chunks_in_kernel"]["value"] \
+        == 3 * 2 * 4 * 3
     _close([loss_k], [loss], 1e-5)
     for name in sorted(after):
         _close(after_k[name], after[name], 1e-3), name

@@ -367,6 +367,6 @@ def test_a_model_without_attention_counts_no_pairs():
     mod = mx.mod.Module(models.mlp.get_symbol(num_classes=2), context=mx.cpu())
     mod.fit(mx.io.NDArrayIter(x, y, batch_size=4), num_epoch=1)
     assert mod._fused_step.ran \
-        and mod._fused_step._schedule_counts() == ((0, 0), (0, 0))
+        and mod._fused_step._schedule_counts() == ((0, 0), (0, 0, 0))
     assert "module.attn.pairs_computed" not in telemetry.snapshot()
     assert "module.gdn.chunk_steps" not in telemetry.snapshot()
