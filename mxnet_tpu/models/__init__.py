@@ -20,5 +20,6 @@ from . import qwen3_next  # noqa: F401
 from . import trinity  # noqa: F401
 from . import joyai_flash  # noqa: F401
 from . import sdar  # noqa: F401
+from . import granite_hybrid  # noqa: F401
 
 get_symbol = resnet.get_symbol

@@ -1,5 +1,6 @@
 """What the decoder language models share (``qwen3_next.py``, ``trinity.py``,
-``joyai_flash.py``, ``sdar.py``): parameters in the storage dtype (one
+``joyai_flash.py``, ``sdar.py``, ``granite_hybrid.py``): parameters in the
+storage dtype (one
 variable a name, so that two nodes may read one parameter and its gradient is
 the sum of both uses), bias-free projections over ``[batch, seq, hidden]``,
 RMSNorm, a SwiGLU MLP, the expert layer's call, the mirror stage of half a
@@ -101,32 +102,41 @@ class LMBuilder:
         return sym.AttrScope(
             **{NAMED_SCOPE: outer + "/" + scope if outer else scope})
 
-    def token_loss(self, x, prefix="", shift=0):
+    def token_loss(self, x, prefix="", shift=0, tied=False, divisor=1.0):
         """The cross-entropy, one mean a sequence, of the normed hidden
         state ``x`` through THE output matrix (``lm_head_weight``, whichever
-        node asks) against ``softmax_label`` ``shift`` positions on: 0 is
-        the next token, 1 the one after it."""
+        node asks; ``embed_weight`` where ``tied``) against
+        ``softmax_label`` ``shift`` positions on: 0 is the next token, 1 the
+        one after it.  The logits are divided by ``divisor`` where it is not
+        1."""
         with self.named("mx:head"):
             logits = self.dense(x, prefix + "lm_head",
-                                self.cfg["vocab_size"], weight="lm_head")
+                                self.cfg["vocab_size"],
+                                weight="embed" if tied else "lm_head")
+            if float(divisor) != 1.0:
+                logits = logits / float(divisor)
             return sym.sequence_cross_entropy(
                 logits, self.label(), shift=int(shift),
                 name=prefix + "ce")
 
-    def outputs(self, x, counts, second=None):
+    def outputs(self, x, counts, second=None, tied=False, divisor=1.0):
         """``Group([loss, expert selection counts])`` from the last block's
-        output through the final norm, ``x``: untied head, the next-token
+        output through the final norm, ``x``: the head (``token_loss``:
+        untied unless ``tied``, logits over ``divisor``), the next-token
         cross-entropy as one mean a sequence under ``MakeLoss``, and the
         layers' counts stacked without gradient and marked for
-        ``Module.update_metric``.
+        ``Module.update_metric``; ``counts`` None (a model without experts):
+        ``Group([loss])``.
 
         ``second = (weight, loss, counter names)`` adds a second head's loss
         [batch] (``token_loss``) to the one that is trained, ``main + weight *
         loss``, and a third output: the two parts stacked without gradient,
         which ``update_metric`` adds to the two counters named."""
-        main = self.token_loss(x)
+        main = self.token_loss(x, tied=tied, divisor=divisor)
         total = main if second is None else main + float(second[0]) * second[1]
         loss = sym.MakeLoss(total, name="loss")
+        if counts is None:
+            return sym.Group([loss])
         counts = self.moe_counts(counts)
         if second is None:
             return sym.Group([loss, counts])

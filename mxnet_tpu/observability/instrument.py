@@ -1007,6 +1007,18 @@ def note_gdn_chunk_steps(steps, in_kernel, local_in_kernel):
                  "Pallas local kernels").inc(local_in_kernel)
 
 
+def note_ssm_lowering(in_kernel):
+    """``ops.ssm.lowered_kernel`` / ``ops.ssm.lowered_xla``: one lowering of
+    Mamba-2's recurrence (``lm_ops.chunked_gated_delta_rule`` without its
+    correction, the ``ssd`` op) into a program being traced, through the
+    Pallas kernels ``ssd_scan_fwd`` / ``ssd_scan_bwd`` or through the
+    ``lax.scan`` fallback; counted as it is traced, so a program that falls
+    back shows without a device trace."""
+    telemetry.counter(
+        "ops.ssm.lowered_kernel" if in_kernel else "ops.ssm.lowered_xla",
+        help="lowerings of the state-space recurrence by path").inc()
+
+
 def note_counter_rows(rows, names):
     """One step's value of the counters a model's output names
     (``__counters__``, read where the loss is read): row ``i`` of ``rows``,

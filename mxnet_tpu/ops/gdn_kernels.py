@@ -41,6 +41,22 @@ MXU, float32 accumulation, float32 state and decays.  ``g_all``, the decay
 over a whole chunk, is ``grow``'s last column (``_chunk_local`` computes both
 as ``exp`` of the same number), so the kernels read it there and the
 backward returns its cotangent inside ``grow``'s.
+
+Without the delta rule's correction (Mamba-2's state-space layer,
+``lm_ops._ssd``) a chunk's local part is q k^T under each value head's
+decay, which costs less to compute again than to keep, so one kernel a pass
+does the whole chunk, grid (key head x head block, chunks):
+
+- ``ssd_scan_fwd``: ``lm_ops._chunk_plain`` and ``_chunk_step`` of one
+  chunk of ``hb`` value heads.  The value heads stand side by side on the
+  lanes as the op's input lays them (``[c, hb P]``: 64-wide heads make
+  lane-dense tiles by twos), the float32 state ``[dk, hb P]`` in a VMEM
+  scratch, q k^T computed once for the heads of a key head, the ``hb``
+  decayed ``[c, c]`` matrices stacked on the sublanes so that one product
+  gives every head's part (each head keeps its own lanes of it).
+- ``ssd_scan_bwd``: the same grid from the last chunk to the first, the
+  state's cotangent in the scratch; emits the cotangents of q, k (one a
+  head block, summed after), v and the running log decay.
 """
 from __future__ import annotations
 
@@ -116,28 +132,71 @@ def _gdn_plan(bh, r, n, c, dk, dv, itemsize, vmem=_gdn_vmem_bytes):
     return None
 
 
-def eligible(dk, dv, chunk, dtype):
-    """Whether the kernels take this shape: dk and dv whole 128-lane tiles,
-    ``chunk`` whole sublane tiles of the element type, bfloat16 or
-    float32."""
+# the lanes of a state-space grid step's value heads, side by side: past
+# 512 the stacked product of the heads' decayed matrices grows as their
+# square and the steps are already few (1,024 a pass at 64 heads of 64 and
+# 8,192 tokens)
+_SSD_MAX_LANES = 512
+
+
+def eligible(dk, dv, chunk, dtype, r=1, correction=True):
+    """Whether the kernels take this shape: dk and dv whole 128-lane tiles
+    (without the ``correction``: the ``r`` value heads of a key head make
+    whole tiles side by side, 64-wide heads by twos), ``chunk`` whole
+    sublane tiles of the element type, bfloat16 or float32."""
     dtype = jnp.dtype(dtype)
+    wide = dv % 128 == 0 if correction else (r * dv) % 128 == 0
     return dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)) \
-        and dk % 128 == 0 and dv % 128 == 0 \
+        and dk % 128 == 0 and wide \
         and chunk % _pk._sublanes(dtype.itemsize) == 0
 
 
-def mode(q_shape, v_shape, chunk, dtype):
+def _ssd_vmem_bytes(hb, c, dk, dv, itemsize):
+    """VMEM one grid step of ``ssd_scan_bwd`` (the larger of the two)
+    holds for ``hb`` value heads of ``dv`` side by side: the
+    double-buffered per-chunk operands and results, the float32 state
+    scratch, and the working tiles (the stacked ``[hb c, c]`` matrices and
+    their cotangents, the ``[hb c, hb dv]`` product, a dozen float32 ``[c,
+    hb dv]`` tiles)."""
+    lanes = lambda w: _pk._round_up(w, 128)
+    w = hb * dv
+    vec = _pk._round_up(hb, 8) * lanes(c) * 4
+    piped = (2 * c * lanes(dk) + 2 * c * w + dk * w) * itemsize + vec \
+        + 2 * c * lanes(dk) * 4 + c * w * itemsize + vec
+    work = hb * c * lanes(c) * (itemsize + 4 + 4) + hb * c * w * 4 \
+        + 12 * c * w * 4
+    return 2 * piped + dk * w * 4 + work
+
+
+def _ssd_plan(r, c, dk, dv, itemsize):
+    """Value heads a grid step of the state-space kernels takes: the most
+    that divide ``r``, make whole 128-lane tiles side by side, stay within
+    ``_SSD_MAX_LANES`` and fit ``_GDN_VMEM_BUDGET``; None where none does."""
+    for hb in range(r, 0, -1):
+        if r % hb == 0 and (hb * dv) % 128 == 0 \
+                and hb * dv <= max(_SSD_MAX_LANES, dv) \
+                and _ssd_vmem_bytes(hb, c, dk, dv, itemsize) \
+                <= _GDN_VMEM_BUDGET:
+            return hb
+    return None
+
+
+def mode(q_shape, v_shape, chunk, dtype, correction=True):
     """How the recurrence of ``chunked_gated_delta_rule`` at q ``[b, hk, t,
     dk]``, v ``[b, hk, r, t, dv]`` runs in the program being traced:
     ``"pallas"`` where that program is for a TPU that XLA does not partition
     by itself (:func:`pallas_kernels.trace_scope`) and the shape is
-    :func:`eligible` and has a plan, else None: the ``lax.scan``.  No knob:
-    the platform and the shape decide."""
+    :func:`eligible` and has a plan (without the ``correction``:
+    :func:`_ssd_plan`), else None: the ``lax.scan``.  No knob: the platform
+    and the shape decide."""
     b, hk, t, dk = (int(x) for x in q_shape)
     r, dv = int(v_shape[2]), int(v_shape[-1])
     if not _pk.traced_for_unpartitioned_tpu() \
-            or not eligible(dk, dv, chunk, dtype):
+            or not eligible(dk, dv, chunk, dtype, r, correction):
         return None
+    if not correction:
+        planned = _ssd_plan(r, chunk, dk, dv, jnp.dtype(dtype).itemsize)
+        return "pallas" if planned else None
     planned = _gdn_plan(b * hk, r, -(-t // chunk), chunk, dk, dv,
                         jnp.dtype(dtype).itemsize)
     return "pallas" if planned else None
@@ -827,3 +886,283 @@ def local_bwd(q, k, v, g, beta, inv, d_local, interpret=None):
                                   d_qk.astype(v.dtype), d_grow, d_shrink)))
     return tuple(x.reshape(like.shape)
                  for x, like in zip(out, (q, k, v, g, beta)))
+
+
+# -- without the correction: one kernel a pass (Mamba-2's state-space layer) --
+#
+# A grid step holds one chunk of ``hb`` value heads of one key head: q, k
+# ``[c, dk]``, v ``[c, hb dv]`` with head j in lanes j dv .. (j + 1) dv, and
+# the running log decay of each head as a row ``[hb, c]``.
+
+def _row(j):
+    """Sublane ``j`` of a block, as a ``[1, ...]`` slice."""
+    from jax.experimental import pallas as pl
+    return pl.ds(j, 1)
+
+
+def _spread(parts, masks, shape):
+    """One ``shape`` tile holding ``parts[j]`` broadcast where ``masks[j]``
+    holds (a head's lanes), 0 elsewhere."""
+    out = jnp.zeros(shape, _F32)
+    for part, mask in zip(parts, masks):
+        out = jnp.where(mask, jnp.broadcast_to(part, shape), out)
+    return out
+
+
+class _Heads:
+    """One chunk of ``hb`` value heads side by side: the masks of their
+    lanes, the decay as each head's column and row, the decayed ``[c, c]``
+    matrices (float32, and stacked on the sublanes in the compute dtype), and
+    the ``[c, hb dv]`` / ``[1, hb dv]`` tiles of grow, shrink and g_all."""
+
+    def __init__(self, gc_ref, qk, hb, dv, c, cd):
+        eye = _eye(c)
+        lower = _iota((c, c), 0) >= _iota((c, c), 1)
+        w = hb * dv
+        lanes, row_lanes = _iota((c, w), 1), _iota((1, w), 1)
+        self.masks = [(lanes >= j * dv) & (lanes < (j + 1) * dv)
+                      for j in range(hb)]
+        self.row_masks = [(row_lanes >= j * dv) & (row_lanes < (j + 1) * dv)
+                          for j in range(hb)]
+        self.rows = [gc_ref[0, 0, _row(j), :] for j in range(hb)]    # [1, c]
+        self.cols = [_picked(row, eye, 1) for row in self.rows]     # [c, 1]
+        self.decays = [jnp.exp(jnp.where(lower, col - row, -jnp.inf))
+                       for col, row in zip(self.cols, self.rows)]
+        self.stacked = jnp.concatenate([(qk * d).astype(cd)
+                                        for d in self.decays], axis=0)
+        self.last = [_last(col) for col in self.cols]               # [1, 1]
+        self.grow = _spread([jnp.exp(col) for col in self.cols], self.masks,
+                            (c, w))
+        self.shrink = _spread([jnp.exp(g - col) for g, col in
+                               zip(self.last, self.cols)], self.masks, (c, w))
+        self.g_all = _spread([jnp.exp(g) for g in self.last], self.row_masks,
+                             (1, w))
+
+    def own(self, stacked_product, c):
+        """``[c, hb dv]``: each head's lanes of its own block of a product
+        ``[hb c, hb dv]`` of the stacked matrices."""
+        out = None
+        for j, mask in enumerate(self.masks):
+            part = jnp.where(mask, stacked_product[j * c:(j + 1) * c],
+                             _F32(0.0))
+            out = part if out is None else out + part
+        return out
+
+
+def _ssd_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, out_ref, *rest, hb, dv, c):
+    """One chunk of ``hb`` value heads: ``lm_ops._chunk_plain`` and
+    ``_chunk_step``.  Grid = (key head x head block, chunks), the chunks
+    innermost and in order; the float32 state ``[dk, hb dv]`` persists in
+    ``state_ref`` across them.  ``rest``: ``start_ref`` (the states a
+    differentiated forward keeps) where asked for, then the scratch."""
+    from jax.experimental import pallas as pl
+    start_ref, state_ref = rest if len(rest) == 2 else (None,) + rest
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    cd = v.dtype
+    state = state_ref[...]
+    s = state.astype(cd)
+    if start_ref is not None:
+        start_ref[0, 0] = s
+    heads = _Heads(gc_ref, _dot(q, k, _NT), hb, dv, c, cd)
+    intra = heads.own(_dot(heads.stacked, v), c)
+    out_ref[0] = (heads.grow * _dot(q, s) + intra).astype(cd)
+    state_ref[...] = state * heads.g_all + _dot(
+        k, (v.astype(_F32) * heads.shrink).astype(cd), _TN)
+
+
+def _ssd_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, start_ref, do_ref, dq_ref,
+                    dk_ref, dv_ref, dgc_ref, dstate_ref, *, hb, dv, c):
+    """One chunk of ``hb`` value heads, walked from the last chunk to the
+    first (the index maps hand chunk ``n - 1 - i``): the transpose of
+    :func:`_ssd_fwd_kernel`'s step at the saved start state, the state's
+    cotangent ``[dk, hb dv]`` in ``dstate_ref``.  Cotangents are float32
+    and meet the MXU in the compute dtype; a float32 one against an operand
+    in the compute dtype goes as two parts (:func:`_dot_split`)."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    cd = v.dtype
+    s = start_ref[0, 0]                                  # [dk, w]
+    d_next = dstate_ref[...]
+    d_next_c = d_next.astype(cd)
+    d_out = do_ref[0].astype(_F32)                       # [c, w]
+    qk = _dot(q, k, _NT)
+    heads = _Heads(gc_ref, qk, hb, dv, c, cd)
+    v32 = v.astype(_F32)
+    qs = _dot(q, s)
+    # next state = state * g_all + k^T (v * shrink)
+    d_vs = _dot(k, d_next_c)                             # [c, w]
+    d_k = _dot((v32 * heads.shrink).astype(cd), d_next_c, _NT)
+    d_g_all = jnp.sum(d_next * s.astype(_F32), axis=0, keepdims=True)
+    # out = grow * (q s) + own(stacked v)
+    d_qs = (heads.grow * d_out).astype(cd)
+    d_grow = d_out * qs
+    dstate_ref[...] = d_next * heads.g_all + _dot(q, d_qs, _TN)
+    d_q = _dot(d_qs, s, _NT)                             # [c, dk]
+    d_r = jnp.concatenate([jnp.where(m, d_out, _F32(0.0)) for m in
+                           heads.masks], axis=0).astype(cd)   # [hb c, w]
+    d_stacked = _dot(d_r, v, _NT)                        # [hb c, c]
+    dv_ref[0] = (_dot(heads.stacked, d_r, _TN)
+                 + d_vs * heads.shrink).astype(cd)
+    d_shrink = d_vs * v32
+    eye = _eye(c)
+    at_last = _iota((c, 1), 0) == c - 1
+    d_qk = None
+    for j in range(hb):
+        mask, decay = heads.masks[j], heads.decays[j]
+        d_att = d_stacked[j * c:(j + 1) * c]
+        d_qk = d_att * decay if d_qk is None else d_qk + d_att * decay
+        # decay = exp(col - row) on and below the diagonal
+        d_seg = d_att * qk * decay
+        sh = jnp.sum(jnp.where(mask, d_shrink * heads.shrink, _F32(0.0)),
+                     axis=1, keepdims=True)              # [c, 1]
+        d_last = jnp.sum(sh, axis=0, keepdims=True) + jnp.sum(
+            jnp.where(heads.row_masks[j], d_g_all * heads.g_all, _F32(0.0)),
+            axis=1, keepdims=True)                       # [1, 1]
+        d_col = jnp.sum(d_seg, axis=1, keepdims=True) + jnp.sum(
+            jnp.where(mask, d_grow * heads.grow, _F32(0.0)), axis=1,
+            keepdims=True) - sh + jnp.where(at_last, d_last, _F32(0.0))
+        dgc_ref[0, 0, _row(j), :] = _picked(d_col, eye, 0) \
+            - jnp.sum(d_seg, axis=0, keepdims=True)
+    dq_ref[0, 0] = d_q + _dot_split(d_qk, k, _NN)
+    dk_ref[0, 0] = d_k + _dot_split(d_qk, q, _TN)
+
+
+def _ssd_specs(nb, hb, c, dk, dv, chunk_of):
+    """BlockSpecs of the state-space kernels over q, k ``[b hk, t, dk]``, v
+    ``[b hk, t, r dv]``, the running decay ``[b hk nb, n, hb, c]``, the
+    states ``[b hk, n, dk, r dv]`` and the per-head-block cotangents of q
+    and k ``[b hk nb, n, c, dk]``; grid row ``h`` is key head ``h // nb``'s
+    head block ``h % nb``."""
+    from jax.experimental import pallas as pl
+    w = hb * dv
+    return {
+        "rows": pl.BlockSpec((1, c, dk),
+                             lambda h, i: (h // nb, chunk_of(i), 0)),
+        "values": pl.BlockSpec((1, c, w),
+                               lambda h, i: (h // nb, chunk_of(i), h % nb)),
+        "decay": pl.BlockSpec((1, 1, hb, c),
+                              lambda h, i: (h, chunk_of(i), 0, 0)),
+        "states": pl.BlockSpec((1, 1, dk, w),
+                               lambda h, i: (h // nb, chunk_of(i), 0, h % nb)),
+        "partial": pl.BlockSpec((1, 1, c, dk),
+                                lambda h, i: (h, chunk_of(i), 0, 0)),
+    }
+
+
+@functools.lru_cache(maxsize=128)
+def _ssd_fwd_jitted(bh, r, n, c, dk, dv, dtype, hb, interpret, keep_states):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    nb = r // hb
+    spec = _ssd_specs(nb, hb, c, dk, dv, lambda i: i)
+    extra = {"interpret": interpret} if interpret is not None else {}
+    out_specs = [spec["values"]] + [spec["states"]] * keep_states
+    out_shape = [jax.ShapeDtypeStruct((bh, n * c, r * dv), dtype)] \
+        + [jax.ShapeDtypeStruct((bh, n, dk, r * dv), dtype)] * keep_states
+
+    def run(q, k, v, gc):
+        with _pk._enable_x64(False):
+            return pl.pallas_call(
+                functools.partial(_ssd_fwd_kernel, hb=hb, dv=dv, c=c),
+                grid=(bh * nb, n),
+                in_specs=[spec["rows"], spec["rows"], spec["values"],
+                          spec["decay"]],
+                out_specs=out_specs, out_shape=out_shape,
+                scratch_shapes=[pltpu.VMEM((dk, hb * dv), _F32)],
+                compiler_params=_params(), name="ssd_scan_fwd", **extra,
+            )(q, k, v, gc)
+
+    return jax.jit(run)
+
+
+@functools.lru_cache(maxsize=128)
+def _ssd_bwd_jitted(bh, r, n, c, dk, dv, dtype, hb, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    nb = r // hb
+    spec = _ssd_specs(nb, hb, c, dk, dv, lambda i: n - 1 - i)
+    extra = {"interpret": interpret} if interpret is not None else {}
+
+    def run(q, k, v, gc, states, d_out):
+        with _pk._enable_x64(False):
+            return pl.pallas_call(
+                functools.partial(_ssd_bwd_kernel, hb=hb, dv=dv, c=c),
+                grid=(bh * nb, n),
+                in_specs=[spec["rows"], spec["rows"], spec["values"],
+                          spec["decay"], spec["states"], spec["values"]],
+                out_specs=[spec["partial"], spec["partial"], spec["values"],
+                           spec["decay"]],
+                out_shape=[
+                    jax.ShapeDtypeStruct((bh * nb, n, c, dk), _F32),
+                    jax.ShapeDtypeStruct((bh * nb, n, c, dk), _F32),
+                    jax.ShapeDtypeStruct((bh, n * c, r * dv), dtype),
+                    jax.ShapeDtypeStruct((bh * nb, n, hb, c), _F32)],
+                scratch_shapes=[pltpu.VMEM((dk, hb * dv), _F32)],
+                compiler_params=_params(), name="ssd_scan_bwd", **extra,
+            )(q, k, v, gc, states, d_out)
+
+    return jax.jit(run)
+
+
+def _ssd_layout(q, k, v, gc):
+    """The kernels' operands: q, k ``[b hk, t, dk]``; v with a key head's
+    value heads side by side ``[b hk, t, r dv]``; the running log decay
+    ``[b, hk, r, n, c]`` as rows ``[b hk nb, n, hb, c]``.  Returns them and
+    the dims (b hk, r, n, c, dk, dv, hb)."""
+    b, hk, t, dk = q.shape
+    r, n, c = int(v.shape[2]), int(gc.shape[3]), int(gc.shape[4])
+    dv = int(v.shape[-1])
+    hb = _ssd_plan(r, c, dk, dv, jnp.dtype(v.dtype).itemsize)
+    if not hb:
+        raise ValueError("ssd_scan: no plan for %d heads of %d at chunk %d"
+                         % (r, dv, c))
+    bh, nb = b * hk, r // hb
+    lay_v = jnp.swapaxes(v, 2, 3).reshape(bh, t, r * dv)
+    lay_gc = gc.reshape(b, hk, nb, hb, n, c).transpose(0, 1, 2, 4, 3, 5) \
+        .reshape(bh * nb, n, hb, c)
+    return (_flat(q), _flat(k), lay_v, lay_gc), (bh, r, n, c, dk, dv, hb)
+
+
+def ssd_fwd(q, k, v, gc, keep_states=True, interpret=None):
+    """The recurrence without the correction, forward: q, k ``[b, hk, t,
+    dk]``, v ``[b, hk, r, t, dv]`` in the compute dtype and ``gc`` the
+    running log decay within each chunk ``[b, hk, r, n, c]`` float32 (``t
+    = n c``).  Returns (outputs ``[b, hk, r, t, dv]``, the state every chunk
+    starts from ``[b hk, n, dk, r dv]`` in the operands' dtype, or None
+    unless ``keep_states``).  ``interpret`` (the tests'): the Pallas
+    interpreter."""
+    ops, dims = _ssd_layout(q, k, v, gc)
+    fn = _ssd_fwd_jitted(*dims[:6], jnp.dtype(v.dtype), dims[6], interpret,
+                         bool(keep_states))
+    out, *states = fn(*ops)
+    b, hk, r, t, dv = v.shape
+    out = jnp.swapaxes(out.reshape(b, hk, t, r, dv), 2, 3)
+    return out, states[0] if states else None
+
+
+def ssd_bwd(q, k, v, gc, states, d_out, interpret=None):
+    """The cotangents of q, k, v and ``gc`` of :func:`ssd_fwd` from the
+    ``states`` it kept and the outputs' cotangent ``[b, hk, r, t, dv]``."""
+    ops, dims = _ssd_layout(q, k, v, gc)
+    bh, r, n, c, dk, dv, hb = dims
+    fn = _ssd_bwd_jitted(*dims[:6], jnp.dtype(v.dtype), hb, interpret)
+    d_o = jnp.swapaxes(d_out.astype(v.dtype), 2, 3).reshape(bh, n * c,
+                                                            r * dv)
+    d_q, d_k, d_v, d_gc = fn(*ops, states, d_o)
+    b, hk = q.shape[:2]
+    summed = lambda x: jnp.sum(x.reshape(bh, r // hb, n * c, dk), axis=1) \
+        .reshape(q.shape).astype(q.dtype)
+    d_v = jnp.swapaxes(d_v.reshape(b, hk, n * c, r, dv), 2, 3)
+    d_gc = d_gc.reshape(b, hk, r // hb, n, hb, c).transpose(0, 1, 2, 4, 3, 5) \
+        .reshape(gc.shape)
+    return summed(d_q), summed(d_k), d_v, d_gc

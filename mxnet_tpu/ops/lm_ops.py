@@ -1,6 +1,7 @@
-"""Graph ops of the modern decoder block (ROADMAP M1, M3, M6): zero-centred
-RMSNorm, partial rotary positions, SwiGLU, causal depth-wise conv1d, the
-gated delta rule as a chunked scan, a dropless top-k expert layer that is
+"""Graph ops of the modern decoder block (ROADMAP M1, M3, M6, M17):
+zero-centred RMSNorm, partial rotary positions, SwiGLU, causal depth-wise
+conv1d, the gated delta rule as a chunked scan and Mamba-2's state-space
+layer on the same scan, a dropless top-k expert layer that is
 told which experts it holds, and a per-sequence softmax cross-entropy.
 
 Each is a pure JAX function registered like every other op, so a model is
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..observability import instrument as _instrument
 from . import gdn_kernels
 from .registry import register, pBool, pFloat, pInt, pStr
 
@@ -110,9 +112,11 @@ register("SwiGLU", _swiglu, input_names=("gate", "up"))
 
 # -- causal depth-wise conv1d -------------------------------------------------
 
-def _causal_conv1d(data, weight, kernel=4, activation="silu"):
-    """``y[t, c] = sum_j w[c, j] x[t - (kernel-1) + j, c]`` on ``[batch, seq,
-    channels]`` (zeros before the sequence, no bias), then SiLU unless
+def _causal_conv1d(data, weight, *bias, kernel=4, activation="silu",
+                   use_bias=False):
+    """``y[t, c] = sum_j w[c, j] x[t - (kernel-1) + j, c] (+ b[c])`` on
+    ``[batch, seq, channels]`` (zeros before the sequence; the ``bias``
+    input where ``use_bias``), accumulated in float32, then SiLU unless
     ``activation`` is ``'none'``."""
     k = int(kernel)
     x = data.astype(_F32)
@@ -120,6 +124,8 @@ def _causal_conv1d(data, weight, kernel=4, activation="silu"):
     s = int(x.shape[1])
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     y = sum(xp[:, j:j + s, :] * w[:, j] for j in range(k))
+    if use_bias:
+        y = y + bias[0].astype(_F32)
     if activation == "silu":
         y = jax.nn.silu(y)
     return y.astype(data.dtype)
@@ -130,12 +136,17 @@ def _conv1d_infer_shape(in_shapes, attrs):
     if in_shapes[0] is None:
         return filled, [None]
     filled[1] = (int(in_shapes[0][-1]), int(attrs["kernel"]))
+    if len(filled) > 2:
+        filled[2] = (int(in_shapes[0][-1]),)
     return filled, [tuple(in_shapes[0])]
 
 
-register("causal_conv1d", _causal_conv1d, input_names=("data", "weight"),
+register("causal_conv1d", _causal_conv1d,
+         input_names=("data", "weight", "bias"),
+         num_inputs=lambda attrs: 2 + bool(attrs.get("use_bias")),
          infer_shape=_conv1d_infer_shape,
-         params={"kernel": (pInt, 4), "activation": (pStr, "silu")})
+         params={"kernel": (pInt, 4), "activation": (pStr, "silu"),
+                 "use_bias": (pBool, False)})
 
 
 # -- the gated delta rule, chunk-wise --------------------------------------------
@@ -152,11 +163,25 @@ register("causal_conv1d", _causal_conv1d, input_names=("data", "weight"),
 # [c, dv] matrix, two [c, c] matrices and three vectors — kept in the
 # operands' dtype (bfloat16 in mixed precision, as the products read them);
 # the decay, the solve and the carried state are float32.
+#
+# Without the correction (``correction=False``: Mamba-2's state-space
+# duality, below) the state takes ``k_t v_t^T`` as it stands: U = V, there is
+# no L, no T and no M, and the chunk needs q k^T under the decay alone.
 
 # the two parts of the op under its ``mx:gdn`` scope, in the forward and in
-# the backward rule alike (docs/observability.md: device time by mechanism)
+# the backward rule alike (docs/observability.md: device time by mechanism);
+# the state-space op's under ``mx:ssm``
 LOCAL_SCOPE = "mx:gdn:local"
 SCAN_SCOPE = "mx:gdn:scan"
+SSM_LOCAL_SCOPE = "mx:ssm:local"
+SSM_SCAN_SCOPE = "mx:ssm:scan"
+
+
+def _scopes(correction):
+    """(local, scan) scope names of the recurrence with or without the
+    delta rule's correction."""
+    return (LOCAL_SCOPE, SCAN_SCOPE) if correction \
+        else (SSM_LOCAL_SCOPE, SSM_SCAN_SCOPE)
 
 
 def _l2norm(x, eps=1e-6):
@@ -222,13 +247,34 @@ def _chunk_local(q, k, v, g, beta):
             jnp.exp(g_last - gc), jnp.exp(g_last[..., 0]))
 
 
+def _chunk_plain(q, k, v, g):
+    """:func:`_chunk_local` without the correction: u is v itself, there is
+    no m (None), and qk is q k^T under the decay.  Same layouts."""
+    cd = v.dtype
+    c = int(q.shape[-2])
+    gc = jnp.cumsum(g, axis=-1)
+    # graftlint: disable=GL003 — static index grids for the triangular mask
+    rows = np.arange(c)[:, None]
+    # graftlint: disable=GL003 — as above
+    cols = np.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(rows >= cols,
+                              gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    qk = jnp.einsum("bhnid,bhnjd->bhnij", q, k,
+                    preferred_element_type=_F32)[:, :, None] * decay
+    g_last = gc[..., -1:]
+    return (v, None, qk.astype(cd), jnp.exp(gc), jnp.exp(g_last - gc),
+            jnp.exp(g_last[..., 0]))
+
+
 def _chunk_step(state, xs):
     """One chunk given the float32 state [b, hk, r, dk, dv] it starts from:
-    (next state, outputs [b, hk, r, c, dv])."""
+    (next state, outputs [b, hk, r, c, dv]).  ``m`` None: no correction,
+    ``v_new`` is ``u``."""
     q, k, u, m, qk, grow, shrink, g_all = xs
     cd = u.dtype
     s = state.astype(cd)
-    v_new = u.astype(_F32) - _mm32(m, _mm32(k[:, :, None], s).astype(cd))
+    v_new = u.astype(_F32) if m is None else \
+        u.astype(_F32) - _mm32(m, _mm32(k[:, :, None], s).astype(cd))
     out = grow[..., None] * _mm32(q[:, :, None], s) \
         + _mm32(qk, v_new.astype(cd))
     nxt = state * g_all[..., None, None] + jnp.einsum(
@@ -245,21 +291,23 @@ def _split(x, axis, n):
 
 def _chunks(q, k, v, g, beta, chunk):
     """The operands cut into chunks: q, k [b, hk, n, c, dk]; v, g, beta [b,
-    hk, r, n, c, ...]."""
+    hk, r, n, c, ...].  ``beta`` None (no correction): left out."""
     n = int(q.shape[2]) // chunk
     return (_split(q, 2, n), _split(k, 2, n), _split(v, 3, n),
-            _split(g, 3, n), _split(beta, 3, n))
+            _split(g, 3, n)) + ((_split(beta, 3, n),) if beta is not None
+                                else ())
 
 
 def _scan_inputs(q, k, v, g, beta, chunk):
     """(per-chunk operands with the chunk axis leading, the vjp of the
-    chunk-local part)."""
+    chunk-local part: :func:`_chunk_plain`'s where ``beta`` is None)."""
     args = _chunks(q, k, v, g, beta, chunk)
-    with jax.named_scope(LOCAL_SCOPE):
-        local, pull = jax.vjp(_chunk_local, *args)
+    with jax.named_scope(_scopes(beta is not None)[0]):
+        local, pull = jax.vjp(_chunk_local if beta is not None
+                              else _chunk_plain, *args)
     lead = lambda x, axis: jnp.moveaxis(x, axis, 0)
     xs = (lead(args[0], 2), lead(args[1], 2)) \
-        + tuple(lead(x, 3) for x in local)
+        + tuple(None if x is None else lead(x, 3) for x in local)
     return xs, pull
 
 
@@ -283,12 +331,27 @@ def _local_part(args, kernel, keep_inverse=True):
                                     interpret=interpret)
 
 
+def _ssd_cumulative(g, chunk):
+    """The running sum of the log decay within each chunk, [b, hk, r, n,
+    c]: all the state-space kernels need of the chunk-local part."""
+    return jnp.cumsum(_split(g, 3, int(g.shape[3]) // chunk), axis=-1)
+
+
 def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
     """q, k: [b, hk, t, dk]; v: [b, hk, r, t, dv]; g, beta: [b, hk, r, t];
     t a multiple of ``chunk``.  Returns (outputs [b, hk, r, t, dv], the
     state each chunk starts from, kept in the operands' dtype: [n, b, hk, r,
     dk, dv], or [b, hk, n, r, dk, dv] from the ``kernel``, which writes them
-    only where ``keep_states``; XLA drops the scan's by itself)."""
+    only where ``keep_states``; XLA drops the scan's by itself).  ``beta``
+    None: no correction (``gdn_kernels.ssd_fwd`` on the kernel path, whose
+    states are [b * hk, n, dk, r * dv])."""
+    if kernel and beta is None:
+        interpret = (kernel == "interpret") or None
+        with jax.named_scope(SSM_LOCAL_SCOPE):
+            gc = _ssd_cumulative(g, chunk)
+        with jax.named_scope(SSM_SCAN_SCOPE):
+            return gdn_kernels.ssd_fwd(q, k, v, gc, keep_states,
+                                       interpret=interpret)
     if kernel:
         args = _chunks(q, k, v, g, beta, chunk)
         with jax.named_scope(LOCAL_SCOPE):
@@ -305,21 +368,23 @@ def _gdr_forward(q, k, v, g, beta, chunk, kernel=None, keep_states=True):
         return nxt, (state.astype(v.dtype), out)
 
     start = jnp.zeros(v.shape[:3] + (q.shape[-1], v.shape[-1]), _F32)
-    with jax.named_scope(SCAN_SCOPE):
+    with jax.named_scope(_scopes(beta is not None)[1]):
         _, (states, outs) = lax.scan(body, start, xs)
     outs = jnp.moveaxis(outs, 0, 3)
     return outs.reshape(v.shape), states
 
 
 @functools.lru_cache(maxsize=None)
-def _make_gdr(chunk, kernel=None):
+def _make_gdr(chunk, kernel=None, correction=True):
     """The differentiable recurrence at one ``chunk``.  ``kernel``: None for
     the ``lax.scan`` over chunks and ``_chunk_local`` in XLA (the CPU path,
     the fallback, the tests' oracle), ``"pallas"`` for ``ops/gdn_kernels.py``'s
     scan kernels in its place and, where the shape has their plan, its
     local kernels in place of ``_chunk_local`` and its pull-back
     (``"interpret"``: the same through the Pallas interpreter, the
-    tests')."""
+    tests').  Without the ``correction`` beta is None, the XLA path's local
+    part is ``_chunk_plain`` and the kernel path's the running sum of g
+    before ``gdn_kernels.ssd_fwd`` / ``ssd_bwd``."""
     @jax.custom_vjp
     def gdr(q, k, v, g, beta):
         return _gdr_forward(q, k, v, g, beta, chunk, kernel,
@@ -335,6 +400,17 @@ def _make_gdr(chunk, kernel=None):
         started from, carrying the state's cotangent."""
         q, k, v, g, beta, states = res
         n = int(q.shape[2]) // chunk
+        local_scope, scan_scope = _scopes(correction)
+        if kernel and not correction:
+            with jax.named_scope(local_scope):
+                gc, pull_gc = jax.vjp(
+                    functools.partial(_ssd_cumulative, chunk=chunk), g)
+            with jax.named_scope(scan_scope):
+                d_q, d_k, d_v, d_gc = gdn_kernels.ssd_bwd(
+                    q, k, v, gc, states, d_out,
+                    interpret=(kernel == "interpret") or None)
+            with jax.named_scope(local_scope):
+                return d_q, d_k, d_v, pull_gc(d_gc)[0], None
         if kernel:
             args = _chunks(q, k, v, g, beta, chunk)
             with jax.named_scope(LOCAL_SCOPE):
@@ -354,40 +430,49 @@ def _make_gdr(chunk, kernel=None):
                 return d_prev, d_x
 
             zero = jnp.zeros(states.shape[1:], _F32)
-            with jax.named_scope(SCAN_SCOPE):
+            with jax.named_scope(scan_scope):
                 _, d_xs = lax.scan(body, zero, (states, xs, d_outs),
                                    reverse=True)
             d_q, d_k = (jnp.moveaxis(x, 0, 2) for x in d_xs[:2])
-            d_local = tuple(jnp.moveaxis(x, 0, 3) for x in d_xs[2:])
-        with jax.named_scope(LOCAL_SCOPE):
-            grads = list(pull(d_local))
+            d_local = tuple(None if x is None else jnp.moveaxis(x, 0, 3)
+                            for x in d_xs[2:])
+        with jax.named_scope(local_scope):
+            grads = list(pull(d_local)) + [None] * (beta is None)
         grads[0], grads[1] = grads[0] + d_q, grads[1] + d_k
-        return tuple(x.reshape(r.shape) for x, r in
-                     zip(grads, (q, k, v, g, beta)))
+        return tuple(None if r is None else x.reshape(r.shape)
+                     for x, r in zip(grads, (q, k, v, g, beta)))
 
     gdr.defvjp(fwd, bwd)
     return gdr
 
 
-def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64, correction=True):
     """The recurrence above for q, k [batch, key heads, seq, dk], v [batch,
     key heads, r, seq, dv] (``r`` value heads share a key head) and float32
     g (the log of the decay), beta [batch, key heads, r, seq], computed
     chunk by chunk; any ``seq``: a tail chunk is padded with tokens that
-    leave the state as it is (beta 0, decay 1).  The recurrence over the
-    chunks, and the chunk-local part where the shape has the local kernels'
-    plan, are ``ops/gdn_kernels.py``'s Pallas kernels where the program is
-    traced for a TPU and the shape is eligible (``gdn_kernels.mode``: no
-    knob), else a ``lax.scan`` after ``_chunk_local``."""
+    leave the state as it is (beta 0 or v 0, decay 1).  The recurrence over
+    the chunks, and the chunk-local part where the shape has the local
+    kernels' plan, are ``ops/gdn_kernels.py``'s Pallas kernels where the
+    program is traced for a TPU and the shape is eligible
+    (``gdn_kernels.mode``: no knob), else a ``lax.scan`` after
+    ``_chunk_local``.  ``correction=False``: ``S_t = gamma_t S_{t-1} + k_t
+    v_t^T`` (beta is not read), through ``_chunk_plain`` or the kernels'
+    ``ssd_scan_fwd`` / ``ssd_scan_bwd``; each lowering is counted
+    (``ops.ssm.lowered_kernel`` / ``ops.ssm.lowered_xla``)."""
     t = int(q.shape[2])
     pad = (-t) % chunk
+    beta = beta if correction else None
     if pad:
         at = lambda x, axis: jnp.pad(
             x, [(0, pad if i == axis else 0) for i in range(x.ndim)])
-        q, k, v, g, beta = (at(q, 2), at(k, 2), at(v, 3), at(g, 3),
-                            at(beta, 3))
-    kernel = gdn_kernels.mode(q.shape, v.shape, chunk, v.dtype)
-    return _make_gdr(chunk, kernel)(q, k, v, g, beta)[:, :, :, :t]
+        q, k, v, g = at(q, 2), at(k, 2), at(v, 3), at(g, 3)
+        beta = None if beta is None else at(beta, 3)
+    kernel = gdn_kernels.mode(q.shape, v.shape, chunk, v.dtype, correction)
+    if not correction:
+        _instrument.note_ssm_lowering(bool(kernel))
+    return _make_gdr(chunk, kernel, bool(correction))(
+        q, k, v, g, beta)[:, :, :, :t]
 
 
 def _gated_delta_rule(query, key, value, a, b, A_log, dt_bias, chunk=64):
@@ -428,6 +513,52 @@ def _gdr_infer_shape(in_shapes, attrs):
 register("gated_delta_rule", _gated_delta_rule,
          input_names=("query", "key", "value", "a", "b", "A_log", "dt_bias"),
          infer_shape=_gdr_infer_shape, params={"chunk": (pInt, 64)})
+
+
+# -- Mamba-2's state-space layer (SSD) on the same recurrence -------------------
+
+def _ssd(x, B, C, dt, A_log, dt_bias, D, chunk=64):
+    """Mamba-2's selective state space (the SSD form) on ``x`` [batch, seq,
+    heads, P], ``B`` / ``C`` [batch, seq, groups, N] and ``dt`` [batch,
+    seq, heads]: per head ``h`` with state S [P, N] from zero,
+    ``delta = softplus(dt + dt_bias)``, ``S_t = exp(-exp(A_log_h) delta_t)
+    S_{t-1} + delta_t x_t B_t^T`` and ``y_t = S_t C_t + D_h x_t``; group
+    ``j`` (its B and C) serves heads ``j*r .. (j+1)*r``.  That is
+    :func:`chunked_gated_delta_rule` without its correction, C its q, B its
+    k and ``delta x`` its v.  The decay, ``delta``, the state and the skip
+    are float32; the products read ``x``'s dtype."""
+    with jax.named_scope("mx:ssm"):
+        cd = x.dtype
+        bsz, t, h, p = x.shape
+        groups = int(B.shape[2])
+        r = h // groups
+        heads_first = lambda a: jnp.swapaxes(a, 1, 2)
+        grouped = lambda a: heads_first(a).reshape(
+            (bsz, groups, r, t) + a.shape[3:])
+        delta = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
+        g = -jnp.exp(A_log.astype(_F32)) * delta
+        u = (x.astype(_F32) * delta[..., None]).astype(cd)
+        out = chunked_gated_delta_rule(
+            heads_first(C).astype(cd), heads_first(B).astype(cd), grouped(u),
+            grouped(g), None, int(chunk), correction=False)
+        y = heads_first(out.reshape(bsz, h, t, p)).astype(_F32) \
+            + D.astype(_F32)[:, None] * x.astype(_F32)
+        return y.astype(cd)
+
+
+def _ssd_infer_shape(in_shapes, attrs):
+    filled = list(in_shapes)
+    x = in_shapes[0]
+    if x is None:
+        return filled, [None]
+    filled[3] = tuple(x[:3])
+    filled[4] = filled[5] = filled[6] = (int(x[2]),)
+    return filled, [tuple(x)]
+
+
+register("ssd", _ssd,
+         input_names=("data", "B", "C", "dt", "A_log", "dt_bias", "D"),
+         infer_shape=_ssd_infer_shape, params={"chunk": (pInt, 64)})
 
 
 # -- dropless top-k experts, this chip's share ---------------------------------

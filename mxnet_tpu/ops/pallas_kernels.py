@@ -429,7 +429,7 @@ def _flash_vmem_bytes(block_q, block_k, d, group, itemsize, d_v=None):
     is the score width as VMEM holds it (:func:`_vmem_width`), ``d_v`` the
     value width where it differs."""
     rows = group * block_q
-    d_v = d_v or d
+    d_v = _round_up(d_v or d, 128)          # a 64-wide tile takes 128 lanes
     piped = 2 * ((rows + block_k) * (d + d_v) * itemsize + rows * 128 * 4)
     scratch = rows * (128 + 128 + d_v) * 4
     scores = rows * block_k * (4 + 4 + itemsize)
@@ -832,7 +832,7 @@ def _flash_bwd_vmem_bytes(block_q, block_k, d, group, itemsize, d_v=None):
     tiles with the casts of ``p`` and ``ds``.  ``d`` and ``d_v`` as
     :func:`_flash_vmem_bytes` takes them."""
     rows = group * block_q
-    d_v = d_v or d
+    d_v = _round_up(d_v or d, 128)          # a 64-wide tile takes 128 lanes
     piped = 2 * (rows + block_k) * (d + d_v) * itemsize \
         + 2 * 2 * 8 * rows * 4
     scores = rows * block_k * (3 * 4 + 2 * itemsize)
@@ -1235,9 +1235,12 @@ def attention(q, k, v, causal=False, scale=None, kv_lens=None, window=0,
 
 def _flash_eligible(d_k, d_v, d_shared, dtype):
     """Whether the flash kernels take these widths and element type: the
-    heads' own key width and the value width whole 128-lane tiles, a shared
-    key part whole or half ones."""
-    return d_k % 128 == 0 and d_v % 128 == 0 and d_shared % 64 == 0 \
+    heads' own key width and the value width whole 128-lane tiles or one
+    half tile (a 64-wide head: a block whose last dim is the whole head,
+    which VMEM pads to 128 lanes and the plans count so), a shared key part
+    whole or half ones."""
+    lanes = lambda w: w % 128 == 0 or w == 64
+    return lanes(d_k) and lanes(d_v) and d_shared % 64 == 0 \
         and jnp.issubdtype(dtype, jnp.floating)
 
 

@@ -670,3 +670,62 @@ def test_note_tap_is_noop_without_open_frame():
         health.note_tap(jnp.float32(1.0))
         health.note_tap(jnp.float32(2.0))
     assert [float(t) for t in frame] == [1.0, 2.0]
+
+
+# ---------------------------------------------------------------------------
+# 4) Head width 64 (a block whose last dim is the whole head): forward and
+#    both backward kernels against the reference, at a softmax scale given
+#    (Granite's attention_multiplier, 1/64, not 1/sqrt(64))
+# ---------------------------------------------------------------------------
+
+HEAD64_CASES = [
+    # (dtype, query heads, K/V heads, seq, block_q, block_k)
+    (jnp.float32, 4, 2, 16, None, None),
+    (jnp.float32, 4, 2, 300, 64, 128),
+    (jnp.bfloat16, 4, 1, 256, 128, 128),
+]
+HEAD64_IDS = ["%s-h%dkv%d-s%d%s" % (np.dtype(c[0]).name, c[1], c[2], c[3],
+                                    _blocks_id(*c[4:]))
+              for c in HEAD64_CASES]
+
+
+@pytest.mark.parametrize("case", HEAD64_CASES, ids=HEAD64_IDS)
+def test_flash_at_head_64_matches_reference(case):
+    dtype, heads, kv, seq, block_q, block_k = case
+    r = _rng(5)
+    mk = lambda h: jnp.asarray(r.normal(0, 1, (2, seq, h, 64)), dtype)
+    q, k, v = mk(heads), mk(kv), mk(kv)
+    w = jnp.asarray(r.normal(0, 1, q.shape), jnp.float32)
+    scale = 1.0 / 64
+
+    def run(fn):
+        def f(q_, k_, v_):
+            return jnp.sum(fn(q_, k_, v_).astype(jnp.float32) * w)
+        return fn(q, k, v), jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    want, want_g = run(lambda *a: pk._reference_attention(*a, True, scale))
+    got, got_g = run(lambda *a: pk.flash_attention(
+        *a, causal=True, scale=scale, use_pallas=True, interpret=True,
+        block_q=block_q, block_k=block_k))
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **_tols(dtype))
+    tol = {"rtol": 3e-2, "atol": 3e-2} if dtype == jnp.bfloat16 \
+        else {"rtol": 2e-4, "atol": 2e-4}
+    for g, ref, name in zip(got_g, want_g, "qkv"):
+        assert g.dtype == ref.dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   err_msg="d%s diverged" % name, **tol)
+
+
+def test_a_64_wide_head_takes_the_kernels_and_is_counted_at_128_lanes():
+    assert pk._flash_eligible(64, 64, 0, jnp.bfloat16)
+    assert not pk._flash_eligible(32, 32, 0, jnp.bfloat16)
+    assert not pk._flash_eligible(96, 96, 0, jnp.bfloat16)
+    assert not pk._flash_eligible(192, 64, 0, jnp.bfloat16)
+    # VMEM holds a 64-wide value tile in 128 lanes: the plans count it so
+    assert pk._flash_vmem_bytes(256, 512, 128, 4, 2, 64) \
+        == pk._flash_vmem_bytes(256, 512, 128, 4, 2, 128)
+    assert pk._flash_bwd_vmem_bytes(256, 512, 128, 4, 2, 64) \
+        == pk._flash_bwd_vmem_bytes(256, 512, 128, 4, 2, 128)

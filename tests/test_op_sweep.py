@@ -98,6 +98,8 @@ TESTED_ELSEWHERE = {
     "gated_delta_rule":
         "tests/test_qwen3_next.py (the chunked scan against the recurrence)",
     "moe_experts": "tests/test_qwen3_next.py (the routed part, the shares)",
+    "ssd": "tests/test_granite_hybrid.py (the op against the token-by-token "
+           "recurrence, on the scan and through the kernels)",
     "sequence_cross_entropy":
         "tests/test_qwen3_next.py "
         "(test_sequence_cross_entropy_and_its_gradient)",

@@ -8,7 +8,8 @@ ResNet-50 train step at batch 32, read off the symbol itself, and flash
 attention at head_dim 128 and at the language-model cell's own shape
 (8,192 tokens, 16 query heads over 2 K/V heads of 256), forward and
 gradient; the delta-rule scan and chunk-local kernels
-(``ops/gdn_kernels.py``) at that cell's shape too.
+(``ops/gdn_kernels.py``) at that cell's shape too; the state-space scan
+kernels and the flash kernels at head width 64 at the Granite cell's.
 
 The lowering cannot see Mosaic's own compile (layout inference, unaligned
 slices).  ``-m slow`` adds it: libtpu compiles for a named v5e topology
@@ -78,6 +79,9 @@ FLASH_CASES = [
     ((1, 512, 4, 128), "float32", True, 2, 100),
     ((1, 8192, 32, 128), "bfloat16", True, 4, 2048),
     ((1, 8192, 32, 128), "bfloat16", True, 4),
+    # head width 64 (a block whose last dim is the whole head): the attention
+    # layer of `granite-h-train-s8k-b1`, 32 query heads over 8 K/V heads
+    ((1, 8192, 32, 64), "bfloat16", True, 8),
 ]
 # score width != value width: (q shape, dtype, K/V heads, the heads' own key
 # width, the value width); what is left of q's width is a key part that all
@@ -203,6 +207,17 @@ def _cases():
                 gdn_kernels.local_bwd(q, k, v, g, b, inv, d),
                 local + (inverses, per_chunk, squares, squares, decays,
                          decays)))
+    # the state-space scan kernels (no correction) at `granite-h-train-s8k-b1`'s
+    # shape: 8,192 tokens, one group of state 128, 64 value heads of 64
+    ssd = lm_ops._make_gdr(64, "pallas", False)
+    ssm = (_aval((1, 1, 8192, 128), "bfloat16"),) * 2 + (
+        _aval((1, 1, 64, 8192, 64), "bfloat16"),
+        _aval((1, 1, 64, 8192), "float32"))
+    plain = lambda q, k, v, g: ssd(q, k, v, g, None)    # no beta
+    out.append(("ssd-fwd", plain, ssm))
+    out.append(("ssd-grad",
+                jax.grad(lambda *a: jnp.sum(plain(*a).astype(jnp.float32)
+                                            ** 2), argnums=(0, 1, 2, 3)), ssm))
     return out
 
 
